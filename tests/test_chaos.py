@@ -19,7 +19,7 @@ import time
 import numpy as np
 import pytest
 
-from trino_tpu.client.client import Client
+from trino_tpu.client.client import Client, QueryError
 from trino_tpu.exec.session import Session
 from trino_tpu.server.coordinator import CoordinatorServer
 from trino_tpu.server.exchange_spool import ExchangeSpool
@@ -622,4 +622,37 @@ def test_secured_cluster_still_executes_distributed(monkeypatch):
     finally:
         for w in workers:
             w.stop()
+        coord.stop()
+
+
+def test_task_timeout_fails_the_query_loudly():
+    """A task still running when task_timeout_s runs out fails the query
+    with the timeout in its message: the node is not marked failed, the
+    splits are not retried, and the coordinator does not run the whole
+    query again locally behind a late right answer."""
+    session = Session(default_schema="tiny")
+    coord = CoordinatorServer(session).start()
+    sched = coord.state.scheduler
+    sched.split_rows = 8192
+    worker = WorkerServer("slow-worker", coord.uri, announce_interval_s=0.1,
+                          catalog=session.catalog).start()
+    try:
+        deadline = time.time() + 5
+        while not coord.state.active_nodes() and time.time() < deadline:
+            time.sleep(0.05)
+        client = Client(coord.uri, user="chaos")
+        client.execute(Q_AGG)               # pays the compiles
+        sched.spool.clear()
+        inj = FailureInjector(seed=105)
+        inj.inject("WORKER_TASK_RUN", times=1, fault=DELAY, delay_s=3.0)
+        worker.task_manager.injector = inj
+        sched.task_timeout_s = 0.5
+        retries = sched.stats["task_retries"]
+        with pytest.raises(QueryError, match="timed out"):
+            client.execute(Q_AGG)
+        assert sched.stats["task_retries"] == retries
+        assert [n.node_id for n in coord.state.active_nodes()] == \
+            ["slow-worker"]
+    finally:
+        worker.stop()
         coord.stop()

@@ -1,0 +1,180 @@
+"""Compile the main path's kernels for a described v5e, without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (`jax.experimental.topologies`). These tests
+hand the `pallas_call` wrappers and the q1 XLA stage the shapes the TPC-H
+SF10 queries produce and ask only: does the chip's compiler accept the
+program, and is the kernel in it (`tpu_custom_call`)? Nothing runs, so
+they say nothing about results or times — `chip_smoke.py` on the chip
+does.
+
+This is the ONLY test file that touches the TPU compiler: one process at
+a time may load the TPU's library, and the xdist worker that is given
+this file is the one that loads it. The topology is described inside a
+module-scoped fixture (never at import or collection time), so every
+worker collects the same tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from trino_tpu.ops import pallas_agg, pallas_gather as pg, pallas_hash as ph
+from trino_tpu.ops.aggregate import AggSpec
+
+N_ROWS = 1 << 20            # rows per kernel call at the SF10 chunk shapes
+SF10_LINEITEM = 59_986_052  # tpch sf10 lineitem rows (60M)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without a chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — any failure = no TPU
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    """shapes: (shape, dtype) pairs -> compiled executable on the
+    described chip (raises what the chip's compiler would raise)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# tiled gather: both modes, P = 2 (one int64 table) and P = MAX_PLANES
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("planes", [2, pg.MAX_PLANES])
+def test_scan_gather_compiles(one_chip, planes):
+    i32 = jnp.int32
+    c = _compile(
+        lambda idx, p: pg._scan_gather_planes(idx, p, (0,) * planes,
+                                              False),
+        one_chip, ((N_ROWS,), i32), ((planes, pg.SCAN_MAX_ELEMS), i32))
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("planes", [2, pg.MAX_PLANES])
+def test_windowed_gather_compiles(one_chip, planes):
+    i32 = jnp.int32
+    words = 1 << 24             # a 16M-word LUT: whole WIN windows
+    c = _compile(
+        lambda idx, base, p: pg._window_gather_planes(
+            idx, base, p, (0,) * planes, False),
+        one_chip, ((N_ROWS,), i32), ((N_ROWS // pg.TILE,), i32),
+        ((planes, words), i32))
+    assert _has_kernel(c)
+
+
+def test_float64_planes_are_refused_and_gated_off(one_chip):
+    """DOUBLE tables never reach the kernel (supports_tables), because
+    the chip's compiler refuses their split into int32 planes."""
+    f64 = jax.ShapeDtypeStruct((1024,), jnp.float64)
+    assert not pg.supports_tables([f64])
+    assert not pg.gather_supported([f64])
+    with pytest.raises(Exception, match="X64 element types"):
+        _compile(pg._split_planes, one_chip, ((1024,), jnp.float64))
+
+
+# ---------------------------------------------------------------------------
+# MXU aggregate: q1's G=6 and the largest G supports() admits
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("groups,aggs", [(6, 6), (pallas_agg.MAX_GROUPS,
+                                                  pallas_agg.MAX_AGGS)])
+def test_mxu_sums_compiles(one_chip, groups, aggs):
+    i32 = jnp.int32
+    c = _compile(
+        lambda gid, hi, lo: pallas_agg._mxu_sums(gid, hi, lo, groups,
+                                                 False),
+        one_chip, ((N_ROWS,), i32), ((aggs, N_ROWS), i32),
+        ((aggs, N_ROWS), i32))
+    assert _has_kernel(c)
+
+
+# ---------------------------------------------------------------------------
+# hash-table family: OFF on TPU by a static rule (pallas_hash.resolve_mode).
+# These pin the compiler's refusal — the day one of them stops raising,
+# the kernel compiles and the rule can go.
+# ---------------------------------------------------------------------------
+
+_Q18_AGGS = (AggSpec("sum", 0),)
+
+
+@pytest.mark.parametrize("slots", [ph.MIN_TABLE_SLOTS,
+                                   ph.max_table_slots(_Q18_AGGS)])
+def test_hash_insert_is_refused(one_chip, slots):
+    i32 = jnp.int32
+    layout, _ns, nv = ph.agg_layout(_Q18_AGGS)
+    with pytest.raises(ValueError, match=ph.TPU_REFUSAL):
+        _compile(
+            lambda s, kl, kh, vb, v: ph._hash_insert(
+                s, kl, kh, vb, v, layout, slots, False),
+            one_chip, *([((N_ROWS,), i32)] * 4), ((nv, N_ROWS), i32))
+
+
+@pytest.mark.parametrize("k", [2, ph.MAX_MULTI_DIMS])
+def test_multiway_probe_is_refused(one_chip, k):
+    i32 = jnp.int32
+    slots = 1 << 14
+    with pytest.raises(ValueError, match=ph.TPU_REFUSAL):
+        _compile(
+            lambda *a: ph._multi_probe(*a, False),
+            one_chip, *([((k, N_ROWS), i32)] * 3),
+            *([((k, slots), i32)] * 3))
+
+
+def test_hash_family_is_off_on_tpu_by_rule(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ph.resolve_mode("auto") == "off"
+    assert ph.resolve_mode("false") == "off"
+    with pytest.raises(NotImplementedError, match=ph.TPU_REFUSAL):
+        ph.resolve_mode("true")
+    # the gather family compiles, so auto turns it on there
+    assert pg.resolve_mode("auto") == "device"
+
+
+# ---------------------------------------------------------------------------
+# the plain XLA path: the q1 stage at 60M rows fits one chip
+# ---------------------------------------------------------------------------
+
+def test_q1_stage_compiles_at_60m_rows(one_chip):
+    import __graft_entry__ as graft
+    fn, args = graft.entry()
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            (SF10_LINEITEM + (-SF10_LINEITEM) % 1024,) + x.shape[1:],
+            x.dtype, sharding=one_chip), args)
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + \
+        mem.output_size_in_bytes
+    assert used < 16 * 10**9, f"q1 at 60M rows needs {used:,} bytes"

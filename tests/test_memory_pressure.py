@@ -19,8 +19,11 @@ from trino_tpu.exec.memory import (ExceededMemoryLimitError,
                                    parse_bytes)
 from trino_tpu.exec.session import Session
 
+# four aggregate states, so that the join + aggregation's real working
+# set at tiny (1.4 MB) exceeds the smallest query_max_memory_mb (1 MB)
 JOIN_Q = """
-SELECT o_custkey, count(*) AS c, sum(o_totalprice) AS s
+SELECT o_custkey, count(*) AS c, sum(o_totalprice) AS s,
+       min(o_orderdate) AS first_order, max(o_orderkey) AS last_key
 FROM orders JOIN customer ON o_custkey = c_custkey
 WHERE c_acctbal > 0
 GROUP BY o_custkey

@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 import trino_tpu.exec.executor as E
-from trino_tpu.batch import batch_from_numpy, batch_to_numpy
+from trino_tpu.batch import (Batch, Column, batch_from_numpy,
+                             batch_to_numpy)
 from trino_tpu.ops.aggregate import (AggSpec, key_pack_plan,
                                      packed_sort_group_aggregate,
                                      sort_group_aggregate)
@@ -66,15 +67,25 @@ def test_packed_agg_all_null_key():
         sorted(rows_of(want), key=repr)
 
 
+@pytest.mark.parametrize("wide", [False, True])
 @pytest.mark.parametrize("asc,nf", [(True, False), (True, True),
                                     (False, False), (False, True)])
-def test_packed_sort_matches_general(asc, nf):
+def test_packed_sort_matches_general(asc, nf, wide):
     b = rand_batch(seed=3)
+    if wide:
+        # a 61-bit span on the leading key pushes the second key into a
+        # second word: the LSD radix over words must order like the
+        # general multi-operand sort, ties and NULLs included
+        k1 = b.columns[0]
+        stretched = jnp.where(k1.data > 0, k1.data << 54, k1.data)
+        b = Batch((Column(stretched, k1.valid),) + b.columns[1:], b.live)
     keys = ((0, asc, nf), (1, not asc, not nf))
     plan = sort_pack_plan(b, keys)
     assert plan is not None
-    kmins, bits = plan
-    got = sort_batch_packed(b, jnp.asarray(kmins), keys, bits, 100)
+    kmins, bits, splits = plan
+    assert len(splits) == (2 if wide else 1)
+    got = sort_batch_packed(b, jnp.asarray(kmins), keys, bits, 100,
+                            splits)
     want = sort_batch(b, keys, 100)
     assert rows_of(got) == rows_of(want)
 
@@ -103,6 +114,51 @@ def test_executor_dispatch_through_packed(monkeypatch):
         " FROM lineitem GROUP BY l_returnflag, l_linestatus"
         " ORDER BY q DESC, l_returnflag, l_linestatus").rows
     assert got == want
+
+
+def test_packed_key_bits_are_rounded_to_a_lattice():
+    """Key bits are static arguments of the packed kernels: batches
+    whose spans differ a little (the splits of one scan) must share one
+    program, so the bits round up to multiples of 4."""
+    from trino_tpu.ops.aggregate import key_pack_plan_words
+    plans = []
+    for span in (70_000, 90_000, 120_000):          # 17 bits each
+        b = batch_from_numpy([np.arange(4096, dtype=np.int64) * span
+                              // 4096 + 10**9,
+                              np.arange(4096, dtype=np.int32) % 5])
+        plans.append(key_pack_plan_words(b, (0, 1)))
+    assert {p[1] for p in plans} == {(20, 4)}
+    assert {p[2] for p in plans} == {((0, 2),)}
+    wide = batch_from_numpy([np.array([0, (1 << 61) - 8] * 512)])
+    assert key_pack_plan_words(wide, (0,))[1] == (62,)   # capped, not 64
+    # 25 + 25 bits and 12 index bits fit lsd_word_sort's one-operand
+    # form; 28 + 28 would not, so the measured bits stay
+    tight = batch_from_numpy([np.array([0, (1 << 25) - 8] * 2048),
+                              np.array([0, (1 << 25) - 8] * 2048)])
+    assert key_pack_plan_words(tight, (0, 1))[1] == (25, 25)
+
+
+def test_mostly_dead_batch_aggregates_through_the_small_kernel(monkeypatch):
+    """A selective join's split leaves a few live rows in a big batch:
+    they are compacted and take the general kernel (data-independent
+    statics) — no packed program per split."""
+    monkeypatch.setattr(E, "SORT_SMALL_ROWS", 64)
+    from trino_tpu.exec.profiler import RECORDER
+    from trino_tpu.exec.session import Session
+    sql = ("SELECT o_orderkey, o_orderdate, sum(o_totalprice) s, count(*)"
+           " FROM orders WHERE o_totalprice > 400000"
+           " GROUP BY o_orderkey, o_orderdate ORDER BY s DESC LIMIT 20")
+
+    def packed_calls():
+        return sum(e["compiles"] + e["hits"] for e in RECORDER.snapshot()
+                   if e["site"] == "aggregate.packed_sort_group_aggregate")
+    before = packed_calls()
+    s = Session(default_schema="tiny")
+    got = s.execute(sql).rows
+    assert packed_calls() == before
+    monkeypatch.setattr(E, "SORT_SMALL_ROWS", 1 << 40)
+    want = Session(default_schema="tiny").execute(sql).rows
+    assert got == want and 0 < len(got) <= 20
 
 
 def test_compact_gather_matches_sort():

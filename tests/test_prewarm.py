@@ -11,7 +11,7 @@ The contracts under test:
   cardinality on the enumerable {2^k, 1.5*2^k} lattice, and a sweep of
   TPC-H-shaped statements adds only a bounded number of distinct
   compiled shapes per jit site.
-- Shared persistent compile cache: the TRINO_TPU_COMPILE_CACHE gate —
+- Shared persistent compile cache: the JAX_COMPILATION_CACHE_DIR contract —
   explicit opt-in persists programs even under JAX_PLATFORMS=cpu,
   explicit "off" wins, and cpu-only defaults to inactive.
 - Compile-aware routing: a host-eligible statement routes to the
@@ -56,7 +56,8 @@ from trino_tpu.server.worker import WorkerServer            # noqa: E402
 def _run_child(code: str, env_extra: dict, timeout=300):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("TRINO_TPU_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
     env.pop("TRINO_TPU_PREWARM", None)
     env.update(env_extra)
     return subprocess.run([sys.executable, "-c", code],
@@ -284,45 +285,81 @@ def test_jit_distinct_shapes_gauge_renders():
 
 
 # ---------------------------------------------------------------------------
-# shared persistent compile cache (the TRINO_TPU_COMPILE_CACHE gate)
+# shared persistent compile cache: placed from outside through
+# JAX_COMPILATION_CACHE_DIR, else one fixed path inside the checkout
 # ---------------------------------------------------------------------------
 
 def test_compile_cache_default_inactive_on_cpu():
-    if os.environ.get("TRINO_TPU_COMPILE_CACHE"):
-        pytest.skip("operator forced a compile cache for this run")
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        pytest.skip("operator placed a compile cache for this run")
     import trino_tpu
     assert trino_tpu.COMPILE_CACHE_DIR is None
     st = compile_cache_stats()
     assert st["active"] is False and st["dir"] is None
 
 
-def test_compile_cache_explicit_optin_persists_on_cpu(tmp_path):
+def test_compile_cache_env_dir_persists_on_cpu(tmp_path):
     cache = str(tmp_path / "cc")
     code = """
-import os, trino_tpu
-assert trino_tpu.COMPILE_CACHE_DIR == os.environ["TRINO_TPU_COMPILE_CACHE"]
-import jax, jax.numpy as jnp
+import os, jax, trino_tpu
+assert trino_tpu.COMPILE_CACHE_DIR == os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert jax.config.jax_compilation_cache_dir == trino_tpu.COMPILE_CACHE_DIR
+import jax.numpy as jnp
 jax.jit(lambda x: x * 3 + 1)(jnp.arange(2048)).block_until_ready()
 files = os.listdir(trino_tpu.COMPILE_CACHE_DIR)
-assert files, "explicit CPU opt-in persisted nothing"
+assert files, "a placed cache persisted nothing on CPU"
 from trino_tpu.exec.prewarm import compile_cache_stats
 st = compile_cache_stats()
 assert st["active"] and st["files"] >= 1 and st["bytes"] > 0, st
 print("CACHE_OK", len(files))
 """
-    p = _run_child(code, {"TRINO_TPU_COMPILE_CACHE": cache})
+    p = _run_child(code, {"JAX_COMPILATION_CACHE_DIR": cache})
     assert p.returncode == 0 and "CACHE_OK" in p.stdout, \
         p.stdout + p.stderr
     assert os.listdir(cache)        # visible to OTHER processes: shared
 
 
-def test_compile_cache_explicit_off_wins(tmp_path):
+def test_compile_cache_unset_is_fixed_path_in_checkout():
+    # not a CPU-only run: the default applies. Importing the package
+    # initialises no backend, so naming tpu here needs no chip.
+    code = """
+import os, jax, trino_tpu
+want = os.path.join(os.getcwd(), ".jax_cache")
+assert trino_tpu.COMPILE_CACHE_DIR == want, trino_tpu.COMPILE_CACHE_DIR
+assert jax.config.jax_compilation_cache_dir == want
+print("FIXED_OK")
+"""
+    p = _run_child(code, {"JAX_PLATFORMS": "tpu,cpu"})
+    assert p.returncode == 0 and "FIXED_OK" in p.stdout, \
+        p.stdout + p.stderr
+    with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compile_cache_env_dir_wins_over_default(tmp_path):
+    cache = str(tmp_path / "placed")
+    code = """
+import os, jax, trino_tpu
+assert trino_tpu.COMPILE_CACHE_DIR == os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert jax.config.jax_compilation_cache_dir == trino_tpu.COMPILE_CACHE_DIR
+print("PLACED_OK")
+"""
+    p = _run_child(code, {"JAX_PLATFORMS": "tpu,cpu",
+                          "JAX_COMPILATION_CACHE_DIR": cache})
+    assert p.returncode == 0 and "PLACED_OK" in p.stdout, \
+        p.stdout + p.stderr
+
+
+def test_compile_cache_disabled_wins(tmp_path):
     code = """
 import trino_tpu
 assert trino_tpu.COMPILE_CACHE_DIR is None
+from trino_tpu.exec.prewarm import compile_cache_stats
+assert compile_cache_stats()["active"] is False
 print("OFF_OK")
 """
-    p = _run_child(code, {"TRINO_TPU_COMPILE_CACHE": "off"})
+    p = _run_child(code, {"JAX_ENABLE_COMPILATION_CACHE": "false",
+                          "JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert p.returncode == 0 and "OFF_OK" in p.stdout, p.stdout + p.stderr
 
 

@@ -21,41 +21,45 @@ jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: query kernels are jitted per
 # (operator, shape-bucket) and identical across processes, so first-run
-# TPU compiles (~20-40s) amortize to milliseconds on every later run —
-# the role of the reference's long-lived JVM keeping generated bytecode
-# warm across queries.
+# device compiles amortize to cache reads on every later run — the role
+# of the reference's long-lived JVM keeping generated bytecode warm
+# across queries.
 #
-# An EXPLICIT TRINO_TPU_COMPILE_CACHE always wins, on any platform —
-# tier-1 exercises persistent-cache hits under JAX_PLATFORMS=cpu, and a
-# mixed string like "tpu,cpu" must not silently disable the cache for
-# the TPU programs. Only the implicit default dir is skipped on
-# CPU-only runs: CPU AOT artifacts are machine-feature-specific (a
-# cache shared across build hosts can SIGILL) and CPU compiles are
-# cheap anyway. "" / "0" / "off" explicitly disable.
-_cache_env = os.environ.get("TRINO_TPU_COMPILE_CACHE")
+# The directory is placed from OUTSIDE: where JAX_COMPILATION_CACHE_DIR
+# is set, JAX itself reads it and this package sets no directory in code.
+# Where it is unset, the cache sits at one fixed path inside the checkout
+# (the path is part of the cache key, so a directory that moves never
+# hits). That default covers accelerator runs only: on a CPU-only run
+# (JAX_PLATFORMS names nothing but cpu) it stays inactive — CPU AOT
+# artifacts are machine-feature-specific and CPU compiles are cheap.
+# JAX's own JAX_ENABLE_COMPILATION_CACHE=false disables it everywhere.
 _platforms = [p.strip().lower()
               for p in os.environ.get("JAX_PLATFORMS", "").split(",")
               if p.strip()]
 _cpu_only = bool(_platforms) and all(p == "cpu" for p in _platforms)
-if _cache_env is not None:
-    _cache_dir = "" if _cache_env.strip().lower() in ("", "0", "off") \
-        else _cache_env
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+if not jax.config.jax_enable_compilation_cache:
+    COMPILE_CACHE_DIR = None
+elif os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    COMPILE_CACHE_DIR = jax.config.jax_compilation_cache_dir
+elif _cpu_only:
+    COMPILE_CACHE_DIR = None
 else:
-    _cache_dir = "" if _cpu_only \
-        else os.path.expanduser("~/.cache/trino_tpu_xla")
-if _cache_dir:
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
+    jax.config.update("jax_compilation_cache_dir",
+                      _DEFAULT_COMPILE_CACHE_DIR)
+    COMPILE_CACHE_DIR = _DEFAULT_COMPILE_CACHE_DIR
+if COMPILE_CACHE_DIR and _cpu_only:
     # CPU compiles finish in milliseconds; an explicit CPU opt-in with
     # the 1s floor would persist nothing and tests could never see a hit
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0 if _cpu_only else 1.0)
-COMPILE_CACHE_DIR = _cache_dir or None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import logging as _logging
 
 _logging.getLogger(__name__).info(
     "persistent compile cache %s",
-    f"active at {_cache_dir}" if _cache_dir else
-    "inactive (set TRINO_TPU_COMPILE_CACHE to enable)")
+    f"active at {COMPILE_CACHE_DIR}" if COMPILE_CACHE_DIR else
+    "inactive (set JAX_COMPILATION_CACHE_DIR to enable)")
 
 __version__ = "0.1.0"

@@ -1,7 +1,7 @@
 """Pallas TPU tiled-gather kernel — the dense-join probe as a native kernel.
 
-XLA's gather on this backend issues ~8-15 ns per gathered element
-regardless of table size (BENCH_NOTES round 5), and a probe site pays
+XLA's gather issued ~8-15 ns per gathered element regardless of table
+size on the earlier v5e rig (not re-measured), and a probe site pays
 that once PER PAYLOAD COLUMN.  This kernel restructures the probe around
 what the hardware is actually good at — (8,128)-aligned VMEM tiles and
 per-lane `take_along_axis` (the only gather form Mosaic lowers natively)
@@ -63,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 SUB = 8                     # sublanes per probe tile
 LANES = 128                 # lanes per probe tile
+_LANE_BITS = 7              # row/lane splits are shifts and masks in-kernel
 TILE = SUB * LANES          # probe indices resolved per grid step
 SLAB_ROWS = 16              # scan mode: LUT rows (of LANES) per slab
 SLAB = SLAB_ROWS * LANES
@@ -98,12 +99,15 @@ def plane_count(dtype) -> int:
 
 
 def supports_tables(tables) -> bool:
-    """Can every table ride int32 planes? (all engine lane dtypes can;
-    the guard exists for exotic inputs like object-backed arrays)."""
+    """Can every table ride int32 planes? Integer, bool and float32
+    lanes can. float64 cannot on the chip: its split into two int32
+    planes is a 64-bit bitcast the TPU compiler refuses ("While rewriting
+    computation to not contain X64 element types ... bitcast-convert"),
+    so DOUBLE tables take the jnp.take path by this gate."""
     for t in tables:
         dt = jnp.dtype(t.dtype)
         if not (jnp.issubdtype(dt, jnp.integer) or
-                jnp.issubdtype(dt, jnp.floating) or dt == jnp.bool_):
+                dt == jnp.dtype(jnp.float32) or dt == jnp.bool_):
             return False
         if dt.itemsize > 8:
             return False
@@ -156,8 +160,8 @@ def _scan_kernel(n_planes: int, fills: tuple):
     def kernel(idx_ref, planes_ref, out_ref):
         s = pl.program_id(1)
         local = idx_ref[...]                             # [SUB, LANES]
-        row = jnp.where(local >= 0, local // LANES, -1)
-        lane = jnp.where(local >= 0, local % LANES, 0)
+        row = jnp.where(local >= 0, local >> _LANE_BITS, -1)
+        lane = jnp.where(local >= 0, local & (LANES - 1), 0)
         accs = [jnp.where(s == 0,
                           jnp.full((SUB, LANES), fills[p], jnp.int32),
                           out_ref[p]) for p in range(n_planes)]
@@ -182,20 +186,24 @@ def _scan_gather_planes(idx32: jax.Array, planes: jax.Array,
     P, W = planes.shape
     n = idx32.shape[0]
     nb, n_slabs = n // TILE, W // SLAB
-    out = pl.pallas_call(
-        _scan_kernel(P, fills),
-        grid=(nb, n_slabs),
-        in_specs=[
-            pl.BlockSpec((SUB, LANES), lambda i, s: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((P, SLAB_ROWS, LANES), lambda i, s: (0, s, 0),
-                         memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((P, SUB, LANES), lambda i, s: (0, i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((P, nb * SUB, LANES), jnp.int32),
-        interpret=interpret,
-    )(idx32.reshape(nb * SUB, LANES),
-      planes.reshape(P, W // LANES, LANES))
+    # traced with 64-bit off (kernel body AND index maps): the package
+    # enables jax_enable_x64 and Mosaic has no 64-bit lanes
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            _scan_kernel(P, fills),
+            grid=(nb, n_slabs),
+            in_specs=[
+                pl.BlockSpec((SUB, LANES), lambda i, s: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((P, SLAB_ROWS, LANES), lambda i, s: (0, s, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((P, SUB, LANES), lambda i, s: (0, i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((P, nb * SUB, LANES),
+                                           jnp.int32),
+            interpret=interpret,
+        )(idx32.reshape(nb * SUB, LANES),
+          planes.reshape(P, W // LANES, LANES))
     return out.reshape(P, n)
 
 
@@ -214,10 +222,14 @@ def _window_kernel(n_planes: int, fills: tuple):
         base = base_ref[i] * WIN               # lo window element offset
         rel = jnp.where(local >= 0, local - base, -1)
         in_win = (rel >= 0) & (rel < 2 * WIN)
-        row = jnp.where(in_win, rel // LANES, -1)
-        lane = jnp.where(in_win, rel % LANES, 0)
-        esc_ref[0, 0] = jnp.sum(
-            ((local >= 0) & ~in_win).astype(jnp.int32)).astype(jnp.int32)
+        row = jnp.where(in_win, rel >> _LANE_BITS, -1)
+        lane = jnp.where(in_win, rel & (LANES - 1), 0)
+
+        @pl.when(i == 0)
+        def _():
+            esc_ref[...] = jnp.zeros((SUB, LANES), jnp.int32)
+
+        esc_ref[...] += ((local >= 0) & ~in_win).astype(jnp.int32)
         accs = [jnp.full((SUB, LANES), fills[p], jnp.int32)
                 for p in range(n_planes)]
         for r in range(2 * WIN_ROWS):
@@ -239,32 +251,41 @@ def _window_gather_planes(idx32: jax.Array, base_blocks: jax.Array,
                           interpret: bool):
     """idx32 [n_pad] int32 (miss = -1), base_blocks [nb] int32 (per-tile
     WIN-block index, <= n_blocks - 2), planes [P, W_pad] int32 ->
-    ([P, n_pad] int32, per-tile escape counts [nb])."""
+    ([P, n_pad] int32, total escape count int32)."""
     P, W = planes.shape
     n = idx32.shape[0]
     nb = n // TILE
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((SUB, LANES), lambda i, base: (i, 0)),
-            pl.BlockSpec((P, WIN_ROWS, LANES),
-                         lambda i, base: (0, base[i], 0)),
-            pl.BlockSpec((P, WIN_ROWS, LANES),
-                         lambda i, base: (0, base[i] + 1, 0))],
-        out_specs=[
-            pl.BlockSpec((P, SUB, LANES), lambda i, base: (0, i, 0)),
-            pl.BlockSpec((1, 1), lambda i, base: (i, 0),
-                         memory_space=pltpu.SMEM)])
     reshaped = planes.reshape(P, W // LANES, LANES)
-    out, esc = pl.pallas_call(
-        _window_kernel(P, fills),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((P, nb * SUB, LANES), jnp.int32),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.int32)],
-        interpret=interpret,
-    )(base_blocks, idx32.reshape(nb * SUB, LANES), reshaped, reshaped)
-    return out.reshape(P, n), esc.reshape(nb)
+    # 64-bit off for the kernel body and the index maps (see the scan
+    # kernel). Escapes accumulate per lane position in ONE (8, 128) VMEM
+    # tile every grid step revisits, summed in XLA afterwards: per-tile
+    # (1, 1) SMEM blocks of an (nb, 1) array are refused by the TPU
+    # lowering (neither (8, 128)-divisible nor the whole array), and an
+    # in-kernel reduction to a scalar is re-traced at lowering time with
+    # the package's 64-bit mode back on ("64-bit types are not
+    # supported").
+    with jax.enable_x64(False):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((SUB, LANES), lambda i, base: (i, 0)),
+                pl.BlockSpec((P, WIN_ROWS, LANES),
+                             lambda i, base: (0, base[i], 0)),
+                pl.BlockSpec((P, WIN_ROWS, LANES),
+                             lambda i, base: (0, base[i] + 1, 0))],
+            out_specs=[
+                pl.BlockSpec((P, SUB, LANES), lambda i, base: (0, i, 0)),
+                pl.BlockSpec((SUB, LANES), lambda i, base: (0, 0))])
+        out, esc = pl.pallas_call(
+            _window_kernel(P, fills),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((P, nb * SUB, LANES), jnp.int32),
+                jax.ShapeDtypeStruct((SUB, LANES), jnp.int32)],
+            interpret=interpret,
+        )(base_blocks, idx32.reshape(nb * SUB, LANES), reshaped, reshaped)
+    return out.reshape(P, n), jnp.sum(esc, dtype=jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -395,7 +416,7 @@ def gather_word_windowed(planes: jax.Array, idx, word_dtype: str,
                                      mode == "interpret")
     word = _join_planes([out[p] for p in range(P)],
                         word_dtype)[:n].astype(jnp.int64)
-    return word, jnp.sum(esc.astype(jnp.int64))
+    return word, esc.astype(jnp.int64)
 
 
 # --------------------------------------------------------------------------

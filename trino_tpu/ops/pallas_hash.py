@@ -60,9 +60,11 @@ gathers.  Because insertion never displaces beyond MAX_PROBES (that is
 an escape), a probe that sees MAX_PROBES non-empty non-matching slots
 is a DEFINITIVE miss — no escape path exists on the probe side.
 
-Session wiring: `enable_pallas_hash` = auto (on for TPU) | true (TPU:
-compiled; CPU: interpret mode — tier-1 runs the kernel through the
-Pallas interpreter) | false.  Every site keeps its sort-path fallback.
+Session wiring: `enable_pallas_hash` = auto | true | false. On a TPU
+backend the kernels do not compile yet (see `resolve_mode`): auto is
+off there and true raises. Off-chip, true runs the kernel logic through
+the Pallas interpreter (tier-1) and auto is off. Every site keeps its
+sort path for when the mode is off.
 """
 
 from __future__ import annotations
@@ -81,6 +83,11 @@ from .aggregate import AggSpec
 
 SUB = 8                      # sublane rows per input block
 LANES = 128                  # lanes per row
+# in-kernel row/lane splits are shifts and masks: the Mosaic lowering of
+# scalar `//` and `%` re-traces helper functions at lowering time, when
+# the package's jax_enable_x64 is back on, and then refuses the int64
+# constants they produce ("64-bit types are not supported")
+_LANE_BITS = 7
 BLOCK = SUB * LANES          # rows inserted per grid step
 MAX_PROBES = 16              # linear-probe bound (breach = escape)
 LOAD_NUM, LOAD_DEN = 5, 8    # occupancy cap 0.625 * T keeps probes short
@@ -111,15 +118,37 @@ _KIND = {"count": _K_COUNT, "count_star": _K_COUNT, "sum": _K_SUM,
          "min": _K_MIN, "max": _K_MAX}
 
 
+# Why the kernels of this module are OFF on a TPU backend (jax 0.9.0,
+# libtpu 0.0.34, compiled for a described v5e): the insert and probe
+# bodies are scalar-core loops that read and write single table words in
+# VMEM, and Mosaic has neither form —
+#   ValueError: Cannot store scalars to VMEM
+# for `tk_lo[sr, sl] = klo`, and once stores go through a one-row
+# read-modify-write,
+#   MosaicError: cannot statically prove that index in dimension 1 is a
+#   multiple of 128 (vector.load ... -> vector<1x1xi32>)
+# for the scalar reads `slot_ref[r, l]` / `tk_hi[sr, sl]`. The table
+# (up to 2.6 MB) does not fit SMEM, so compiling needs a vector-form
+# rewrite of the probe loop, not a spec change. Until then `auto` is off
+# on the chip by this rule (no run-time fallback), `true` raises, and
+# tests/test_chip_compile.py pins the refusal so the day it compiles the
+# test says so. Off-chip the interpreter runs the kernel logic as before.
+TPU_REFUSAL = "Cannot store scalars to VMEM"
+
+
 def resolve_mode(setting) -> str:
-    """Session-property value -> kernel mode ('device' | 'interpret' |
-    'off') — same contract as pallas_gather.resolve_mode."""
+    """Session-property value -> kernel mode ('interpret' | 'off'):
+    interpret is the CPU/tier-1 path; on a TPU backend the kernels do
+    not compile (see TPU_REFUSAL above), so auto is off and true
+    raises."""
     s = str(setting).lower()
     on_tpu = jax.default_backend() == "tpu"
     if s in ("true", "1"):
-        return "device" if on_tpu else "interpret"
-    if s == "auto":
-        return "device" if on_tpu else "off"
+        if on_tpu:
+            raise NotImplementedError(
+                "the Pallas hash-table kernels do not compile for TPU "
+                f"(Mosaic: {TPU_REFUSAL}); leave the property at auto")
+        return "interpret"
     return "off"
 
 
@@ -204,8 +233,8 @@ def _insert_kernel(layout: tuple, table_slots: int):
 
         def row(j, carry):
             esc, occ = carry
-            r = j // LANES
-            l = j % LANES
+            r = j >> _LANE_BITS
+            l = j & (LANES - 1)
             slot = slot_ref[r, l]
             alive = slot >= 0
             klo = klo_ref[r, l]
@@ -216,8 +245,8 @@ def _insert_kernel(layout: tuple, table_slots: int):
 
             def probe_body(c):
                 s, p, _ = c
-                sr = s // LANES
-                sl = s % LANES
+                sr = s >> _LANE_BITS
+                sl = s & (LANES - 1)
                 thi = tk_hi[sr, sl]
                 tlo = tk_lo[sr, sl]
                 empty = (thi == _EMPTY_HI) & (tlo == _EMPTY_LO)
@@ -238,8 +267,8 @@ def _insert_kernel(layout: tuple, table_slots: int):
             ok = (alive & (outcome == 1)) | claim
             esc = esc + jnp.where(alive & ~ok, 1, 0).astype(jnp.int32)
             occ = occ + jnp.where(claim, 1, 0).astype(jnp.int32)
-            sr = s_f // LANES
-            sl = s_f % LANES
+            sr = s_f >> _LANE_BITS
+            sl = s_f & (LANES - 1)
 
             @pl.when(claim)
             def _():
@@ -312,38 +341,41 @@ def _hash_insert(slot: jax.Array, klo: jax.Array, khi: jax.Array,
     npad = slot.shape[0]
     nb = npad // BLOCK
     t_rows = table_slots // LANES
-    outs = pl.pallas_call(
-        _insert_kernel(layout, table_slots),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((nv, SUB, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((t_rows, LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((t_rows, LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((ns, t_rows, LANES), lambda i: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 2), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM)],
-        out_shape=[
-            jax.ShapeDtypeStruct((t_rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((t_rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((ns, t_rows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, 2), jnp.int32)],
-        interpret=interpret,
-    )(slot.reshape(nb * SUB, LANES), klo.reshape(nb * SUB, LANES),
-      khi.reshape(nb * SUB, LANES), vbits.reshape(nb * SUB, LANES),
-      vals.reshape(nv, nb * SUB, LANES))
+    # traced with 64-bit off (kernel body AND index maps): the package
+    # enables jax_enable_x64 and Mosaic refuses 64-bit types
+    with jax.enable_x64(False):
+        outs = pl.pallas_call(
+            _insert_kernel(layout, table_slots),
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((SUB, LANES), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((nv, SUB, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=[
+                pl.BlockSpec((t_rows, LANES), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((t_rows, LANES), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((ns, t_rows, LANES), lambda i: (0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, 2), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM)],
+            out_shape=[
+                jax.ShapeDtypeStruct((t_rows, LANES), jnp.int32),
+                jax.ShapeDtypeStruct((t_rows, LANES), jnp.int32),
+                jax.ShapeDtypeStruct((ns, t_rows, LANES), jnp.int32),
+                jax.ShapeDtypeStruct((1, 2), jnp.int32)],
+            interpret=interpret,
+        )(slot.reshape(nb * SUB, LANES), klo.reshape(nb * SUB, LANES),
+          khi.reshape(nb * SUB, LANES), vbits.reshape(nb * SUB, LANES),
+          vals.reshape(nv, nb * SUB, LANES))
     tk_lo, tk_hi, st, sc = outs
     return (tk_lo.reshape(table_slots), tk_hi.reshape(table_slots),
             st.reshape(st.shape[0], table_slots), sc[0, 0], sc[0, 1])
@@ -588,8 +620,8 @@ def _multiprobe_kernel(k: int, table_slots: int):
                 sc_ref[0, d] = jnp.int32(0)
 
         def row(j, miss):
-            r = j // LANES
-            l = j % LANES
+            r = j >> _LANE_BITS
+            l = j & (LANES - 1)
             # slot encoding: -2 dead fact row (skip entirely), -1 live
             # row whose key is NULL/sentinel (counts as a miss), else
             # the home slot.  The dead/live split is per row, so dim 0's
@@ -607,8 +639,8 @@ def _multiprobe_kernel(k: int, table_slots: int):
 
                 def probe_body(c, d=d):
                     s, p, _ = c
-                    sr = s // LANES
-                    sl = s % LANES
+                    sr = s >> _LANE_BITS
+                    sl = s & (LANES - 1)
                     thi = tk_hi[d, sr, sl]
                     tlo = tk_lo[d, sr, sl]
                     empty = (thi == _EMPTY_HI) & (tlo == _EMPTY_LO)
@@ -628,8 +660,8 @@ def _multiprobe_kernel(k: int, table_slots: int):
                     (jnp.where(ok, slot, 0), jnp.int32(0),
                      jnp.where(ok, jnp.int32(0), jnp.int32(3))))
                 hit = ok & (outcome == 1)
-                sr = s_f // LANES
-                sl = s_f % LANES
+                sr = s_f >> _LANE_BITS
+                sl = s_f & (LANES - 1)
                 found_ref[d, r, l] = jnp.where(
                     hit, src_ref[d, sr, sl], jnp.int32(-1))
                 out_miss.append(
@@ -645,6 +677,54 @@ def _multiprobe_kernel(k: int, table_slots: int):
     return kernel
 
 
+def _multi_probe(slot: jax.Array, klo: jax.Array, khi: jax.Array,
+                 tk_lo: jax.Array, tk_hi: jax.Array, src: jax.Array,
+                 interpret: bool):
+    """Run the fused star-probe kernel. slot/klo/khi are [k, n] int32
+    (slot -2 = dead row, -1 = NULL key), tk_lo/tk_hi/src [k, T] int32
+    table planes. Returns (found [k, n_pad] int32, miss [k] int32)."""
+    k, table_slots = tk_lo.shape
+    slot = _pad_rows(slot, -2)
+    klo = _pad_rows(klo, 0)
+    khi = _pad_rows(khi, 0)
+    npad = slot.shape[-1]
+    nb = npad // BLOCK
+    t_rows = table_slots // LANES
+    with jax.enable_x64(False):      # see _hash_insert
+        found, sc = pl.pallas_call(
+            _multiprobe_kernel(k, table_slots),
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=[
+                pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, k), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM)],
+            out_shape=[
+                jax.ShapeDtypeStruct((k, nb * SUB, LANES), jnp.int32),
+                jax.ShapeDtypeStruct((1, k), jnp.int32)],
+            interpret=interpret,
+        )(slot.reshape(k, nb * SUB, LANES),
+          klo.reshape(k, nb * SUB, LANES),
+          khi.reshape(k, nb * SUB, LANES),
+          tk_lo.reshape(k, t_rows, LANES),
+          tk_hi.reshape(k, t_rows, LANES),
+          src.reshape(k, t_rows, LANES))
+    return found.reshape(k, npad), sc[0]
+
+
 @recorded_jit(static_argnums=(4, 5))
 def multiway_probe(probe: Batch, tk_lo, tk_hi, src,
                    probe_keys: tuple, mode: str):
@@ -658,7 +738,6 @@ def multiway_probe(probe: Batch, tk_lo, tk_hi, src,
     gathers stay in the caller, which shares the dense-join machinery
     with the pairwise ladder for bit-exactness."""
     from .join import _combined_key
-    k = len(probe_keys)
     table_slots = tk_lo.shape[1]
     slots, klos, khis = [], [], []
     for pk_idx in probe_keys:
@@ -670,45 +749,10 @@ def multiway_probe(probe: Batch, tk_lo, tk_hi, src,
         slots.append(slot)
         klos.append(jnp.where(ok, klo, 0))
         khis.append(jnp.where(ok, khi, 0))
-    n = probe.capacity
-    slot = _pad_rows(jnp.stack(slots), -2)
-    klo = _pad_rows(jnp.stack(klos), 0)
-    khi = _pad_rows(jnp.stack(khis), 0)
-    npad = slot.shape[-1]
-    nb = npad // BLOCK
-    t_rows = table_slots // LANES
-    found, sc = pl.pallas_call(
-        _multiprobe_kernel(k, table_slots),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, t_rows, LANES), lambda i: (0, 0, 0),
-                         memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((k, SUB, LANES), lambda i: (0, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM)],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, nb * SUB, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, k), jnp.int32)],
-        interpret=(mode == "interpret"),
-    )(slot.reshape(k, nb * SUB, LANES),
-      klo.reshape(k, nb * SUB, LANES),
-      khi.reshape(k, nb * SUB, LANES),
-      tk_lo.reshape(k, t_rows, LANES),
-      tk_hi.reshape(k, t_rows, LANES),
-      src.reshape(k, t_rows, LANES))
-    return found.reshape(k, npad)[:, :n], sc[0].astype(jnp.int64)
+    found, miss = _multi_probe(
+        jnp.stack(slots), jnp.stack(klos), jnp.stack(khis),
+        tk_lo, tk_hi, src, mode == "interpret")
+    return found[:, :probe.capacity], miss.astype(jnp.int64)
 
 
 def shard_join(probe: Batch, build: Batch, probe_keys: tuple,

@@ -63,37 +63,48 @@ def sort_batch(batch: Batch, keys: tuple, limit) -> Batch:
 
 
 def sort_pack_plan(batch: Batch, keys: tuple, fetch=None):
-    """Range-compress integer ORDER BY keys into one int64 (direction and
-    null placement baked into the rank encoding) so the big sort is
-    always (packed, index) — measurement and bit layout shared with the
-    aggregation kernels (ops.aggregate.key_pack_plan; the +3 slack there
-    keeps the DESC rank range clear of the nulls-first slot 0 and the
-    ASC range clear of the nulls-last slot 2^b - 1)."""
-    from .aggregate import key_pack_plan
-    return key_pack_plan(batch, tuple(idx for idx, _, _ in keys),
-                         fetch=fetch)
+    """Range-compress integer ORDER BY keys into int64 words (direction
+    and null placement baked into the rank encoding) so the big sort is
+    always (word, index) — measurement and bit layout shared with the
+    aggregation kernels (ops.aggregate.key_pack_plan_words; the +3 slack
+    there keeps the DESC rank range clear of the nulls-first slot 0 and
+    the ASC range clear of the nulls-last slot 2^b - 1). Returns (kmins,
+    bits, word_splits), or None when a key is not integer-typed or the
+    keys need more than three words."""
+    from .aggregate import key_pack_plan_words
+    return key_pack_plan_words(batch, tuple(idx for idx, _, _ in keys),
+                               fetch=fetch)
 
 
-@recorded_jit(static_argnums=(2, 3, 4))
+@recorded_jit(static_argnums=(2, 3, 4, 5))
 def sort_batch_packed(batch: Batch, kmins, keys: tuple, key_bits: tuple,
-                      limit) -> Batch:
-    """sort_batch via one packed int64 key (see sort_pack_plan): rank
+                      limit, word_splits: tuple = None) -> Batch:
+    """sort_batch via packed int64 key words (see sort_pack_plan): rank
     within each key's field realizes ASC/DESC + null placement; dead
-    rows pack to int64.max. The sort itself is 2 operands at any key
-    count."""
+    rows pack to int64.max in every word. One word sorts directly;
+    several run an LSD radix of stable sorts from the least-significant
+    word up (as packed_sort_group_aggregate does), so every sort is 2
+    operands at any key count and width."""
     n = batch.capacity
-    packed = jnp.zeros(n, dtype=jnp.int64)
-    for j, ((idx, asc, nf), b) in enumerate(zip(keys, key_bits)):
-        col = batch.columns[idx]
-        span_max = (1 << b) - 1
-        norm = col.data.astype(jnp.int64) - kmins[j] + 1
-        rank = norm if asc else (span_max - 1) - norm
-        null_slot = 0 if nf else span_max
-        rank = jnp.where(col.valid, rank, null_slot)
-        packed = (packed << b) | rank
-    packed = jnp.where(batch.live, packed, jnp.iinfo(jnp.int64).max)
-    idx_arr = jnp.arange(n, dtype=jnp.int32)
-    _, perm = jax.lax.sort((packed, idx_arr), num_keys=1, is_stable=True)
+    if word_splits is None:
+        word_splits = ((0, len(keys)),)
+    words = []
+    for (s, e) in word_splits:
+        packed = jnp.zeros(n, dtype=jnp.int64)
+        for j in range(s, e):
+            (idx, asc, nf), b = keys[j], key_bits[j]
+            col = batch.columns[idx]
+            span_max = (1 << b) - 1
+            norm = col.data.astype(jnp.int64) - kmins[j] + 1
+            rank = norm if asc else (span_max - 1) - norm
+            null_slot = 0 if nf else span_max
+            rank = jnp.where(col.valid, rank, null_slot)
+            packed = (packed << b) | rank
+        words.append(jnp.where(batch.live, packed,
+                               jnp.iinfo(jnp.int64).max))
+    from .aggregate import lsd_word_sort
+    perm = lsd_word_sort(words, [sum(key_bits[s:e])
+                                 for (s, e) in word_splits])
     out_n = n
     if limit is not None and int(limit) < n:
         # TopN: dead rows sort last, so the winners live in the prefix —

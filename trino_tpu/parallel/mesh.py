@@ -26,17 +26,11 @@ AXIS = "workers"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable shard_map: newer jax exposes `jax.shard_map`
-    (replication checking via check_vma), older releases only
-    `jax.experimental.shard_map` (check_rep). Stage programs always
-    disable the replication checker — collective-carrying bodies with
-    manually asserted out_specs are exactly the case it rejects."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with the replication checker off: stage programs
+    are collective-carrying bodies with manually asserted out_specs,
+    exactly the case the checker rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pad_to_multiple(batch: Batch, multiple: int) -> Batch:

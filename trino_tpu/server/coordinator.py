@@ -729,10 +729,13 @@ class Dispatcher:
                 tq.fallback_reason = self.scheduler.fallback_reason \
                     if result is None else None
             except TaskFailedError as te:
-                from .scheduler import RetryBudgetExhaustedError
-                if isinstance(te, RetryBudgetExhaustedError):
-                    raise    # the budget forbade more attempts: fail,
-                             # don't silently degrade to local re-run
+                from .scheduler import (RetryBudgetExhaustedError,
+                                        TaskTimeoutError)
+                if isinstance(te, (RetryBudgetExhaustedError,
+                                   TaskTimeoutError)):
+                    raise    # the budget forbade more attempts, or a
+                             # task overran task_timeout_s: fail, don't
+                             # silently degrade to local re-run
                 result = None   # degrade to local execution
                 tq.fallback_reason = f"task failure: {te}"
             finally:

@@ -56,6 +56,14 @@ class RetryBudgetExhaustedError(TaskFailedError):
     cluster, and another full-query attempt would amplify further."""
 
 
+class TaskTimeoutError(TaskFailedError):
+    """A task was still running when `task_timeout_s` ran out. NOT
+    retried and NOT degraded to a local re-run: the node is not broken,
+    the work is too long for the timeout, and running the whole query
+    again on the coordinator would hide that behind a late right
+    answer. The query fails with this message."""
+
+
 class PageIntegrityError(TaskFailedError):
     """A drained page failed its CRC32C check: corruption detected on the
     wire/buffer and converted into a retryable task failure (the split
@@ -129,7 +137,7 @@ class _HedgedUnit:
 
     __slots__ = ("first_node", "splits", "key", "pages", "live", "hedged",
                  "nodes_used", "failed_nodes", "drained_nodes", "started",
-                 "tasks", "winner")
+                 "tasks", "winner", "timed_out")
 
     def __init__(self, first_node: str, splits: List[Split], key: str):
         self.first_node = first_node
@@ -147,6 +155,7 @@ class _HedgedUnit:
         self.started = time.monotonic()
         self.tasks: List["RemoteTask"] = []
         self.winner: Optional["RemoteTask"] = None
+        self.timed_out: Optional["TaskTimeoutError"] = None
 
 
 class RemoteTask:
@@ -228,7 +237,8 @@ class RemoteTask:
                     f"task {self.task_id} on {self.node.node_id}: "
                     f"{st.get('error', st.get('state'))}")
             time.sleep(0.02)
-        raise TaskFailedError(f"task {self.task_id} timed out")
+        raise TaskTimeoutError(
+            f"task {self.task_id} on {self.node.node_id} timed out")
 
     def _verified(self, frame: bytes) -> bytes:
         """Chaos corruption hook + CRC32C integrity gate for one drained
@@ -281,7 +291,8 @@ class RemoteTask:
                 self.done = True
                 return self.pages
             time.sleep(0.02)
-        raise TaskFailedError(f"task {self.task_id} timed out")
+        raise TaskTimeoutError(
+            f"task {self.task_id} on {self.node.node_id} timed out")
 
     def cancel(self) -> None:
         try:
@@ -924,7 +935,7 @@ class StageScheduler:
                 max_attempts = 4
                 while len(done) < P:
                     if time.time() > t_deadline:
-                        raise TaskFailedError("write stage timed out")
+                        raise TaskTimeoutError("write stage timed out")
                     for p in range(P):
                         if p in done:
                             continue
@@ -1393,6 +1404,11 @@ class StageScheduler:
                 self.stats["tasks"] += 1
                 SCHED_TASKS.inc()
                 drained = task.drain(deadline)
+            except TaskTimeoutError as e:
+                task.cancel()
+                with lock:
+                    unit.timed_out = e
+                    unit.live -= 1
             except (TaskFailedError, InjectedFailure, URLError,
                     HTTPError, OSError) as e:
                 if isinstance(e, HTTPError) and e.code == 409:
@@ -1505,6 +1521,10 @@ class StageScheduler:
         migrated = 0
         with lock:
             resolved = [(u, u.pages, u.winner) for u in units]
+            overrun = next((u.timed_out for u in units
+                            if u.pages is None and u.timed_out), None)
+        if overrun is not None:
+            raise overrun
         for u, got, winner in resolved:
             if got is not None:
                 pages.extend(got)

@@ -735,6 +735,12 @@ class TaskManager:
                     if profiling:
                         self._fold_node_stats(ex, names, op_agg)
                     live_prev = self._live_totals(op_agg)
+                    # the split loop IS a chunked loop over the driver
+                    # scan: in chunk mode a pinned build's dense LUT is
+                    # built and validated once per task and every split
+                    # probes it without a row-count fetch, instead of
+                    # re-scattering a domain-sized LUT in every split
+                    ex.enter_chunk_mode()
                     for si, split in enumerate(task.splits):
                         if task.state in ("CANCELED", "ABANDONED"):
                             return
@@ -807,6 +813,7 @@ class TaskManager:
                         self._note_busy(
                             d_dev, max(0.0, sp_wall_ms - d_dev))
                 finally:
+                    ex.exit_chunk_mode()
                     ex.profile = saved_profile
                     ex.node_stats = saved_node_stats
                     ex.deadline = None
