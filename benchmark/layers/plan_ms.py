@@ -1,0 +1,20 @@
+"""Parse, plan and admission: timeline phases `queued` + `plan`
+(GET /v1/query/{id}/timeline), median per statement, in ms. The `plan`
+phase is read from the coordinator's plan spans; a statement that has
+none (the single-node path writes none as the tree stands) gives nothing
+to read."""
+
+import statistics
+
+PLAN_SPANS = ("plan", "optimize", "plan-distributed")
+
+
+def read(run):
+    vals = []
+    for s in run["statements"]:
+        if not any(sp.get("name") in PLAN_SPANS
+                   for sp in s.get("spans") or ()):
+            continue
+        ph = s["timeline"]["phases"]
+        vals.append((ph["queued"] + ph["plan"]) * 1e3)
+    return statistics.median(vals) if vals else None
