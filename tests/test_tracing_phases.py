@@ -1,0 +1,452 @@
+"""Phase spans: the tracer carried per query (utils/tracing.py `use` /
+`current`), the spans of the split loop, the stage drain and the final
+merge, `compile` spans that tell literal-keyed from shape-keyed
+(exec/profiler.py), and tracing that no longer implies fences.
+"""
+
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from trino_tpu.client.client import Client
+from trino_tpu.exec.profiler import RECORDER, CompileRecorder, instrument
+from trino_tpu.exec.session import Session
+from trino_tpu.server.coordinator import CoordinatorServer
+from trino_tpu.server.worker import WorkerServer
+from trino_tpu.utils import tracing
+from trino_tpu.utils.tracing import NOOP, Tracer
+
+# every span of docs/operations.md's catalogue that a split-streamed
+# aggregation opens (build-stage, pin-builds' children and the write and
+# exchange spans belong to other plan shapes)
+Q6_SPANS = {"query", "exec-lock-wait", "plan-distributed", "stage-prepare",
+            "source-stage", "spool-lookup", "stage-wait", "task-create",
+            "task-drain", "task-record",
+            "task-decode", "worker-task", "pin-builds", "split-read",
+            "split-put", "split", "split-fetch", "split-emit", "compile",
+            "final-stage", "merge-decode", "merge-partials", "merge-run",
+            "result-fetch", "decode-rows"}
+SPLIT_PHASES = ("split-read", "split-put", "split", "split-fetch",
+                "split-emit")
+
+
+def q6(quantity: str) -> str:
+    return ("SELECT sum(l_extendedprice * l_discount) AS revenue "
+            "FROM lineitem WHERE l_shipdate >= DATE '1994-01-01' "
+            "AND l_shipdate < DATE '1995-01-01' "
+            "AND l_discount BETWEEN 0.05 AND 0.07 "
+            f"AND l_quantity < {quantity}")
+
+
+# ---------------------------------------------------------------------------
+# the carrier
+# ---------------------------------------------------------------------------
+
+def test_current_is_noop_outside_use():
+    assert tracing.current() is NOOP
+    assert tracing.carried() is None
+    t = Tracer()
+    with tracing.use(t):
+        assert tracing.current() is t
+        with tracing.use(NOOP):
+            # an untraced query inside: carried, but off
+            assert tracing.carried() is NOOP
+        assert tracing.current() is t
+    assert tracing.current() is NOOP
+
+
+def test_use_carries_onto_a_spawned_thread_under_the_named_parent():
+    t = Tracer()
+    seen = {}
+
+    def helper(parent_id):
+        seen["before"] = tracing.current()
+        with tracing.use(t, parent=parent_id):
+            seen["inside"] = tracing.current()
+            seen["traceparent"] = t.traceparent()
+            with t.span("helper-work"):
+                with t.span("helper-inner"):
+                    pass
+        seen["after"] = tracing.current()
+
+    with tracing.use(t), t.span("stage") as stage:
+        th = threading.Thread(target=helper, args=(stage.span_id,))
+        th.start()
+        th.join()
+    assert seen["before"] is NOOP and seen["after"] is NOOP
+    assert seen["inside"] is t
+    assert seen["traceparent"].split("-")[2] == stage.span_id
+    by = {s["name"]: s for s in t.export()}
+    assert by["helper-work"]["parentSpanId"] == stage.span_id
+    assert by["helper-inner"]["parentSpanId"] == by["helper-work"]["spanId"]
+    assert by["stage"]["parentSpanId"] is None
+
+
+def test_span_parent_argument_and_record():
+    t = Tracer()
+    with t.span("a") as a:
+        with t.span("b") as b:
+            t0 = time.monotonic()
+            time.sleep(0.01)
+            # known only afterwards; lands under the innermost open span
+            t.record("late", t0, time.monotonic(), site="x")
+        with t.span("c", parent=b.span_id):
+            pass
+        t.record("late-explicit", t0, t0 + 0.001, parent=a.span_id)
+    by = {s["name"]: s for s in t.export()}
+    assert by["late"]["parentSpanId"] == b.span_id
+    assert by["late"]["attributes"] == {"site": "x"}
+    assert 9.0 <= by["late"]["durationMs"] < 200.0
+    # its wall-clock start lies inside its parent's interval
+    assert by["b"]["startTimeUnixNano"] - 2e6 <= \
+        by["late"]["startTimeUnixNano"] <= \
+        by["b"]["startTimeUnixNano"] + by["b"]["durationMs"] * 1e6 + 2e6
+    assert by["c"]["parentSpanId"] == b.span_id
+    assert by["late-explicit"]["parentSpanId"] == a.span_id
+
+
+def test_laps_leave_no_moment_unnamed():
+    t = Tracer()
+    with t.span("task") as task:
+        with t.laps() as lap:
+            for i in range(3):
+                lap("read", index=i)
+                sp = lap("run", index=i)
+                # the open phase is the thread's innermost span
+                assert t.current_span() is sp
+                t.record("compile", time.monotonic() - 0.001,
+                         time.monotonic())
+                lap("emit", index=i)
+        assert t.current_span() is task
+    spans = t.export()
+    phases = sorted((s for s in spans if s["name"] in
+                     ("read", "run", "emit")),
+                    key=lambda s: s["startTimeUnixNano"])
+    assert [s["name"] for s in phases] == ["read", "run", "emit"] * 3
+    assert all(s["parentSpanId"] == task.span_id for s in phases)
+    runs = {s["spanId"] for s in phases if s["name"] == "run"}
+    assert all(s["parentSpanId"] in runs for s in spans
+               if s["name"] == "compile")
+    # each phase starts exactly where the one before ended
+    for a, b in zip(phases, phases[1:]):
+        end = a["startTimeUnixNano"] + a["durationMs"] * 1e6
+        assert abs(end - b["startTimeUnixNano"]) <= 2e3     # rounding
+    # off: the same code runs and builds nothing
+    with NOOP.laps() as lap:
+        assert lap("read", index=0) is None
+    assert NOOP.export() == []
+
+
+def test_laps_close_the_open_phase_on_an_error():
+    t = Tracer()
+    with pytest.raises(ValueError):
+        with t.laps() as lap:
+            lap("read")
+            raise ValueError("boom")
+    assert t.current_span() is None
+    assert [s["name"] for s in t.export()] == ["read"]
+
+
+class CountingAnnotation:
+    names = []
+
+    def __init__(self, name, **_kw):
+        CountingAnnotation.names.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_tracing_off_builds_nothing(monkeypatch):
+    import jax.profiler
+    CountingAnnotation.names = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    with tracing.use(NOOP):
+        with tracing.current().span("split", index=0) as sp:
+            assert sp is None
+        tracing.current().record("compile", 0.0, 1.0, key="shape")
+    assert CountingAnnotation.names == []
+    assert NOOP.export() == []
+    # and on: one annotation a live span, under the program's prefix,
+    # never the benchmark's
+    t = Tracer()
+    with t.span("split"):
+        with t.span("compile-ish"):
+            pass
+    t.record("compile", 0.0, 1.0)       # after the fact: no annotation
+    assert CountingAnnotation.names == ["tt:split", "tt:compile-ish"]
+    assert not any(n.startswith("bench:") for n in CountingAnnotation.names)
+
+
+def test_bare_session_keeps_its_own_tracer_and_current_comes_first():
+    s = Session(default_schema="tiny")
+    assert s.tracer is NOOP
+    s.execute("SET SESSION enable_tracing = true")
+    own = s.tracer
+    assert own.enabled
+    per_query = Tracer()
+    with tracing.use(per_query):
+        assert s.tracer is per_query
+        s.execute("SELECT count(*) FROM nation")
+    assert s.tracer is own
+    assert {"plan", "execute"} <= {x["name"] for x in per_query.export()}
+    assert own.export() == []
+    s.execute("SET SESSION enable_tracing = false")
+    assert s.tracer is NOOP
+
+
+# ---------------------------------------------------------------------------
+# literal-keyed or shape-keyed
+# ---------------------------------------------------------------------------
+
+def test_compile_kind_literal_or_shape():
+    from functools import partial
+    rec = CompileRecorder()
+
+    @partial(jax.jit, static_argnums=(1,))
+    def scaled(x, k):
+        return x * k
+
+    f = instrument(scaled, "test.scaled", recorder=rec)
+    t = Tracer()
+    with tracing.use(t):
+        f(jnp.arange(8), 3)
+        shapes_1 = rec.site_shape_counts()["test.scaled"]
+        f(jnp.arange(8), 3)            # a hit: no event kind, no span
+        f(jnp.arange(8), 4)            # new static value, shapes seen
+        shapes_2 = rec.site_shape_counts()["test.scaled"]
+        f(jnp.arange(16), 4)           # new array shape
+        shapes_3 = rec.site_shape_counts()["test.scaled"]
+    kinds = [e.key for e in rec.events if not e.hit]
+    assert kinds == ["shape", "literal", "shape"]
+    assert [e.key for e in rec.events if e.hit] == [""]
+    # a counter named for shapes counts shapes, not literals
+    assert (shapes_1, shapes_2, shapes_3) == (1, 1, 2)
+    tot = rec.totals()
+    assert tot["shapeKeyedCompiles"] == 2
+    assert tot["literalKeyedCompiles"] == 1
+    assert tot["compiles"] == 3
+    assert tot["shapeKeyedCompileSeconds"] + \
+        tot["literalKeyedCompileSeconds"] == \
+        pytest.approx(tot["compileSeconds"], abs=1e-5)
+    spans = [s for s in t.export() if s["name"] == "compile"]
+    assert [s["attributes"]["key"] for s in spans] == kinds
+    assert all(s["attributes"]["site"] == "test.scaled" for s in spans)
+    assert {e["key"] for e in rec.snapshot()} == {"shape", "literal"}
+    assert sum(s["durationMs"] for s in spans) / 1e3 == \
+        pytest.approx(tot["compileSeconds"], rel=0.02, abs=1e-3)
+
+
+def test_fixed_fingerprint_miss_still_learns_its_kind():
+    rec = CompileRecorder()
+    f = instrument(jax.jit(lambda x: x + 1), "test.fixed",
+                   fingerprint="plan-a", recorder=rec)
+    g = instrument(jax.jit(lambda x: x + 2), "test.fixed",
+                   fingerprint="plan-b", recorder=rec)
+    f(jnp.arange(4))
+    f(jnp.arange(4))
+    g(jnp.arange(4))                    # another plan, the same shapes
+    assert [e.key for e in rec.events] == ["shape", "", "literal"]
+    assert rec.site_shape_counts()["test.fixed"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the spans of a split-streamed statement
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def cluster():
+    session = Session(default_schema="tiny")
+    coord = CoordinatorServer(session).start()
+    coord.state.scheduler.split_rows = 8192
+    worker = WorkerServer("phase-w0", coord.uri, announce_interval_s=0.1,
+                          catalog=session.catalog).start()
+    deadline = time.time() + 5
+    while not coord.state.active_nodes() and time.time() < deadline:
+        time.sleep(0.05)
+    yield coord, worker, session
+    coord.stop()
+    worker.stop()
+
+
+def _interval(sp):
+    s0 = sp["startTimeUnixNano"]
+    return s0, s0 + sp["durationMs"] * 1e6
+
+
+def _covered_ms(intervals, lo, hi):
+    total, at = 0.0, lo
+    for s, e in sorted(intervals):
+        s, e = max(s, at), min(e, hi)
+        if e > s:
+            total += e - s
+            at = e
+    return total / 1e6
+
+
+def _traced(coord, sql, profiling=False):
+    coord.state.scheduler.spool.clear()
+    client = Client(coord.uri, user="phases")
+    client.execute("SET SESSION enable_tracing = true")
+    if profiling:
+        client.execute("SET SESSION enable_profiling = true")
+    try:
+        before = RECORDER.totals()
+        res = client.execute(sql)
+        after = RECORDER.totals()
+    finally:
+        client.execute("SET SESSION enable_profiling = false")
+        client.execute("SET SESSION enable_tracing = false")
+    info = client.query_info(res.query_id)
+    assert info["distributed"], info["fallbackReason"]
+    spans = client._request(
+        "GET", f"{coord.uri}/v1/query/{res.query_id}/trace")["spans"]
+    return spans, before, after
+
+
+def test_traced_q6_yields_every_phase_span(cluster):
+    coord, worker, session = cluster
+    # a literal no other test sends: its filter compiles here
+    spans, before, after = _traced(coord, q6("23.37"))
+    names = {s["name"] for s in spans}
+    assert Q6_SPANS <= names, Q6_SPANS - names
+    ids = {s["spanId"]: s for s in spans}
+    roots = [s for s in spans if s["parentSpanId"] not in ids]
+    assert [s["name"] for s in roots] == ["query"]
+
+    def parent(sp):
+        return ids[sp["parentSpanId"]]
+
+    # who hangs under whom
+    want = {"exec-lock-wait": {"query"}, "stage-prepare": {"query"},
+            "source-stage": {"query"}, "final-stage": {"query"},
+            "task-create": {"source-stage"}, "task-drain": {"source-stage"},
+            "task-record": {"source-stage"}, "task-decode": {"source-stage"},
+            "spool-lookup": {"source-stage"}, "stage-wait": {"source-stage"},
+            "worker-task": {"source-stage"}, "pin-builds": {"worker-task"},
+            "merge-decode": {"final-stage"},
+            "merge-partials": {"final-stage"},
+            "merge-run": {"final-stage"}, "result-fetch": {"final-stage"},
+            "decode-rows": {"final-stage"}}
+    want.update({n: {"worker-task"} for n in SPLIT_PHASES})
+    for s in spans:
+        if s["name"] in want:
+            assert parent(s)["name"] in want[s["name"]], s
+    # children inside their parents. One process, one clock: adopted
+    # worker spans (rebased by the announce offset, ~0 here) too
+    for s in spans:
+        if s["parentSpanId"] in ids:
+            p0, p1 = _interval(parent(s))
+            s0, s1 = _interval(s)
+            assert p0 - 3e6 <= s0 and s1 <= p1 + 3e6, (s, parent(s))
+    stage = next(s for s in spans if s["name"] == "source-stage")
+    lo, hi = _interval(stage)
+    for s in spans:
+        if s["name"] in SPLIT_PHASES:
+            s0, s1 = _interval(s)
+            assert lo - 3e6 <= s0 and s1 <= hi + 3e6, s
+    # five spans a split
+    n_splits = stage["attributes"]["splits"]
+    for n in SPLIT_PHASES:
+        assert sum(s["name"] == n for s in spans) == n_splits
+    drain = next(s for s in spans if s["name"] == "task-drain")
+    assert drain["attributes"]["pages"] == n_splits
+    assert drain["attributes"]["bytes"] > 0
+    wait = next(s for s in spans if s["name"] == "stage-wait")
+    assert drain["attributes"]["polls"] >= 0 and \
+        wait["attributes"]["polls"] >= 1
+    # the children tile the parent: 95% of worker-task outside
+    # pin-builds, of final-stage, and of source-stage
+    for name in ("worker-task", "final-stage", "source-stage"):
+        p = next(s for s in spans if s["name"] == name)
+        lo, hi = _interval(p)
+        # `stage-wait` (the stage loop, looking every 20 ms) covers the
+        # stage by itself; leave it out, and the work under the stage
+        # still tiles it but for one look between the last page and
+        # `task-record`: 5% of a stage of seconds, more of this one
+        kids = [_interval(s) for s in spans
+                if s["parentSpanId"] == p["spanId"]
+                and s["name"] != "stage-wait"]
+        wall = (hi - lo) / 1e6
+        slack = 45.0 if name == "source-stage" else 0.0
+        assert _covered_ms(kids, lo, hi) >= 0.95 * wall - slack, \
+            (name, wall, _covered_ms(kids, lo, hi))
+    # the statement's compile spans are the recorder's compile seconds
+    compiles = [s for s in spans if s["name"] == "compile"]
+    assert compiles and all(
+        s["attributes"]["key"] in ("shape", "literal") and
+        s["attributes"]["site"] for s in compiles)
+    assert any(parent(s)["name"] == "split" for s in compiles)
+    span_s = sum(s["durationMs"] for s in compiles) / 1e3
+    rec_s = after["compileSeconds"] - before["compileSeconds"]
+    assert rec_s > 0 and span_s == pytest.approx(rec_s, rel=0.02, abs=2e-3)
+    assert len(compiles) == after["compiles"] - before["compiles"]
+
+
+def test_tracing_alone_does_not_fence(cluster):
+    from trino_tpu.metrics import OPERATOR_DEVICE_MS
+
+    def fenced_ms():
+        return sum(OPERATOR_DEVICE_MS.value(operator=op) for op in
+                   ("FilterNode", "AggregateNode", "ProjectNode",
+                    "ScanNode"))
+
+    coord, worker, session = cluster
+    f0 = fenced_ms()
+    spans, _, _ = _traced(coord, q6("22.91"))
+    # no operator was fenced: the fenced branch (block_until_ready and
+    # its jit__reduce_sum row count, exec/executor.py) never ran
+    assert fenced_ms() == f0
+    task = next(s for s in spans if s["name"] == "worker-task")
+    assert "deviceMs" not in task["attributes"]
+    assert {s["name"] for s in spans} >= set(SPLIT_PHASES)
+    # profiling on as well: the fences are back, on the worker too
+    spans, _, _ = _traced(coord, q6("22.92"), profiling=True)
+    assert fenced_ms() > f0
+    task = next(s for s in spans if s["name"] == "worker-task")
+    assert task["attributes"]["deviceMs"] >= 0
+    assert "hostMs" in task["attributes"]
+
+
+def test_single_node_route_writes_plan_spans():
+    """The serving layer plans ahead of Session.execute_planned: its
+    `plan` / `optimize` spans fill the timeline's plan phase."""
+    session = Session(default_schema="tiny")
+    coord = CoordinatorServer(session).start()
+    try:
+        client = Client(coord.uri, user="phases")
+        client.execute("SET SESSION enable_tracing = true")
+        res = client.execute(
+            "SELECT n_regionkey, count(*) FROM nation GROUP BY n_regionkey")
+        base = f"{coord.uri}/v1/query/{res.query_id}"
+        spans = client._request("GET", f"{base}/trace")["spans"]
+        by = {}
+        for s in spans:
+            by.setdefault(s["name"], []).append(s)
+        assert by["plan"][0]["attributes"]["planCache"] == "miss"
+        assert "optimize" in by
+        query = by["query"][0]["spanId"]
+        assert by["plan"][0]["parentSpanId"] == query
+        assert by["optimize"][0]["parentSpanId"] == query
+        timeline = client._request("GET", f"{base}/timeline")
+        assert timeline["phases"]["plan"] > 0
+    finally:
+        coord.stop()
+
+
+def test_dispatcher_has_one_path():
+    """Traced and untraced statements take the exec lock at the same
+    place: concurrent traced statements do not serialise end to end on
+    an outer lock, and each gets its own spans."""
+    import inspect
+    from trino_tpu.server import coordinator
+    src = inspect.getsource(coordinator.Dispatcher)
+    assert "saved_tracer" not in src
+    assert src.count("exec_lock.acquire()") == 1
+    assert "with self.exec_lock" not in src

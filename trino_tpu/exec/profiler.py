@@ -24,7 +24,14 @@ compile duration, into:
   stats object per dispatch thread, so per-executor counts attribute
   compiles to the executor whose dispatch triggered them),
 - a per-thread compile-seconds accumulator the profiled dispatch path
-  reads to split operator wall into device/host/compile components.
+  reads to split operator wall into device/host/compile components,
+- a `compile` span of the thread's active tracer (utils/tracing.py), so
+  a traced query shows each compile inside the span that paid for it.
+
+A compile is `shape`-keyed when the site has not seen the arguments'
+array shapes and dtypes before (a new capacity bucket) and `literal`-keyed
+when it has and only static arguments differ (`filter_project`'s IR with
+new literals): two different repairs, told apart here.
 
 Design constraints: recording must never change execution (a wrapper
 failure falls through to the raw call), must cost ~a cache-size probe
@@ -41,7 +48,7 @@ import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -51,27 +58,36 @@ class CompileEvent:
     duration_s: float       # trace+compile wall for misses, 0.0 for hits
     hit: bool               # True = the program cache already had it
     when: float             # time.time() at record
+    key: str = ""           # misses: "shape" or "literal" (see above)
 
 
-def _arg_fingerprint(args, kwargs) -> str:
-    """Cheap jaxpr-identity proxy: the tree of array (shape, dtype)
-    leaves plus static leaves, hashed. Two calls with the same
-    fingerprint hit the same compiled program for a given jit site.
+def _hex(parts: list) -> str:
+    return f"{hash(tuple(parts)) & 0xFFFFFFFFFFFFFFFF:016x}"
+
+
+def _arg_fingerprint(args, kwargs) -> Tuple[str, str]:
+    """Cheap jaxpr-identity proxy, in one pass over the leaves: the
+    tree of array (shape, dtype) leaves plus static leaves, hashed, and
+    the hash of the array leaves alone. Two calls with the same full
+    fingerprint hit the same compiled program for a given jit site; two
+    that differ only in the first differ only in static arguments.
     Built on Python's tuple hash (not a cryptographic digest) because
     this runs on EVERY instrumented dispatch — the fingerprint is an
     in-process cache key, not a cross-process identity."""
     import jax
-    parts = []
+    parts, shapes = [], []
     for leaf in jax.tree_util.tree_leaves((args, kwargs)):
         shape = getattr(leaf, "shape", None)
         if shape is not None:
-            parts.append((shape, str(getattr(leaf, "dtype", "?"))))
+            part = (shape, str(getattr(leaf, "dtype", "?")))
+            parts.append(part)
+            shapes.append(part)
         else:
             try:
                 parts.append(hash(leaf))
             except TypeError:
                 parts.append(repr(leaf)[:48])
-    return f"{hash(tuple(parts)) & 0xFFFFFFFFFFFFFFFF:016x}"
+    return _hex(parts), _hex(shapes)
 
 
 class CompileRecorder:
@@ -90,9 +106,13 @@ class CompileRecorder:
         self.total_compiles = 0
         self.total_hits = 0
         self.total_compile_s = 0.0
-        # shape-canonicalization signal: every fingerprint ever seen per
-        # site (survives the entry LRU — the lint cares about distinct
-        # shapes produced, not about what is still cached)
+        # misses by what keyed them: [count, seconds]
+        self.by_key: Dict[str, list] = {"shape": [0, 0.0],
+                                        "literal": [0, 0.0]}
+        # shape-canonicalization signal: every hash of array shapes and
+        # dtypes ever seen per site (survives the entry LRU — the lint
+        # cares about distinct shapes produced, not about what is still
+        # cached, and a new literal is not a new shape)
         self._site_shapes: Dict[str, set] = {}
         # (site, fingerprint) -> off-path compile seconds, pending the
         # first query-path hit that claims the saving
@@ -145,21 +165,30 @@ class CompileRecorder:
     # -- recording ---------------------------------------------------------
 
     def record(self, site: str, fingerprint: str, duration_s: float,
-               hit: bool) -> None:
+               hit: bool, shape: Optional[str] = None) -> CompileEvent:
+        """`shape` is the hash of the call's array shapes and dtypes;
+        None where the caller did not compute one (a hit under a fixed
+        fingerprint: nothing to learn; a miss: the fingerprint stands
+        in)."""
         prefix = getattr(self._tl, "site_prefix", None)
         if prefix:
             site = f"{prefix}:{site}"
+        if shape is None and not hit:
+            shape = fingerprint
         from ..metrics import (COMPILE_SECONDS_SAVED, JIT_CACHE_HITS,
                                JIT_COMPILES, JIT_COMPILE_SECONDS,
                                JIT_DISTINCT_SHAPES, PREWARM_COMPILES,
                                PREWARM_HITS)
         prewarming = getattr(self._tl, "prewarm", False)
-        ev = CompileEvent(site, fingerprint, duration_s if not hit
-                          else 0.0, hit, time.time())
         shape_count = None
         saved_s = None
         prewarm_hit = False
         with self._lock:
+            shapes = self._site_shapes.setdefault(site, set())
+            kind = "" if hit else \
+                ("literal" if shape in shapes else "shape")
+            ev = CompileEvent(site, fingerprint, duration_s if not hit
+                              else 0.0, hit, time.time(), kind)
             self.events.append(ev)
             key = (site, fingerprint)
             e = self._entries.get(key)
@@ -170,10 +199,10 @@ class CompileRecorder:
                     "site": site, "fingerprint": fingerprint,
                     "compiles": 0, "hits": 0, "compile_ms": 0.0,
                     "last_compile_ms": 0.0, "last_used": 0.0,
-                    "prewarmed": False, "prewarm_hits": 0}
-            shapes = self._site_shapes.setdefault(site, set())
-            if fingerprint not in shapes:
-                shapes.add(fingerprint)
+                    "prewarmed": False, "prewarm_hits": 0,
+                    "shape": shape or "", "key": ""}
+            if shape is not None and shape not in shapes:
+                shapes.add(shape)
                 shape_count = len(shapes)
             e["last_used"] = ev.when
             if hit:
@@ -192,8 +221,11 @@ class CompileRecorder:
                 e["compiles"] += 1
                 e["compile_ms"] += duration_s * 1000
                 e["last_compile_ms"] = duration_s * 1000
+                e["key"] = kind
                 self.total_compiles += 1
                 self.total_compile_s += duration_s
+                self.by_key[kind][0] += 1
+                self.by_key[kind][1] += duration_s
                 if prewarming:
                     e["prewarmed"] = True
                     self._prewarm_pending[key] = duration_s
@@ -218,6 +250,7 @@ class CompileRecorder:
             stats = getattr(self._tl, "stats", None)
             if stats is not None:
                 stats.jit_compiles += 1
+        return ev
 
     # -- read surface ------------------------------------------------------
 
@@ -232,14 +265,21 @@ class CompileRecorder:
             return {"compiles": self.total_compiles,
                     "hits": self.total_hits,
                     "compileSeconds": round(self.total_compile_s, 6),
+                    "shapeKeyedCompiles": self.by_key["shape"][0],
+                    "shapeKeyedCompileSeconds": round(
+                        self.by_key["shape"][1], 6),
+                    "literalKeyedCompiles": self.by_key["literal"][0],
+                    "literalKeyedCompileSeconds": round(
+                        self.by_key["literal"][1], 6),
                     "entries": len(self._entries),
                     "prewarmedPrograms": self.total_prewarmed,
                     "prewarmHits": self.total_prewarm_hits,
                     "compileSecondsSaved": round(self.total_saved_s, 6)}
 
     def site_shape_counts(self) -> Dict[str, int]:
-        """Distinct fingerprints ever recorded per site — what the
-        shape-canonicalization lint asserts ceilings over."""
+        """Distinct array-shape hashes ever recorded per site — what
+        the shape-canonicalization lint asserts ceilings over (a new
+        literal in a static argument is not a new shape)."""
         with self._lock:
             return {s: len(fps) for s, fps in self._site_shapes.items()}
 
@@ -251,6 +291,7 @@ class CompileRecorder:
             self.total_compiles = 0
             self.total_hits = 0
             self.total_compile_s = 0.0
+            self.by_key = {"shape": [0, 0.0], "literal": [0, 0.0]}
             sites = list(self._site_shapes)
             self._site_shapes.clear()
             self._prewarm_pending.clear()
@@ -296,9 +337,21 @@ def instrument(jitted: Callable, site: str,
         dt = time.monotonic() - t0
         try:
             hit = probe() == before
-            fp = fingerprint if fingerprint is not None else \
-                _arg_fingerprint(args, kwargs)
-            rec.record(site, fp, dt, hit)
+            if fingerprint is None:
+                fp, shape = _arg_fingerprint(args, kwargs)
+            else:
+                # a fixed fingerprint skips the hash on the hot path; a
+                # miss still learns what keyed it
+                fp = fingerprint
+                shape = None if hit else _arg_fingerprint(args, kwargs)[1]
+            ev = rec.record(site, fp, dt, hit, shape)
+            if not hit:
+                # the span lands inside whatever span of this thread's
+                # tracer paid for the compile (split, pin-builds, ...)
+                from ..utils import tracing
+                tracing.current().record(
+                    "compile", t0, t0 + dt, site=ev.site,
+                    fingerprint=fp, key=ev.key)
         except Exception:        # noqa: BLE001 — never break the call
             pass
         return out

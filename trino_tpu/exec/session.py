@@ -208,7 +208,21 @@ class Session:
         self.properties = {k: v for k, (v, _) in
                            SESSION_PROPERTY_DEFAULTS.items()}
         from ..utils.tracing import NOOP
-        self.tracer = NOOP          # swap for utils.tracing.Tracer()
+        self._tracer = NOOP         # swap for utils.tracing.Tracer()
+
+    @property
+    def tracer(self):
+        """The tracer of the query running on this thread (the
+        dispatcher activates one per query, utils/tracing.py); a bare
+        Session falls back on the one of its own that `SET SESSION
+        enable_tracing` or an assignment gives it."""
+        from ..utils import tracing
+        carried = tracing.carried()
+        return carried if carried is not None else self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._tracer = tracer
 
     def planner(self) -> Planner:
         return Planner(self.catalog, self.default_cat, self.default_schema,
@@ -478,8 +492,11 @@ class Session:
             self.executor.enable_pallas_gather = \
                 self.properties[stmt.name]
         elif stmt.name == "enable_tracing":
-            from ..utils.tracing import NOOP, Tracer
-            self.tracer = Tracer() if self.properties[stmt.name] else NOOP
+            from ..utils.tracing import NOOP, Tracer, carried
+            # under the dispatcher every query brings its own tracer: a
+            # session-level one would only soak up spans nobody reads
+            on = self.properties[stmt.name] and carried() is None
+            self.tracer = Tracer() if on else NOOP
         return QueryResult(["result"], [("SET SESSION",)],
                            time.monotonic() - t0)
 

@@ -26,6 +26,7 @@ import threading
 import time
 import traceback
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import contextmanager
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
 
@@ -532,12 +533,15 @@ class Dispatcher:
             pass              # with its real parse/analysis error
         # per-query tracer (enable_tracing sessions): adopts the client's
         # traceparent when present so the query trace continues the
-        # caller's trace; exported to tq.trace at the end either way
-        tracer = None
+        # caller's trace; exported to tq.trace at the end either way.
+        # It travels by `tracing.use()`: whatever runs below on this
+        # thread (session, scheduler, compile recorder) finds it with
+        # `tracing.current()`, traced and untraced on ONE path.
+        from ..utils import tracing
+        tracer = tracing.NOOP
         if self.session.properties.get("enable_tracing"):
-            from ..utils.tracing import Tracer
-            tracer = Tracer.from_traceparent(tq.traceparent,
-                                             service="coordinator")
+            tracer = tracing.Tracer.from_traceparent(
+                tq.traceparent, service="coordinator")
             tq.tracer = tracer
         last_error: Optional[str] = None
         last_exc: Optional[Exception] = None
@@ -561,49 +565,21 @@ class Dispatcher:
                     if self.failure_injector is not None:
                         self.failure_injector.maybe_fail("DISPATCH",
                                                          tq.sql)
-                    if tracer is not None:
-                        # tracing swaps the SHARED session tracer, so a
-                        # traced attempt serializes end-to-end like the
-                        # pre-serving coordinator did
-                        with self.exec_lock:
-                            if sm.is_done():
-                                return
-                            sm.transition("RUNNING")
-                            if self.failure_injector is not None:
-                                self.failure_injector.maybe_fail(
-                                    "EXECUTION", tq.sql)
-                            saved_tracer = self.session.tracer
-                            self.session.tracer = tracer
-                            try:
-                                with tracer.span("query",
-                                                 queryId=tq.query_id,
-                                                 user=tq.session_user,
-                                                 attempt=attempt):
-                                    self._execute_attempt(tq)
-                            finally:
-                                self.session.tracer = saved_tracer
-                    else:
-                        # untraced path: the exec lock moves INSIDE the
-                        # attempt (serving layer) so host-routed and
-                        # cache-served queries run concurrently while
-                        # device executions still serialize
-                        if sm.is_done():
-                            return
-                        sm.transition("RUNNING")
-                        if self.failure_injector is not None:
-                            self.failure_injector.maybe_fail(
-                                "EXECUTION", tq.sql)
-                        # restore the session tracer afterwards even
-                        # untraced: a SET SESSION enable_tracing=true
-                        # must not leave a live session-level tracer
-                        # soaking up every later query's spans (the
-                        # per-query tracer swap above is the only way
-                        # spans reach a protocol query)
-                        saved_tracer = self.session.tracer
-                        try:
-                            self._execute_attempt(tq)
-                        finally:
-                            self.session.tracer = saved_tracer
+                    if sm.is_done():
+                        return
+                    sm.transition("RUNNING")
+                    if self.failure_injector is not None:
+                        self.failure_injector.maybe_fail(
+                            "EXECUTION", tq.sql)
+                    # the exec lock is taken INSIDE the attempt (here
+                    # for the cluster path, in the serving layer for the
+                    # local one) so host-routed and cache-served queries
+                    # run concurrently while device executions serialize
+                    with tracing.use(tracer), \
+                            tracer.span("query", queryId=tq.query_id,
+                                        user=tq.session_user,
+                                        attempt=attempt):
+                        self._execute_attempt(tq)
                     sm.transition("FINISHING")
                     sm.transition("FINISHED")
                     return
@@ -623,8 +599,20 @@ class Dispatcher:
                     error_code=getattr(last_exc, "error_code", 1)
                     if last_error else 1)
         finally:
-            if tracer is not None:
+            if tracer.enabled:
                 tq.trace = tracer.export()
+
+    @contextmanager
+    def _exec_locked(self):
+        """The one place the dispatcher takes the exec lock; the wait
+        for it is a span of its own (`exec-lock-wait`)."""
+        from ..utils import tracing
+        with tracing.current().span("exec-lock-wait"):
+            self.exec_lock.acquire()
+        try:
+            yield
+        finally:
+            self.exec_lock.release()
 
     def _execute_attempt(self, tq: TrackedQuery) -> None:
         """One execution attempt under the exec lock: cluster path first,
@@ -723,7 +711,7 @@ class Dispatcher:
             if fair is not None:
                 fair.device_begin(getattr(tq, "tenant", "default"))
             try:
-                with self.exec_lock:
+                with self._exec_locked():
                     result = self.scheduler.execute(tq.sql,
                                                     query_id=tq.query_id)
                 tq.fallback_reason = self.scheduler.fallback_reason \
@@ -764,7 +752,7 @@ class Dispatcher:
                 # take the exec lock inside)
                 result = serving.execute_local(tq)
             else:
-                with self.exec_lock:
+                with self._exec_locked():
                     result = self.session.execute(tq.sql)
         tq.elapsed_s = time.monotonic() - t0
         tq.result = result

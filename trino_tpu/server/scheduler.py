@@ -38,7 +38,7 @@ from ..planner.fragmenter import Fragment, fragment_plan
 from ..planner.optimizer import prune_plan
 from ..sql import ast_nodes as A
 from ..sql.parser import parse
-from ..utils.tracing import NOOP
+from ..utils import tracing
 from .failureinjector import InjectedFailure
 from .pageserde import PageChecksumError, verify_page
 from .retrypolicy import RetryPolicy
@@ -181,6 +181,7 @@ class RemoteTask:
         self.deadline = deadline
         self.pages: List[dict] = []
         self.bytes_drained = 0            # frame bytes pulled (shuffle)
+        self.polls = 0                    # drain polls that found nothing
         self.done = False
 
     def _url(self, suffix: str = "") -> str:
@@ -290,6 +291,7 @@ class RemoteTask:
             if out.get("complete"):
                 self.done = True
                 return self.pages
+            self.polls += 1
             time.sleep(0.02)
         raise TaskTimeoutError(
             f"task {self.task_id} on {self.node.node_id} timed out")
@@ -483,9 +485,10 @@ class StageScheduler:
     # -- per-query observability rollup -----------------------------------
 
     def _tracer(self):
-        """The session's tracer (the dispatcher swaps a per-query tracer
-        in while a traced query executes); NOOP otherwise."""
-        return getattr(self.session, "tracer", None) or NOOP
+        """The tracer of the query on this thread (the dispatcher
+        activates one per traced query, utils/tracing.py), else the
+        session's own; NOOP otherwise."""
+        return getattr(self.session, "tracer", None) or tracing.current()
 
     def _begin_query(self, query_id: Optional[str]) -> None:
         self._stats_snap = dict(self.stats)
@@ -1146,8 +1149,12 @@ class StageScheduler:
             batch = self._merge_pages(out_node, analysis, pages)
         else:
             ex = self.session.executor
-            batch = ex.run(plan)
-        arrays, valids = batch_to_numpy(batch)
+            with self._tracer().span("merge-run", local=True):
+                batch = ex.run(plan)
+        with self._tracer().span("result-fetch") as sp:
+            arrays, valids = batch_to_numpy(batch)
+            if sp is not None:
+                sp.attributes["rows"] = len(arrays[0]) if arrays else 0
         # build output now lives on host inside the ValuesNode: drop the
         # device-side reservations the stage's plan-node runs took
         self.session.executor.release_all_reservations()
@@ -1163,33 +1170,56 @@ class StageScheduler:
         concat-mode pages concatenate below the output node."""
         from ..batch import batch_from_numpy
         ex = self.session.executor
+        tracer = self._tracer()
         saved = dict(ex._subst)
         saved_opaque = set(ex._subst_opaque)
+
+        def put(node, arrs, vals):
+            # merged host columns onto the device, in the node's place
+            with tracer.span("merge-decode", pages=len(pages),
+                             rows=len(arrs[0]) if len(arrs) else 0):
+                ex._subst[id(node)] = batch_from_numpy(arrs, valids=vals)
+            ex._subst_opaque.add(id(node))
+
         try:
             if analysis.merge_agg is not None:
                 partials = []
-                for p in pages:
-                    arrs, vals = decode_columns(p)
-                    if len(arrs) == 0 or len(arrs[0]) == 0:
-                        continue
-                    partials.append(batch_from_numpy(arrs, valids=vals))
-                merged = merge_partials(ex, analysis.merge_agg, partials) \
-                    if partials else self._empty_like(analysis.merge_agg)
+                with tracer.span("merge-decode", pages=len(pages)) as sp:
+                    rows = 0
+                    for p in pages:
+                        arrs, vals = decode_columns(p)
+                        if len(arrs) == 0 or len(arrs[0]) == 0:
+                            continue
+                        rows += len(arrs[0])
+                        partials.append(
+                            batch_from_numpy(arrs, valids=vals))
+                    if sp is not None:
+                        sp.attributes.update(
+                            rows=rows, bytes=sum(
+                                len(p) for p in pages
+                                if isinstance(p, (bytes, bytearray))))
+                with tracer.span("merge-partials",
+                                 partials=len(partials)):
+                    merged = merge_partials(
+                        ex, analysis.merge_agg, partials) \
+                        if partials else \
+                        self._empty_like(analysis.merge_agg)
                 ex._subst[id(analysis.merge_agg)] = merged
                 ex._subst_opaque.add(id(analysis.merge_agg))
             elif analysis.merge_sort is not None:
-                arrs, vals = _merge_sorted_runs(
-                    analysis.merge_sort, pages)
-                ex._subst[id(analysis.merge_sort)] = batch_from_numpy(
-                    arrs, valids=vals)
-                ex._subst_opaque.add(id(analysis.merge_sort))
+                with tracer.span("merge-partials", pages=len(pages),
+                                 mode="sorted-runs"):
+                    arrs, vals = _merge_sorted_runs(
+                        analysis.merge_sort, pages)
+                put(analysis.merge_sort, arrs, vals)
             else:
                 from .tasks import concat_pages
-                arrs, vals = concat_pages(pages, root.child.output)
-                ex._subst[id(root.child)] = batch_from_numpy(
-                    arrs, valids=vals)
-                ex._subst_opaque.add(id(root.child))
-            return ex.run(root.child)
+                with tracer.span("merge-partials", pages=len(pages),
+                                 mode="concat"):
+                    arrs, vals = concat_pages(pages, root.child.output)
+                put(root.child, arrs, vals)
+            with tracer.span("merge-run"):
+                return ex.run(root.child)
         finally:
             ex._subst.clear()
             ex._subst.update(saved)
@@ -1249,31 +1279,42 @@ class StageScheduler:
             is not None else (analysis.merge_sort
                               if analysis.merge_sort is not None
                               else root.child)
-        frag = {"root": fragment_root, "driver": analysis.driver}
-        if self._profile_tasks:
-            # EXPLAIN ANALYZE: workers profile per-operator device time
-            # (also keys the spool differently, so profiled runs never
-            # reuse unprofiled spooled output)
-            frag["profile"] = True
-        blob = encode_fragment(frag)
-        # the work key hashes (fragment, splits) but not data contents:
-        # only deterministic generator sources may reuse spooled outputs
-        # (a memory-connector table can change between attempts)
-        use_spool = analysis.driver.catalog in ("tpch", "tpcds")
-        splits = self._make_splits(analysis)
-        # memory-aware placement: order workers by heartbeat-reported
-        # reserved bytes so the round-robin lands extra splits on the
-        # least-pressured nodes first (UniformNodeSelector weighted by
-        # the ClusterMemoryManager's per-node view)
-        workers = sorted(
-            workers,
-            key=lambda w: (getattr(w, "memory", None) or {}).get(
-                "reserved", 0))
-        # uniform assignment (UniformNodeSelector's round-robin core)
-        assignment: Dict[str, List[Split]] = {w.node_id: [] for w in workers}
-        by_id = {w.node_id: w for w in workers}
-        for i, s in enumerate(splits):
-            assignment[workers[i % len(workers)].node_id].append(s)
+        # encoding the fragment (a broadcast build rides inside it) and
+        # cutting the splits is stage time too; `source-stage` itself
+        # stays what it was, dispatch to last page (split_wall_ms)
+        with self._tracer().span("stage-prepare") as prep:
+            frag = {"root": fragment_root, "driver": analysis.driver}
+            if self._profile_tasks or getattr(
+                    self.session, "properties", {}).get("enable_profiling"):
+                # EXPLAIN ANALYZE or `enable_profiling`: workers fence
+                # every operator for its device time (also keys the
+                # spool differently, so profiled runs never reuse
+                # unprofiled spooled output). Tracing alone does not:
+                # spans cost spans.
+                frag["profile"] = True
+            blob = encode_fragment(frag)
+            # the work key hashes (fragment, splits) but not data
+            # contents: only deterministic generator sources may reuse
+            # spooled outputs (a memory-connector table can change
+            # between attempts)
+            use_spool = analysis.driver.catalog in ("tpch", "tpcds")
+            splits = self._make_splits(analysis)
+            # memory-aware placement: order workers by heartbeat-reported
+            # reserved bytes so the round-robin lands extra splits on the
+            # least-pressured nodes first (UniformNodeSelector weighted by
+            # the ClusterMemoryManager's per-node view)
+            workers = sorted(
+                workers,
+                key=lambda w: (getattr(w, "memory", None) or {}).get(
+                    "reserved", 0))
+            # uniform assignment (UniformNodeSelector's round-robin core)
+            assignment: Dict[str, List[Split]] = {
+                w.node_id: [] for w in workers}
+            by_id = {w.node_id: w for w in workers}
+            for i, s in enumerate(splits):
+                assignment[workers[i % len(workers)].node_id].append(s)
+            if prep is not None:
+                prep.attributes.update(bytes=len(blob), splits=len(splits))
 
         pages: List[dict] = []
         pending = {nid: sp for nid, sp in assignment.items() if sp}
@@ -1286,9 +1327,11 @@ class StageScheduler:
                               max_attempts=self.max_task_retries + 2
                               ).delays()
         with self._tracer().span("source-stage", splits=len(splits),
-                                 workers=len(workers)):
+                                 workers=len(workers)) as stage:
             pages = self._drain_rounds(pending, by_id, blob, use_spool,
                                        backoff)
+            if stage is not None:
+                stage.attributes["pages"] = len(pages)
         return pages
 
     def _drain_rounds(self, pending, by_id, blob, use_spool,
@@ -1302,16 +1345,20 @@ class StageScheduler:
                 raise QueryTerminatedError(
                     "query terminated during stage drain")
             units: List[_HedgedUnit] = []
-            for nid, sp in list(pending.items()):
-                # durable-exchange hit: a prior attempt already produced
-                # this work's output — consume the spool, skip dispatch
-                key = self.spool.work_key(blob, sp)
-                spooled = self.spool.get(key) if use_spool else None
-                if spooled is not None:
-                    pages.extend(spooled)
-                    self.stats["spool_hits"] += 1
-                    continue
-                units.append(_HedgedUnit(nid, sp, key))
+            # the work key hashes the whole fragment, broadcast build
+            # included: tens of milliseconds for a 35 MB one
+            with self._tracer().span("spool-lookup", units=len(pending)):
+                for nid, sp in list(pending.items()):
+                    # durable-exchange hit: a prior attempt already
+                    # produced this work's output — consume the spool,
+                    # skip dispatch
+                    key = self.spool.work_key(blob, sp)
+                    spooled = self.spool.get(key) if use_spool else None
+                    if spooled is not None:
+                        pages.extend(spooled)
+                        self.stats["spool_hits"] += 1
+                        continue
+                    units.append(_HedgedUnit(nid, sp, key))
             failed_splits, failed_nodes, migrated = self._drain_units(
                 units, by_id, blob, use_spool, pages)
             if not failed_splits:
@@ -1381,29 +1428,45 @@ class StageScheduler:
             deadline = min(deadline, qd)
         lock = threading.Lock()
         durations: List[float] = []
-        # capture the trace context ON THIS THREAD (the source-stage span
-        # is open here; drain threads have empty span stacks)
-        traceparent = self._tracer().traceparent()
+        # the stage span is open on THIS thread; a drain thread carries
+        # the query's tracer on with it as the parent of what it opens
+        tracer = self._tracer()
+        stage = tracer.current_span()
+        stage_id = stage.span_id if stage is not None else None
 
         def attempt(unit: "_HedgedUnit", node) -> None:
+            with tracing.use(tracer, parent=stage_id):
+                run_attempt(unit, node)
+
+        def run_attempt(unit: "_HedgedUnit", node) -> None:
             t0 = time.monotonic()
             with self._lock:
                 self._seq += 1
                 tid = f"t{self._seq}"
             task = RemoteTask(node, tid, blob, unit.splits,
                               injector=self.failure_injector,
-                              traceparent=traceparent,
+                              traceparent=tracer.traceparent(),
                               deadline=qd)
             with lock:
                 unit.tasks.append(task)
             losers: List[RemoteTask] = []
             try:
-                task.start()
-                self._ledger_assign(task)
-                self._livestats_register(task)
+                with tracer.span("task-create", taskId=tid,
+                                 splits=len(unit.splits)):
+                    task.start()
+                    self._ledger_assign(task)
+                    self._livestats_register(task)
                 self.stats["tasks"] += 1
                 SCHED_TASKS.inc()
-                drained = task.drain(deadline)
+                with tracer.span("task-drain", taskId=tid) as sp:
+                    try:
+                        drained = task.drain(deadline)
+                    finally:
+                        if sp is not None:
+                            sp.attributes.update(
+                                pages=len(task.pages),
+                                bytes=task.bytes_drained,
+                                polls=task.polls)
             except TaskTimeoutError as e:
                 task.cancel()
                 with lock:
@@ -1455,7 +1518,52 @@ class StageScheduler:
 
         for u in units:
             launch(u, by_id[u.first_node])
+        with tracer.span("stage-wait") as wait:
+            polls = self._await_units(units, lock, durations, deadline,
+                                      launch)
+            if wait is not None:
+                # looks (20 ms apart) that found a unit unresolved
+                wait.attributes["polls"] = polls
 
+        failed_splits: List[Split] = []
+        failed_nodes: Set[str] = set()
+        migrated = 0
+        with lock:
+            resolved = [(u, u.pages, u.winner) for u in units]
+            overrun = next((u.timed_out for u in units
+                            if u.pages is None and u.timed_out), None)
+        if overrun is not None:
+            raise overrun
+        for u, got, winner in resolved:
+            if got is not None:
+                with tracer.span("task-record", pages=len(got),
+                                 spooled=use_spool):
+                    pages.extend(got)
+                    if use_spool:
+                        self.spool.put(u.key, got)
+                        self._ledger_spool(u.key)
+                    if winner is not None:
+                        # TaskStats + worker spans ride the terminal
+                        # status — fetched HERE (main thread, before the
+                        # stage returns) so the rollup is complete by
+                        # the time the dispatcher publishes the
+                        # completion event
+                        self._record_task(winner)
+            else:
+                failed_splits.extend(u.splits)
+                failed_nodes.update(u.failed_nodes or {u.first_node})
+                if u.failed_nodes and \
+                        u.failed_nodes <= u.drained_nodes:
+                    migrated += len(u.splits)
+        return failed_splits, failed_nodes, migrated
+
+    def _await_units(self, units: List["_HedgedUnit"], lock, durations,
+                     deadline: float, launch) -> int:
+        """The stage loop of `_drain_units`: look every 20 ms until
+        every unit is resolved (or the deadline passes, or the query is
+        killed), hedging stragglers meanwhile. Returns the looks that
+        found a unit unresolved."""
+        polls = 0
         while time.time() < deadline + 5.0:
             if self._query_dead():
                 break    # terminate() fan-out already DELETEd the tasks
@@ -1514,36 +1622,9 @@ class StageScheduler:
                     self.stats["hedged_tasks"] += 1
                     SCHED_HEDGES.inc()
                     launch(u, candidate)
+            polls += 1
             time.sleep(0.02)
-
-        failed_splits: List[Split] = []
-        failed_nodes: Set[str] = set()
-        migrated = 0
-        with lock:
-            resolved = [(u, u.pages, u.winner) for u in units]
-            overrun = next((u.timed_out for u in units
-                            if u.pages is None and u.timed_out), None)
-        if overrun is not None:
-            raise overrun
-        for u, got, winner in resolved:
-            if got is not None:
-                pages.extend(got)
-                if use_spool:
-                    self.spool.put(u.key, got)
-                    self._ledger_spool(u.key)
-                if winner is not None:
-                    # TaskStats + worker spans ride the terminal status —
-                    # fetched HERE (main thread, before the stage
-                    # returns) so the rollup is complete by the time the
-                    # dispatcher publishes the completion event
-                    self._record_task(winner)
-            else:
-                failed_splits.extend(u.splits)
-                failed_nodes.update(u.failed_nodes or {u.first_node})
-                if u.failed_nodes and \
-                        u.failed_nodes <= u.drained_nodes:
-                    migrated += len(u.splits)
-        return failed_splits, failed_nodes, migrated
+        return polls
 
     def _mark_failed(self, node_id: str, err: Exception) -> None:
         with self.state.nodes_lock:
@@ -1566,9 +1647,13 @@ class StageScheduler:
                          analysis: ChunkAnalysis, pages: List[dict]):
         from ..exec.session import QueryResult
         ex = self.session.executor
+        tracer = self._tracer()
         batch = self._merge_pages(root, analysis, pages)
-        names, arrays, valids = ex.result_to_host(root, batch)
-        rows = self.session.decode_rows(rel, arrays, valids)
+        with tracer.span("result-fetch"):
+            names, arrays, valids = ex.result_to_host(root, batch)
+        with tracer.span("decode-rows",
+                         rows=len(arrays[0]) if arrays else 0):
+            rows = self.session.decode_rows(rel, arrays, valids)
         # the merge ran plan nodes outside execute(): release their pool
         # reservations now that the result is host rows — otherwise a
         # stream of distributed queries leaks the pool dry

@@ -50,6 +50,7 @@ from ..planner import logical as L
 from ..planner.optimizer import prune_plan
 from ..sql import ast_nodes as A
 from ..sql.parser import parse
+from ..utils import tracing
 from ..utils.log import tq_context
 from .history import plan_fingerprint
 
@@ -526,19 +527,28 @@ class ServingLayer:
         if fp in self._bypass:
             return None
         session = self.session
+        # the single-node route plans HERE, ahead of
+        # Session.execute_planned, so the spans of Session.execute_query
+        # never open: these are their twins (the timeline's `plan` phase)
+        tracer = tracing.current()
         enabled = bool(session.properties.get("enable_plan_cache", True))
         key = (fp, self.props_key(), self.catalog_version())
         if enabled:
+            t0 = time.monotonic()
             entry = self.plan_cache.get(key)
             if entry is not None:
+                tracer.record("plan", t0, time.monotonic(),
+                              planCache="hit")
                 return entry
         with self.plan_lock:
-            stmt = parse(sql)
-            if not isinstance(stmt, (A.Query, A.SetOp, A.Values)):
-                self._remember_bypass(fp)
-                return None
-            rel = session.planner().plan_query(stmt)
-            root = prune_plan(rel.node)
+            with tracer.span("plan", planCache="miss"):
+                stmt = parse(sql)
+                if not isinstance(stmt, (A.Query, A.SetOp, A.Values)):
+                    self._remember_bypass(fp)
+                    return None
+                rel = session.planner().plan_query(stmt)
+            with tracer.span("optimize"):
+                root = prune_plan(rel.node)
         cacheable = self._cacheable(root)
         if not cacheable:
             # volatile scans (system / information_schema): the data can

@@ -241,7 +241,10 @@ class SystemConnector:
         base = _strings_table(
             "jit_cache",
             [("site", [r["site"] for r in recs]),
-             ("fingerprint", [r["fingerprint"] for r in recs])])
+             ("fingerprint", [r["fingerprint"] for r in recs]),
+             # what keyed the entry's last compile: "shape" (new array
+             # shapes at the site) or "literal" (only statics differ)
+             ("key_kind", [r.get("key", "") for r in recs])])
         compiles = np.array([r["compiles"] for r in recs],
                             dtype=np.int64)
         hits = np.array([r["hits"] for r in recs], dtype=np.int64)

@@ -30,6 +30,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..metrics import TASK_OUTPUT_BYTES, TASK_OUTPUT_ROWS
+from ..utils import tracing
 from ..utils.tracing import NOOP, Tracer
 
 log = logging.getLogger("trino_tpu.tasks")
@@ -662,8 +663,92 @@ class TaskManager:
                 task.spans = tracer.export()
         self._executor.flush_metrics()
 
+    def _run_splits(self, task: WorkerTask, ex, root, driver_scan,
+                    cap: int, lap, names: Optional[Dict[int, str]],
+                    op_agg: Dict[str, list], live_prev: tuple) -> tuple:
+        """The split loop of one task. Five spans a split (`lap`,
+        utils/tracing.py), each starting where the last one ended, so
+        every moment of the loop has a name; benchmark/layers/
+        split_*_ms.py read them. `names` is set when the fragment is
+        profiled (fenced)."""
+        from ..batch import batch_from_numpy, batch_to_numpy
+        for si, split in enumerate(task.splits):
+            lap("split-read", index=si, rows=split.count)
+            if task.state in ("CANCELED", "ABANDONED"):
+                break
+            if task.deadline is not None and \
+                    time.monotonic() > task.deadline:
+                from ..exec.executor import QueryDeadlineError
+                raise QueryDeadlineError(
+                    "task deadline exceeded (query_max_run_time_s)")
+            if self.injector is not None:
+                # chaos mid-split: CRASH kills the executor with work
+                # half-done (partial pages already buffered — the
+                # coordinator's all-or-nothing drain discards them),
+                # DELAY makes this worker a straggler (hedge-mitigation
+                # target)
+                self.injector.maybe_fail("WORKER_TASK_RUN",
+                                         f"{task.task_id}:{si}")
+            data = self.catalog.get_table(
+                split.catalog, split.schema_name, split.table)
+            arrays = [np.asarray(data.columns[i])
+                      [split.start:split.start + split.count]
+                      for i in driver_scan.column_indices]
+            valids = None
+            if data.valids is not None:
+                valids = [None if data.valids[i] is None else
+                          np.asarray(data.valids[i])
+                          [split.start:split.start + split.count]
+                          for i in driver_scan.column_indices]
+            sp = lap("split-put", index=si)
+            if sp is not None:
+                sp.attributes["bytes"] = sum(a.nbytes for a in arrays)
+            chunk = batch_from_numpy(arrays, valids=valids, capacity=cap)
+            ex._subst[id(driver_scan)] = chunk
+            ex._subst_opaque.add(id(driver_scan))
+            sp_t0 = time.monotonic()
+            lap("split", index=si, rows=split.count)
+            try:
+                out = ex.run(root)
+            finally:
+                ex._subst.pop(id(driver_scan), None)
+                ex._subst_opaque.discard(id(driver_scan))
+                # per-split outputs die here; pinned builds keep their
+                # reservations until task end
+                ex.release_path_reservations(root, keep=ex._subst)
+            sp = lap("split-fetch", index=si)
+            if names is not None:
+                self._fold_node_stats(ex, names, op_agg)
+            arrs, vals = batch_to_numpy(out)
+            if sp is not None:
+                sp.attributes["rows"] = len(arrs[0]) if arrs else 0
+            sp = lap("split-emit", index=si)
+            bytes0 = task.bytes_out
+            self._emit(task, arrs, vals)
+            if sp is not None:
+                sp.attributes["bytes"] = task.bytes_out - bytes0
+            # live tier attribution: fenced device/host/compile deltas
+            # when profiling; unprofiled splits ride entirely in host
+            # (the round-10 convention), so the live so-far numbers
+            # match what _finalize_stats will report
+            sp_wall_ms = (time.monotonic() - sp_t0) * 1000
+            d_dev, d_host, d_comp = 0.0, sp_wall_ms, 0.0
+            if names is not None:
+                tot = self._live_totals(op_agg)
+                d_dev = max(0.0, tot[0] - live_prev[0])
+                d_host = max(0.0, tot[1] - live_prev[1])
+                d_comp = max(0.0, tot[2] - live_prev[2])
+                live_prev = tot
+            with task.lock:
+                task.splits_done += 1
+                task.device_ms += d_dev
+                task.host_ms += d_host
+                task.compile_ms += d_comp
+            self._note_live_change(task)
+            self._note_busy(d_dev, max(0.0, sp_wall_ms - d_dev))
+        return live_prev
+
     def _run(self, task: WorkerTask) -> None:
-        from ..batch import batch_from_numpy, batch_to_numpy, bucket_capacity
         with task.lock:
             if task.state != "PENDING":   # canceled before the thread ran
                 return
@@ -672,6 +757,13 @@ class TaskManager:
         self._note_live_change(task)
         self.tasks_run += 1
         tracer = self._tracer_for(task)
+        # the task's thread carries its tracer: the compile recorder
+        # (exec/profiler.py) finds it with tracing.current()
+        with tracing.use(tracer):
+            self._run_traced(task, tracer)
+
+    def _run_traced(self, task: WorkerTask, tracer: Tracer) -> None:
+        from ..batch import bucket_capacity
         t_start = time.monotonic()
         op_agg: Dict[str, list] = {}
         try:
@@ -689,14 +781,18 @@ class TaskManager:
                     if task.state == "RUNNING":
                         task.state = "FINISHED"
                 return
-            fragment = decode_fragment(task.fragment_blob)
+            with tracer.span("task-decode",
+                             bytes=len(task.fragment_blob)):
+                # broadcast builds ride inside the fragment
+                fragment = decode_fragment(task.fragment_blob)
             root, driver_scan = fragment["root"], fragment["driver"]
             cap = bucket_capacity(max(s.count for s in task.splits)) \
                 if task.splits else 1024
-            # per-operator profiling: on for traced tasks AND for
-            # fragments flagged by the coordinator (EXPLAIN ANALYZE) —
-            # pays a per-node device sync for true operator times
-            profiling = tracer.enabled or bool(fragment.get("profile"))
+            # per-operator profiling: on for fragments the coordinator
+            # flagged (EXPLAIN ANALYZE, `enable_profiling`) — pays a
+            # per-node device sync for true operator times. Tracing
+            # alone does not fence: its spans cost what spans cost
+            profiling = bool(fragment.get("profile"))
             names = {id(n): type(n).__name__ for n in
                      _subtree_nodes_all(root)} if profiling else {}
             # The executor (and its _subst/pool state) is shared by every
@@ -741,77 +837,11 @@ class TaskManager:
                     # probes it without a row-count fetch, instead of
                     # re-scattering a domain-sized LUT in every split
                     ex.enter_chunk_mode()
-                    for si, split in enumerate(task.splits):
-                        if task.state in ("CANCELED", "ABANDONED"):
-                            return
-                        if task.deadline is not None and \
-                                time.monotonic() > task.deadline:
-                            from ..exec.executor import QueryDeadlineError
-                            raise QueryDeadlineError(
-                                "task deadline exceeded "
-                                "(query_max_run_time_s)")
-                        if self.injector is not None:
-                            # chaos mid-split: CRASH kills the executor
-                            # with work half-done (partial pages already
-                            # buffered — the coordinator's all-or-nothing
-                            # drain discards them), DELAY makes this
-                            # worker a straggler (hedge-mitigation target)
-                            self.injector.maybe_fail(
-                                "WORKER_TASK_RUN",
-                                f"{task.task_id}:{si}")
-                        data = self.catalog.get_table(
-                            split.catalog, split.schema_name, split.table)
-                        arrays = [np.asarray(data.columns[i])
-                                  [split.start:split.start + split.count]
-                                  for i in driver_scan.column_indices]
-                        valids = None
-                        if data.valids is not None:
-                            valids = [
-                                None if data.valids[i] is None else
-                                np.asarray(data.valids[i])
-                                [split.start:split.start + split.count]
-                                for i in driver_scan.column_indices]
-                        chunk = batch_from_numpy(arrays, valids=valids,
-                                                 capacity=cap)
-                        ex._subst[id(driver_scan)] = chunk
-                        ex._subst_opaque.add(id(driver_scan))
-                        sp_t0 = time.monotonic()
-                        try:
-                            with tracer.span("split", index=si,
-                                             rows=split.count):
-                                out = ex.run(root)
-                        finally:
-                            ex._subst.pop(id(driver_scan), None)
-                            ex._subst_opaque.discard(id(driver_scan))
-                            # per-split outputs die here; pinned builds
-                            # keep their reservations until task end
-                            ex.release_path_reservations(
-                                root, keep=ex._subst)
-                        if profiling:
-                            self._fold_node_stats(ex, names, op_agg)
-                        arrs, vals = batch_to_numpy(out)
-                        self._emit(task, arrs, vals)
-                        # live tier attribution: fenced device/host/
-                        # compile deltas when profiling; unprofiled
-                        # splits ride entirely in host (the round-10
-                        # convention), so the live so-far numbers match
-                        # what _finalize_stats will report
-                        sp_wall_ms = (time.monotonic() - sp_t0) * 1000
-                        d_dev, d_host, d_comp = 0.0, sp_wall_ms, 0.0
-                        if profiling:
-                            tot = self._live_totals(op_agg)
-                            d_dev = max(0.0, tot[0] - live_prev[0])
-                            d_host = max(0.0, tot[1] - live_prev[1])
-                            d_comp = max(0.0, tot[2] - live_prev[2])
-                            live_prev = tot
-                        with task.lock:
-                            task.splits_done += 1
-                            task.device_ms += d_dev
-                            task.host_ms += d_host
-                            task.compile_ms += d_comp
-                        self._note_live_change(task)
-                        self._note_busy(
-                            d_dev, max(0.0, sp_wall_ms - d_dev))
+                    with tracer.laps() as lap:
+                        live_prev = self._run_splits(
+                            task, ex, root, driver_scan, cap, lap,
+                            names if profiling else None, op_agg,
+                            live_prev)
                 finally:
                     ex.exit_chunk_mode()
                     ex.profile = saved_profile

@@ -15,6 +15,15 @@ POST, task create, exchange pulls, spooled-segment gets), and remote spans
 adopted back into the originating tracer so the coordinator can serve the
 stitched query trace as OTLP-like JSON. Disabled tracers are zero-overhead
 no-ops.
+
+The tracer travels with the query, not with a shared object: the
+dispatcher (and a worker task) activates one per thread with `use()`,
+and whatever runs below finds it with `current()` — the scheduler, the
+session, the compile recorder. A thread the query spawns carries it on
+with `use(tracer, parent=span_id)`. While a tracer is enabled every live
+span is also a `jax.profiler.TraceAnnotation("tt:<name>")`, so a JAX
+profiler trace taken meanwhile shows the program's spans on the
+profiler's own clock, above the device's operations.
 """
 
 from __future__ import annotations
@@ -22,11 +31,14 @@ from __future__ import annotations
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 _ROOT_SPAN_ID = "0" * 16
+# profiler annotations of the program's spans; `bench:` belongs to the
+# benchmark's anchors (benchmark/trace_reduce.py)
+ANNOTATION_PREFIX = "tt:"
 
 
 def new_trace_id() -> str:
@@ -122,36 +134,114 @@ class Tracer:
             self._local.stack = []
         return self._local.stack
 
+    def current_span(self) -> Optional[Span]:
+        """The innermost span open on this thread, None when there is
+        none or tracing is off."""
+        if not self.enabled:
+            return None
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def _context_parent(self) -> Optional[str]:
+        """Parent for a span opened now on this thread: the innermost
+        open span, else the span `use(parent=...)` named for this
+        thread, else the adopted remote parent."""
+        stack = self._stack()
+        if stack:
+            return stack[-1].span_id
+        return getattr(self._local, "base", None) or self.remote_parent
+
     def traceparent(self) -> Optional[str]:
         """Header value for the CURRENT context (innermost open span on
         this thread, else the adopted remote parent). None when tracing
         is off, so callers can skip the header entirely."""
         if not self.enabled:
             return None
-        stack = self._stack()
-        sid = stack[-1].span_id if stack else \
-            (self.remote_parent or _ROOT_SPAN_ID)
-        return format_traceparent(self.trace_id, sid)
+        return format_traceparent(
+            self.trace_id, self._context_parent() or _ROOT_SPAN_ID)
 
     @contextmanager
-    def span(self, name: str, **attributes):
+    def span(self, name: str, parent: Optional[str] = None, **attributes):
+        """A live span. `parent` (a span id) overrides the thread's
+        context: a helper thread names the span it works for."""
         if not self.enabled:
             yield None
             return
-        stack = self._stack()
-        parent = stack[-1].span_id if stack else self.remote_parent
-        s = Span(name, time.monotonic(), attributes=dict(attributes),
+        s = Span(name, time.monotonic(), attributes=attributes,
                  trace_id=self.trace_id, span_id=new_span_id(),
-                 parent_id=parent, service=self.service,
-                 start_unix=time.time())
+                 parent_id=parent or self._context_parent(),
+                 service=self.service, start_unix=time.time())
+        stack = self._stack()
         stack.append(s)
         try:
-            yield s
+            with _annotation(name):
+                yield s
         finally:
             s.end = time.monotonic()
             stack.pop()
             with self._lock:
                 self.spans.append(s)
+
+    def record(self, name: str, start: float, end: float,
+               parent: Optional[str] = None, **attributes) -> None:
+        """A span known only after the fact (a call turned out to have
+        compiled): `start` and `end` are `time.monotonic()` readings."""
+        if not self.enabled:
+            return
+        s = Span(name, start, end, attributes=attributes,
+                 trace_id=self.trace_id, span_id=new_span_id(),
+                 parent_id=parent or self._context_parent(),
+                 service=self.service,
+                 start_unix=time.time() - (time.monotonic() - start))
+        with self._lock:
+            self.spans.append(s)
+
+    @contextmanager
+    def laps(self):
+        """Back-to-back spans for the phases of a loop: yields
+        `lap(name, **attributes)`, which closes the phase before and
+        opens the next at the same instant, so no moment between two
+        phases is left without a name (a sibling `with span()` pair
+        leaves a sliver, and whatever thread takes the GIL there
+        stretches it). The open phase is the thread's innermost span
+        like any other; leaving the block closes it."""
+        if not self.enabled:
+            yield lambda name, **attributes: None
+            return
+        stack = self._stack()
+        parent = self._context_parent()
+        unix0, mono0 = time.time(), time.monotonic()
+        current = None                 # (span, its annotation)
+
+        def close(now: float) -> None:
+            nonlocal current
+            if current is not None:
+                s, note = current
+                note.__exit__(None, None, None)
+                s.end = now
+                stack.remove(s)
+                with self._lock:
+                    self.spans.append(s)
+                current = None
+
+        def lap(name: str, **attributes) -> Span:
+            nonlocal current
+            now = time.monotonic()
+            close(now)
+            s = Span(name, now, attributes=attributes,
+                     trace_id=self.trace_id, span_id=new_span_id(),
+                     parent_id=parent, service=self.service,
+                     start_unix=unix0 + (now - mono0))
+            stack.append(s)
+            note = _annotation(name)
+            note.__enter__()
+            current = (s, note)
+            return s
+
+        try:
+            yield lap
+        finally:
+            close(time.monotonic())
 
     def adopt(self, span_dicts, offset_s: float = 0.0) -> None:
         """Merge remote spans (exported dicts shipped back in task
@@ -189,3 +279,47 @@ class Tracer:
 
 
 NOOP = Tracer(enabled=False)
+
+
+def _annotation(name: str):
+    """The span's twin on the JAX profiler's clock; costs a flag test
+    while no profile is being taken. Never built for a disabled tracer
+    (`span()` returns before it gets here)."""
+    try:
+        import jax.profiler
+        return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    except ImportError:          # spans work without jax
+        return nullcontext()
+
+
+# -- the carrier: one tracer per query, found by whatever runs below -------
+
+_active = threading.local()
+
+
+def carried() -> Optional[Tracer]:
+    """The tracer `use()` activated on this thread, NOOP included; None
+    outside any `use()` (a bare Session then keeps its own)."""
+    return getattr(_active, "tracer", None)
+
+
+def current() -> Tracer:
+    """This thread's active tracer; NOOP by default."""
+    return getattr(_active, "tracer", None) or NOOP
+
+
+@contextmanager
+def use(tracer: Tracer, parent: Optional[str] = None):
+    """Activate `tracer` on this thread. On a thread the query spawned,
+    `parent` is the span id its top-level spans hang under (the spawner
+    reads it from the span it has open)."""
+    prev = getattr(_active, "tracer", None)
+    prev_base = getattr(tracer._local, "base", None)
+    _active.tracer = tracer
+    if parent is not None:
+        tracer._local.base = parent
+    try:
+        yield tracer
+    finally:
+        _active.tracer = prev
+        tracer._local.base = prev_base
