@@ -9,11 +9,13 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from trino_tpu.client.client import Client
 from trino_tpu.exec.profiler import RECORDER, CompileRecorder, instrument
 from trino_tpu.exec.session import Session
+from trino_tpu.planner import logical as L
 from trino_tpu.server.coordinator import CoordinatorServer
 from trino_tpu.server.worker import WorkerServer
 from trino_tpu.utils import tracing
@@ -412,6 +414,128 @@ def test_tracing_alone_does_not_fence(cluster):
     task = next(s for s in spans if s["name"] == "worker-task")
     assert task["attributes"]["deviceMs"] >= 0
     assert "hostMs" in task["attributes"]
+
+
+# ---------------------------------------------------------------------------
+# the broadcast build's hand-over: once a task, at a lattice capacity
+# ---------------------------------------------------------------------------
+
+JOIN_SITES = ("join.dense_build_lut", "join.dense_join_with_lut")
+
+
+def orders_join(before: str) -> str:
+    # `orders` (15,000 rows at tiny) is over the fixture's split_rows, so
+    # its filtered output is a build stage of its own and reaches the
+    # lineitem stage's tasks as a ValuesNode inside the fragment
+    return ("SELECT o_orderpriority, count(*) AS n, "
+            "sum(l_extendedprice) AS revenue "
+            "FROM lineitem JOIN orders ON l_orderkey = o_orderkey "
+            f"WHERE o_orderdate < DATE '{before}' "
+            "GROUP BY o_orderpriority ORDER BY o_orderpriority")
+
+
+def _join_task(spans):
+    """(worker-task, pin-builds) of the stage that probes the build."""
+    tasks = {s["spanId"]: s for s in spans if s["name"] == "worker-task"}
+    pin = max((s for s in spans if s["name"] == "pin-builds"),
+              key=lambda s: s["attributes"]["builds"])
+    return tasks[pin["parentSpanId"]], pin
+
+
+def test_builds_of_one_lattice_point_share_the_join_programs(cluster):
+    coord, worker, session = cluster
+    # 4,653 and 6,000 build rows: 5,120 and 6,144 in multiples of 1,024,
+    # both 6,144 on the lattice
+    spans, _, _ = _traced(coord, orders_join("1994-01-07"))
+    _, pin = _join_task(spans)
+    first_rows = pin["attributes"]["rows"]
+    assert pin["attributes"]["capacity"] == 6144
+    shapes = RECORDER.site_shape_counts()
+    assert all(shapes.get(site, 0) >= 1 for site in JOIN_SITES), shapes
+    spans, before, after = _traced(coord, orders_join("1994-08-19"))
+    _, pin = _join_task(spans)
+    assert pin["attributes"]["rows"] - first_rows > 1024
+    assert pin["attributes"] == {"builds": 1, "capacity": 6144,
+                                 "rows": pin["attributes"]["rows"]}
+    again = RECORDER.site_shape_counts()
+    assert {s: again[s] for s in JOIN_SITES} == \
+        {s: shapes[s] for s in JOIN_SITES}
+    assert after["shapeKeyedCompiles"] == before["shapeKeyedCompiles"]
+    assert not [s for s in spans if s["name"] == "compile"
+                and s["attributes"]["key"] == "shape"]
+
+
+def test_a_task_puts_its_build_once_and_every_split_joins(cluster):
+    coord, worker, session = cluster
+    sql = orders_join("1995-06-17")
+    spans, _, _ = _traced(coord, sql)
+    task, pin = _join_task(spans)
+    assert task["attributes"]["splits"] == 8
+    assert task["attributes"]["valuePuts"] == 1
+    assert pin["attributes"]["builds"] == 1
+    # the stage that made the build has none of its own to put
+    other = [s for s in spans if s["name"] == "worker-task"
+             and s is not task]
+    assert other and all(s["attributes"]["valuePuts"] == 0 for s in other)
+    # the pinned build's reservation went with the task
+    assert worker.task_manager.memory_info()["reserved"] == 0
+    # every split probed the pinned build: the single-node answer
+    coord.state.scheduler.spool.clear()
+    got = Client(coord.uri, user="phases").execute(sql).rows
+    want = Session(default_schema="tiny").execute(sql).rows
+    assert [[str(c) for c in r] for r in got] == \
+        [[str(c) for c in r] for r in want]
+    assert sum(r[1] for r in want) > 0
+
+
+@pytest.mark.parametrize("rows", [0, 1, 1024, 1025, 5000, 6000, 1_483_000])
+def test_run_values_puts_at_a_lattice_capacity(rows):
+    from trino_tpu.batch import bucket_capacity
+    ex = Session(default_schema="tiny").executor
+    zero_column = rows > 100_000        # a live mask only: cheap at any size
+    if zero_column:
+        node = L.ValuesNode((), (), rows, (), ())
+    else:
+        node = L.ValuesNode((np.arange(rows, dtype=np.int64),),
+                            (np.ones(rows, dtype=np.bool_),), rows, (), ())
+    puts = ex.stats.value_puts
+    batch = ex.run_values(node)
+    assert ex.stats.value_puts == puts + 1
+    cap = batch.capacity
+    assert cap >= rows and cap == bucket_capacity(rows)
+    # at most a third of the capacity is padding above 1,024 rows
+    assert cap == 1024 if rows <= 1024 else 2 * cap < 3 * rows
+    # on the lattice {2^k, 1.5 * 2^k}
+    assert cap & (cap - 1) == 0 or (cap // 3) & (cap // 3 - 1) == 0
+    live = np.asarray(batch.live)
+    assert live[:rows].all() and not live[rows:].any()
+    if not zero_column:
+        col = batch.columns[0]
+        assert col.data.shape == (cap,)
+        assert np.array_equal(np.asarray(col.data)[:rows], node.arrays[0])
+        assert not np.asarray(col.valid)[rows:].any()
+
+
+def test_static_subtrees_pin_a_values_build_and_not_a_scan():
+    from trino_tpu.server.tasks import _static_subtrees
+
+    def scan(table):
+        return L.ScanNode("tpch", "tiny", table, None, (0,), ())
+
+    def join(probe, build):
+        return L.JoinNode("inner", probe, build, (0,), (0,), None, True, ())
+
+    driver = scan("lineitem")
+    values = L.ValuesNode((np.arange(3),), (np.ones(3, dtype=bool),), 3,
+                          (), ())
+    filtered = L.FilterNode(scan("customer"), None, ())
+    bare = scan("orders")
+    root = join(join(join(L.FilterNode(driver, None, ()), values),
+                     filtered), bare)
+    pinned = _static_subtrees(root, driver)
+    assert sorted(map(id, pinned)) == sorted(map(id, (values, filtered)))
+    # no driver in the fragment: nothing is constant "across splits"
+    assert _static_subtrees(join(values, bare), driver) == []
 
 
 def test_single_node_route_writes_plan_spans():

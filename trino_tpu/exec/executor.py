@@ -53,6 +53,8 @@ class ExecStats:
     mxu_agg_calls: int = 0
     fact_cache_chunks: int = 0       # chunks sliced from device-resident
     chunk_lut_joins: int = 0         # sync-free reused-LUT probes
+    value_puts: int = 0              # ValuesNodes put on the device
+                                     # (run_values calls)
     fused_chunk_pipelines: int = 0   # whole-chunk-path single programs
     pallas_gather_calls: int = 0     # probe sites dispatched with the
                                      # tiled-gather kernel enabled
@@ -813,11 +815,17 @@ class Executor:
         return batch_from_numpy(out_arrays, valids=out_valids)
 
     def run_values(self, node: L.ValuesNode) -> Batch:
+        # a materialised broadcast build arrives as a ValuesNode with a
+        # data-dependent row count, and its Batch is an argument of the
+        # join programs: on the capacity lattice every row count of one
+        # bucket shares their compiled forms (1,024 up to 1,024 rows)
+        self.stats.value_puts += 1
+        cap = bucket_capacity(node.num_rows)
         if node.arrays:
             return batch_from_numpy(list(node.arrays),
-                                    valids=list(node.valids))
+                                    valids=list(node.valids),
+                                    capacity=cap)
         # zero-column values (SELECT without FROM): live mask only
-        cap = pad_capacity(node.num_rows)
         live = np.zeros(cap, dtype=np.bool_)
         live[:node.num_rows] = True
         return Batch(columns=(), live=jnp.asarray(live))

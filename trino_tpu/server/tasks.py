@@ -113,8 +113,11 @@ def _subtree_nodes_all(root):
 
 def _static_subtrees(root, driver) -> list:
     """Maximal subtrees of `root` that do not contain the driver scan —
-    join build sides and friends, constant across splits. Bare scans and
-    values leaves are excluded (the scan cache already memoizes them)."""
+    join build sides and friends, constant across splits. A bare scan is
+    left out: the scan cache already serves it from the device. A bare
+    values leaf is NOT left out: nothing memoizes `run_values`, and a
+    materialised broadcast build is exactly that, a ValuesNode of a
+    million rows that every split would put on the device again."""
     from ..planner import logical as L
     memo = {}
 
@@ -131,12 +134,24 @@ def _static_subtrees(root, driver) -> list:
         for c in L.children(n):
             if contains(c):
                 walk(c)
-            elif not isinstance(c, (L.ScanNode, L.ValuesNode)):
+            elif not isinstance(c, L.ScanNode):
                 out.append(c)
 
     if contains(root):
         walk(root)
     return out
+
+
+def _pinned_attrs(pinned) -> dict:
+    """`pin-builds` span attributes: how many subtrees the task pinned,
+    and the live rows and capacity of the largest (the broadcast build
+    whose capacity keys the join programs)."""
+    attrs = {"builds": len(pinned)}
+    if pinned:
+        largest = max(pinned, key=lambda b: b.capacity)
+        attrs["capacity"] = largest.capacity
+        attrs["rows"] = int(np.asarray(largest.live).sum())
+    return attrs
 
 
 @dataclass(frozen=True)
@@ -816,6 +831,7 @@ class TaskManager:
                 ex._cancel_reason = None
                 ex.deadline = task.deadline
                 self._current_task_id = task.task_id
+                puts0 = ex.stats.value_puts
                 saved_profile = ex.profile
                 saved_node_stats = ex.node_stats
                 if profiling:
@@ -825,9 +841,13 @@ class TaskManager:
                     # pin maximal driver-free subtrees ONCE per task (join
                     # build sides, HashBuilderOperator's build-once-probe-
                     # many): else every split re-executes every build join
-                    with tracer.span("pin-builds"):
+                    with tracer.span("pin-builds") as pspan:
                         for sub in _static_subtrees(root, driver_scan):
                             ex._subst[id(sub)] = ex.run(sub)
+                        if pspan is not None:
+                            # _subst was cleared above: the pinned builds
+                            pspan.attributes.update(
+                                _pinned_attrs(list(ex._subst.values())))
                     if profiling:
                         self._fold_node_stats(ex, names, op_agg)
                     live_prev = self._live_totals(op_agg)
@@ -854,6 +874,11 @@ class TaskManager:
                     for b in ex._node_bytes.values():
                         ex.pool.free(b)
                     ex._node_bytes.clear()
+                    if wspan is not None:
+                        # 1 a pinned values build; `splits` times that
+                        # if a split ever puts its build again
+                        wspan.attributes["valuePuts"] = \
+                            ex.stats.value_puts - puts0
                     if wspan is not None and op_agg:
                         # fenced split totals ride the worker-task span
                         # so the stitched trace carries device time, not
