@@ -569,10 +569,10 @@ def run_four_chips(schema: str) -> None:
         mesh_rows = on_mesh.result()
         # every device holds a shard of the scanned fact table (code that
         # has only met virtual devices may put everything on the first)
-        scanned = [b for key, b in ex._scan_cache.items()
-                   if key[2] == "lineitem"]
-        assert scanned, "the mesh executor kept no scanned lineitem batch"
-        shards = scanned[0].live.addressable_shards
+        scanned = [ex.resident.get(key)[1] for key in ex.resident.keys()
+                   if key[3] == "lineitem" and key[4] is None]
+        assert scanned, "the mesh executor kept no scanned lineitem column"
+        shards = scanned[0].addressable_shards
         holders = {s.device for s in shards}
         rows = [s.data.shape[0] for s in shards]
         assert holders == set(jax.devices()) and min(rows) > 0, \
@@ -584,8 +584,9 @@ def run_four_chips(schema: str) -> None:
     check_rows("q3 one device", one_rows, want, (2,))
     assert mesh_rows == one_rows, \
         "q3 over four devices differs from q3 on one device"
-    one_dev = {d for b in one.executor._scan_cache.values()
-               for d in b.live.devices()}
+    one_dev = {d for key in one.executor.resident.keys()
+               if key[0] == "column" and key[4] is None
+               for d in one.executor.resident.get(key)[1].devices()}
     assert len(one_dev) == 1, f"one-device session used {one_dev}"
     say(f"one-device session scanned onto {sorted(str(d) for d in one_dev)}"
         f"; mesh = one device = numpy on {len(want)} rows")

@@ -108,11 +108,13 @@ def test_run_scan_pads_odd_capacity_to_shard_multiple():
     ref_count = Session(default_schema="tiny").execute(
         "SELECT count(*) FROM lineitem").rows
     assert s.execute("SELECT count(*) FROM lineitem").rows == ref_count
-    # the cached scan batch must be an exact shard multiple and actually
+    # the resident copy must be an exact shard multiple and actually
     # laid out across all 6 devices
-    (batch,) = [b for b in s.executor._scan_cache.values()]
-    assert batch.capacity % 6 == 0
-    assert len(batch.live.sharding.device_set) == 6
+    resident = s.executor.resident
+    (live,) = [resident.get(k)[1] for k in resident.keys()
+               if k[3] == "lineitem" and k[4] is None]
+    assert live.shape[0] % 6 == 0
+    assert len(live.sharding.device_set) == 6
 
 
 def test_q77_completes_on_mesh_with_filtering_on():

@@ -164,16 +164,17 @@ def test_query_deadline_enforced():
 def test_scan_cache_lru_eviction():
     s = Session(default_schema="tiny")
     s.execute("SET SESSION scan_cache_max_mb = 0")
+    resident = s.executor.resident
     for t in ("nation", "region", "supplier", "customer", "orders"):
         s.execute(f"SELECT count(*) FROM {t}")
-        # a zero budget keeps at most the current table resident
-        assert len(s.executor._scan_cache) <= 1
-    # results stay correct with continuous eviction
+        # a zero budget keeps nothing resident
+        assert len(resident) == 0 and resident.total_bytes() == 0
+    # results stay correct with nothing kept
     assert s.execute("SELECT count(*) FROM nation").rows[0][0] == 25
     s.execute("SET SESSION scan_cache_max_mb = 1024")
     s.execute("SELECT count(*) FROM nation")
     s.execute("SELECT count(*) FROM region")
-    assert len(s.executor._scan_cache) == 2
+    assert {k[3] for k in resident.keys()} == {"nation", "region"}
 
 
 def test_dynamic_filtering_toggle():

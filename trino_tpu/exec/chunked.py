@@ -801,12 +801,8 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
         if executor.fact_cache.estimate_bytes(
                 data, plan.driver.column_indices) <= \
                 executor.fact_cache.max_bytes:
-            if executor.fact_cache.get(key) is None:
-                # about to claim several GB of HBM: raw cached scans are
-                # dead weight now (the pinned builds already consumed
-                # them) — drop them first, NOT the fact cache itself
-                executor._scan_cache.clear()
-                executor._scan_cache_bytes.clear()
+            # the load makes its room in the one resident set: the
+            # least recently used scanned columns go first
             fact = executor.fact_cache.load(
                 key, data, plan.driver.column_indices,
                 persist_ok=plan.driver.catalog in ("tpch", "tpcds",

@@ -1,4 +1,6 @@
-"""Device-resident fact-column cache with range-compressed dtypes.
+"""What stays on the device across statements: the one resident set
+(scanned table columns, fact tables, pinned builds under one budget),
+and the fact-column cache with range-compressed dtypes.
 
 Reference role: Trino's memory-pinned page cache / the Hive split cache
 keep hot table pages in RAM near the workers; the columnar formats
@@ -20,9 +22,123 @@ from the resident arrays.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional
 
 import numpy as np
+
+
+# budget of a backend that reports no allocator limit (the CPU): finite,
+# so a long-lived process stays bounded there too
+HOST_RESIDENT_BYTES = 8 << 30
+
+
+def default_resident_bytes() -> int:
+    """Half of the device's own `memory_stats()["bytes_limit"]`: the
+    other half is a statement's (its projected columns, join LUTs, sort
+    operands — TPC-H q18 at SF10 peaked 8.6 GB above its tables on a
+    16 GB v5e, q3 2.2 GB)."""
+    from .profiler import device_memory_stats
+    limit = device_memory_stats().get("bytesLimit") or 0
+    return limit // 2 if limit else HOST_RESIDENT_BYTES
+
+
+class ResidentSet:
+    """Everything an executor keeps on the device from one statement to
+    the next — scanned table columns, the chunked driver's narrowed
+    fact tables and its pinned builds — under ONE byte budget and one
+    LRU order. Keys say what an entry IS (table and column, table
+    version by the identity of the connector's TableData held in the
+    value), never which statement asked for it.
+
+    `max_bytes` None = `default_resident_bytes()`, resolved at first
+    use (asking the device initialises the backend). An entry larger
+    than the whole budget is not kept: accounted bytes never exceed it.
+    Eviction drops the set's references; the buffers go back to the
+    allocator as soon as no running statement holds them either.
+    """
+
+    def __init__(self, max_bytes: Optional[int] = None):
+        self._max_bytes = max_bytes
+        # key -> (value, nbytes, on_evict)
+        self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self._total = 0
+        # cumulative, for the `evict` span of whoever caused them
+        self.evicted_entries = 0
+        self.evicted_bytes = 0
+
+    @property
+    def max_bytes(self) -> int:
+        if self._max_bytes is None:
+            self._max_bytes = default_resident_bytes()
+        return self._max_bytes
+
+    @max_bytes.setter
+    def max_bytes(self, value: Optional[int]) -> None:
+        self._max_bytes = value
+        if value is not None:
+            self._evict_to(value)
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def keys(self) -> list:
+        return list(self._entries)
+
+    def total_bytes(self) -> int:
+        return self._total
+
+    def get(self, key):
+        hit = self._entries.get(key)
+        if hit is None:
+            return None
+        self._entries.move_to_end(key)
+        return hit[0]
+
+    def put(self, key, value, nbytes: int, on_evict=None) -> bool:
+        """Keep `value`, evicting least-recently-used entries to fit.
+        False (and nothing kept under `key`) when it exceeds the whole
+        budget."""
+        self.pop(key)
+        if nbytes > self.max_bytes:
+            return False
+        self._evict_to(self.max_bytes - nbytes)
+        self._entries[key] = (value, nbytes, on_evict)
+        self._total += nbytes
+        return True
+
+    def pop(self, key) -> int:
+        """Drop one entry (not an eviction); returns its bytes."""
+        hit = self._entries.pop(key, None)
+        if hit is None:
+            return 0
+        self._total -= hit[1]
+        if hit[2] is not None:
+            hit[2]()
+        return hit[1]
+
+    def _evict_to(self, target: int) -> None:
+        while self._entries and self._total > target:
+            self.evicted_bytes += self.pop(next(iter(self._entries)))
+            self.evicted_entries += 1
+
+    def evict_kind(self, kind: str, target_bytes: int) -> int:
+        """Evict `kind` entries (a key's first element), eldest first,
+        until `target_bytes` are released; returns the bytes released."""
+        freed = 0
+        for key in [k for k in self._entries if k[0] == kind]:
+            if freed >= target_bytes:
+                break
+            freed += self.pop(key)
+            self.evicted_entries += 1
+        self.evicted_bytes += freed
+        return freed
+
+    def clear(self) -> int:
+        """Drop everything (DML invalidation); returns bytes released."""
+        freed = self._total
+        for key in list(self._entries):
+            self.pop(key)
+        return freed
 
 
 class NarrowColumn:
@@ -137,34 +253,31 @@ def _narrow_dtype(arr: np.ndarray, valid: Optional[np.ndarray]):
 
 
 class FactTableCache:
-    """LRU of device-resident narrowed fact tables, capped by HBM bytes.
+    """Device-resident narrowed fact tables of the chunked driver:
+    entries of the executor's ResidentSet (one budget, one LRU order
+    with the scanned columns), of kind "fact".
 
-    Keys are (catalog, schema, table, column_indices, table_version) so a
-    mutated memory-connector table never aliases a stale resident copy.
+    Keys are (catalog, schema, table, column_indices); every DML drops
+    the whole set, so a mutated memory-connector table never aliases a
+    stale resident copy.
     """
 
-    def __init__(self, max_bytes: int = 9 << 30):
-        self.max_bytes = max_bytes
-        self._entries: "OrderedDict[tuple, Tuple[List[NarrowColumn], int]]" \
-            = OrderedDict()
-        self._bytes: Dict[tuple, int] = {}
+    KIND = "fact"
 
-    def total_bytes(self) -> int:
-        return sum(self._bytes.values())
+    def __init__(self, resident: Optional[ResidentSet] = None):
+        self.resident = resident if resident is not None else ResidentSet()
+
+    @property
+    def max_bytes(self) -> int:
+        return self.resident.max_bytes
 
     def get(self, key) -> Optional[List[NarrowColumn]]:
-        hit = self._entries.get(key)
-        if hit is None:
-            return None
-        self._entries.move_to_end(key)
-        return hit[0]
+        return self.resident.get((self.KIND,) + tuple(key))
 
     def invalidate(self) -> int:
-        """Drop everything (DML invalidation); returns bytes released."""
-        freed = self.total_bytes()
-        self._entries.clear()
-        self._bytes.clear()
-        return freed
+        """Drop every fact table; returns bytes released."""
+        return sum(self.resident.pop(k) for k in self.resident.keys()
+                   if k[0] == self.KIND)
 
     def estimate_bytes(self, data, column_indices) -> int:
         """Cheap upper estimate WITHOUT the min/max pass: assumes int32
@@ -328,9 +441,5 @@ class FactTableCache:
             self._save_narrow_disk(key, to_persist,
                                    self._source_fingerprint(
                                        data, column_indices))
-        while self._entries and self.total_bytes() + total > self.max_bytes:
-            old, _ = self._entries.popitem(last=False)
-            self._bytes.pop(old, None)
-        self._entries[key] = (cols, data.num_rows)
-        self._bytes[key] = total
+        self.resident.put((self.KIND,) + tuple(key), cols, total)
         return cols

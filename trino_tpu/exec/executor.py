@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import math
 import os
+import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -37,6 +38,7 @@ from ..ops.join import (join_expand, join_mark, join_unique_build,
 from ..ops.project import apply_filter, filter_project, project
 from ..ops.sort import limit_batch, sort_batch
 from ..planner import logical as L
+from ..utils import tracing
 
 
 @dataclass
@@ -133,9 +135,15 @@ def _subtree_nodes(node: "L.PlanNode"):
 
 class Executor:
     def __init__(self, catalog: Catalog):
-        from collections import OrderedDict
         self.catalog = catalog
-        self._scan_cache: "OrderedDict[tuple, Batch]" = OrderedDict()
+        # everything this executor keeps on the device across statements
+        # (exec/device_cache.ResidentSet): scanned table columns, the
+        # chunked driver's fact tables and pinned builds — one budget
+        from .device_cache import FactTableCache, ResidentSet
+        self.resident = ResidentSet()
+        # host-to-device bytes of this statement's scans (`scanPutBytes`
+        # on the `execute` span)
+        self.scan_put_bytes = 0
         self._scalar_cache: Dict[object, object] = {}
         self.stats = ExecStats()
         self.profile = False           # EXPLAIN ANALYZE per-node timing
@@ -220,19 +228,9 @@ class Executor:
         # coverage of executor-side worker threads; None outside tests
         self.failure_injector = None
         self.deadline: Optional[float] = None     # time.monotonic() cutoff
-        self.scan_cache_max_bytes = 24 << 30      # LRU cap (device bytes)
-        self._scan_cache_bytes: Dict[tuple, int] = {}
-        # zone-prune verdicts replayed on cache hits so EXPLAIN ANALYZE
-        # still renders the scan line for a cached (pruned) batch
-        self._scan_prune_info: Dict[tuple, str] = {}
         # build sides estimated above this stream chunk-wise through the
         # dense LUT instead of materializing on device (0/None = off)
         self.stream_build_bytes: Optional[int] = None
-        # chunked-mode build results keyed by structural plan hash —
-        # persists across query executions for deterministic sources;
-        # cached batches keep their memory-pool reservation until evicted
-        self._build_cache: Dict[str, Batch] = {}
-        self._build_cache_bytes: Dict[str, int] = {}
         # chunk-mode state: inside the chunked driver loop every host
         # sync stalls the dispatch pipeline, so joins
         # build+validate their dense LUT once per pinned build and then
@@ -247,9 +245,8 @@ class Executor:
         self._lut_cache: Dict[tuple, object] = {}
         # device-resident narrowed fact columns (exec/device_cache.py):
         # steady-state chunked scans slice HBM instead of re-streaming
-        # the host link
-        from .device_cache import FactTableCache
-        self.fact_cache = FactTableCache()
+        # the host link; entries of the one resident set
+        self.fact_cache = FactTableCache(self.resident)
         self.enable_fact_cache = True
         # cross-run DECISION cache: every data-dependent host decision
         # (join dup/oob validation, live counts for compaction capacity,
@@ -279,15 +276,7 @@ class Executor:
         """Revocation callback: evict cached build batches (revocable
         reservations) until the target is met. Evicted builds re-run on
         next use — correctness never depends on the cache."""
-        freed = 0
-        for key in list(self._build_cache):
-            if freed >= target_bytes:
-                break
-            self._build_cache.pop(key, None)
-            b = self._build_cache_bytes.pop(key, 0)
-            self.pool.free_revocable(b, tag="build-cache")
-            freed += b
-        return freed
+        return self.resident.evict_kind("build", target_bytes)
 
     def request_kill(self, reason: str) -> None:
         """Cluster LowMemoryKiller's hook: the next plan-node boundary
@@ -333,28 +322,20 @@ class Executor:
         cached counts would poison replay."""
         return Executor._NoDecisions(self)
 
-    def _scan_key(self, node) -> tuple:
-        """Scan-cache key. The pushed-down predicate participates only
-        when zone-map pruning is on: a pruned batch holds fewer rows than
-        the full table, so it must never be served to a different
-        predicate (or to the same scan with pruning disabled). Subclasses
-        that re-cache a scan (e.g. the mesh executor's sharded placement)
-        must use this same key so they replace the base entry instead of
-        duplicating it."""
-        pruning = node.predicate is not None and self.enable_zone_map_pruning
-        return (node.catalog, node.schema_name, node.table,
-                node.column_indices,
-                repr(node.predicate) if pruning else None)
+    @property
+    def scan_cache_max_bytes(self) -> int:
+        """The resident set's budget (session property
+        `scan_cache_max_mb`; None = derive it from the device)."""
+        return self.resident.max_bytes
+
+    @scan_cache_max_bytes.setter
+    def scan_cache_max_bytes(self, value: Optional[int]) -> None:
+        self.resident.max_bytes = value
 
     def invalidate_scan_cache(self) -> None:
-        """Drop cached scans AND their byte accounting together — clearing
-        only the OrderedDict leaves ghost sizes that permanently shrink the
-        effective LRU budget. Device-resident fact columns alias the same
-        tables, so they drop too."""
-        self._scan_cache.clear()
-        self._scan_cache_bytes.clear()
-        self._scan_prune_info.clear()
-        self.fact_cache.invalidate()
+        """Drop everything resident: scanned columns, the fact tables
+        that alias the same tables, and the builds made from them."""
+        self.resident.clear()
         # decision values never cache for mutable catalogs, but clearing
         # costs nothing and removes any doubt after DML
         self._decision_cache.clear()
@@ -397,6 +378,7 @@ class Executor:
         self._subst.clear()
         self._subst_opaque.clear()
         self._skey_memo.clear()
+        self.scan_put_bytes = 0
         try:
             if self.spill_chunk_rows:
                 from .chunked import execute_chunked
@@ -535,15 +517,18 @@ class Executor:
 
     def build_structure_key(self, node: L.PlanNode) -> Optional[str]:
         """Cross-run cache key for a DETERMINISTIC build subtree: the
-        wire-form hash (serde is canonical), or None when any scan
-        reads a mutable catalog (memory tables change between runs)."""
+        hash of its canonical wire form (`serde.structure_text`: a
+        table's schema, dictionaries and all, enters as a digest made
+        once), or None when any scan reads a mutable catalog (memory
+        tables change between runs)."""
         scans = [s for s in _subtree_scans(node)]
         if any(s.catalog not in ("tpch", "tpcds", "bench")
                for s in scans) or not scans:
             return None
         import hashlib
         from ..server import serde
-        return hashlib.sha256(serde.dumps(node).encode()).hexdigest()
+        return hashlib.sha256(
+            serde.structure_text(node).encode()).hexdigest()
 
     def _decision_salt(self) -> tuple:
         """Session knobs that change runtime decision values for the
@@ -679,28 +664,22 @@ class Executor:
         key = self.build_structure_key(node)
         if key is None:
             return self.run(node)
-        hit = self._build_cache.get(key)
+        hit = self.resident.get(("build", key))
         if hit is not None:
             return hit
         out = self.run(node)
-        if len(self._build_cache) >= 8:      # bounded: drop eldest
-            old = next(iter(self._build_cache))
-            self._build_cache.pop(old)
-            self.pool.free_revocable(
-                self._build_cache_bytes.pop(old, 0), tag="build-cache")
-        # transfer the reservation run() made from the per-query ledger
-        # to the cache's REVOCABLE ledger: the batch outlives the query,
-        # so the pool keeps counting it until eviction — but as spillable
-        # bytes the revocation callback may reclaim under pressure
         from .memory import batch_bytes
-        b = self._node_bytes.pop(id(node), None)
-        if b is not None:
-            self.pool.free(b)
-        else:
-            b = batch_bytes(out)
-        self.pool.reserve_revocable(b, tag="build-cache")
-        self._build_cache[key] = out
-        self._build_cache_bytes[key] = b
+        b = batch_bytes(out)
+        # the key holds the subtree's literals, so the entries are
+        # bounded in bytes by the resident set's budget. A kept batch
+        # outlives the query: its reservation moves from the per-query
+        # ledger to the pool's REVOCABLE one (freed when it is evicted,
+        # or reclaimed under pressure by the revocation callback)
+        if self.resident.put(
+                ("build", key), out, b, on_evict=lambda: \
+                self.pool.free_revocable(b, tag="build-cache")):
+            self.pool.free(self._node_bytes.pop(id(node), 0))
+            self.pool.reserve_revocable(b, tag="build-cache")
         return out
 
     def release_all_reservations(self) -> None:
@@ -914,115 +893,158 @@ class Executor:
             # codes go stale against freshly planned decode scopes)
             data = self.catalog.get_table(node.catalog, node.schema_name,
                                           node.table)
-            arrays = [data.columns[i] for i in node.column_indices]
-            valids = None if data.valids is None else \
-                [data.valids[i] for i in node.column_indices]
             self.stats.scans += 1
             self.stats.rows_scanned += data.num_rows
-            return batch_from_numpy(arrays, valids=valids)
-        pruning = node.predicate is not None and self.enable_zone_map_pruning
-        key = self._scan_key(node)
-        hit = self._scan_cache.get(key)
-        if hit is not None:
-            self._scan_cache.move_to_end(key)     # LRU touch
-            info = self._scan_prune_info.get(key)
-            if info is not None:
-                self.strategy_decisions[f"TableScan[{node.table}]"] = info
-            return hit
-        data = self._scan_table_data(node, pruning)
-        arrays = [data.columns[i] for i in node.column_indices]
-        valids = None
-        if data.valids is not None:
-            valids = [data.valids[i] for i in node.column_indices]
-        if pruning:
-            arrays, valids, kept_rows = self._prune_scan_rows(
-                node, data, arrays, valids)
-        else:
-            kept_rows = data.num_rows
-        batch = batch_from_numpy(arrays, valids=valids)
-        self.stats.scans += 1
-        self.stats.rows_scanned += kept_rows
-        # bounded scan cache: evict least-recently-scanned tables so a
-        # long-lived server's device memory stays flat (the round-2 cache
-        # pinned every table ever scanned)
-        from .memory import batch_bytes
-        b = batch_bytes(batch)
-        total = sum(self._scan_cache_bytes.values())
-        while self._scan_cache and total + b > self.scan_cache_max_bytes:
-            old_key, _ = self._scan_cache.popitem(last=False)
-            total -= self._scan_cache_bytes.pop(old_key, 0)
-        self._scan_cache[key] = batch
-        self._scan_cache_bytes[key] = b
-        if pruning:
-            dec = self.strategy_decisions.get(f"TableScan[{node.table}]")
-            if dec is not None:
-                self._scan_prune_info[key] = dec
+            return self._host_batch(data, node.column_indices)
+        tracer = tracing.current()
+        evicted = (self.resident.evicted_entries,
+                   self.resident.evicted_bytes)
+        with tracer.span("scan") as sp:
+            batch, facts = self._scan_table(node)
+            if sp is not None:
+                fields = node.table_schema.fields
+                sp.attributes.update(
+                    facts, table=node.table, columns=",".join(
+                        fields[i].name for i in node.column_indices))
+        self.scan_put_bytes += facts["putBytes"]
+        entries = self.resident.evicted_entries - evicted[0]
+        if entries and tracer.enabled:
+            # the budget made room during the scan: a sibling of `scan`
+            # under `execute`, stamped where the scan ended
+            now = time.monotonic()
+            tracer.record("evict", now, now, entries=entries,
+                          bytes=self.resident.evicted_bytes - evicted[1])
         return batch
 
-    def _scan_table_data(self, node: L.ScanNode, pruning: bool):
-        """Fetch the table, preferring a connector-side pruned decode
-        (ORC stripe / Parquet row-group skipping) when the scan carries a
-        pushed predicate, the connector supports it, and the full table
-        is not already decoded in its cache. Dictionary-encoded scan
-        columns disqualify the pruned path: a pruned decode rebuilds
-        string pools from surviving rows only, and those codes would
-        not line up with the dictionaries the plan was analyzed against."""
-        if pruning:
-            try:
-                conn = self.catalog.connector(node.catalog)
-            except KeyError:
-                conn = None
-            if conn is not None and \
-                    hasattr(conn, "get_table_pruned") and \
-                    (node.schema_name, node.table) not in \
-                    getattr(conn, "_cache", {}) and \
-                    all(node.table_schema.fields[i].dictionary is None
-                        for i in node.column_indices):
-                from .zonemap import column_ranges
-                ranges = column_ranges(node.predicate, node.column_indices,
-                                       node.table_schema)
-                if ranges:
-                    try:
-                        return conn.get_table_pruned(
-                            node.schema_name, node.table, ranges)
-                    except Exception:
-                        pass      # fall back to the full decode
-        return self.catalog.get_table(node.catalog, node.schema_name,
+    def _scan_table(self, node: L.ScanNode):
+        """The scan's batch and the facts of its `scan` span. The
+        table's columns come from the device copy the resident set keeps
+        (put there now where one is missing); a statement's zone-map
+        verdict only narrows `live`, it never makes a second copy."""
+        pruning = node.predicate is not None and self.enable_zone_map_pruning
+        data = self._pruned_decode(node) if pruning else None
+        self.stats.scans += 1
+        if data is not None:
+            # the connector decoded only the stripes / row groups the
+            # predicate may match: this statement's own rows, not the
+            # table, so nothing of it stays resident
+            batch = self._host_batch(data, node.column_indices)
+            self.stats.rows_scanned += data.num_rows
+            from .memory import batch_bytes
+            return batch, {"resident": "miss", "zonesPruned": 0,
+                           "putBytes": batch_bytes(batch)}
+        data = self.catalog.get_table(node.catalog, node.schema_name,
                                       node.table)
+        table = (node.catalog, node.schema_name, node.table)
+        cap = self._scan_capacity(data.num_rows)
+        live, put = self._resident_column(table, None, data, cap, None)
+        columns = []
+        for i in node.column_indices:
+            col, b = self._resident_column(table, i, data, cap, live)
+            columns.append(col)
+            put += b
+        kept_rows, pruned = data.num_rows, 0
+        if pruning:
+            live, kept_rows, pruned = self._zone_live(node, data, live, cap)
+            put += cap if pruned else 0
+        self.stats.rows_scanned += kept_rows
+        return Batch(columns=tuple(columns), live=live), {
+            "resident": "miss" if put else "hit", "zonesPruned": pruned,
+            "putBytes": put}
 
-    def _prune_scan_rows(self, node: L.ScanNode, data, arrays, valids):
-        """Drop row ranges the pushed predicate provably cannot match
-        (zone-map evaluation); surviving ranges concatenate in order, so
-        the post-residual-filter row stream is identical to the unpruned
-        scan's."""
+    @staticmethod
+    def _host_batch(data, column_indices) -> Batch:
+        """The connector's rows put whole and kept nowhere."""
+        arrays = [data.columns[i] for i in column_indices]
+        valids = None if data.valids is None else \
+            [data.valids[i] for i in column_indices]
+        return batch_from_numpy(arrays, valids=valids)
+
+    def _scan_capacity(self, rows: int) -> int:
+        """Padded capacity of a table's device copy."""
+        return pad_capacity(rows)
+
+    def _place(self, host: np.ndarray):
+        """A host array onto this executor's device(s)."""
+        return jnp.asarray(host)
+
+    def _resident_column(self, table: tuple, index, data, cap: int, live):
+        """-> (table column `index` as a device Column at `cap`, bytes
+        put now). `index` None is the table's live mask (a bare array),
+        which is also the validity mask of every column without NULLs.
+        The entry holds the connector's TableData it was made from: a
+        mutated table is a new TableData (its version), so a stale copy
+        is never served and is replaced under the same key."""
+        key = ("column",) + table + (index,)
+        hit = self.resident.get(key)
+        if hit is not None and hit[0] is data:
+            return hit[1], 0
+        rows = data.num_rows
+
+        def put(host, dtype):
+            padded = np.zeros(cap, dtype=dtype)
+            padded[:rows] = host
+            return self._place(padded)
+
+        if index is None:
+            value, nbytes = put(True, np.bool_), cap
+        else:
+            valid, nbytes = live, 0
+            if data.valids is not None and data.valids[index] is not None:
+                valid, nbytes = put(data.valids[index], np.bool_), cap
+            host = np.asarray(data.columns[index])
+            value = Column(data=put(host, host.dtype), valid=valid)
+            nbytes += cap * host.dtype.itemsize
+        self.resident.put(key, (data, value), nbytes)
+        return value, nbytes
+
+    def _pruned_decode(self, node: L.ScanNode):
+        """A connector-side pruned decode (ORC stripe / Parquet row-group
+        skipping) when the scan carries a pushed predicate, the connector
+        supports it, and the full table is not already decoded in its
+        cache; else None. Dictionary-encoded scan columns disqualify the
+        pruned path: a pruned decode rebuilds string pools from surviving
+        rows only, and those codes would not line up with the
+        dictionaries the plan was analyzed against."""
+        try:
+            conn = self.catalog.connector(node.catalog)
+        except KeyError:
+            return None
+        if not hasattr(conn, "get_table_pruned") or \
+                (node.schema_name, node.table) in \
+                getattr(conn, "_cache", {}) or \
+                any(node.table_schema.fields[i].dictionary is not None
+                    for i in node.column_indices):
+            return None
+        from .zonemap import column_ranges
+        ranges = column_ranges(node.predicate, node.column_indices,
+                               node.table_schema)
+        if not ranges:
+            return None
+        try:
+            return conn.get_table_pruned(node.schema_name, node.table,
+                                         ranges)
+        except Exception:
+            return None      # fall back to the full decode
+
+    def _zone_live(self, node: L.ScanNode, data, live, cap: int):
+        """-> (live mask, rows kept, zones pruned): `live` of the
+        resident copy with the row ranges taken out that the pushed
+        predicate provably cannot match (zone-map evaluation on the
+        host). Rows keep their places, so the post-residual-filter row
+        stream is identical to the unpruned scan's; where no zone is
+        cut the resident mask itself comes back and nothing is put."""
         from . import zonemap
         zm = zonemap.zone_map_for(data, self.zone_map_rows)
         idx = zonemap.surviving_zone_indices(zm, node.predicate,
                                              node.column_indices)
         pruned = zm.num_zones - len(idx)
         if pruned == 0:
-            return arrays, valids, data.num_rows
-        ranges = []
+            return live, data.num_rows, 0
+        keep = np.zeros(cap, dtype=np.bool_)
         for i in idx:
-            s, c = zm.starts[i], zm.counts[i]
-            if ranges and ranges[-1][0] + ranges[-1][1] == s:
-                ranges[-1][1] += c
-            else:
-                ranges.append([s, c])
-
-        def take(a):
-            a = np.asarray(a)
-            if not ranges:
-                return a[:0]
-            if len(ranges) == 1:
-                s, c = ranges[0]
-                return a[s:s + c]
-            return np.concatenate([a[s:s + c] for s, c in ranges])
-
-        kept_rows = sum(c for _, c in ranges)
-        arrays = [take(a) for a in arrays]
-        if valids is not None:
-            valids = [None if v is None else take(v) for v in valids]
+            keep[zm.starts[i]:zm.starts[i] + zm.counts[i]] = True
+        kept_rows = sum(zm.counts[i] for i in idx)
         self.stats.scan_zones_pruned += pruned
         self.stats.scan_rows_pruned += data.num_rows - kept_rows
         from ..metrics import SCAN_ZONES_PRUNED
@@ -1030,7 +1052,7 @@ class Executor:
         self.strategy_decisions[
             f"TableScan[{node.table}]"] = \
             f"zone-pruned:{pruned}/{zm.num_zones}"
-        return arrays, valids, kept_rows
+        return self._place(keep), kept_rows, pruned
 
     def run_window(self, node: L.WindowNode) -> Batch:
         from ..ops.window import WinSpec, window_compute

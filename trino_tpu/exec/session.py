@@ -125,8 +125,10 @@ SESSION_PROPERTY_DEFAULTS = {
     # gather-free sort-merge unique join at small shapes (compile-cost
     # gated regardless; this disables it outright)
     "merge_join": (True, _bool),
-    # device bytes the scan cache may pin before LRU eviction
-    "scan_cache_max_mb": (24 << 10, int),
+    # device bytes the executor may keep resident across statements
+    # (scanned columns, fact tables, pinned builds) before LRU eviction;
+    # -1 = half of the device's own bytes_limit (exec/device_cache.py)
+    "scan_cache_max_mb": (-1, int),
     # zone-map scan pruning (exec/zonemap.py): skip decoding row ranges
     # the pushed-down predicate provably cannot match. Conservative-only;
     # the residual filter always re-runs, so off is bit-exact with on
@@ -264,8 +266,8 @@ class Session:
         ex.mesh_dynamic_filtering = \
             self.properties["mesh_dynamic_filtering"]
         ex.enable_merge_join = self.properties["merge_join"]
-        ex.scan_cache_max_bytes = \
-            self.properties["scan_cache_max_mb"] << 20
+        mb = self.properties["scan_cache_max_mb"]
+        ex.scan_cache_max_bytes = (mb << 20) if mb >= 0 else None
         ex.enable_zone_map_pruning = \
             self.properties["enable_zone_map_pruning"]
         ex.zone_map_rows = max(1, self.properties["zone_map_rows"])
@@ -306,16 +308,22 @@ class Session:
             batch = self.executor.execute(root)
             names, arrays, valids = self.executor.result_to_host(root,
                                                                  batch)
-            if sp is not None and self.executor.profile:
-                ns = [v for v in self.executor.node_stats.values()
-                      if len(v) >= 5]
-                sp.attributes["profiled"] = True
-                sp.attributes["deviceMs"] = round(
-                    sum(v[2] for v in ns) * 1000, 3)
-                sp.attributes["hostMs"] = round(
-                    sum(v[3] for v in ns) * 1000, 3)
-                sp.attributes["compileMs"] = round(
-                    sum(v[4] for v in ns) * 1000, 3)
+            if sp is not None:
+                resident = self.executor.resident
+                sp.attributes.update(
+                    residentBytes=resident.total_bytes(),
+                    residentEntries=len(resident),
+                    scanPutBytes=self.executor.scan_put_bytes)
+                if self.executor.profile:
+                    ns = [v for v in self.executor.node_stats.values()
+                          if len(v) >= 5]
+                    sp.attributes["profiled"] = True
+                    sp.attributes["deviceMs"] = round(
+                        sum(v[2] for v in ns) * 1000, 3)
+                    sp.attributes["hostMs"] = round(
+                        sum(v[3] for v in ns) * 1000, 3)
+                    sp.attributes["compileMs"] = round(
+                        sum(v[4] for v in ns) * 1000, 3)
         with self.tracer.span("decode", rows=len(arrays[0])
                               if arrays else 0):
             rows = self.decode_rows(rel, arrays, valids)

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..batch import Batch, bucket_capacity
+from ..batch import Batch, bucket_capacity, pad_capacity
 from ..catalog import Catalog
 from ..exec.executor import Executor, compact_batch
 from ..exec.profiler import recorded_jit
@@ -124,19 +124,19 @@ class MeshExecutor(Executor):
         return jax.tree_util.tree_map(
             lambda x: jax.device_put(x, self._row_sharding), batch)
 
-    def run_scan(self, node: L.ScanNode) -> Batch:
-        batch = super().run_scan(node)
-        if batch.capacity % self.n_shards != 0:
+    def _scan_capacity(self, rows: int) -> int:
+        cap = super()._scan_capacity(rows)
+        if cap % self.n_shards != 0:
             # odd capacity (mesh size does not divide the 1024-row
             # buckets): pad with dead rows to the next shard multiple
             # instead of silently staying single-device — the live mask
             # keeps padding invisible to every kernel
-            batch = pad_to_multiple(batch, self.n_shards * 8)
-        key = self._scan_key(node)
-        sharded = jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, self._row_sharding), batch)
-        self._scan_cache[key] = sharded   # keep the sharded placement
-        return sharded
+            cap = pad_capacity(cap, self.n_shards * 8)
+        return cap
+
+    def _place(self, host):
+        # the resident copy of a table IS the row-sharded placement
+        return jax.device_put(host, self._row_sharding)
 
     # -- dynamic filtering (batched collectives) -----------------------
 

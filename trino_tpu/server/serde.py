@@ -69,9 +69,10 @@ def register(cls: type) -> type:
 
 
 class _Encoder:
-    def __init__(self):
+    def __init__(self, digest_schemas: bool = False):
         self.memo: Dict[int, int] = {}     # id(obj) -> slot
         self.slots = []                    # slot -> encoded node
+        self.digest_schemas = digest_schemas
 
     def enc(self, obj: Any) -> Any:
         if obj is None or isinstance(obj, (bool, int, float, str)):
@@ -94,6 +95,8 @@ class _Encoder:
         if isinstance(obj, enum.Enum):
             return {"$enum": type(obj).__name__, "v": obj.value}
         if dataclasses.is_dataclass(obj):
+            if self.digest_schemas and type(obj).__name__ == "Schema":
+                return {"$schema": _schema_digest(obj)}
             slot = self.memo.get(id(obj))
             if slot is not None:
                 return {"$ref": slot}
@@ -160,6 +163,38 @@ class _Decoder:
 
 def dumps(obj: Any) -> str:
     e = _Encoder()
+    root = e.enc(obj)
+    return json.dumps({"v": 1, "slots": e.slots, "root": root})
+
+
+# id(Schema) -> (the Schema, the sha256 of its wire form). A connector's
+# Schema is one long-lived frozen object a table; the entry holds it, so
+# a recycled id never aliases
+_SCHEMA_DIGESTS_MAX = 256
+_schema_digests: Dict[int, tuple] = {}
+
+
+def _schema_digest(schema) -> str:
+    hit = _schema_digests.get(id(schema))
+    if hit is not None and hit[0] is schema:
+        return hit[1]
+    import hashlib
+    digest = hashlib.sha256(dumps(schema).encode()).hexdigest()
+    with _registry_lock:
+        if len(_schema_digests) >= _SCHEMA_DIGESTS_MAX:
+            _schema_digests.clear()
+        _schema_digests[id(schema)] = (schema, digest)
+    return digest
+
+
+def structure_text(obj: Any) -> str:
+    """Canonical text of a plan subtree for a structural hash: the wire
+    form with every Schema written as the digest of its own wire form,
+    computed once a Schema object. A scanned table's schema carries its
+    dictionary pools (customer's at SF10: 35 MB), and a key that walks
+    them in every statement costs more than the statement's device
+    time. Not decodable: `loads` takes `dumps`' output only."""
+    e = _Encoder(digest_schemas=True)
     root = e.enc(obj)
     return json.dumps({"v": 1, "slots": e.slots, "root": root})
 
