@@ -29,7 +29,7 @@ Q6_SPANS = {"query", "exec-lock-wait", "plan-distributed", "stage-prepare",
             "task-drain", "task-record",
             "task-decode", "worker-task", "pin-builds", "split-read",
             "split-put", "split", "split-fetch", "split-emit", "compile",
-            "final-stage", "merge-decode", "merge-partials", "merge-run",
+            "task-merge", "task-emit", "final-stage", "merge-decode", "merge-partials", "merge-run",
             "result-fetch", "decode-rows"}
 SPLIT_PHASES = ("split-read", "split-put", "split", "split-fetch",
                 "split-emit")
@@ -332,6 +332,7 @@ def test_traced_q6_yields_every_phase_span(cluster):
             "task-record": {"source-stage"}, "task-decode": {"source-stage"},
             "spool-lookup": {"source-stage"}, "stage-wait": {"source-stage"},
             "worker-task": {"source-stage"}, "pin-builds": {"worker-task"},
+            "task-merge": {"worker-task"}, "task-emit": {"worker-task"},
             "merge-decode": {"final-stage"},
             "merge-partials": {"final-stage"},
             "merge-run": {"final-stage"}, "result-fetch": {"final-stage"},
@@ -357,9 +358,22 @@ def test_traced_q6_yields_every_phase_span(cluster):
     n_splits = stage["attributes"]["splits"]
     for n in SPLIT_PHASES:
         assert sum(s["name"] == n for s in spans) == n_splits
+    # the task folded its splits' partials and staged one page
     drain = next(s for s in spans if s["name"] == "task-drain")
-    assert drain["attributes"]["pages"] == n_splits
+    assert drain["attributes"]["pages"] == 1
     assert drain["attributes"]["bytes"] > 0
+    task = next(s for s in spans if s["name"] == "worker-task")
+    assert (task["attributes"]["foldedSplits"], task["attributes"]["pagesOut"],
+            task["attributes"]["flushes"]) == (n_splits, 1, 0)
+    merge = next(s for s in spans if s["name"] == "task-merge")
+    assert merge["attributes"]["partials"] == n_splits
+    emit = next(s for s in spans if s["name"] == "task-emit")
+    assert emit["attributes"]["rows"] == 1 and \
+        emit["attributes"]["bytes"] == drain["attributes"]["bytes"]
+    assert all(s["attributes"]["bytes"] == 0 for s in spans
+               if s["name"] == "split-emit")
+    final = next(s for s in spans if s["name"] == "final-stage")
+    assert final["attributes"]["pages"] == 1
     wait = next(s for s in spans if s["name"] == "stage-wait")
     assert drain["attributes"]["polls"] >= 0 and \
         wait["attributes"]["polls"] >= 1

@@ -531,7 +531,8 @@ def _chunked_partial_aggregate(executor, node: L.AggregateNode,
 # --------------------------------------------------------------------------
 
 class PartialState:
-    """The chunked driver's partial-aggregate accumulator, made
+    """The partial-aggregate accumulator of the chunked driver and of a
+    worker task's split loop (server/tasks.py), made
     spillable (SpillableHashAggregationBuilder's role): device partials
     are revocable reservations; when the pool asks (or the watermark
     trips) they move to host pages, and the merge step re-aggregates
@@ -558,6 +559,13 @@ class PartialState:
         with self._lock:
             self.device.append(batch)
             self._device_bytes.append(b)
+
+    def held_bytes(self) -> int:
+        """Bytes of every partial held, resident or revoked to the host
+        (a worker task flushes a page when this passes its budget)."""
+        with self._lock:
+            return sum(self._device_bytes) + sum(
+                _host_bytes(a, v) for a, v in self.host)
 
     def _revoke(self, target_bytes: int) -> int:
         """Revocation callback: move device partials to host until the

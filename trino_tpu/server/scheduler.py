@@ -1167,8 +1167,15 @@ class StageScheduler:
         """Merge source-stage partial pages and run the rest of the
         fragment — the FINAL step shared by build stages and the root
         stage. Partial-agg states re-aggregate with merge functions;
-        concat-mode pages concatenate below the output node."""
-        from ..batch import batch_from_numpy
+        concat-mode pages concatenate below the output node.
+
+        What a page is: in agg mode a TASK's fold of its splits'
+        partials (one a task; more when the task flushed because the
+        partials it held passed its output buffer's bound, or when the
+        spool answers with pages of an older layout), so it may hold a
+        few rows or a million and goes onto the device at a lattice
+        capacity; in sort and concat mode one split's output."""
+        from ..batch import batch_from_numpy, bucket_capacity
         ex = self.session.executor
         tracer = self._tracer()
         saved = dict(ex._subst)
@@ -1191,8 +1198,9 @@ class StageScheduler:
                         if len(arrs) == 0 or len(arrs[0]) == 0:
                             continue
                         rows += len(arrs[0])
-                        partials.append(
-                            batch_from_numpy(arrs, valids=vals))
+                        partials.append(batch_from_numpy(
+                            arrs, valids=vals,
+                            capacity=bucket_capacity(len(arrs[0]))))
                     if sp is not None:
                         sp.attributes.update(
                             rows=rows, bytes=sum(
@@ -1284,6 +1292,12 @@ class StageScheduler:
         # stays what it was, dispatch to last page (split_wall_ms)
         with self._tracer().span("stage-prepare") as prep:
             frag = {"root": fragment_root, "driver": analysis.driver}
+            if analysis.merge_agg is not None:
+                # the root is this stage's merge aggregate: a task may
+                # fold its splits' partials with `merge_partials` and
+                # stage one page (tasks._run_splits); the worker is told,
+                # it does not guess from the node's type
+                frag["merge_agg"] = True
             if self._profile_tasks or getattr(
                     self.session, "properties", {}).get("enable_profiling"):
                 # EXPLAIN ANALYZE or `enable_profiling`: workers fence

@@ -29,6 +29,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..exec.spill import PartialState
 from ..metrics import TASK_OUTPUT_BYTES, TASK_OUTPUT_ROWS
 from ..utils import tracing
 from ..utils.tracing import NOOP, Tracer
@@ -152,6 +153,71 @@ def _pinned_attrs(pinned) -> dict:
         attrs["capacity"] = largest.capacity
         attrs["rows"] = int(np.asarray(largest.live).sum())
     return attrs
+
+
+def _partial_entry(out):
+    """A split's partial aggregate as it joins the ones its task holds:
+    at a lattice capacity of its live rows, never at the capacity of
+    the split it came from (240 partials at a 250,000-row split's
+    capacity are gigabytes; `batch_to_numpy` used to trim them on the
+    host). A batch no larger than SORT_SMALL_ROWS enters as it is, with
+    no count fetched: the executor's small kernels made it (a global or
+    direct-domain aggregate, a sort aggregate of a compacted split), its
+    shape does not depend on the data, and there is at most that much to
+    save."""
+    from ..batch import bucket_capacity
+    from ..exec.executor import SORT_SMALL_ROWS, compact_batch
+    if out.capacity <= SORT_SMALL_ROWS:
+        return out
+    live = int(np.asarray(out.live).sum())
+    capacity = max(SORT_SMALL_ROWS, bucket_capacity(live))
+    return compact_batch(out, capacity) if capacity < out.capacity else out
+
+
+class _HeldPartials:
+    """The partial aggregates a folding task holds on the device: one a
+    split, in split order, in a `PartialState` (exec/spill.py) whose
+    reservations are revocable in the worker's pool as the chunked
+    driver's are. Nothing is folded before the task's end or a flush: a
+    fold every few splits would walk a high-cardinality accumulator up
+    the capacity lattice, one sort program compiled a point."""
+
+    def __init__(self, executor, node, tag: str):
+        self.executor = executor
+        self.node = node                  # the stage's merge aggregate
+        self.tag = tag
+        self.state = self._fresh()
+        self.folded_splits = 0
+        self.flushes = 0
+        self._last = None
+
+    def _fresh(self) -> PartialState:
+        return PartialState(self.executor, tag=self.tag)
+
+    def add(self, out) -> None:
+        """The fetch a split was also the loop's back-pressure, so wait
+        for the split BEFORE this one: two splits' inputs in flight at
+        most."""
+        if self._last is not None:
+            self._last.live.block_until_ready()
+        self._last = out
+        self.state.add(_partial_entry(out))
+        self.folded_splits += 1
+
+    def count(self) -> int:
+        return len(self.state.device) + len(self.state.host)
+
+    def bytes(self) -> int:
+        return self.state.held_bytes()
+
+    def fold(self):
+        """`merge_partials` of what is held (the program the coordinator
+        runs on what arrives); nothing is held after."""
+        state, self.state = self.state, self._fresh()
+        return state.merge(self.node)
+
+    def close(self) -> None:
+        self.state.close()
 
 
 @dataclass(frozen=True)
@@ -679,13 +745,25 @@ class TaskManager:
         self._executor.flush_metrics()
 
     def _run_splits(self, task: WorkerTask, ex, root, driver_scan,
-                    cap: int, lap, names: Optional[Dict[int, str]],
+                    cap: int, lap, held: Optional[_HeldPartials],
+                    names: Optional[Dict[int, str]],
                     op_agg: Dict[str, list], live_prev: tuple) -> tuple:
         """The split loop of one task. Five spans a split (`lap`,
         utils/tracing.py), each starting where the last one ended, so
         every moment of the loop has a name; benchmark/layers/
         split_*_ms.py read them. `names` is set when the fragment is
-        profiled (fenced)."""
+        profiled (fenced).
+
+        What a page is. With `held` (the fragment's root is the stage's
+        merge aggregate, no partition spec) a split stages nothing: its
+        partial stays on the device with the ones before it, and the
+        task stages ONE page at its end, their fold (`_emit_held`). A
+        page staged here is a flush: what is held passed the output
+        buffer's bound (`max_buffer_bytes`: a page larger than the
+        buffer that stages it helps nobody), so it is folded and staged
+        and the loop goes on. Without `held` (concat, sorted runs, a
+        partition spec) a page is one split's output, as it always
+        was."""
         from ..batch import batch_from_numpy, batch_to_numpy
         for si, split in enumerate(task.splits):
             lap("split-read", index=si, rows=split.count)
@@ -698,8 +776,9 @@ class TaskManager:
                     "task deadline exceeded (query_max_run_time_s)")
             if self.injector is not None:
                 # chaos mid-split: CRASH kills the executor with work
-                # half-done (partial pages already buffered — the
-                # coordinator's all-or-nothing drain discards them),
+                # half-done (a folding task has staged nothing yet, or
+                # its flushes; a page-a-split task its pages so far —
+                # the coordinator's all-or-nothing drain discards them),
                 # DELAY makes this worker a straggler (hedge-mitigation
                 # target)
                 self.injector.maybe_fail("WORKER_TASK_RUN",
@@ -734,12 +813,19 @@ class TaskManager:
             sp = lap("split-fetch", index=si)
             if names is not None:
                 self._fold_node_stats(ex, names, op_agg)
-            arrs, vals = batch_to_numpy(out)
-            if sp is not None:
-                sp.attributes["rows"] = len(arrs[0]) if arrs else 0
+            if held is None:
+                arrs, vals = batch_to_numpy(out)
+                if sp is not None:
+                    sp.attributes["rows"] = len(arrs[0]) if arrs else 0
+            else:
+                held.add(out)           # no fetch
             sp = lap("split-emit", index=si)
             bytes0 = task.bytes_out
-            self._emit(task, arrs, vals)
+            if held is None:
+                self._emit(task, arrs, vals)
+            elif held.bytes() > self.max_buffer_bytes:
+                self._emit(task, *batch_to_numpy(held.fold()))
+                held.flushes += 1
             if sp is not None:
                 sp.attributes["bytes"] = task.bytes_out - bytes0
             # live tier attribution: fenced device/host/compile deltas
@@ -762,6 +848,21 @@ class TaskManager:
             self._note_live_change(task)
             self._note_busy(d_dev, max(0.0, sp_wall_ms - d_dev))
         return live_prev
+
+    def _emit_held(self, task: WorkerTask, tracer: Tracer,
+                   held: _HeldPartials) -> None:
+        """A folding task's end: the last fold, then its one page (its
+        last, after a flush) fetched, encoded and staged."""
+        from ..batch import batch_to_numpy
+        with tracer.span("task-merge", partials=held.count()):
+            merged = held.fold()
+        with tracer.span("task-emit") as sp:
+            arrs, vals = batch_to_numpy(merged)
+            bytes0 = task.bytes_out
+            self._emit(task, arrs, vals)
+            if sp is not None:
+                sp.attributes.update(rows=len(arrs[0]) if arrs else 0,
+                                     bytes=task.bytes_out - bytes0)
 
     def _run(self, task: WorkerTask) -> None:
         with task.lock:
@@ -837,6 +938,13 @@ class TaskManager:
                 if profiling:
                     ex.profile = True
                     ex.node_stats = {}
+                # the coordinator says when the root is the stage's merge
+                # aggregate; a partition spec routes rows by key, so
+                # those tasks keep a page a split
+                held = _HeldPartials(
+                    ex, root, f"task-partials:{task.task_id}") \
+                    if fragment.get("merge_agg") and \
+                    task.partition is None else None
                 try:
                     # pin maximal driver-free subtrees ONCE per task (join
                     # build sides, HashBuilderOperator's build-once-probe-
@@ -859,9 +967,13 @@ class TaskManager:
                     ex.enter_chunk_mode()
                     with tracer.laps() as lap:
                         live_prev = self._run_splits(
-                            task, ex, root, driver_scan, cap, lap,
+                            task, ex, root, driver_scan, cap, lap, held,
                             names if profiling else None, op_agg,
                             live_prev)
+                    # a cancelled task stages nothing
+                    if held is not None and held.count() and \
+                            task.state == "RUNNING":
+                        self._emit_held(task, tracer, held)
                 finally:
                     ex.exit_chunk_mode()
                     ex.profile = saved_profile
@@ -874,11 +986,23 @@ class TaskManager:
                     for b in ex._node_bytes.values():
                         ex.pool.free(b)
                     ex._node_bytes.clear()
+                    if held is not None:
+                        held.close()    # what a failure or a cancel left
                     if wspan is not None:
                         # 1 a pinned values build; `splits` times that
                         # if a split ever puts its build again
                         wspan.attributes["valuePuts"] = \
                             ex.stats.value_puts - puts0
+                        # that the fold engaged: a folding task holds
+                        # every split and stages 1 page, more if it
+                        # flushed; any other a page a split
+                        with task.lock:
+                            wspan.attributes["pagesOut"] = \
+                                task.total_pages()
+                        wspan.attributes["foldedSplits"] = \
+                            held.folded_splits if held is not None else 0
+                        wspan.attributes["flushes"] = \
+                            held.flushes if held is not None else 0
                     if wspan is not None and op_agg:
                         # fenced split totals ride the worker-task span
                         # so the stitched trace carries device time, not
