@@ -436,192 +436,6 @@ def gather_micro(table_sizes=None, probe_rows=None, n_tables=3, runs=3,
 
 
 # ---------------------------------------------------------------------------
-# --agg-micro: hash vs sort vs direct aggregation across cardinalities
-# ---------------------------------------------------------------------------
-
-def agg_micro(cardinalities=None, rows=None, runs=3,
-              out_path="BENCH_agg_micro.json"):
-    """Microbenchmark the aggregation strategies (ops/pallas_hash.py
-    hash table, ops/aggregate.py sort kernel, direct masked reductions
-    where the domain allows) across group cardinalities, recording the
-    per-strategy walls as one JSON artifact so the q18-class trajectory
-    (hash >= 5x sort at high cardinality) is measurable round over
-    round and gated by --check-regressions.
-
-    On TPU this sweeps 10^2..10^7 groups over a large batch; under
-    JAX_PLATFORMS=cpu it drops to a tiny smoke configuration in Pallas
-    interpret mode (numbers meaningless there — the run exists so
-    tier-1 exercises the harness end to end)."""
-    import jax
-    import jax.numpy as jnp
-
-    from trino_tpu.batch import batch_from_numpy
-    from trino_tpu.ops import pallas_hash as ph
-    from trino_tpu.ops.aggregate import (AggSpec, direct_group_aggregate,
-                                         key_pack_plan,
-                                         sort_group_aggregate)
-
-    on_tpu = jax.default_backend() == "tpu"
-    mode = "device" if on_tpu else "interpret"
-    if cardinalities is None:
-        cardinalities = [100, 1000, 10_000, 100_000, 1_000_000,
-                         10_000_000] if on_tpu else [16, 256]
-    if rows is None:
-        rows = (1 << 24) if on_tpu else (1 << 12)
-    rng = np.random.default_rng(11)
-
-    def timed(fn):
-        import jax as _jax
-        _jax.block_until_ready(fn())            # warm (compile)
-        walls = []
-        for _ in range(runs):
-            t0 = time.monotonic()
-            _jax.block_until_ready(fn())
-            walls.append(time.monotonic() - t0)
-        return min(walls) * 1000
-
-    records = []
-    aggs = (AggSpec("sum", 1), AggSpec("count_star", None))
-    for groups in cardinalities:
-        keys = rng.integers(0, groups, rows)
-        vals = rng.integers(-(1 << 40), 1 << 40, rows)
-        batch = batch_from_numpy([keys, vals])
-        cap = 1 << max(10, int(1.3 * groups).bit_length())
-        rec = {"groups": groups, "rows": rows}
-
-        rec["sort_ms"] = round(timed(lambda: sort_group_aggregate(
-            batch, (0,), aggs, min(cap, len(keys) or 1))), 3)
-        if groups <= 64:
-            rec["direct_ms"] = round(timed(
-                lambda: direct_group_aggregate(batch, (0,), (groups,),
-                                               aggs)), 3)
-        plan = key_pack_plan(batch, (0,))
-        if plan is not None:
-            kmins, bits = plan
-            slots, fits = ph.pick_table_slots(groups, aggs)
-            kd = jnp.asarray(kmins)
-            out = ph.hash_group_aggregate(batch, kd, (0,), bits, aggs,
-                                          slots, mode)
-            esc = int(out[1])
-            rec["hash_table_slots"] = slots
-            rec["hash_escapes"] = esc
-            if esc == 0 and fits:
-                rec["hash_ms"] = round(timed(
-                    lambda: ph.hash_group_aggregate(
-                        batch, kd, (0,), bits, aggs, slots, mode)), 3)
-                rec["hash_vs_sort"] = round(
-                    rec["sort_ms"] / max(rec["hash_ms"], 1e-6), 2)
-        records.append(rec)
-
-    out = {"metric": "agg_micro_ms", "device": str(jax.devices()[0]),
-           "mode": mode, "smoke": not on_tpu, "records": records}
-    with open(out_path, "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out), flush=True)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# --star-micro: fused multiway star probe vs the pairwise join ladder
-# ---------------------------------------------------------------------------
-
-def _star_tables(k, fact_rows, dim_rows, hit_rate, seed=40231):
-    """Synthetic star: one fact with k FK columns + a value, k unique-
-    keyed dims each carrying one payload column. `hit_rate` sets the
-    per-dim probe match fraction (fact keys drawn past the dim's key
-    range miss, so the inner join drops 1-hit_rate of rows per hop)."""
-    from trino_tpu.batch import Field, Schema
-    from trino_tpu.connectors.tpch.datagen import TableData
-    from trino_tpu.types import BIGINT
-    rng = np.random.default_rng(seed + k)
-    t = {}
-    span = max(1, int(dim_rows / max(hit_rate, 1e-9)))
-    fact_cols = [rng.integers(0, span, fact_rows).astype(np.int64)
-                 for _ in range(k)]
-    fact_cols.append(rng.integers(0, 1 << 20, fact_rows).astype(np.int64))
-    t["fact"] = TableData(
-        "fact",
-        Schema.of(*[Field(f"f_d{i}key", BIGINT) for i in range(k)],
-                  Field("f_value", BIGINT)),
-        fact_cols)
-    for i in range(k):
-        t[f"dim{i}"] = TableData(
-            f"dim{i}",
-            Schema.of(Field(f"d{i}_key", BIGINT),
-                      Field(f"d{i}_attr", BIGINT)),
-            [np.arange(dim_rows, dtype=np.int64),
-             rng.integers(0, 1000, dim_rows).astype(np.int64)],
-            primary_key=(f"d{i}_key",))
-    return t
-
-
-def star_micro(shapes=None, fact_rows=None, dim_rows=None, runs=3,
-               out_path="BENCH_star_micro.json"):
-    """Microbenchmark the fused multiway star probe (ops/pallas_hash.py
-    multiway_probe, one Pallas pass over every VMEM-resident dimension
-    table) against the pairwise join ladder it replaces, across star
-    widths and probe selectivities. Emits one JSON artifact so the
-    ISSUE-13 claim (fused >= 2x pairwise at >= 3 dims on TPU) is
-    measurable round over round and gated by --check-regressions.
-
-    Under JAX_PLATFORMS=cpu this drops to a tiny smoke shape in Pallas
-    interpret mode (numbers meaningless — the run exists so tier-1
-    exercises the harness and the bit-exactness assert end to end)."""
-    import jax
-
-    from trino_tpu.catalog import Catalog
-    from trino_tpu.exec.session import Session
-    from trino_tpu.metrics import MULTIJOIN_FUSED_PROBES
-
-    on_tpu = jax.default_backend() == "tpu"
-    mode = "device" if on_tpu else "interpret"
-    if shapes is None:
-        shapes = [(2, 0.9), (3, 0.9), (3, 0.2), (5, 0.9)] if on_tpu \
-            else [(2, 0.9), (3, 0.5)]
-    if fact_rows is None:
-        fact_rows = (1 << 22) if on_tpu else (1 << 12)
-    if dim_rows is None:
-        dim_rows = 4096 if on_tpu else 256
-
-    records = []
-    for k, hit_rate in shapes:
-        tables = _star_tables(k, fact_rows, dim_rows, hit_rate)
-        cat = Catalog()
-        cat.register("bench", BenchConnector(tables, "star"))
-        s = Session(catalog=cat, default_cat="bench",
-                    default_schema="star")
-        sql = ("SELECT sum(f_value"
-               + "".join(f" + d{i}_attr" for i in range(k))
-               + ") FROM fact "
-               + " ".join(f"JOIN dim{i} ON f_d{i}key = d{i}_key"
-                          for i in range(k)))
-        rec = {"dims": k, "hit_rate": hit_rate,
-               "fact_rows": fact_rows, "dim_rows": dim_rows}
-
-        s.execute("SET SESSION enable_multiway_join = 'true'")
-        before = MULTIJOIN_FUSED_PROBES.value()
-        fused_res, _, fused_ms = run_config(s, sql, runs=runs, prewarm=2)
-        rec["fused_engaged"] = \
-            MULTIJOIN_FUSED_PROBES.value() > before
-        s.execute("SET SESSION enable_multiway_join = 'false'")
-        pair_res, _, pair_ms = run_config(s, sql, runs=runs, prewarm=2)
-        assert fused_res.rows == pair_res.rows, \
-            (k, hit_rate, fused_res.rows, pair_res.rows)
-        rec["fused_ms"] = round(fused_ms, 3)
-        rec["pairwise_ms"] = round(pair_ms, 3)
-        rec["fused_vs_pairwise"] = round(
-            pair_ms / max(fused_ms, 1e-6), 2)
-        records.append(rec)
-
-    out = {"metric": "star_micro_ms", "device": str(jax.devices()[0]),
-           "mode": mode, "smoke": not on_tpu, "records": records}
-    with open(out_path, "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out), flush=True)
-    return out
-
-
-# ---------------------------------------------------------------------------
 # --scan-micro: zone-map pruning + prefetch-pipeline scan-path microbench
 # ---------------------------------------------------------------------------
 
@@ -1764,6 +1578,11 @@ def concurrency_soak(n_clients=None, queries_per_client=None,
                             max_queued=100_000))
 
     reg0 = REGISTRY.snapshot()
+    # one statement is planned before the clients start, so whichever of
+    # them sends it first finds its plan whatever the interleaving: left
+    # to the herd a plan-cache hit needs a second client to arrive after
+    # the first has planned a text and before its result is cached
+    coord.state.dispatcher.serving.plan_entry(mixes["scan_heavy"][0])
     mix_names = list(mixes)
     lock = _th.Lock()
     latencies = {m: [] for m in mix_names}
@@ -2491,28 +2310,6 @@ def load_bench_round(path, key="tpu_steady_ms"):
             if r.get("ratio") is not None:
                 out[f"cold_{r['query']}_ratio"] = float(r["ratio"])
         return out or None
-    if str(doc.get("metric", "")).startswith("star_micro"):
-        # --star-micro rounds gate on BOTH walls per star shape: a
-        # slower fused kernel OR a slower pairwise ladder in a later
-        # round reads as a regressed star_micro_* config
-        out = {}
-        for r in doc.get("records", ()):
-            tag = f"star_micro_k{r['dims']}_h{r['hit_rate']}"
-            if r.get("fused_ms") is not None:
-                out[f"{tag}_fused"] = float(r["fused_ms"])
-            if r.get("pairwise_ms") is not None:
-                out[f"{tag}_pairwise"] = float(r["pairwise_ms"])
-        return out or None
-    if str(doc.get("metric", "")).startswith("agg_micro"):
-        # --agg-micro rounds gate on the strategy the gate would pick
-        # (hash where present, else sort): a slower kernel in a later
-        # round reads as a regressed agg_micro_g<cardinality> config
-        out = {}
-        for r in doc.get("records", ()):
-            ms = r.get("hash_ms", r.get("sort_ms"))
-            if ms is not None:
-                out[f"agg_micro_g{r['groups']}"] = float(ms)
-        return out or None
     detail = doc.get("detail", doc)
     out = {}
     for cfg, d in detail.items():
@@ -2678,14 +2475,6 @@ def build_parser():
     mode.add_argument("--gather-micro", action="store_true",
                       help="Pallas tiled-gather microbench -> "
                            "BENCH_gather_micro.json")
-    mode.add_argument("--agg-micro", action="store_true",
-                      help="hash vs sort vs direct aggregation "
-                           "microbench across group cardinalities -> "
-                           "BENCH_agg_micro.json")
-    mode.add_argument("--star-micro", action="store_true",
-                      help="fused multiway star probe vs the pairwise "
-                           "join ladder across star widths and probe "
-                           "selectivities -> BENCH_star_micro.json")
     mode.add_argument("--scan-micro", action="store_true",
                       help="zone-map pruning + prefetch pipeline "
                            "scan-path microbench across predicate "
@@ -2756,12 +2545,6 @@ def main(argv=None):
     if args.gather_micro:
         gather_micro()
         return 0
-    if args.agg_micro:
-        agg_micro()
-        return 0
-    if args.star_micro:
-        star_micro()
-        return 0
     if args.scan_micro:
         scan_micro()
         return 0
@@ -2777,25 +2560,6 @@ def main(argv=None):
         ok, report = check_regressions(
             sorted(_glob.glob(args.rounds_glob)),
             ratio=args.ratio, mad_k=args.mad_k)
-        # the aggregation trajectory gates as its own series: later
-        # rounds append BENCH_agg_micro_r*.json next to the canonical
-        # BENCH_agg_micro.json, and a slower hash kernel fails the gate
-        agg_paths = sorted(_glob.glob("BENCH_agg_micro*.json"))
-        if agg_paths:
-            ok2, report2 = check_regressions(agg_paths,
-                                             ratio=args.ratio,
-                                             mad_k=args.mad_k)
-            report["agg_micro"] = report2
-            ok = ok and ok2
-        # the star-join trajectory gates as its own series the same way
-        # (BENCH_star_micro.json + later rounds' BENCH_star_micro_r*.json)
-        star_paths = sorted(_glob.glob("BENCH_star_micro*.json"))
-        if star_paths:
-            ok7, report7 = check_regressions(star_paths,
-                                             ratio=args.ratio,
-                                             mad_k=args.mad_k)
-            report["star_micro"] = report7
-            ok = ok and ok7
         # the scan-path trajectory gates as its own series the same way
         # (BENCH_scan_micro.json + later rounds' BENCH_scan_micro_r*.json)
         scan_paths = sorted(_glob.glob("BENCH_scan_micro*.json"))

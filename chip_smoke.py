@@ -353,8 +353,7 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
                 # device again (compiled programs and data stay)
                 sched.spool.clear()
                 before = RECORDER.totals()["compiles"]
-                calls0 = {k: (ex.stats.hash_agg_calls,
-                              ex.stats.mxu_agg_calls,
+                calls0 = {k: (ex.stats.mxu_agg_calls,
                               ex.stats.pallas_gather_calls)
                           for k, ex in executors.items()}
                 t0 = time.monotonic()
@@ -380,8 +379,7 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
                               for k, ex in executors.items()
                               if ex.strategy_decisions}
                 calls = {k: tuple(b - a for a, b in zip(
-                    calls0[k], (ex.stats.hash_agg_calls,
-                                ex.stats.mxu_agg_calls,
+                    calls0[k], (ex.stats.mxu_agg_calls,
                                 ex.stats.pallas_gather_calls)))
                          for k, ex in executors.items()}
                 say(f"{name} {sch} {run}: {secs:.2f}s, {len(res.rows)} rows"
@@ -390,9 +388,9 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
                     f" fallback={fallback!r});"
                     f" compiles={RECORDER.totals()['compiles'] - before};"
                     f" strategies={strategies};"
-                    f" (hash_agg, mxu_agg, pallas_gather) calls={calls};"
+                    f" (mxu_agg, pallas_gather) calls={calls};"
                     f" peak_bytes_in_use={peak_bytes()}")
-                expect_strategies(name, strategies, calls)
+                expect_strategies(name, strategies)
         say(f"compile cache at {compile_cache_dir()}: "
             f"{cache.hits} hits, {cache.misses} misses; "
             f"{RECORDER.totals()['compiles']} jit compiles recorded")
@@ -401,15 +399,8 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
         coord.stop()
 
 
-def expect_strategies(name: str, strategies: dict, calls: dict) -> None:
-    """What the defaults must have picked on the chip. The hash-table
-    family does not compile for TPU and is off there by a static rule
-    (trino_tpu/ops/pallas_hash.py), so a hash aggregation or a hash join
-    that ran would mean the rule was bypassed."""
-    ran = {s for per_ex in strategies.values() for s in per_ex.values()}
-    hash_calls = sum(c[0] for c in calls.values())
-    assert hash_calls == 0 and "hybrid-hash" not in ran, \
-        f"{name}: the hash-table kernel ran though it is off on TPU"
+def expect_strategies(name: str, strategies: dict) -> None:
+    """What the defaults must have picked on the chip."""
     aggs = {per_ex.get("AggregateNode") for per_ex in strategies.values()}
     if name == "q6":
         assert "global" in aggs, (name, strategies)
@@ -435,13 +426,12 @@ def run_kernels() -> None:
     import jax
     import jax.numpy as jnp
     from trino_tpu.batch import batch_from_numpy
-    from trino_tpu.ops import pallas_agg, pallas_gather as pg, pallas_hash
+    from trino_tpu.ops import pallas_agg, pallas_gather as pg
     from trino_tpu.ops.aggregate import AggSpec, direct_group_aggregate
 
     n, words, rows = 1 << 22, 1 << 24, 1 << 23
     mode = pg.resolve_mode("auto")
     assert mode == "device", f"gather kernels resolve to {mode!r}"
-    assert pallas_hash.resolve_mode("auto") == "off"
     rng = np.random.default_rng(23)
 
     def custom_calls(jitted, *args, **kw) -> int:
@@ -509,8 +499,6 @@ def run_kernels() -> None:
     say(f"kernel MXU aggregate: G={g} rows={rows:,} equals the XLA "
         f"direct aggregate, tpu_custom_call x{n_cc}, "
         f"{time.monotonic() - t0:.1f}s")
-    say(f"kernel hash table (insert + multiway probe): off on TPU by "
-        f"rule — Mosaic: {pallas_hash.TPU_REFUSAL}")
 
 
 # ---------------------------------------------------------------------------

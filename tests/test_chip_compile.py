@@ -22,8 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from trino_tpu.ops import pallas_agg, pallas_gather as pg, pallas_hash as ph
-from trino_tpu.ops.aggregate import AggSpec
+from trino_tpu.ops import pallas_agg, pallas_gather as pg
 
 N_ROWS = 1 << 20            # rows per kernel call at the SF10 chunk shapes
 SF10_LINEITEM = 59_986_052  # tpch sf10 lineitem rows (60M)
@@ -120,46 +119,11 @@ def test_mxu_sums_compiles(one_chip, groups, aggs):
     assert _has_kernel(c)
 
 
-# ---------------------------------------------------------------------------
-# hash-table family: OFF on TPU by a static rule (pallas_hash.resolve_mode).
-# These pin the compiler's refusal — the day one of them stops raising,
-# the kernel compiles and the rule can go.
-# ---------------------------------------------------------------------------
-
-_Q18_AGGS = (AggSpec("sum", 0),)
-
-
-@pytest.mark.parametrize("slots", [ph.MIN_TABLE_SLOTS,
-                                   ph.max_table_slots(_Q18_AGGS)])
-def test_hash_insert_is_refused(one_chip, slots):
-    i32 = jnp.int32
-    layout, _ns, nv = ph.agg_layout(_Q18_AGGS)
-    with pytest.raises(ValueError, match=ph.TPU_REFUSAL):
-        _compile(
-            lambda s, kl, kh, vb, v: ph._hash_insert(
-                s, kl, kh, vb, v, layout, slots, False),
-            one_chip, *([((N_ROWS,), i32)] * 4), ((nv, N_ROWS), i32))
-
-
-@pytest.mark.parametrize("k", [2, ph.MAX_MULTI_DIMS])
-def test_multiway_probe_is_refused(one_chip, k):
-    i32 = jnp.int32
-    slots = 1 << 14
-    with pytest.raises(ValueError, match=ph.TPU_REFUSAL):
-        _compile(
-            lambda *a: ph._multi_probe(*a, False),
-            one_chip, *([((k, N_ROWS), i32)] * 3),
-            *([((k, slots), i32)] * 3))
-
-
-def test_hash_family_is_off_on_tpu_by_rule(monkeypatch):
+def test_gather_family_is_on_on_tpu_by_rule(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ph.resolve_mode("auto") == "off"
-    assert ph.resolve_mode("false") == "off"
-    with pytest.raises(NotImplementedError, match=ph.TPU_REFUSAL):
-        ph.resolve_mode("true")
     # the gather family compiles, so auto turns it on there
     assert pg.resolve_mode("auto") == "device"
+    assert pg.resolve_mode("false") == "off"
 
 
 # ---------------------------------------------------------------------------

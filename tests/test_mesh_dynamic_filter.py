@@ -1,10 +1,9 @@
-"""Mesh-partitioned join + batched dynamic filtering (tier-1, 8 devices).
+"""Mesh joins + batched dynamic filtering (tier-1, 8 devices).
 
-The quick-tier guards for the round-13 surface: the partitioned hash
-join (all_to_all repartition + per-shard VMEM hash kernel inside one
-shard_map program) must be bit-exact against the single-chip executor
-with dynamic filtering on AND off, the TPC-DS q77 shape that used to
-deadlock the mesh (rendezvous.cc "only 7 of 8 arrived" — one tiny
+The quick-tier guards for the mesh join surface: a join on the mesh is
+the single-device ladder under GSPMD and must be bit-exact against the
+single-chip executor with dynamic filtering on AND off, the TPC-DS q77
+shape that used to deadlock the mesh (rendezvous.cc "only 7 of 8 arrived" — one tiny
 cross-module all-reduce per filter bound) must complete with filtering
 ON, and the pruned-row observability surface must light up. Reference
 pattern: TestDynamicFiltering / AbstractTestJoinQueries on a
@@ -41,10 +40,6 @@ PROBE_ROWS = """
 def mesh_session(n_devices=8, **props):
     s = Session(default_schema="tiny")
     s.executor = MeshExecutor(s.catalog, make_mesh(n_devices))
-    s.execute("SET SESSION join_distribution_type = 'partitioned'")
-    # 'auto' resolves the hash kernel OFF on CPU; force interpret mode
-    # so the tier-1 mesh exercises the same partitioned program TPUs run
-    s.execute("SET SESSION enable_pallas_hash = true")
     for k, v in props.items():
         s.properties[k] = v
     return s
@@ -55,14 +50,15 @@ def ref():
     return Session(default_schema="tiny")
 
 
-def test_partitioned_join_bit_exact_vs_single_chip(ref):
-    """Forced-partitioned mesh join == single-chip executor, row for
-    row, with dynamic filtering on — and the partitioned path actually
-    ran (not a silent broadcast demote)."""
-    s = mesh_session()
-    for sql in (JOIN_AGG, PROBE_ROWS):
-        assert s.execute(sql).rows == ref.execute(sql).rows
-    assert s.executor.stats.mesh_partitioned_joins >= 1
+@pytest.mark.parametrize("filtering", [True, False], ids=["df-on", "df-off"])
+@pytest.mark.parametrize("sql", [JOIN_AGG, SELECTIVE],
+                         ids=["join-agg", "selective"])
+def test_mesh_join_rows_equal_single_chip(ref, sql, filtering):
+    """The GSPMD join a mesh of chips runs == the single-chip executor,
+    row for row, with the batched filter on and off (PROBE_ROWS: the
+    next test)."""
+    s = mesh_session(mesh_dynamic_filtering=filtering)
+    assert s.execute(sql).rows == ref.execute(sql).rows
 
 
 def test_probe_rows_bit_exact_filtering_on_vs_off(ref):
@@ -90,13 +86,6 @@ def test_pruned_row_counters_nonzero_on_selective_join(ref):
     pruned = s.executor.stats.dynamic_filter_rows_pruned
     assert pruned > 0
     assert DYNAMIC_FILTER_ROWS_PRUNED.value() - before >= pruned
-
-
-def test_explain_surfaces_join_distribution():
-    s = mesh_session()
-    s.execute(JOIN_AGG)
-    text = "\n".join(r[0] for r in s.execute("EXPLAIN " + JOIN_AGG).rows)
-    assert "join distribution: partitioned" in text
 
 
 def test_run_scan_pads_odd_capacity_to_shard_multiple():

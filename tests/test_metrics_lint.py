@@ -106,15 +106,11 @@ REQUIRED_FAMILIES = (
     "trino_tpu_router_decisions_total",
     "trino_tpu_microbatch_queries_total",
     "trino_tpu_microbatch_batches_total",
-    # round-12 TPU-native hash aggregation / hybrid hash join surface:
     # the per-operator strategy gate's decision counters
     "trino_tpu_agg_strategy_decisions_total",
     "trino_tpu_join_strategy_decisions_total",
-    # round-13 mesh-partitioned join surface: distribution decisions,
-    # batched dynamic-filter pruning, all_to_all exchange accounting
-    "trino_tpu_join_distribution_decisions_total",
+    # the mesh executor's batched dynamic-filter pruning
     "trino_tpu_dynamic_filter_rows_pruned_total",
-    "trino_tpu_mesh_repartition_bytes_total",
     # round-14 scan-path surface: zone-map pruning + the chunked-driver
     # prefetch pipeline
     "trino_tpu_scan_splits_pruned_total",
@@ -133,10 +129,6 @@ REQUIRED_FAMILIES = (
     "trino_tpu_prewarm_hits_total",
     "trino_tpu_compile_seconds_saved_total",
     "trino_tpu_jit_distinct_shapes",
-    # round-17 fused multiway star join: kernel launches + per-reason
-    # dim degrades back to the pairwise ladder
-    "trino_tpu_multijoin_fused_probes_total",
-    "trino_tpu_multijoin_degrades_total",
     # round-18 exactly-once distributed writes: staged attempts, commit
     # outcomes, first-success-wins dedup, orphan sweeps
     "trino_tpu_write_tasks_total",

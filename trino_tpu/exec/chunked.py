@@ -156,14 +156,12 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     value-packed gather.
 
     `adapt` applies a previous run's measurements (AdaptivePlanner.java:87's
-    role, replayed through the cross-run decision cache): {"windows":
-    {join_idx: W}} probes a packed join through a W-sized LUT window
-    (near-sorted keys); {"compact": (join_idx, cap)} compacts live rows
-    to `cap` after that join so later operators run at the real
-    selectivity. Both are guesses that may be invalidated by new data,
-    so the program reports per-join (escaped, span, live) + compaction
-    overflow in a stats vector the DRIVER must verify (nonzero escaped/
-    overflow => rerun the plain program).
+    role, replayed through the cross-run decision cache): {join_idx: W}
+    probes a packed join through a W-sized LUT window (near-sorted
+    keys). A window is a guess that new data may invalidate, so the
+    program reports the escaped rows and per-join key spans in a stats
+    vector the DRIVER must verify (nonzero escaped => rerun the plain
+    program).
 
     `gather_mode` routes windowed packed probes through the Pallas
     tiled-gather kernel (ops/pallas_gather.py): the driver prepares
@@ -173,28 +171,24 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     plain exactly as before.
 
     Returns (fn, join_nodes) where fn(chunk, builds, luts, gplanes) ->
-    (partial Batch, stats int64[2 + 3*n_joins]); stats layout:
-    [escaped_total, compact_overflow, span_0, live_0, 0, span_1, ...].
+    (partial Batch, stats int64[1 + n_joins]); stats layout:
+    [escaped_total, span_0, span_1, ...].
     None when the shape doesn't apply (caller uses the per-node loop)."""
     from ..ops.aggregate import (AggSpec, direct_group_aggregate,
                                  global_aggregate)
-    from ..ops.join import (compact_live, dense_join_packed,
-                            dense_join_packed_windowed,
+    from ..ops.join import (dense_join_packed, dense_join_packed_windowed,
                             dense_join_with_lut)
     from ..ops.project import apply_filter, filter_project
 
     joins: List[L.JoinNode] = []
-    windows = (adapt or {}).get("windows", {})
-    compact_at = (adapt or {}).get("compact")
+    windows = adapt or {}
 
     def emit(node):
         """Returns f(chunk, builds, luts, gplanes) -> (Batch, stats
-        dict) or None. stats: {"escaped": scalar, "overflow": scalar,
-        "joins": [(span, live), ...]}."""
+        dict) or None. stats: {"escaped": scalar, "spans": [...]}."""
         if node is driver:
             return lambda chunk, builds, luts, gp: (chunk, {
-                "escaped": jnp.int64(0), "overflow": jnp.int64(0),
-                "joins": []})
+                "escaped": jnp.int64(0), "spans": []})
         if isinstance(node, L.FilterNode):
             child = emit(node.child)
             if child is None:
@@ -226,12 +220,10 @@ def compile_fused_chunk(executor, target: L.PlanNode,
             lk, rk, kind = node.left_keys, node.right_keys, node.kind
             spec = lut_specs.get(id(node)) if lut_specs else None
             window = windows.get(idx)
-            cap = compact_at[1] if compact_at is not None and \
-                compact_at[0] == idx else None
 
             def run_join(chunk, b, l, g, _child=child, _idx=idx,
                          _lk=lk, _rk=rk, _kind=kind, _spec=spec,
-                         _win=window, _cap=cap):
+                         _win=window):
                 bt, st = _child(chunk, b, l, g)
                 esc = jnp.int64(0)
                 if _spec is not None and _spec[0] == "packed":
@@ -251,13 +243,8 @@ def compile_fused_chunk(executor, target: L.PlanNode,
                     out = dense_join_with_lut(bt, b[_idx], l[_idx], _lk,
                                               _rk, _kind, gather_mode)
                     span = _key_span(bt, _lk)
-                live = jnp.sum(out.live, dtype=jnp.int64)
-                if _cap is not None:
-                    out, over = compact_live(out, _cap)
-                    st = dict(st, overflow=st["overflow"] + over)
-                return out, dict(
-                    st, escaped=st["escaped"] + esc,
-                    joins=st["joins"] + [(span, live)])
+                return out, {"escaped": st["escaped"] + esc,
+                             "spans": st["spans"] + [span]}
             return run_join
         if isinstance(node, L.AggregateNode):
             child = emit(node.child)
@@ -291,11 +278,7 @@ def compile_fused_chunk(executor, target: L.PlanNode,
 
     def fn(chunk, builds, luts, gplanes=()):
         out, st = inner(chunk, builds, luts, gplanes)
-        parts = [st["escaped"], st["overflow"]]
-        for span, live in st["joins"]:
-            parts.extend((span, live, jnp.int64(0)))
-        return out, jnp.stack(parts) if parts else \
-            jnp.zeros(2, jnp.int64)
+        return out, jnp.stack([st["escaped"]] + st["spans"])
     return fn, joins
 
 
@@ -438,26 +421,24 @@ def _windowed_planes(gmode: str, adapt, specs, luts, k):
     windowed probe won't run for it (mode off, not adapted to a window,
     not value-packed, or domain too wide for 32-bit kernel indices)."""
     from ..ops import pallas_gather
-    windows = (adapt or {}).get("windows", {})
-    if gmode == "off" or k not in windows or specs[k] is None or \
+    if gmode == "off" or k not in (adapt or {}) or specs[k] is None or \
             specs[k][0] != "packed" or \
             luts[k].shape[0] > pallas_gather.MAX_WINDOWED_ELEMS:
         return None
     return pallas_gather.prepare_word_planes(luts[k])
 
 
-# adaptive re-optimization safety margins: windows/capacities pad the
-# measured maxima so ordinary chunk-to-chunk variance doesn't trip the
-# rerun path; real data changes still do (and then re-measure)
+# adaptive re-optimization safety margin: windows pad the measured
+# maxima so ordinary chunk-to-chunk variance doesn't trip the rerun
+# path; real data changes still do (and then re-measure)
 _ADAPT_MARGIN = 1.25
 
 
-def _fused_adaptation(executor, skey, spine, specs, chunk_cap):
+def _fused_adaptation(executor, skey, spine, specs):
     """Build the `adapt` argument for compile_fused_chunk from a
     previous run's recorded measurements (cross-run decision cache):
-    window sizes for packed joins with near-sorted probe keys, and one
-    compaction point where measured selectivity is low. None on the
-    first-ever run (the plain program measures)."""
+    window sizes for packed joins with near-sorted probe keys. None on
+    the first-ever run (the plain program measures)."""
     from ..batch import bucket_capacity
     if skey is None:
         return None
@@ -465,67 +446,43 @@ def _fused_adaptation(executor, skey, spine, specs, chunk_cap):
         executor._load_decisions()
     rec = executor._decision_cache.get(
         ("fusedadapt", skey, executor._decision_salt()))
-    if rec is None or len(rec) != 2 * len(spine):
+    if rec is None or len(rec) != len(spine):
         return None
-    allow_windows = getattr(executor, "enable_adapt_windows", True)
-    allow_compact = getattr(executor, "enable_adapt_compact", False)
     windows = {}
-    compact = None
     for i, j in enumerate(spine):
-        span, live = rec[2 * i], rec[2 * i + 1]
+        span = rec[i]
         domain = j.build_key_domain
-        if allow_windows and specs[i] is not None and \
-                specs[i][0] == "packed" and span > 0 and domain:
+        if specs[i] is not None and specs[i][0] == "packed" and \
+                span > 0 and domain:
             w = bucket_capacity(int(span * _ADAPT_MARGIN))
             if w * 2 <= domain:      # window must actually shrink reads
                 windows[i] = w
-        if allow_compact and compact is None and live >= 0:
-            # NOTE measured on v5e: jnp.nonzero's lowering scatters, and
-            # TPU scatter costs ~80ns/row — in-program compaction LOSES
-            # unless later stages are very wide. Off by default.
-            c = max(1024, bucket_capacity(int(live * _ADAPT_MARGIN)))
-            if c * 4 <= chunk_cap:   # only pay the compact gather when
-                compact = (i, c)     # later operators shrink >=4x
-    if not windows and compact is None:
-        return None
-    return {"windows": windows, "compact": compact}
+    return windows or None
 
 
 def _verify_record_adaptation(executor, skey, adapt, chunk_stats) -> bool:
-    """ONE fetch over the run's stacked per-chunk stats: correctness
-    flags (escaped window rows, compaction overflow) plus span/live
-    maxima. Plain runs record measurements for the next run's
-    adaptation; adapted runs verify their guesses — False means results
-    are unusable and the caller must rerun plain (the stale record is
-    removed so the rerun re-measures)."""
+    """ONE fetch over the run's stacked per-chunk stats: the escaped
+    window rows plus the per-join span maxima. Plain runs record the
+    spans for the next run's adaptation; adapted runs verify their
+    guesses — False means results are unusable and the caller must
+    rerun plain (the stale record is removed so the rerun
+    re-measures)."""
     key = ("fusedadapt", skey, executor._decision_salt()) \
         if skey is not None else None
     if adapt is None and (key is None or key in executor._decision_cache):
         return True      # nothing to verify or record: skip the sync
     stk = jnp.stack(chunk_stats)
-    esc = jnp.sum(stk[:, 0])
-    over = jnp.sum(stk[:, 1])
-    spans = jnp.max(stk[:, 2::3], axis=0)
-    lives = jnp.max(stk[:, 3::3], axis=0)
     vals = np.asarray(jnp.concatenate(
-        [jnp.stack([esc, over]), spans, lives]))
-    n_joins = len(spans)
-    esc_h, over_h = int(vals[0]), int(vals[1])
-    measured = []
-    for i in range(n_joins):
-        measured.extend((int(vals[2 + i]), int(vals[2 + n_joins + i])))
-    if esc_h > 0 or over_h > 0:
-        if over_h > 0:
-            executor.stats.compaction_overflows += 1
+        [jnp.sum(stk[:, :1], axis=0), jnp.max(stk[:, 1:], axis=0)]))
+    if int(vals[0]) > 0:
         # stale guesses: drop the record so the rerun runs PLAIN and
-        # re-measures (an adapted rerun from these numbers could loop —
-        # escaped rows depress the live measurement)
+        # re-measures
         if key is not None:
             executor._decision_cache.pop(key, None)
             executor._decision_dirty = True
         return False
     if adapt is None and key is not None:
-        executor._decision_cache[key] = tuple(measured)
+        executor._decision_cache[key] = tuple(int(v) for v in vals[1:])
         executor._decision_dirty = True
     return True
 
@@ -588,13 +545,6 @@ def analyze(root: L.OutputNode, catalog, chunk_rows: int,
             if parent.left is not node:
                 return None       # driver on the build side: can't stream
             build_roots.append(parent.right)
-        elif isinstance(parent, L.MultiJoinNode):
-            # fused star: the driver must BE the fact side; every
-            # dimension pins like a pairwise build side, so the fused
-            # tables build once and each chunk probes them sync-free
-            if parent.fact is not node:
-                return None
-            build_roots.extend(parent.dims)
         elif isinstance(parent, L.AggregateNode):
             if any(a.distinct for a in parent.aggs):
                 return None       # distinct needs global dedup
@@ -831,7 +781,7 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
             # produces new node objects but identical static values)
             gmode = executor.gather_mode()
             skey = executor.build_structure_key(per_chunk_target)
-            adapt = _fused_adaptation(executor, skey, spine, specs, cap)
+            adapt = _fused_adaptation(executor, skey, spine, specs)
             # Pallas windowed probes gather off int32 planes prepared
             # ONCE per pinned LUT (per-chunk re-splitting would re-read
             # the whole domain-sized table every chunk)
@@ -1176,8 +1126,7 @@ def merge_partials(executor, node: L.AggregateNode,
                    partials: List[Batch]) -> Batch:
     """FINAL step: concat partial states, re-aggregate with merge
     functions over the partial layout (keys at 0..n_keys-1, states
-    after). Hash-strategy operators merge through the hash-partial
-    path (executor.merge_group_aggregate) instead of the sort merge."""
+    after)."""
     from ..ops.aggregate import AggSpec, global_aggregate
     from .executor import concat_all
 

@@ -64,21 +64,11 @@ class ExecStats:
                                      # chunk pipelines compiled fresh)
     escaped_window_reruns: int = 0   # adapted fused runs whose window /
                                      # capacity guesses were violated
-    compaction_overflows: int = 0    # in-program compaction capacity hit
     spilled_joins: int = 0           # joins retried through host-spill
                                      # radix partitioning (exec/spill.py)
     spilled_aggregations: int = 0    # aggregations/partial states spilled
     spilled_sorts: int = 0           # sorts retried on host (TopN under
                                      # pressure)
-    hash_agg_calls: int = 0          # VMEM hash-table aggregations run
-                                     # (ops/pallas_hash.py)
-    hash_agg_escapes: int = 0        # hash-agg overflow escapes that
-                                     # radix-partitioned and re-entered
-    hash_join_calls: int = 0         # hybrid hash-join builds attempted
-    hash_join_escapes: int = 0       # join builds that overflowed the
-                                     # table and degraded partition-wise
-    mesh_partitioned_joins: int = 0  # joins hash-repartitioned over the
-                                     # mesh (parallel/dist_executor.py)
     dynamic_filter_rows_pruned: int = 0   # probe rows cut by build-side
                                           # bounds before the join ran
     scan_zones_pruned: int = 0       # zone-map row ranges skipped at scan
@@ -88,10 +78,6 @@ class ExecStats:
     scan_prefetched_chunks: int = 0  # chunks served from the prefetch
                                      # pipeline (exec/chunked.py)
     scan_prefetch_stalls: int = 0    # consumer waits on an unstaged chunk
-    multijoin_fused_probes: int = 0  # fused multiway star passes run
-                                     # (ops/pallas_hash.multiway_probe)
-    multijoin_degrades: int = 0      # star dimensions degraded back to
-                                     # the pairwise ladder (any reason)
 
 
 class QueryDeadlineError(RuntimeError):
@@ -193,20 +179,6 @@ class Executor:
         # off-TPU, which is how tier-1 exercises the kernel logic);
         # "false" = every site keeps its jnp.take path
         self.enable_pallas_gather = "auto"
-        # Pallas VMEM hash-table kernel (ops/pallas_hash.py): hash
-        # aggregation + hybrid hash join; same auto/true/false contract
-        self.enable_pallas_hash = "auto"
-        self.hash_table_slots = 0      # 0 = size from stats; tests pin
-        # fused multiway star join (ops/pallas_hash.multiway_probe):
-        # same auto/true/false contract; the planner consults its OWN
-        # copy of the property when deciding to emit MultiJoinNode, this
-        # one gates the executor's kernel-vs-ladder choice
-        self.enable_multiway_join = "auto"
-        self.multiway_max_dims = 5
-        # resident-table budget for the fused pass, in KiB (per-dim
-        # tables share one slot count; dims are dropped largest-first to
-        # the pairwise path until the stack fits)
-        self.multiway_vmem_kb = 8192
         # per-query record of the strategy each operator class actually
         # ran with (EXPLAIN `agg strategy:` lines, operator_stats column)
         self.strategy_decisions: Dict[str, str] = {}
@@ -436,19 +408,14 @@ class Executor:
         error propagates so an enclosing operator (or the query
         boundary) handles it."""
         if not self.enable_spill or \
-                not isinstance(node, (L.JoinNode, L.MultiJoinNode,
-                                      L.AggregateNode, L.SortNode)):
+                not isinstance(node, (L.JoinNode, L.AggregateNode,
+                                      L.SortNode)):
             raise
         # drop this subtree's partial reservations from the failed
         # attempt; the spill path re-executes the children bounded
         self.release_path_reservations(node, keep=self._subst)
         from .spill import spill_aggregate, spill_join, spill_sort
-        if isinstance(node, L.MultiJoinNode):
-            # the spill tier partitions pairwise joins: reconstruct the
-            # exact ladder the star fused and spill its top hop
-            self._note_multijoin_degrade("spill", len(node.dims))
-            out = spill_join(self, L.multijoin_to_ladder(node))
-        elif isinstance(node, L.JoinNode):
+        if isinstance(node, L.JoinNode):
             out = spill_join(self, node)
         elif isinstance(node, L.AggregateNode):
             out = spill_aggregate(self, node)
@@ -536,9 +503,7 @@ class Executor:
         counts, merge-join toggles which kernel's dup check runs)."""
         return (self.enable_dynamic_filtering, self.enable_merge_join,
                 str(self.enable_mxu_agg), bool(self.stream_build_bytes),
-                self.spill_chunk_rows, self.hash_mode() != "off",
-                self.hash_table_slots, self.multiway_mode() != "off",
-                self.multiway_vmem_kb)
+                self.spill_chunk_rows)
 
     _DECISION_CACHE_FILE = "decisions.pkl"
 
@@ -721,8 +686,6 @@ class Executor:
             return self.run_aggregate(node)
         if isinstance(node, L.JoinNode):
             return self.run_join(node)
-        if isinstance(node, L.MultiJoinNode):
-            return self.run_multijoin(node)
         if isinstance(node, L.WindowNode):
             return self.run_window(node)
         if isinstance(node, L.SortNode):
@@ -1078,28 +1041,6 @@ class Executor:
         from ..ops.pallas_gather import resolve_mode
         return resolve_mode(self.enable_pallas_gather)
 
-    def hash_mode(self) -> str:
-        """Resolved Pallas hash-table mode: 'device' | 'interpret' |
-        'off' (ops/pallas_hash.resolve_mode; interpret is the CPU/tier-1
-        path, like the tiled gather's)."""
-        from ..ops.pallas_hash import resolve_mode
-        return resolve_mode(self.enable_pallas_hash)
-
-    def multiway_mode(self) -> str:
-        """Resolved fused multiway-join mode: 'device' | 'interpret' |
-        'off' (ops/pallas_hash.resolve_mode — the same contract as the
-        other Pallas kernels; interpret is the CPU/tier-1 path)."""
-        from ..ops.pallas_hash import resolve_mode
-        return resolve_mode(self.enable_multiway_join)
-
-    def _note_multijoin_degrade(self, reason: str,
-                                count: int = 1) -> None:
-        """Count star dimensions degraded back to the pairwise ladder,
-        per reason (kernel_off/vmem/dup/escape/dtype/mesh/spill)."""
-        self.stats.multijoin_degrades += count
-        from ..metrics import MULTIJOIN_DEGRADES
-        MULTIJOIN_DEGRADES.inc(count, reason=reason)
-
     def _note_strategy(self, op: str, strategy: str, kind: str) -> None:
         """Record the strategy an operator actually ran with: the
         per-query EXPLAIN/operator_stats surface plus the
@@ -1160,12 +1101,6 @@ class Executor:
             self._note_strategy("AggregateNode", "direct", "agg")
             return direct_group_aggregate(child, node.group_keys,
                                           node.key_domains, aggs)
-        if node.strategy == "hash":
-            out = self.hash_aggregate(node, child, aggs)
-            if out is not None:
-                return out
-            # kernel off / keys unpackable / value shape unsupported:
-            # the sort path below is the general fallback
         capacity = min(node.out_capacity, child.capacity)   # groups <= rows
         # planner NDV products overestimate real group counts by orders
         # of magnitude on join outputs, and the sorted kernel's key
@@ -1244,163 +1179,17 @@ class Executor:
             return global_aggregate(child, plain)
         return out
 
-    # ---- hash aggregation (ops/pallas_hash.py) -----------------------
-
-    def hash_aggregate(self, node: L.AggregateNode, child: Batch,
-                       aggs) -> Optional[Batch]:
-        """Strategy 'hash': the VMEM hash-table kernel with the
-        escape -> radix-partition -> re-enter degradation chain. The
-        group-count estimate sizes the table (the decision cache's
-        measured count on re-execution, the planner estimate first
-        time). None = shape unsupported; caller runs the sort path."""
-        est = node.out_capacity
-        if self.decisions_cacheable(node):
-            skey = self.memo_structure_key(node)
-            if skey is not None and not self._decision_loaded:
-                self._load_decisions()
-            known = self._decision_cache.get(
-                ("aggfinal", skey, self._decision_salt())) \
-                if skey is not None else None
-            if known is not None:
-                est = max(1, known[0])
-        out = self.try_hash_group_agg(child, node.group_keys, aggs,
-                                      est, node=node)
-        if out is None:
-            return None
-        self._note_strategy("AggregateNode", "hash", "agg")
-        return out
-
-    def try_hash_group_agg(self, child: Batch, keys: tuple, aggs,
-                           est_groups: int,
-                           node=None) -> Optional[Batch]:
-        """One hash aggregation over `child` grouped by `keys`:
-        kernel-first, and on overflow escape the batch radix-partitions
-        by the spill tier's splitmix64 key hash so every group lands
-        wholly inside one partition and each partition re-enters the
-        kernel (still-escaping partitions finish on the sort kernel —
-        exact either way). Used for both the PARTIAL step and the
-        hash-partial FINAL merge. None = ineligible."""
-        from ..ops import pallas_hash as ph
-        mode = self.hash_mode()
-        if mode == "off" or not keys:
-            return None
-        if not ph.supports_aggs(child, aggs) or \
-                any(a.distinct for a in aggs):
-            return None
-        from ..ops.aggregate import key_pack_plan
-        pack = key_pack_plan(
-            child, keys,
-            fetch=(lambda *v: self.fetch_ints(node, "hashpack", *v))
-            if node is not None else None)
-        if pack is None:
-            return None                  # unpackable keys: sort path
-        kmins, bits = pack
-        cap = ph.max_table_slots(aggs)
-        if self.hash_table_slots:
-            t = ph.MIN_TABLE_SLOTS
-            while t * 2 <= min(self.hash_table_slots, cap):
-                t *= 2
-            slots, fits = t, True        # pinned size: escapes decide
-        else:
-            slots, fits = ph.pick_table_slots(max(1, int(est_groups)),
-                                              aggs)
-        self.stats.hash_agg_calls += 1
-        kmins_d = jnp.asarray(kmins)
-        if fits:
-            out, esc, occ = ph.hash_group_aggregate(
-                child, kmins_d, keys, bits, aggs, slots, mode)
-            esc_h, n_groups = self.fetch_ints(
-                node, f"hashagg{slots}", esc, occ)
-            if esc_h == 0:
-                if node is not None and self.decisions_cacheable(node):
-                    skey = self.memo_structure_key(node)
-                    if skey is not None:
-                        self._decision_cache[
-                            ("aggfinal", skey,
-                             self._decision_salt())] = (n_groups,)
-                        self._decision_dirty = True
-                return out
-        self.stats.hash_agg_escapes += 1
-        return self._partitioned_hash_agg(child, keys, aggs, kmins_d,
-                                          bits, est_groups, slots, mode)
-
-    def _partitioned_hash_agg(self, child: Batch, keys: tuple, aggs,
-                              kmins_d, bits: tuple, est_groups: int,
-                              slots: int, mode: str) -> Batch:
-        """The escape path: radix-partition the batch host-side with
-        the SAME splitmix64 partitioner the host-spill tier uses
-        (exec/spill._partition_ids), so a partition that later spills
-        under memory pressure is already kernel-shaped. Groups never
-        straddle partitions, so per-partition results concatenate
-        exactly."""
-        from ..batch import batch_from_numpy, batch_to_numpy, \
-            bucket_capacity
-        from ..ops import pallas_hash as ph
-        from ..ops.aggregate import sort_group_aggregate
-        from .spill import _partition_ids
-        arrs, vals = batch_to_numpy(child)
-        n = len(arrs[0]) if arrs else 0
-        load = ph.LOAD_NUM / ph.LOAD_DEN
-        want = max(2, -(-int(max(est_groups, 1)) //
-                        max(1, int(slots * load))))
-        count = 2
-        while count < want and count < 256:
-            count *= 2
-        part = _partition_ids(arrs, vals, keys, count)
-        outs: List[tuple] = []
-        with self.no_decisions():
-            for p in range(count):
-                m = part == p
-                if not m.any():
-                    continue
-                pb = batch_from_numpy([a[m] for a in arrs],
-                                      valids=[v[m] for v in vals])
-                out, esc, _occ = ph.hash_group_aggregate(
-                    pb, kmins_d, keys, bits, aggs, slots, mode)
-                if int(esc) > 0:
-                    # still too many groups in this partition (skew):
-                    # the sort kernel finishes it — groups are disjoint
-                    # across partitions either way
-                    out = sort_group_aggregate(
-                        pb, keys, aggs, bucket_capacity(int(m.sum())),
-                        self.gather_mode())
-                oa, ov = batch_to_numpy(out)
-                if oa and len(oa[0]):
-                    outs.append((oa, ov))
-        if not outs:
-            empty = batch_from_numpy(
-                [np.zeros(0, np.asarray(a).dtype) for a in arrs],
-                valids=[np.zeros(0, np.bool_) for _ in arrs])
-            # shape the empty output like the kernel's (keys + states)
-            out, _e, _o = ph.hash_group_aggregate(
-                empty, kmins_d, keys, bits, aggs, ph.MIN_TABLE_SLOTS,
-                mode)
-            return out
-        ncols = len(outs[0][0])
-        return batch_from_numpy(
-            [np.concatenate([o[0][j] for o in outs])
-             for j in range(ncols)],
-            valids=[np.concatenate([o[1][j] for o in outs])
-                    for j in range(ncols)])
-
     def merge_group_aggregate(self, node: L.AggregateNode,
                               merged: Batch, merge_aggs,
                               capacity: int) -> Batch:
         """FINAL merge of grouped partial states (keys at 0..n_keys-1,
-        mergeable states after): hash-partial merge when the operator's
-        gate picked hash and the partial batch qualifies, the sort
-        merge otherwise — shared by the chunked driver's PartialState
-        and the spill tier's partial pages."""
+        mergeable states after) — shared by the chunked driver's
+        PartialState and the spill tier's partial pages."""
         from ..ops.aggregate import (key_pack_plan_words,
                                      packed_sort_group_aggregate,
                                      sort_group_aggregate)
         n_keys = len(node.group_keys)
         keys = tuple(range(n_keys))
-        if node.strategy == "hash":
-            out = self.try_hash_group_agg(merged, keys, merge_aggs,
-                                          capacity)
-            if out is not None:
-                return out
         # the same compile-cost rule as aggregate_batch: past
         # SORT_SMALL_ROWS the keys pack into int64 words so every sort
         # is (word, index)
@@ -1744,14 +1533,6 @@ class Executor:
                     self._note_strategy("JoinNode", "dense-lut", "join")
                     return self.maybe_compact(out, live=live)
                 self.stats.join_domain_fallbacks += 1
-        # sparse key domain (no dense LUT): the hybrid hash join beats
-        # the sorted fallback's ~24 serial searchsorted gather rounds
-        status, hout = self.try_hash_join(node, probe, build,
-                                          allow_dup=False)
-        if status == "ok":
-            return hout
-        if status == "dup":
-            return None                # caller expands (dup build keys)
         out, dup = join_unique_build(probe, build, node.left_keys,
                                      node.right_keys, node.kind)
         dup, live = self.fetch_ints(node, "jsorted", dup,
@@ -1760,127 +1541,6 @@ class Executor:
             self._note_strategy("JoinNode", "sorted", "join")
             return self.maybe_compact(out, live=live)
         return None
-
-    def try_hash_join(self, node: L.JoinNode, probe: Batch,
-                      build: Batch, allow_dup: bool):
-        """Hybrid hash join (ops/pallas_hash.py): build side hashed into
-        the VMEM kernel table (min(row_id) per key), probe walks the
-        linear chains with pallas_gather-fused plane gathers. When the
-        build exceeds the table's load cap, degrade partition-by-
-        partition to the host equi-join over the SAME splitmix64 radix
-        fanout the spill tier uses — spilled partitions are already
-        kernel-shaped.
-
-        Returns (status, batch): 'ok' = joined; 'dup' = build broke the
-        uniqueness contract (caller falls back to the expansion join);
-        'skip' = shape unsupported (caller continues down its ladder)."""
-        from ..ops import pallas_hash as ph
-        mode = self.hash_mode()
-        if mode == "off" or node.kind not in ("inner", "left", "semi",
-                                              "anti") or \
-                node.residual is not None or node.null_aware:
-            return "skip", None
-        # the partitioned degrade needs integer-typed keys host-side
-        for side, keys in ((probe, node.left_keys),
-                           (build, node.right_keys)):
-            for k in keys:
-                dt = side.columns[k].data.dtype
-                if not (jnp.issubdtype(dt, jnp.integer) or
-                        dt == jnp.bool_):
-                    return "skip", None
-        slots, fits = ph.join_table_slots(build.capacity)
-        if self.hash_table_slots:
-            t = ph.MIN_TABLE_SLOTS
-            while t * 2 <= min(self.hash_table_slots,
-                               ph.MAX_TABLE_SLOTS):
-                t *= 2
-            slots = t
-            fits = t * ph.LOAD_NUM // ph.LOAD_DEN >= build.capacity
-        self.stats.hash_join_calls += 1
-        if fits:
-            # chunk mode: build + validate ONCE per pinned build, probe
-            # every chunk sync-free (the dense LUT's caching policy)
-            ckey = (id(node), "hash", slots)
-            rec = self._chunk_lut_cache.get(ckey) if self.chunk_mode \
-                else None
-            if rec is None:
-                tkl, tkh, src, dup, esc = ph.build_join_table(
-                    build, node.right_keys, slots, mode)
-                dup_h, esc_h = self.fetch_ints(
-                    node, f"hashbuild{slots}", dup, esc)
-                rec = (tkl, tkh, src, dup_h, esc_h)
-                if self.chunk_mode:
-                    self._chunk_lut_cache[ckey] = rec
-            tkl, tkh, src, dup_h, esc_h = rec
-            if esc_h == 0:
-                if dup_h > 0 and not allow_dup:
-                    return "dup", None
-                out = ph.hash_join_probe(
-                    probe, build, tkl, tkh, src, node.left_keys,
-                    node.right_keys, node.kind, self.gather_mode())
-                self._note_strategy("JoinNode", "hybrid-hash", "join")
-                if node.kind == "inner" and not self.chunk_mode:
-                    live = self.fetch_ints(node, "hashjoinlive",
-                                           jnp.sum(out.live))[0]
-                    out = self.maybe_compact(out, live=live)
-                return "ok", out
-        self.stats.hash_join_escapes += 1
-        out = self._partitioned_hash_join(node, probe, build)
-        if out is None:
-            return "skip", None
-        self._note_strategy("JoinNode", "hybrid-hash", "join")
-        return "ok", out
-
-    def _partitioned_hash_join(self, node: L.JoinNode, probe: Batch,
-                               build: Batch) -> Optional[Batch]:
-        """Graceful degradation ("Design Trade-offs for a Robust
-        Dynamic Hybrid Hash Join"): both sides radix-partition by the
-        exchange's splitmix64 hash and each partition joins alone
-        through the host equi-join the spill tier already proves
-        bit-exact (exec/spill._host_equi_join). Handles duplicate build
-        keys by expansion, so the unique-build contract cannot be
-        violated here."""
-        from ..batch import batch_from_numpy, batch_to_numpy
-        from .spill import _host_equi_join, _partition_ids
-        parrs, pvalids = batch_to_numpy(probe)
-        barrs, bvalids = batch_to_numpy(build)
-        from ..ops import pallas_hash as ph
-        load_cap = ph.MAX_TABLE_SLOTS * ph.LOAD_NUM // ph.LOAD_DEN
-        want = max(2, -(-len(barrs[0]) // load_cap)) if barrs else 2
-        count = 2
-        while count < want and count < 256:
-            count *= 2
-        part_p = _partition_ids(parrs, pvalids, node.left_keys, count)
-        part_b = _partition_ids(barrs, bvalids, node.right_keys, count)
-        outs: List[list] = []
-        outs_v: List[list] = []
-        for p in range(count):
-            mp = part_p == p
-            mb = part_b == p
-            if not mp.any():
-                continue
-            arrs, vals = _host_equi_join(
-                [a[mp] for a in parrs], [v[mp] for v in pvalids],
-                [a[mb] for a in barrs], [v[mb] for v in bvalids],
-                node.left_keys, node.right_keys, node.kind)
-            if arrs and len(arrs[0]):
-                outs.append(arrs)
-                outs_v.append(vals)
-        if not outs:
-            out_arrs = []
-            out_valids = []
-            srcs = list(probe.columns)
-            if node.kind in ("inner", "left"):
-                srcs += list(build.columns)
-            for c in srcs:
-                out_arrs.append(np.zeros(0, np.asarray(c.data).dtype))
-                out_valids.append(np.zeros(0, np.bool_))
-            return batch_from_numpy(out_arrs, valids=out_valids)
-        ncols = len(outs[0])
-        return batch_from_numpy(
-            [np.concatenate([o[j] for o in outs]) for j in range(ncols)],
-            valids=[np.concatenate([o[j] for o in outs_v])
-                    for j in range(ncols)])
 
     def _chunk_lut_join(self, node: L.JoinNode, probe: Batch,
                         build: Batch, domain: int) -> Optional[Batch]:
@@ -1912,205 +1572,6 @@ class Executor:
         return dense_join_with_lut(probe, build, rec, node.left_keys,
                                    node.right_keys, node.kind,
                                    self.gather_mode())
-
-    # ------------------------------------------------------------------
-    # fused multiway star join (MultiJoinNode)
-    # ------------------------------------------------------------------
-
-    def run_multijoin(self, node: "L.MultiJoinNode") -> Batch:
-        """Lower a MultiJoinNode to the fused single-pass kernel
-        (ops/pallas_hash.multiway_probe), degrading DIMENSION-BY-
-        DIMENSION to the pairwise path whenever a dim's table overflows
-        the VMEM budget, its build keys turn out duplicated, or its
-        insert escaped — and wholesale to the reconstructed ladder when
-        the kernel is off or fewer than two dims survive.  Every output
-        is bit-exact vs `multijoin_to_ladder`'s pairwise ladder: fused
-        dims ride the SAME payload-gather machinery the dense/hash
-        joins use, and column order is restored to ladder order at the
-        end.  The fact side is authoritative (never flipped to build).
-
-        Chunk mode caches the validated dimension tables per node, so
-        each streamed fact chunk probes sync-free like the pairwise
-        dense-LUT path."""
-        from ..ops import pallas_hash as ph
-        mode = self.multiway_mode()
-        if mode == "off":
-            self._note_multijoin_degrade("kernel_off", len(node.dims))
-            return self._run_multijoin_ladder(node)
-        fact = self.run(node.fact)
-        dims = [self.run(d) for d in node.dims]
-        k = len(dims)
-        ckey = (id(node), "multiway")
-        rec = self._chunk_lut_cache.get(ckey) if self.chunk_mode \
-            else None
-        if rec is None:
-            degraded: Dict[int, str] = {}
-            sized = []
-            for d in range(k):
-                ok_dtype = True
-                for side, keys in ((fact, node.fact_keys[d]),
-                                   (dims[d], node.dim_keys[d])):
-                    for ki in keys:
-                        dt = side.columns[ki].data.dtype
-                        if not (jnp.issubdtype(dt, jnp.integer) or
-                                dt == jnp.bool_):
-                            ok_dtype = False
-                if not ok_dtype:
-                    degraded[d] = "dtype"
-                    continue
-                slots, fits = ph.join_table_slots(dims[d].capacity)
-                if self.hash_table_slots:
-                    t = ph.MIN_TABLE_SLOTS
-                    while t * 2 <= min(self.hash_table_slots,
-                                       ph.MAX_TABLE_SLOTS):
-                        t *= 2
-                    slots = t
-                    fits = t * ph.LOAD_NUM // ph.LOAD_DEN >= \
-                        dims[d].capacity
-                if not fits:
-                    degraded[d] = "vmem"
-                    continue
-                sized.append((d, slots))
-            # all resident tables share ONE slot count (rectangular
-            # stack on the bucket_capacity-style power-of-two lattice);
-            # shed the largest dims until the stack fits the budget
-            budget = self.multiway_vmem_kb << 10
-            while sized and ph.multiway_table_bytes(
-                    len(sized), max(s for _, s in sized)) > budget:
-                drop = max(sized, key=lambda x: x[1])
-                sized.remove(drop)
-                degraded[drop[0]] = "vmem"
-            fused = []
-            if len(sized) >= 2:
-                table_slots = max(s for _, s in sized)
-                builds, checks = [], []
-                for d, _s in sized:
-                    tkl, tkh, src, dup, esc = ph.build_join_table(
-                        dims[d], node.dim_keys[d], table_slots, mode)
-                    builds.append((d, tkl, tkh, src))
-                    checks.extend((dup, esc))
-                # ONE fused validation fetch for all k builds
-                vals = self.fetch_ints(node, f"mjbuild{table_slots}",
-                                       *checks)
-                for i, b in enumerate(builds):
-                    if vals[2 * i] > 0:
-                        degraded[b[0]] = "dup"
-                    elif vals[2 * i + 1] > 0:
-                        degraded[b[0]] = "escape"
-                    else:
-                        fused.append(b)
-            for _d, reason in sorted(degraded.items()):
-                self._note_multijoin_degrade(reason)
-            rec = (fused, sorted(degraded))
-            if self.chunk_mode:
-                self._chunk_lut_cache[ckey] = rec
-        fused, degraded_dims = rec
-        if len(fused) < 2:
-            # nothing left worth a fused pass: run the whole ladder
-            # over the already-materialized children
-            return self._run_multijoin_ladder(node, fact, dims)
-        from ..metrics import (JOIN_STRATEGY_DECISIONS,
-                               MULTIJOIN_FUSED_PROBES)
-        found, _miss = ph.multiway_probe(
-            fact,
-            jnp.stack([b[1] for b in fused]),
-            jnp.stack([b[2] for b in fused]),
-            jnp.stack([b[3] for b in fused]),
-            tuple(node.fact_keys[b[0]] for b in fused), mode)
-        self.stats.multijoin_fused_probes += 1
-        MULTIJOIN_FUSED_PROBES.inc()
-        self.strategy_decisions["MultiJoinNode"] = \
-            f"multiway[k={len(fused)}]"
-        JOIN_STRATEGY_DECISIONS.inc(strategy="multiway")
-        # payload assembly: fused dims first (their found rows align to
-        # fact rows), then each degraded dim through the pairwise path;
-        # unique-build hops are commutative live-mask ANDs and dup
-        # expansions keep their original relative order, so the row
-        # sequence matches the ladder's
-        from ..ops.join import _combined_key, _gather_build_payload
-        gm = self.gather_mode()
-        acc = fact
-        acc_out = list(node.fact.output)
-        col_ranges: Dict[int, tuple] = {}
-        pos = len(fact.columns)
-        for i, (d, _tl, _th, _sr) in enumerate(fused):
-            matched = found[i] >= 0
-            pk, _pk_valid = _combined_key(fact, node.fact_keys[d])
-            src_c = jnp.clip(found[i], 0, dims[d].capacity - 1)
-            acc = _gather_build_payload(acc, dims[d], src_c, matched,
-                                        pk, node.dim_keys[d], "inner",
-                                        gm)
-            col_ranges[d] = (pos, len(dims[d].columns))
-            acc_out.extend(node.dims[d].output)
-            pos += len(dims[d].columns)
-        for d in degraded_dims:
-            # chunk mode: keep the synthesized hop alive across chunks
-            # so its id stays stable — the pairwise LUT/hash caches key
-            # on id(node), and a per-chunk temporary could both miss
-            # every chunk AND alias a dead node's reused id
-            jkey = (id(node), "mjpair", d)
-            j = self._chunk_lut_cache.get(jkey) if self.chunk_mode \
-                else None
-            if j is None:
-                j = L.JoinNode(
-                    "inner", node.fact, node.dims[d],
-                    node.fact_keys[d], node.dim_keys[d], None, True,
-                    tuple(acc_out) + tuple(node.dims[d].output),
-                    distribution=node.distribution,
-                    build_key_domain=node.dim_domains[d])
-                if self.chunk_mode:
-                    self._chunk_lut_cache[jkey] = j
-            # per-partition batches differ from what the structure key
-            # describes (fused columns ride along): no cached decisions
-            with self.no_decisions():
-                acc = self._run_join_inner(j, acc, dims[d])
-            col_ranges[d] = (pos, len(dims[d].columns))
-            acc_out.extend(node.dims[d].output)
-            pos += len(dims[d].columns)
-        perm = list(range(len(fact.columns)))
-        for d in range(k):
-            start, ln = col_ranges[d]
-            perm.extend(range(start, start + ln))
-        if perm != list(range(len(acc.columns))):
-            acc = Batch(tuple(acc.columns[i] for i in perm), acc.live)
-        if not self.chunk_mode and not degraded_dims:
-            acc = self.maybe_compact(acc, node=node)
-        return acc
-
-    def _run_multijoin_ladder(self, node: "L.MultiJoinNode",
-                              fact: Optional[Batch] = None,
-                              dims: Optional[list] = None) -> Batch:
-        """Full degrade: execute the exact pairwise ladder the star
-        fused.  Already-run children are substituted in so they are not
-        recomputed; the ladder is cached per node in chunk mode so the
-        pairwise LUT/hash caches stay keyed on stable node ids."""
-        from ..metrics import JOIN_STRATEGY_DECISIONS
-        self.strategy_decisions["MultiJoinNode"] = "ladder"
-        JOIN_STRATEGY_DECISIONS.inc(strategy="ladder")
-        lkey = (id(node), "mjladder")
-        ladder = self._chunk_lut_cache.get(lkey) if self.chunk_mode \
-            else None
-        if ladder is None:
-            ladder = L.multijoin_to_ladder(node)
-            if self.chunk_mode:
-                self._chunk_lut_cache[lkey] = ladder
-        temp = []
-        try:
-            if fact is not None:
-                for child, batch in zip((node.fact,) + node.dims,
-                                        [fact] + list(dims)):
-                    if id(child) not in self._subst:
-                        self._subst[id(child)] = batch
-                        temp.append(id(child))
-            out = self.run(ladder)
-        finally:
-            for i in temp:
-                self._subst.pop(i, None)
-        # the outer run() re-reserves this result under the
-        # MultiJoinNode's own id; drop the ladder-top ledger entry so
-        # the bytes are not double-counted
-        self.pool.free(self._node_bytes.pop(id(ladder), 0))
-        return out
 
     def enter_chunk_mode(self) -> None:
         self.chunk_mode = True
@@ -2224,12 +1685,6 @@ class Executor:
                     self._note_strategy("JoinNode", "dense-lut", "join")
                     return out
                 self.stats.join_domain_fallbacks += 1
-            # membership joins tolerate duplicate build keys (the hash
-            # table keeps one row per key, which IS the semantics)
-            status, hout = self.try_hash_join(node, probe, build,
-                                              allow_dup=True)
-            if status == "ok":
-                return hout
             out, _dup = join_unique_build(probe, build, node.left_keys,
                                           node.right_keys, node.kind)
             self._note_strategy("JoinNode", "sorted", "join")
@@ -2289,11 +1744,8 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
     the per-operator strategy gate will pick for this plan (pre-order,
     matching explain_text). After EXPLAIN ANALYZE the executor's
     recorded decision is appended when it differs from the prediction
-    (e.g. a hash plan whose keys could not pack fell back to sort)."""
+    (e.g. a direct plan the MXU kernel took)."""
     lines: List[str] = []
-    hash_on = executor.hash_mode() != "off"
-    multiway_on = executor.multiway_mode() != "off"
-    max_dims = int(getattr(executor, "multiway_max_dims", 5))
     ran = executor.strategy_decisions
 
     def verdict(predicted: str, op: str) -> str:
@@ -2302,7 +1754,7 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
             return f"{predicted} [ran: {actual}]"
         return predicted
 
-    def walk(node: L.PlanNode, spine: bool = False) -> None:
+    def walk(node: L.PlanNode) -> None:
         if isinstance(node, L.AggregateNode) and \
                 node.strategy != "global":
             if node.strategy == "direct":
@@ -2310,56 +1762,20 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
                 for d in node.key_domains:
                     g *= d
                 pred = f"direct ({g} groups)"
-            elif node.strategy == "hash":
-                pred = (f"hash (est {node.out_capacity} groups)"
-                        if hash_on else
-                        f"hash (est {node.out_capacity} groups; "
-                        f"kernel off -> sort)")
             else:
                 pred = f"sort (est {node.out_capacity} groups)"
             lines.append("agg strategy: "
                          + verdict(pred, "AggregateNode"))
         elif isinstance(node, L.JoinNode):
-            # star-detector verdict at the TOP of each probe spine: why
-            # a ladder that stayed pairwise would (not) fuse — printed
-            # either way, so declined stars are as visible as fused ones
-            if not spine:
-                sv = L.star_verdict(node, max_dims)
-                if sv is not None:
-                    lines.append("multiway star: " + sv)
             if node.build_key_domain is not None and node.build_unique:
                 pred = f"dense-lut (domain {node.build_key_domain})"
             elif not node.build_unique:
                 pred = "expand"
-            elif hash_on:
-                pred = "hybrid-hash"
             else:
                 pred = "sort-merge"
             lines.append("join strategy: " + verdict(pred, "JoinNode"))
-            # mesh placement verdict (parallel/dist_executor.py gate):
-            # the planner's stats choice, overridden by what the mesh
-            # executor actually ran (a partitioned ask can degrade to
-            # broadcast on shape/skew grounds)
-            dist = getattr(node, "distribution", "auto")
-            lines.append("join distribution: "
-                         + verdict(dist, "JoinDistribution"))
-        elif isinstance(node, L.MultiJoinNode):
-            kk = len(node.dims)
-            pred = f"multiway[k={kk}]" if multiway_on else \
-                f"multiway[k={kk}] (kernel off -> ladder)"
-            lines.append("join strategy: "
-                         + verdict(pred, "MultiJoinNode"))
-            lines.append("join distribution: "
-                         + verdict(node.distribution,
-                                   "JoinDistribution"))
-        if isinstance(node, L.JoinNode):
-            walk(node.left, spine=True)
-            walk(node.right)
-        elif isinstance(node, L.FilterNode):
-            walk(node.child, spine=spine)
-        else:
-            for c in L.children(node):
-                walk(c)
+        for c in L.children(node):
+            walk(c)
 
     walk(root)
     return lines

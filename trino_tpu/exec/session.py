@@ -77,32 +77,6 @@ SESSION_PROPERTY_DEFAULTS = {
     # on for TPU backends; true forces it (interpret mode on CPU, the
     # tier-1 test path); false = jnp.take everywhere
     "enable_pallas_gather": ("auto", lambda v: str(v).lower()),
-    # Pallas VMEM hash-table kernel (ops/pallas_hash.py): hash
-    # aggregation + hybrid hash join; same auto/true/false contract as
-    # the tiled gather (true = interpret mode on CPU, the tier-1 path)
-    "enable_pallas_hash": ("auto", lambda v: str(v).lower()),
-    # hash-agg table size in slots (0 = size from the group estimate;
-    # tests pin it small to exercise the overflow->partition escape)
-    "hash_table_slots": (0, int),
-    # fused multiway star join (ops/pallas_hash.multiway_probe): the
-    # planner's star detector emits MultiJoinNode and the executor
-    # probes every dimension table in one Pallas pass; same
-    # auto/true/false contract as the other Pallas kernels (true =
-    # interpret mode on CPU, the tier-1 path)
-    "enable_multiway_join": ("auto", lambda v: str(v).lower()),
-    # star-detector cap on fused dimensions per MultiJoinNode
-    "multiway_max_dims": (5, int),
-    # resident-table VMEM budget for the fused pass, in KiB; dims are
-    # shed largest-first to the pairwise ladder until the stack fits
-    # (tests pin it tiny to prove the overflow degrade bit-exact)
-    "multiway_vmem_kb": (8192, int),
-    # planner hash-vs-sort gate: auto applies the rows-per-group rule,
-    # force always picks hash for grouped aggregates, off never does
-    "hash_agg_mode": ("auto", lambda v: str(v).lower()),
-    # auto mode thresholds: hash needs at least this many estimated
-    # groups AND at most this many estimated rows per group
-    "hash_agg_min_groups": (8192, int),
-    "hash_agg_max_rows_per_group": (64, int),
     # dense 'direct' aggregation bound (GroupByHash strategy choice);
     # capped by the kernel's compile-bound MAX_DIRECT_GROUPS
     "direct_agg_max_groups": (64, int),
@@ -278,11 +252,6 @@ class Session:
         kb = self.properties["stream_build_min_kb"]
         ex.stream_build_bytes = (kb << 10) if kb else None
         ex.enable_pallas_gather = self.properties["enable_pallas_gather"]
-        ex.enable_pallas_hash = self.properties["enable_pallas_hash"]
-        ex.hash_table_slots = self.properties["hash_table_slots"]
-        ex.enable_multiway_join = self.properties["enable_multiway_join"]
-        ex.multiway_max_dims = max(2, self.properties["multiway_max_dims"])
-        ex.multiway_vmem_kb = max(1, self.properties["multiway_vmem_kb"])
         ex.enable_mxu_agg = self.properties["mxu_agg"]
         ex.profile = self.properties["enable_profiling"]
         if ex.profile:
@@ -365,7 +334,7 @@ class Session:
         # apply session properties the same way execute_query would:
         # ANALYZE really executes, and even the plain-EXPLAIN strategy
         # predictions below read executor knobs that must reflect
-        # SET SESSION (zone_map_rows, enable_multiway_join, ...)
+        # SET SESSION (zone_map_rows, ...)
         self._apply_executor_properties(t0)
         if stmt.analyze and wstmt is not None:
             # ANALYZE of a write really writes (local staged path); the
@@ -416,11 +385,7 @@ class Session:
         # choice; after ANALYZE the executed strategy is authoritative)
         try:
             from .executor import explain_strategy_lines
-            # walk the pre-prune plan: column pruning interleaves
-            # ProjectNodes into join spines, which would hide the
-            # multiway-star verdict; every field the predictions read
-            # (strategy, build_unique, key domains) survives pruning
-            for line in explain_strategy_lines(rel.node, self.executor):
+            for line in explain_strategy_lines(root, self.executor):
                 rows.append((line,))
         except Exception:    # noqa: BLE001 — EXPLAIN must never fail
             pass             # on a strategy estimate

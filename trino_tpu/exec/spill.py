@@ -649,7 +649,6 @@ def merge_partial_pages(executor, node: L.AggregateNode,
         return global_aggregate(merged, merge_aggs)
     total = _host_bytes(arrs, vals)
     # 3x: input + sort scratch + output headroom for the device merge
-    # (hash-strategy operators merge through the hash-partial path)
     if executor.pool.available() >= 3 * total:
         merged = batch_from_numpy(arrs, valids=vals)
         capacity = max(node.out_capacity, bucket_capacity(len(arrs[0])))

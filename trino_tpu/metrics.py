@@ -390,8 +390,8 @@ MICROBATCH_BATCHES = REGISTRY.counter(
     "trino_tpu_microbatch_batches_total",
     "Micro-batch gather windows flushed as one dispatch")
 
-# per-operator strategy decisions (exec/executor.py gate: hash vs sort
-# vs direct aggregation, dense-LUT vs hybrid-hash vs merge joins)
+# per-operator strategy decisions (exec/executor.py gate: sort vs direct
+# aggregation, dense-LUT vs merge vs expansion joins)
 AGG_STRATEGY_DECISIONS = REGISTRY.counter(
     "trino_tpu_agg_strategy_decisions_total",
     "Aggregation strategy picked per operator execution", ("strategy",))
@@ -399,20 +399,11 @@ JOIN_STRATEGY_DECISIONS = REGISTRY.counter(
     "trino_tpu_join_strategy_decisions_total",
     "Join strategy picked per operator execution", ("strategy",))
 
-# mesh join distribution (parallel/dist_executor.py gate: replicate the
-# build over the mesh vs hash-repartition both sides) and the batched
-# dynamic-filter / repartition data plane it rides on
-JOIN_DISTRIBUTION_DECISIONS = REGISTRY.counter(
-    "trino_tpu_join_distribution_decisions_total",
-    "Join distribution picked per mesh join execution", ("mode",))
+# the mesh executor's batched dynamic filter (parallel/dist_executor.py)
 DYNAMIC_FILTER_ROWS_PRUNED = REGISTRY.counter(
     "trino_tpu_dynamic_filter_rows_pruned_total",
     "Probe rows pruned by build-side dynamic-filter bounds before the "
     "join ran")
-MESH_REPARTITION_BYTES = REGISTRY.counter(
-    "trino_tpu_mesh_repartition_bytes_total",
-    "Bytes moved through all_to_all repartition exchanges by "
-    "mesh-partitioned joins")
 
 # scan-path acceleration (exec/zonemap.py + exec/chunked.py prefetch):
 # zone-map split/zone pruning and the double-buffered chunk pipeline
@@ -474,18 +465,6 @@ JIT_DISTINCT_SHAPES = REGISTRY.gauge(
     "trino_tpu_jit_distinct_shapes",
     "Distinct (fingerprint) program shapes recorded per jit site — the "
     "shape-canonicalization regression signal", ("site",))
-
-# fused multiway star join (ops/pallas_hash.py multiway_probe +
-# exec/executor.py run_multijoin): one Pallas pass probing every
-# VMEM-resident dimension table, degrading dim-by-dim to the ladder
-MULTIJOIN_FUSED_PROBES = REGISTRY.counter(
-    "trino_tpu_multijoin_fused_probes_total",
-    "Fused multiway probe kernel launches (one per fact chunk that "
-    "probed >= 2 resident dimension tables in a single pass)")
-MULTIJOIN_DEGRADES = REGISTRY.counter(
-    "trino_tpu_multijoin_degrades_total",
-    "Dimension hops evicted from the fused star probe back to the "
-    "pairwise ladder, by reason", ("reason",))
 
 # query history + latency-regression detection (server/history.py)
 LATENCY_REGRESSIONS = REGISTRY.counter(
@@ -645,16 +624,10 @@ for _op in ("ScanNode", "JoinNode", "AggregateNode"):
     OPERATOR_COMPILE_MS.init_labels(operator=_op)
 for _target in ("host", "device"):
     ROUTER_DECISIONS.init_labels(target=_target)
-for _s in ("global", "direct", "mxu", "sort", "hash"):
+for _s in ("global", "direct", "mxu", "sort"):
     AGG_STRATEGY_DECISIONS.init_labels(strategy=_s)
-for _s in ("dense-lut", "hybrid-hash", "sort-merge", "sorted", "expand",
-           "multiway", "ladder"):
+for _s in ("dense-lut", "sort-merge", "sorted", "expand"):
     JOIN_STRATEGY_DECISIONS.init_labels(strategy=_s)
-for _r in ("kernel_off", "vmem", "dup", "escape", "dtype", "mesh",
-           "spill"):
-    MULTIJOIN_DEGRADES.init_labels(reason=_r)
-for _m in ("broadcast", "partitioned"):
-    JOIN_DISTRIBUTION_DECISIONS.init_labels(mode=_m)
 for _ls in ("ACTIVE", "DRAINING", "DRAINED", "LEFT", "FAILED"):
     NODE_LIFECYCLE_TRANSITIONS.init_labels(state=_ls)
 TENANT_QUERIES.init_labels(tenant="default")

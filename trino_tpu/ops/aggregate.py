@@ -19,13 +19,6 @@ masked reduction over the same rows costs ~0.1ms):
   associative scan; group results land via `searchsorted` *gathers*, never
   scatters. Exact (sorts real key values, no hash collisions), static
   shapes throughout.
-- **hash** (high cardinality — few rows per group): the VMEM-resident
-  open-addressing table kernel in `ops/pallas_hash.py`, picked by the
-  planner's rows-per-group gate and dispatched by
-  `Executor.hash_aggregate`; keys pack losslessly through this module's
-  `key_pack_plan` (range compression — equality stays exact, hash
-  collisions can never merge groups) and every run keeps the sort kernel
-  as its fallback (kernel off, unpackable keys, DISTINCT, escapes).
 
 Both paths produce *partial aggregate states* (sum/count/min/max); AVG is
 decomposed by the planner into (sum, count) and finalized in the

@@ -18,15 +18,6 @@ at 60M probe / 15M build rows and have not been re-measured:
   build (0.2s at 15M — TPU sorts are fast) and `searchsorted` probes.
   searchsorted lowers to ~24 sequential gather rounds (30s at 60M probes)
   — usable for small/medium probes, pathological at scale, hence the LUT.
-- **hybrid hash** (sparse key domains the dense LUT refuses): the VMEM
-  hash-table kernel (`ops/pallas_hash.py`) builds a key -> min(row_id)
-  table (duplicates detected as inserted > occupied) and the probe walks
-  each linear chain with MAX_PROBES rounds of fused plane gathers —
-  bounded chains, so exhausting them is a definitive miss. Sits in the
-  unique-build ladder ahead of this fallback and carries semi/anti
-  membership joins; a build past the table's load cap degrades
-  partition-by-partition through the spill tier's radix fanout
-  (`Executor.try_hash_join`).
 
 Output-row mapping in the expansion kernels uses scatter + cummax
 (associative scan) instead of a second searchsorted for the same reason.
@@ -359,22 +350,6 @@ def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
     live = probe.live & matched if kind == "inner" else probe.live
     return (Batch(columns=probe.columns + tuple(build_cols), live=live),
             escaped, span)
-
-
-def compact_live(batch: Batch, cap: int):
-    """In-jit compaction to a STATIC capacity (decision-cached measured
-    live count, padded). Returns (batch, overflow) where overflow counts
-    live rows beyond `cap` — the caller must check it is zero at the end
-    of the chunk loop and rerun unfused otherwise."""
-    n = batch.capacity
-    idx = jnp.nonzero(batch.live, size=cap, fill_value=n)[0]
-    ok = idx < n
-    idxc = jnp.clip(idx, 0, n - 1)
-    cols = tuple(Column(c.data[idxc], c.valid[idxc] & ok)
-                 for c in batch.columns)
-    overflow = jnp.sum(batch.live, dtype=jnp.int64) - \
-        jnp.sum(ok, dtype=jnp.int64)
-    return Batch(cols, ok), overflow
 
 
 @recorded_jit(static_argnums=(2, 3, 4, 5, 6, 7))

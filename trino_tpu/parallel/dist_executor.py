@@ -18,14 +18,10 @@ semantics. Hand-tuned shard_map stage programs (parallel/stages.py) remain
 the fast path for hot shapes; this executor is the general one — every SQL
 feature the local executor supports runs distributed unchanged.
 
-Join distribution (DetermineJoinDistributionType's choice, on the mesh):
-the planner stamps JoinNode.distribution from build-size stats; BROADCAST
-joins run the replicated default path below (XLA reads the build from
-every shard), PARTITIONED joins hash-repartition both sides over the mesh
-and run the VMEM hash kernel per shard
-(parallel/stages.partitioned_hash_join_step) — each chip owns 1/N of the
-key space. Skewed or duplicate-key partitions degrade exactly like the
-single-chip hybrid join (host equi-join / expansion fallback).
+A join on the mesh is what GSPMD makes of the single-device ladder
+(dense-LUT, sort-merge/sorted, expand): XLA reads the build from every
+shard. Its dynamic-filter bounds are batched into one program in front
+(`_batched_dynamic_filter`).
 
 Scheduling note: one process drives the whole mesh (single-controller JAX),
 so the coordinator/worker HTTP runtime (server/) carries control-plane
@@ -81,12 +77,6 @@ class MeshExecutor(Executor):
     shardings through the plan and inserts ICI collectives where global
     semantics require them."""
 
-    # repartitioning doubles a side n_shards x during the exchange
-    # (parallel/exchange.py's static bucket layout); above this estimate
-    # the partitioned path would trade the gather win for an HBM cliff,
-    # so the gate degrades to broadcast
-    MESH_EXCHANGE_BUDGET_BYTES = 8 << 30
-
     def __init__(self, catalog: Catalog, mesh: Optional[Mesh] = None):
         super().__init__(catalog)
         self.mesh = mesh if mesh is not None else make_mesh()
@@ -101,19 +91,15 @@ class MeshExecutor(Executor):
         # and those independent rendezvous intermittently deadlocked the
         # virtual-CPU-device runtime (rendezvous.cc "only 7 of 8
         # arrived", deterministic on TPC-DS q77). The batched design
-        # (_batched_dynamic_filter + join_filter_bounds inside
-        # partitioned_hash_join_step) folds every filter collective into
-        # the operator's own program, so that deadlock class cannot
-        # occur; this flag remains as the session escape hatch
+        # (_batched_dynamic_filter) folds a join's filter collectives
+        # into one program, so that deadlock class cannot occur; this
+        # flag remains as the session escape hatch
         # (mesh_dynamic_filtering=off).
         self.mesh_dynamic_filtering = True
-        # compiled partitioned-join stage programs, keyed by static shape
-        self._partitioned_steps: dict = {}
 
     def _decision_salt(self) -> tuple:
         # mesh knobs change decision values for the same plan structure
-        # (the pruned-row count flips with the filter hatch; dup/escape
-        # totals depend on the shard fanout)
+        # (the pruned-row count flips with the filter hatch)
         return super()._decision_salt() + (self.n_shards,
                                            self.mesh_dynamic_filtering)
 
@@ -174,126 +160,3 @@ class MeshExecutor(Executor):
         from ..metrics import DYNAMIC_FILTER_ROWS_PRUNED
         self.stats.dynamic_filter_rows_pruned += pruned
         DYNAMIC_FILTER_ROWS_PRUNED.inc(pruned)
-
-    # -- join distribution (broadcast vs partitioned) ------------------
-
-    def run_multijoin(self, node):
-        # The fused star kernel assumes a single-device VMEM-resident
-        # build set; on a mesh the pairwise ladder keeps the
-        # partitioned/broadcast machinery per hop instead.
-        self._note_multijoin_degrade("mesh", len(node.dims))
-        return self._run_multijoin_ladder(node)
-
-    def _run_join_inner(self, node: L.JoinNode, probe: Batch,
-                        build: Batch) -> Batch:
-        mode = "partitioned" if self._partitioned_eligible(
-            node, probe, build) else "broadcast"
-        from ..metrics import JOIN_DISTRIBUTION_DECISIONS
-        JOIN_DISTRIBUTION_DECISIONS.inc(mode=mode)
-        self.strategy_decisions["JoinDistribution"] = mode
-        if mode == "partitioned":
-            out = self._mesh_partitioned_join(node, probe, build)
-            if out is not None:
-                return out
-            # dup build keys or an unjoinable degrade: the replicated
-            # ladder below handles it (expansion path included)
-            self.strategy_decisions["JoinDistribution"] = "broadcast"
-        return super()._run_join_inner(node, probe, build)
-
-    def _partitioned_eligible(self, node: L.JoinNode, probe: Batch,
-                              build: Batch) -> bool:
-        """May this join hash-repartition over the mesh? The planner's
-        stats gate asks for it (JoinNode.distribution, estimated build
-        bytes vs broadcast_join_threshold_mb); the executor additionally
-        requires the shape the per-shard kernel supports. Everything
-        else broadcasts — that is today's replicated path, always
-        correct."""
-        if self.n_shards <= 1:
-            return False
-        if getattr(node, "distribution", "auto") != "partitioned":
-            return False
-        if node.kind != "inner" or node.residual is not None or \
-                node.null_aware:
-            return False
-        if len(node.left_keys) != 1:
-            # multi-key joins arrive here single-keyed via the packed
-            # key column (Executor.pack_join_keys); a genuinely
-            # multi-key shape cannot co-partition on one hash
-            return False
-        if self.hash_mode() == "off":
-            return False
-        for side, keys in ((probe, node.left_keys),
-                           (build, node.right_keys)):
-            for k in keys:
-                if not jnp.issubdtype(side.columns[k].data.dtype,
-                                      jnp.integer):
-                    return False
-        n_cols = len(probe.columns) + len(build.columns) + 2
-        est = (probe.capacity + build.capacity) * self.n_shards * \
-            8 * n_cols
-        if est > self.MESH_EXCHANGE_BUDGET_BYTES:
-            return False
-        return True
-
-    def _mesh_partitioned_join(self, node: L.JoinNode, probe: Batch,
-                               build: Batch) -> Optional[Batch]:
-        """The tentpole path: hash-repartition both sides over the mesh
-        (splitmix64 fanout, all_to_all) and run the VMEM hash join
-        per shard, with the dynamic-filter collectives batched into the
-        same program. Returns None when the build broke the unique-key
-        contract (caller expands on the replicated path)."""
-        from ..metrics import MESH_REPARTITION_BYTES
-        from ..ops import pallas_hash as ph
-        from .stages import partitioned_hash_join_step
-        n = self.n_shards
-        probe = pad_to_multiple(probe, n)
-        build = pad_to_multiple(build, n)
-        # per-shard table sized for the 1/N key slice with 2x slack:
-        # heavier skew escapes at runtime and degrades below, exactly
-        # like a single-chip table overflow
-        slots, _ = ph.join_table_slots(
-            max(ph.MIN_TABLE_SLOTS, 2 * build.capacity // n))
-        df = bool(self.enable_dynamic_filtering and
-                  self.mesh_dynamic_filtering)
-        skey = (n, node.left_keys, node.right_keys, node.kind, slots,
-                probe.capacity, build.capacity, self.hash_mode(),
-                self.gather_mode(), df)
-        step = self._partitioned_steps.get(skey)
-        if step is None:
-            step = partitioned_hash_join_step(
-                self.mesh, n, node.left_keys, node.right_keys,
-                node.kind, slots, self.hash_mode(), self.gather_mode(),
-                dynamic_filter=df)
-            self._partitioned_steps[skey] = step
-        out, dup, esc, pruned = step(self._shard_batch(probe),
-                                     self._shard_batch(build))
-        # exchange accounting (static estimate: each side moves its full
-        # padded capacity once, data + valid + live planes)
-        MESH_REPARTITION_BYTES.inc(
-            probe.capacity * (len(probe.columns) * 9 + 1) +
-            build.capacity * (len(build.columns) * 9 + 1))
-        self.stats.hash_join_calls += 1
-        self.stats.mesh_partitioned_joins += 1
-        dup, esc, pruned = self.fetch_ints(
-            node, f"meshjoin{slots}", dup, esc, pruned)
-        if pruned:
-            self._note_pruned(pruned)
-        if esc > 0:
-            # skewed partition overflowed its shard table: degrade to
-            # the host equi-join over the same splitmix64 fanout (the
-            # single-chip hybrid join's graceful path)
-            self.stats.hash_join_escapes += 1
-            host = self._partitioned_hash_join(node, probe, build)
-            if host is None:
-                return None
-            self._note_strategy("JoinNode", "hybrid-hash", "join")
-            return host
-        if dup > 0:
-            return None
-        self._note_strategy("JoinNode", "hybrid-hash", "join")
-        # the repartitioned output rides at n_shards x probe capacity
-        # (the exchange's static bucket layout): compact by the fused
-        # live count before anything downstream pays for the padding
-        live = self.fetch_ints(node, "meshjoinlive",
-                               jnp.sum(out.live))[0]
-        return self.maybe_compact(out, live=live)

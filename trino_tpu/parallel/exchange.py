@@ -93,50 +93,6 @@ def repartition_by_key(batch: Batch, key_index: int, n_shards: int,
     return Batch(columns=new_cols, live=new_live)
 
 
-def join_filter_bounds(build: Batch, build_keys: Tuple[int, ...],
-                       axis: str = AXIS):
-    """Global [min, max] per build key, computed INSIDE the sharded
-    stage body — the batched form of dynamic filtering. The old mesh
-    path fetched per-key bounds eagerly, dispatching one tiny
-    cross-module all-reduce per probe; those independent rendezvous
-    deadlock intermittently on the virtual-device runtime (TPC-DS q77).
-    Here every key's (min, -max) rides ONE all_gather in the SAME
-    program as the join, so there is no mid-execution rendezvous to
-    miss. The sign flip is the line-102 idiom above: min(-x) = -max(x),
-    one local reduce shape serves both bounds through the sum-only /
-    all_gather collective contract."""
-    imax = jnp.iinfo(jnp.int64).max
-    stats = []
-    for bk_i in build_keys:
-        col = build.columns[bk_i]
-        m = build.live & col.valid
-        d = col.data.astype(jnp.int64)
-        stats.append(jnp.min(jnp.where(m, d, imax)))
-        stats.append(jnp.min(jnp.where(m, -d, imax)))
-    gathered = lax.all_gather(jnp.stack(stats), axis)   # [n_shards, 2K]
-    merged = jnp.min(gathered, axis=0)
-    kmins = merged[0::2]
-    kmaxs = -merged[1::2]
-    return kmins, kmaxs
-
-
-def apply_filter_bounds(probe: Batch, probe_keys: Tuple[int, ...],
-                        kmins, kmaxs) -> Tuple[Batch, jax.Array]:
-    """Prune probe rows whose key falls outside the build's [min, max]
-    (per key pair, all inside the stage program). Returns the filtered
-    batch and the local pruned-row count (caller psums it into the
-    dynamic_filter_rows_pruned metric). NULL keys stay live — they are
-    dropped by join semantics, not by the filter."""
-    keep = probe.live
-    for j, pk_i in enumerate(probe_keys):
-        col = probe.columns[pk_i]
-        d = col.data.astype(jnp.int64)
-        keep = keep & (~col.valid | ((d >= kmins[j]) & (d <= kmaxs[j])))
-    pruned = jnp.sum(probe.live, dtype=jnp.int64) - \
-        jnp.sum(keep, dtype=jnp.int64)
-    return probe.with_live(keep), pruned
-
-
 def merge_partial_states(partial: Batch, agg_funcs: Tuple[str, ...],
                          n_keys: int, axis: str = AXIS) -> Batch:
     """Merge per-shard dense aggregate tables (direct strategy) into the

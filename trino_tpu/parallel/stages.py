@@ -15,7 +15,6 @@ import functools
 from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import ir
@@ -23,8 +22,7 @@ from ..batch import Batch
 from ..ops.aggregate import direct_group_aggregate
 from ..ops.join import join_unique_build
 from ..ops.project import apply_filter, project
-from .exchange import (apply_filter_bounds, join_filter_bounds,
-                       merge_partial_states, repartition_by_key)
+from .exchange import merge_partial_states, repartition_by_key
 from .mesh import AXIS, shard_map
 
 
@@ -109,49 +107,4 @@ def broadcast_join_step(mesh, probe_filter, probe_keys: tuple,
 
     mapped = shard_map(body, mesh=mesh, in_specs=(P(AXIS), P()),
                        out_specs=P(AXIS))
-    return jax.jit(mapped)
-
-
-def partitioned_hash_join_step(mesh, n_shards: int, probe_keys: tuple,
-                               build_keys: tuple, kind: str,
-                               table_slots: int, hash_mode: str,
-                               gather_mode: str = "off",
-                               dynamic_filter: bool = True):
-    """Mesh-partitioned hybrid hash join (PARTITIONED distribution):
-
-    build shards --bounds--> ONE all_gather          [DynamicFilterSource]
-    probe shards --prune---> all_to_all(hash(key))   [PartitionedOutput]
-    build shards ----------> all_to_all(hash(key))   [+ExchangeOperator]
-    -> per-shard VMEM hash build + probe (ops/pallas_hash.py): each chip
-       owns 1/N of the key space, so the per-chip table shrinks N x and
-       probe gathers stay local to ICI.
-
-    Dynamic filtering is BATCHED into this same jitted program: the
-    build-key bounds collective and the probe prune live in one XLA
-    module with the join, so the per-probe cross-module rendezvous that
-    deadlocked the old mesh path (TPC-DS q77) cannot occur by
-    construction. Returns (joined row-sharded, total_dup, total_escape,
-    total_pruned); the caller checks dup (fall back to the expansion
-    join) and escape (skewed partition overflowed its table — degrade
-    to the host equi-join like the single-chip hybrid join)."""
-    from ..ops import pallas_hash as ph
-
-    def body(probe: Batch, build: Batch):
-        kmins, kmaxs = join_filter_bounds(build, build_keys)
-        if dynamic_filter:
-            probe, pruned = apply_filter_bounds(probe, probe_keys,
-                                                kmins, kmaxs)
-        else:
-            pruned = jnp.zeros((), jnp.int64)
-        probe = repartition_by_key(probe, probe_keys[0], n_shards)
-        build = repartition_by_key(build, build_keys[0], n_shards)
-        joined, dup, esc = ph.shard_join(
-            probe, build, probe_keys, build_keys, kind, table_slots,
-            hash_mode, gather_mode)
-        return (joined, jax.lax.psum(dup, AXIS),
-                jax.lax.psum(esc, AXIS), jax.lax.psum(pruned, AXIS))
-
-    mapped = shard_map(body, mesh=mesh,
-                       in_specs=(P(AXIS), P(AXIS)),
-                       out_specs=(P(AXIS), P(), P(), P()))
     return jax.jit(mapped)

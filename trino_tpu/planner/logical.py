@@ -14,7 +14,7 @@ from typing import List, Optional, Tuple
 
 from .. import ir
 from ..batch import Schema
-from ..types import DataType, TypeKind
+from ..types import DataType
 
 
 @dataclass(frozen=True)
@@ -91,39 +91,15 @@ class JoinNode(PlanNode):
     build_unique: bool                # planner's guarantee/assumption
     output: Tuple
     null_aware: bool = False          # NOT IN semantics (anti only)
-    # cost-chosen exchange strategy for the build side on a mesh
-    # (DetermineJoinDistributionType.java:51): REPLICATED vs PARTITIONED
+    # cost-chosen exchange strategy for the build side
+    # (DetermineJoinDistributionType.java:51): REPLICATED vs PARTITIONED.
+    # EXPLAIN prints it; no executor acts on it (the HTTP scheduler's
+    # partitioned stage tree reads the session property)
     distribution: str = "auto"        # auto|broadcast|partitioned
     # dense-LUT probe domain (exclusive key upper bound) when connector
     # stats prove the single build key lives in [0, domain) — the
     # BigintGroupByHash-style fast path; None = sorted+searchsorted
     build_key_domain: Optional[int] = None
-
-
-@dataclass(frozen=True)
-class MultiJoinNode(PlanNode):
-    """Fused star join: one fact relation inner-joined to k snowflaked
-    dimension builds on conjunctive single-column equi-keys, probed in
-    ONE Pallas pass (ops/pallas_hash.multiway_probe). Emitted by the
-    planner's star detector (fuse_star_joins) as the fusion of a
-    pairwise JoinNode ladder; `multijoin_to_ladder` reconstructs that
-    ladder exactly, so every degrade path is bit-exact by construction.
-
-    The fact side is AUTHORITATIVE: unlike the pairwise path, the
-    executor never re-derives probe/build orientation per hop, so a
-    mis-sized dimension can't silently flip the fact table into a VMEM
-    build — it degrades that one dimension to the pairwise ladder
-    instead.  `output` is the ladder-top layout: fact columns, then
-    each dimension's columns in join order (dims[0] = bottom hop)."""
-    fact: PlanNode
-    dims: Tuple[PlanNode, ...]
-    fact_keys: Tuple[Tuple[int, ...], ...]   # per dim, fact-side keys
-    dim_keys: Tuple[Tuple[int, ...], ...]    # per dim, build-side keys
-    # per-dim dense-LUT domains, preserved so the reconstructed ladder
-    # keeps the original JoinNodes' fast paths
-    dim_domains: Tuple[Optional[int], ...]
-    output: Tuple
-    distribution: str = "broadcast"
 
 
 @dataclass(frozen=True)
@@ -284,8 +260,6 @@ def children(node: PlanNode):
         return (node.child,)
     if isinstance(node, (JoinNode, SetOpNode)):
         return (node.left, node.right)
-    if isinstance(node, MultiJoinNode):
-        return (node.fact,) + node.dims
     return ()
 
 
@@ -311,158 +285,6 @@ def replace_nodes(root: PlanNode, mapping) -> PlanNode:
     return _dc.replace(root, **changes) if changes else root
 
 
-# --------------------------------------------------------------------------
-# star detection: fuse a pairwise JoinNode ladder into one MultiJoinNode
-# --------------------------------------------------------------------------
-
-# key kinds the fused kernel can probe: `_combined_key` packs these into
-# one int64 losslessly (VARCHAR rides its dictionary codes — make_join's
-# `$jk` pool alignment guarantees both sides share a pool).  DOUBLE and
-# DECIMAL would truncate through the int64 pack.
-_STAR_KEY_KINDS = (TypeKind.BIGINT, TypeKind.INTEGER, TypeKind.BOOLEAN,
-                   TypeKind.DATE, TypeKind.TIMESTAMP, TypeKind.VARCHAR)
-
-
-def _spine_has_join(node: PlanNode) -> bool:
-    while isinstance(node, FilterNode):
-        node = node.child
-    return isinstance(node, JoinNode)
-
-
-def _star_hop_ok(j: JoinNode, n_fact: int) -> Optional[str]:
-    """None if the hop can join the fused star, else the decline reason
-    (surfaced verbatim in EXPLAIN's star verdict)."""
-    if j.kind != "inner":
-        return "non-inner hop"
-    if j.residual is not None:
-        return "residual predicate on hop"
-    if j.null_aware:
-        return "null-aware hop"
-    if not j.build_unique:
-        return "build not provably unique"
-    if len(j.left_keys) != 1:
-        return "multi-column key"
-    if j.left_keys[0] >= n_fact:
-        # the probe key is a column PRODUCED by an earlier dimension:
-        # it does not exist in the fact batch the single pass probes
-        return "snowflake key (dim-derived)"
-    if j.left.output[j.left_keys[0]][1].kind not in _STAR_KEY_KINDS or \
-            j.right.output[j.right_keys[0]][1].kind not in _STAR_KEY_KINDS:
-        return "non-integer key"
-    return None
-
-
-def collect_star(root: PlanNode, max_dims: int):
-    """Walk the probe spine of a join ladder (JoinNodes, with conjunct
-    FilterNodes interleaved) bottom-up, committing the longest fusable
-    prefix of hops.  Returns None when the spine holds fewer than two
-    joins, else (fact, hops, hoisted, upper, note):
-
-    - `fact`    first non-spine node (the probe side of the bottom hop)
-    - `hops`    committed JoinNodes, bottom-up (possibly < 2: declined)
-    - `hoisted` FilterNodes that sat BETWEEN committed hops, bottom-up;
-      their predicates reference prefix columns of the fused layout, so
-      they re-apply above the MultiJoinNode without remapping
-    - `upper`   spine nodes (top-down) left above the fusion point
-    - `note`    why fusion stopped (None = every hop committed)
-    """
-    spine = []
-    node = root
-    while True:
-        if isinstance(node, FilterNode) and _spine_has_join(node.child):
-            spine.append(node)
-            node = node.child
-        elif isinstance(node, JoinNode):
-            spine.append(node)
-            node = node.left
-        else:
-            break
-    fact = node
-    if sum(1 for n in spine if isinstance(n, JoinNode)) < 2:
-        return None
-    n_fact = len(fact.output)
-    hops, hoisted, pend_filters = [], [], []
-    note = None
-    cut = len(spine)
-    for idx in range(len(spine) - 1, -1, -1):
-        nd = spine[idx]
-        if isinstance(nd, FilterNode):
-            pend_filters.append(nd)
-            continue
-        why = _star_hop_ok(nd, n_fact)
-        if why is None and len(hops) >= max_dims:
-            why = f"dim cap ({max_dims})"
-        if why is not None:
-            note = why
-            break
-        hops.append(nd)
-        hoisted.extend(pend_filters)
-        pend_filters = []
-        cut = idx
-    return fact, hops, hoisted, spine[:cut], note
-
-
-def fuse_star_joins(root: PlanNode, max_dims: int) -> PlanNode:
-    """Rewrite the longest fusable star prefix of `root`'s join ladder
-    into a MultiJoinNode (identity when nothing qualifies).  The fused
-    node's output equals the topmost committed hop's, so everything
-    above re-attaches unchanged."""
-    import dataclasses as _dc
-    got = collect_star(root, max_dims)
-    if got is None:
-        return root
-    fact, hops, hoisted, upper, _note = got
-    if len(hops) < 2:
-        return root
-    cur: PlanNode = MultiJoinNode(
-        fact=fact,
-        dims=tuple(h.right for h in hops),
-        fact_keys=tuple(tuple(h.left_keys) for h in hops),
-        dim_keys=tuple(tuple(h.right_keys) for h in hops),
-        dim_domains=tuple(h.build_key_domain for h in hops),
-        output=tuple(hops[-1].output))
-    for f in hoisted:
-        cur = FilterNode(cur, f.predicate, cur.output)
-    for nd in reversed(upper):
-        if isinstance(nd, FilterNode):
-            cur = FilterNode(cur, nd.predicate, cur.output)
-        else:
-            cur = _dc.replace(nd, left=cur)
-    return cur
-
-
-def multijoin_to_ladder(node: MultiJoinNode) -> JoinNode:
-    """Reconstruct the exact pairwise ladder a MultiJoinNode fused —
-    the executor's full-degrade path and the bit-exactness oracle."""
-    acc: PlanNode = node.fact
-    out = tuple(node.fact.output)
-    ladder = None
-    for d, dim in enumerate(node.dims):
-        out = out + tuple(dim.output)
-        ladder = JoinNode(
-            "inner", acc, dim, node.fact_keys[d], node.dim_keys[d],
-            None, True, out, distribution=node.distribution,
-            build_key_domain=node.dim_domains[d])
-        acc = ladder
-    return ladder
-
-
-def star_verdict(root: PlanNode, max_dims: int = 5) -> Optional[str]:
-    """EXPLAIN's star-detector verdict for a join-ladder spine: None
-    when the spine holds fewer than two joins, else the fuse/decline
-    outcome with the stopping reason."""
-    got = collect_star(root, max_dims)
-    if got is None:
-        return None
-    _fact, hops, _hoisted, _upper, note = got
-    if len(hops) >= 2:
-        v = f"fusable k={len(hops)}"
-        if note:
-            v += f"; stopped: {note}"
-        return v
-    return f"declined: {note}"
-
-
 def explain_text(node: PlanNode, indent: int = 0, annotate=None) -> str:
     """EXPLAIN rendering (textual plan like Trino's PlanPrinter).
     `annotate(node) -> str` appends per-node runtime stats
@@ -486,12 +308,6 @@ def explain_text(node: PlanNode, indent: int = 0, annotate=None) -> str:
         line = (f"{pad}Join[{node.kind}, probe={list(node.left_keys)}, "
                 f"build={list(node.right_keys)}, "
                 f"dist={node.distribution}]")
-    elif isinstance(node, MultiJoinNode):
-        hops = "; ".join(
-            f"{list(fk)}={list(dk)}"
-            for fk, dk in zip(node.fact_keys, node.dim_keys))
-        line = (f"{pad}MultiJoin[star, k={len(node.dims)}, "
-                f"keys=[{hops}], dist={node.distribution}]")
     elif isinstance(node, WindowNode):
         fns = ", ".join(s.func for s in node.specs)
         line = (f"{pad}Window[partition={list(node.partition_by)}, "

@@ -297,18 +297,6 @@ def _prune(node: L.PlanNode, needed: frozenset):
             tuple(node.fields[i] for i in keep),
             tuple(node.output[i] for i in keep)), mapping
 
-    if isinstance(node, L.MultiJoinNode):
-        # The fused star probe consumes every fact/dim column that the
-        # ladder it replaces would have; keep children exact (scans
-        # beneath them still prune via their own Project/Filter layers)
-        fact = _prune_exact(node.fact,
-                            frozenset(range(len(node.fact.output))))
-        dims = tuple(_prune_exact(d, frozenset(range(len(d.output))))
-                     for d in node.dims)
-        return L.MultiJoinNode(
-            fact, dims, node.fact_keys, node.dim_keys, node.dim_domains,
-            node.output, node.distribution), _identity(len(node.output))
-
     if isinstance(node, L.SetOpNode):
         # distinct/intersect/except semantics are over the whole row:
         # children must keep every column, in order

@@ -402,7 +402,7 @@ class Planner:
         if 2 < len(pending) <= self.DP_REORDER_MAX:
             planned = self._dp_reorder(pending, conjuncts)
             if planned is not None:
-                return self._maybe_multijoin(planned)
+                return planned
         pending.sort(key=lambda r: -self.estimate_rows(r.node))
         acc = pending.pop(0)
         while pending:
@@ -425,22 +425,7 @@ class Planner:
             pending.remove(chosen)
             acc = self.join_pair(acc, chosen, conjuncts, kind="inner")
             acc = self.apply_local_filters(acc, conjuncts)
-        return self._maybe_multijoin(acc)
-
-    def _maybe_multijoin(self, rel: PlannedRelation) -> PlannedRelation:
-        """Star detector (ISSUE round-17): fuse the ladder's longest
-        fact-to-dims prefix into a MultiJoinNode when the session allows
-        it.  The rewrite is plan-shape only — the executor owns every
-        runtime degrade back to the pairwise path."""
-        from ..ops.pallas_hash import resolve_mode
-        setting = self.properties.get("enable_multiway_join", "auto")
-        if resolve_mode(setting) == "off":
-            return rel
-        max_dims = int(self.properties.get("multiway_max_dims", 5))
-        fused = L.fuse_star_joins(rel.node, max_dims)
-        if fused is rel.node:
-            return rel
-        return PlannedRelation(fused, rel.scope)
+        return acc
 
     # cost-based join reordering explores all connected bushy splits up
     # to this many relations (2^n subsets; TPC-DS join graphs past ~10
@@ -1120,16 +1105,14 @@ class Planner:
         else:
             output = tuple(probe_node.output)
         # DetermineJoinDistributionType.java:51's choice, by estimated
-        # build bytes: small builds replicate over the mesh (all_gather),
-        # large ones hash-repartition both sides (all_to_all). The
-        # session can force either (join_distribution_type).
+        # build bytes: small builds replicate, large ones would
+        # hash-repartition both sides. The session can force either
+        # (join_distribution_type).
         forced = self.properties.get("join_distribution_type", "auto")
         if forced in ("broadcast", "partitioned"):
             distribution = forced
         elif kind != "inner" or residual is not None or null_aware:
-            # only inner equi-joins can co-partition on the mesh today;
-            # predicting "partitioned" for shapes the executor must
-            # demote would make every EXPLAIN verdict a miss
+            # only inner equi-joins can co-partition
             distribution = "broadcast"
         else:
             threshold_mb = self.properties.get(
@@ -2308,12 +2291,6 @@ class Planner:
             return "global", (), 0
         if any_distinct:
             return "sort", (), DEFAULT_SORT_GROUPS   # needs the sort kernel
-        hmode = str(self.properties.get("hash_agg_mode", "auto")).lower()
-        if hmode == "force":
-            # ops/test knob: route every grouped aggregate through the
-            # hash kernel (DISTINCT stays on sort — kernel contract)
-            return "hash", (), self._sort_capacity(group_irs, scope,
-                                                   pre_node)
         domains = []
         for e in group_irs:
             d = self.domain_of(e, scope)
@@ -2335,25 +2312,7 @@ class Planner:
             est = self._input_rows_estimate(pre_node)
             if prod <= limit and (est is None or est >= prod * 64):
                 return "direct", tuple(domains), prod
-        capacity = self._sort_capacity(group_irs, scope, pre_node)
-        # hash vs sort: the rows-per-group gate ("Hash-Based vs.
-        # Sort-Based Group-By-Aggregate" — hash wins at HIGH cardinality,
-        # i.e. FEW rows per group, where the sort pays O(n log n) to
-        # discover mostly-distinct keys while the VMEM hash table pays
-        # one insert per row). The executor still falls back to sort at
-        # runtime when the kernel is off or the keys cannot pack.
-        if hmode not in ("off", "false", "0"):
-            est_groups, rows = self._group_rows_estimate(
-                group_irs, scope, pre_node)
-            min_groups = int(self.properties.get(
-                "hash_agg_min_groups", 8192))
-            max_rpg = float(self.properties.get(
-                "hash_agg_max_rows_per_group", 64))
-            if est_groups is not None and rows is not None and \
-                    est_groups >= min_groups and \
-                    rows <= est_groups * max_rpg:
-                return "hash", (), capacity
-        return "sort", (), capacity
+        return "sort", (), self._sort_capacity(group_irs, scope, pre_node)
 
     def _input_rows_estimate(self, pre_node) -> Optional[int]:
         """Rough input-row bound for strategy choice: the largest scan
@@ -2374,24 +2333,21 @@ class Planner:
             return None
 
     def _group_rows_estimate(self, group_irs, scope: Scope, pre_node):
-        """(estimated group count, estimated input rows) from column
-        NDV stats — the shared input of the sort-capacity sizing and
-        the hash-vs-sort rows-per-group gate. (None, None) without
-        stats."""
+        """Estimated group count from column NDV stats (their product,
+        capped by the estimated input rows); None without stats."""
         cstats = self.chain_column_stats(pre_node.child) \
             if isinstance(pre_node, L.ProjectNode) else None
         if cstats is None:
-            return None, None
+            return None
         # group keys are the pre-projection's leading exprs
         prod = 1.0
         for e in group_irs:
             s = cstats.get(e.index) if isinstance(e, ir.ColumnRef) \
                 else None
             if s is None:
-                return None, None
+                return None
             prod *= max(1.0, s.ndv)
-        rows = self.estimate_rows(pre_node.child)
-        return min(prod, rows), rows
+        return min(prod, self.estimate_rows(pre_node.child))
 
     def _sort_capacity(self, group_irs, scope: Scope, pre_node) -> int:
         """Size the sort-aggregation output from stats (NDV product capped
@@ -2399,8 +2355,7 @@ class Planner:
         a fresh XLA compile plus a full re-sort, so landing right the
         first time is the difference between one device pass and four
         (GroupByHash's expectedSize estimation)."""
-        est, _rows = self._group_rows_estimate(group_irs, scope,
-                                               pre_node)
+        est = self._group_rows_estimate(group_irs, scope, pre_node)
         if est is None:
             return DEFAULT_SORT_GROUPS
         # 1.3x headroom, pow2 bucket (stable jit cache), floor at the

@@ -20,6 +20,7 @@ table, so any worker can recompute it identically.
 from __future__ import annotations
 
 import json
+import logging
 import statistics
 import threading
 import time
@@ -43,6 +44,8 @@ from .failureinjector import InjectedFailure
 from .pageserde import PageChecksumError, verify_page
 from .retrypolicy import RetryPolicy
 from .tasks import Split, decode_columns, encode_fragment
+
+log = logging.getLogger("trino_tpu.scheduler")
 
 
 class TaskFailedError(RuntimeError):
@@ -532,8 +535,7 @@ class StageScheduler:
             acc = lq["operators"].setdefault(
                 "TableScan", {"rows": 0, "wall_ms": 0.0, "calls": 0,
                               "device_ms": 0.0, "host_ms": 0.0,
-                              "compile_ms": 0.0, "strategy": "",
-                              "distribution": ""})
+                              "compile_ms": 0.0, "strategy": ""})
             acc["strategy"] = (f"zone-pruned:{lq['splits_pruned']}/"
                                f"{lq.get('splits_total', 0)} splits")
         with self._lock:
@@ -545,8 +547,7 @@ class StageScheduler:
                      "device_ms": d.get("device_ms", 0.0),
                      "host_ms": d.get("host_ms", 0.0),
                      "compile_ms": d.get("compile_ms", 0.0),
-                     "strategy": d.get("strategy", ""),
-                     "distribution": d.get("distribution", "")})
+                     "strategy": d.get("strategy", "")})
 
     def _record_task(self, task: "RemoteTask") -> None:
         """Fetch a finished task's terminal status — TaskStats + spans —
@@ -586,8 +587,7 @@ class StageScheduler:
                     acc = lq["operators"].setdefault(
                         op, {"rows": 0, "wall_ms": 0.0, "calls": 0,
                              "device_ms": 0.0, "host_ms": 0.0,
-                             "compile_ms": 0.0, "strategy": "",
-                             "distribution": ""})
+                             "compile_ms": 0.0, "strategy": ""})
                     acc["rows"] += int(d.get("rows", 0))
                     acc["wall_ms"] += float(d.get("wallMs", 0.0))
                     acc["calls"] += int(d.get("calls", 0))
@@ -596,8 +596,6 @@ class StageScheduler:
                     acc["compile_ms"] += float(d.get("compileMs", 0.0))
                     if d.get("strategy"):
                         acc["strategy"] = d["strategy"]
-                    if d.get("distribution"):
-                        acc["distribution"] = d["distribution"]
         # rebase the worker's span stamps onto the coordinator clock
         # using the offset estimated at announce (skew satellite)
         self._tracer().adopt(
@@ -1641,6 +1639,8 @@ class StageScheduler:
         return polls
 
     def _mark_failed(self, node_id: str, err: Exception) -> None:
+        log.warning("node %s marked FAILED on the task path: %r",
+                    node_id, err)
         with self.state.nodes_lock:
             n = self.state.nodes.get(node_id)
             if n is not None:

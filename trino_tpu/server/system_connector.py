@@ -211,9 +211,7 @@ class SystemConnector:
             "operator_stats",
             [("query_id", [r["query_id"] for r in recs]),
              ("operator", [r["operator"] for r in recs]),
-             ("strategy", [r.get("strategy", "") for r in recs]),
-             ("distribution", [r.get("distribution", "")
-                               for r in recs])])
+             ("strategy", [r.get("strategy", "") for r in recs])])
         rows = np.array([r["rows"] for r in recs], dtype=np.int64)
         wall = np.array([r["wall_ms"] for r in recs], dtype=np.float64)
         calls = np.array([r["calls"] for r in recs], dtype=np.int64)

@@ -725,11 +725,7 @@ class TaskManager:
                     "calls": int(v[2]), "deviceMs": round(v[3], 3),
                     "hostMs": round(v[4], 3),
                     "compileMs": round(v[5], 3),
-                    "strategy": strategies.get(op, ""),
-                    # mesh placement (broadcast vs partitioned) rides
-                    # beside the strategy; only joins carry one
-                    "distribution": strategies.get("JoinDistribution", "")
-                    if op == "JoinNode" else ""}
+                    "strategy": strategies.get(op, "")}
                for op, v in op_agg.items()}
         with task.lock:
             task.stats = {"rowsOut": task.rows_out,
