@@ -142,3 +142,57 @@ def test_q1_stage_compiles_at_60m_rows(one_chip):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + \
         mem.output_size_in_bytes
     assert used < 16 * 10**9, f"q1 at 60M rows needs {used:,} bytes"
+
+
+# ---------------------------------------------------------------------------
+# filter_project with its literals as operands: q6's filter at a 250,000-row
+# split's capacity, and a decimal comparison whose LITERAL has the larger
+# scale (the traced scalar is the side _decimal_compare floor-divides)
+# ---------------------------------------------------------------------------
+
+def test_filter_project_with_operand_literals_compiles(one_chip):
+    from trino_tpu import ir
+    from trino_tpu.batch import Batch, Column
+    from trino_tpu.ops.project import filter_project
+    from trino_tpu.types import BIGINT, DATE, VARCHAR, decimal
+    n = 262_144
+    d122 = decimal(12, 2)
+    qty, price, disc = (ir.ColumnRef(i, d122) for i in range(3))
+    ship, seg = ir.ColumnRef(3, DATE), ir.ColumnRef(4, VARCHAR)
+
+    def q6(day0, day1, lo, hi, quantity, lut):
+        return (ir.Logical('and', (
+            ir.Compare('>=', ship, ir.Literal(day0, DATE)),
+            ir.Compare('<', ship, ir.Literal(day1, DATE)),
+            ir.Between(disc, ir.Literal(lo, decimal(1, 2)),
+                       ir.Literal(hi, decimal(2, 2))),
+            ir.Compare('<', qty, ir.Literal(quantity, BIGINT)),
+            ir.Compare('<', price, ir.Literal(quantity * 10 ** 6,
+                                              decimal(9, 6))),
+            ir.DictPredicate(seg, lut))),
+            (ir.arith('*', price, disc),
+             ir.arith('*', price, ir.arith(
+                 '-', ir.Literal(1, decimal(1, 0)), disc))))
+
+    ta, va = ir.parametrise(q6(8766, 9131, 5, 7, 24, (True,) + (False,) * 4))
+    tb, vb = ir.parametrise(q6(9862, 10227, 8, 10, 25,
+                               (False,) * 4 + (True,)))
+    assert ta == tb and ir.slot_count(va) == 8
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def column(dtype):
+        return Column(
+            data=jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip),
+            valid=jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip))
+
+    batch = Batch(columns=(column(jnp.int64),) * 3
+                  + (column(jnp.int32),) * 2,
+                  live=jax.ShapeDtypeStruct((n,), jnp.bool_,
+                                            sharding=one_chip))
+    compiled = filter_project.__wrapped__.lower(
+        batch, jax.tree_util.tree_map(shape, va), *ta).compile()
+    mem = compiled.memory_analysis()
+    # the operands: one int64 vector of 7 slots and a 5-entry table
+    assert mem.argument_size_in_bytes < 5 * n * 9 + n + 4096

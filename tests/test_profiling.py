@@ -70,8 +70,8 @@ def test_exec_stats_jit_compiles_agree_with_recorder():
     s.execute("SELECT count(*) FROM region")       # warm common kernels
     stats0 = s.executor.stats.jit_compiles
     rec0 = RECORDER.totals()["compiles"]
-    # a fresh literal is a fresh static in the fused filter trace, so at
-    # least one program compiles for this query
+    # the first statement of this shape in the process: its fused filter
+    # compiles (a literal alone would compile nothing: it is an operand)
     s.execute("SELECT count(*) FROM nation WHERE n_nationkey > 17")
     d_stats = s.executor.stats.jit_compiles - stats0
     d_rec = RECORDER.totals()["compiles"] - rec0
@@ -423,3 +423,105 @@ def test_bench_main_check_regressions_exit_codes(tmp_path, monkeypatch):
     assert bench.main(["--check-regressions"]) == 0
     _round_file(tmp_path, "BENCH_r04.json", {"q": 900.0})
     assert bench.main(["--check-regressions"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# a new literal compiles nothing: filter_project is keyed by the
+# expression's shape, its literals are operands bound once a plan node
+# ---------------------------------------------------------------------------
+
+TINY = "tpch.tiny"
+# two parameter sets a template (TPC-H 2.4), the second with every value
+# new; q3's keep the join outputs inside the capacities of the first
+LITERAL_PAIRS = {
+    "q6": ({"year": 1994, "discount": 6, "quantity": 24},
+           {"year": 1997, "discount": 9, "quantity": 25}),
+    "q1": ({"delta": 90}, {"delta": 63}),
+    "q3": ({"segment": "BUILDING", "day": 15},
+           {"segment": "MACHINERY", "day": 31}),
+}
+
+
+def _filter_project_compiles():
+    return sum(e["compiles"] for e in RECORDER.snapshot()
+               if e["site"] in ("project.filter_project",
+                                "executor.filter_project_fused"))
+
+
+@pytest.fixture(scope="module", params=["single-node", "one-worker"])
+def literal_route(request):
+    """(client, coordinator, name of the span that carries literalSlots):
+    the coordinator alone on its device route, or with one worker and
+    8,192-row split tasks. Traced, so the spans can be read."""
+    from trino_tpu.client.client import Client
+    from trino_tpu.server.coordinator import CoordinatorServer
+    from trino_tpu.server.worker import WorkerServer
+    session = Session(default_schema="tiny")
+    coord = CoordinatorServer(session).start()
+    worker = None
+    client = Client(coord.uri, user="literals")
+    try:
+        if request.param == "one-worker":
+            coord.state.scheduler.split_rows = 8192
+            worker = WorkerServer("lit-w0", coord.uri,
+                                  announce_interval_s=0.1,
+                                  catalog=session.catalog).start()
+            deadline = time.time() + 5
+            while not coord.state.active_nodes() and \
+                    time.time() < deadline:
+                time.sleep(0.05)
+        else:
+            client.execute("SET SESSION routing_mode = device")
+        client.execute("SET SESSION enable_tracing = true")
+        yield client, coord, session, request.param
+    finally:
+        if worker is not None:
+            worker.stop()
+        coord.stop()
+
+
+@pytest.mark.parametrize("name", ["q6", "q1", "q3"])
+def test_second_statement_with_new_literals_compiles_nothing(
+        literal_route, name):
+    # the benchmark's templates with their plain numpy references
+    from test_resident_tables import bench_module, reference_tables
+    client, coord, session, route = literal_route
+    template = bench_module(f"queries.{name}")
+    compare = bench_module("compare")
+    tables = reference_tables(session, [template])
+    readings = []
+    for params in LITERAL_PAIRS[name]:
+        coord.state.scheduler.spool.clear()
+        t0, fp0 = RECORDER.totals(), _filter_project_compiles()
+        res = client.execute(template.render(params, TINY))
+        t1, fp1 = RECORDER.totals(), _filter_project_compiles()
+        assert compare.mismatched_cells(
+            res.rows, template.reference(tables, params),
+            template.COLUMNS) == (0, None)
+        info = client.query_info(res.query_id)
+        spans = client._request(
+            "GET", f"{coord.uri}/v1/query/{res.query_id}/trace")["spans"]
+        if route == "one-worker":
+            assert info["distributed"], info.get("fallbackReason")
+            carriers = [s for s in spans if s["name"] == "worker-task"]
+        else:
+            assert info["route"] == "device" and not info["distributed"]
+            carriers = [s for s in spans if s["name"] == "execute"]
+        assert carriers and all(
+            "literalSlots" in s["attributes"] for s in carriers)
+        readings.append({
+            "literal": t1["literalKeyedCompiles"]
+            - t0["literalKeyedCompiles"],
+            "filter_project": fp1 - fp0,
+            "slots": sum(s["attributes"]["literalSlots"]
+                         for s in carriers),
+            "compile_spans": [s["attributes"]["site"] for s in spans
+                              if s["name"] == "compile" and
+                              "filter_project" in s["attributes"]["site"]],
+        })
+    first, second = readings
+    # the literals were bound as operands, once a plan node: a task of
+    # many splits binds what a task of one split binds
+    assert first["slots"] > 0 and second["slots"] == first["slots"]
+    assert second["filter_project"] == 0 and not second["compile_spans"]
+    assert second["literal"] == 0

@@ -133,7 +133,7 @@ def test_filter_project_jit_caches():
     batch = make_batch()
     f = ir.Compare('>=', col(0), lit(2))
     p = (ir.arith('*', col(0), col(1)),)
-    out = filter_project(batch, f, p)
+    out = filter_project(batch, None, f, p)
     live = np.asarray(out.live)[:4]
     np.testing.assert_array_equal(live, [False, True, True, True])
     np.testing.assert_array_equal(np.asarray(out.columns[0].data)[:4],
@@ -217,3 +217,235 @@ def test_decimal_compare_no_int64_overflow():
     d, _ = evaluate(ir.Compare('=', lit(exact, decimal(18, 12)),
                                col(0, decimal(12, 2))), b2, n=1)
     assert d[0]
+
+
+# ---------------------------------------------------------------------------
+# literals as operands (ir.parametrise): the program is keyed by the
+# expression's shape, the values are traced arguments
+# ---------------------------------------------------------------------------
+
+def _operand_batch():
+    """big int64 | int32 | date | bool (with a NULL) | double | decimal(12,2)
+    | decimal(15,6) | varchar codes"""
+    big = np.array([-5, 0, 7, 2 ** 40, 24, -(2 ** 40)], dtype=np.int64)
+    i32 = np.array([-3, 0, 3, 2 ** 30, 24, 9], dtype=np.int32)
+    day = np.array([9131, 9204, 9205, 9206, 10000, 0], dtype=np.int32)
+    flag = np.array([True, False, True, False, True, False])
+    dbl = np.array([0.1, 0.30000000000000004, -2.5, 1e300, 24.0, 0.0])
+    d2 = np.array([2400, 2399, 2401, -50, 5, 7], dtype=np.int64)
+    d6 = np.array([24_000_000, 23_999_999, 24_000_001, -500_000,
+                   50_000, 70_001], dtype=np.int64)
+    codes = np.array([0, 1, 2, 1, 0, 2], dtype=np.int32)
+    valid = np.array([True, True, False, True, True, True])
+    return batch_from_numpy(
+        [big, i32, day, flag, dbl, d2, d6, codes],
+        valids=[None, None, None, valid, None, None, None, None],
+        pad_multiple=8)
+
+
+def _literal_cases():
+    from trino_tpu.types import INTEGER, TIMESTAMP, VARCHAR
+    big, i32, day = col(0), col(1, INTEGER), col(2, DATE)
+    flag, dbl = col(3, BOOLEAN), col(4, DOUBLE)
+    d2, d6 = col(5, decimal(12, 2)), col(6, decimal(15, 6))
+    code = col(7, VARCHAR)
+    cases = {
+        "bigint": ir.Compare('<', big, lit(2 ** 40)),
+        "integer": ir.arith('+', i32, lit(-7, INTEGER)),
+        "date": ir.Logical('and', (ir.Compare('>=', day, lit(9131, DATE)),
+                                   ir.Compare('<', day, lit(9205, DATE)))),
+        "date_minus_date": ir.arith('-', day, lit(9204, DATE)),
+        "timestamp_vs_date": ir.Compare(
+            '>', ir.Cast(day, TIMESTAMP), lit(795139200000000, TIMESTAMP)),
+        "boolean": ir.Logical('or', (flag, lit(False, BOOLEAN))),
+        "double": ir.arith('/', dbl, lit(3.0, DOUBLE)),
+        "double_times": ir.arith('*', dbl, lit(0.1, DOUBLE)),
+        "double_compare": ir.Compare('<=', dbl, lit(0.3, DOUBLE)),
+        "decimal_same_scale": ir.Compare('>=', d2,
+                                         lit(2400, decimal(4, 2))),
+        # the q11 case: scales 2 and 6, never rescaled to a common one
+        "decimal_scales_lt": ir.Compare('<', d2,
+                                        lit(24_000_000, decimal(8, 6))),
+        "decimal_scales_eq": ir.Compare('=', d6, lit(2400, decimal(4, 2))),
+        "decimal_scales_ge": ir.Compare('>=', d6, lit(-50, decimal(4, 2))),
+        "decimal_vs_int": ir.Compare('<', d2, lit(24)),
+        "decimal_arith": ir.arith('*', d2, ir.arith(
+            '-', lit(1, decimal(1, 0)), lit(6, decimal(2, 2)))),
+        "decimal_plus_int": ir.arith('+', d2, lit(3)),
+        "between": ir.Between(d2, lit(5, decimal(1, 2)),
+                              lit(2400, decimal(4, 2))),
+        "in": ir.InList(big, (lit(7), lit(24), lit(-5))),
+        "in_dates": ir.InList(day, (lit(9204, DATE), lit(0, DATE))),
+        "case": ir.Case(
+            whens=((ir.Compare('<', big, lit(1)), lit(100)),
+                   (ir.Compare('<', big, lit(25)), lit(200))),
+            default=lit(300), dtype=BIGINT),
+        "case_no_default": ir.Case(
+            whens=((ir.Compare('=', i32, lit(24, INTEGER)),
+                    lit(1.5, DOUBLE)),), default=None, dtype=DOUBLE),
+        "cast_literal": ir.arith('+', dbl, ir.Cast(lit(150, decimal(3, 2)),
+                                                   DOUBLE)),
+        "cast_literal_decimal": ir.Compare(
+            '<', d6, ir.Cast(lit(24), decimal(12, 2))),
+        "null_literal": ir.Logical('or', (ir.Compare('>', big, lit(0)),
+                                          lit(None, BOOLEAN))),
+        "null_coalesce": ir.ScalarFunc(
+            "coalesce", (lit(None, BIGINT), big, lit(9)), BIGINT),
+        "varchar_literal": ir.Compare('=', code, ir.Literal("x", VARCHAR)),
+        "dict_predicate": ir.DictPredicate(code, (False, True, True)),
+        "dict_predicate_empty": ir.DictPredicate(code, ()),
+        "dict_value_map": ir.arith(
+            '+', ir.DictValueMap(code, (8, 10, 9), BIGINT), lit(1)),
+        "round_param": ir.ScalarFunc("round", (ir.arith(
+            '*', dbl, lit(2.5, DOUBLE)),), DOUBLE, params=(1,)),
+        "mod": ir.ScalarFunc("mod", (big, lit(7)), BIGINT),
+        "negate": ir.Negate(lit(5), BIGINT),
+    }
+    return cases
+
+
+@pytest.mark.parametrize("name", sorted(_literal_cases()))
+def test_literals_as_operands_match_static_literals(name):
+    """Each expression form, once with its literals as static constants
+    (the unparametrised IR) and once with them as operands: the arrays
+    are the same bit for bit, values and validity and dtype."""
+    import jax
+    import jax.numpy as jnp
+    expr = _literal_cases()[name]
+    batch = _operand_batch()
+    # one jitted program of the static IR, as the parent ran it. One form
+    # differs: XLA folds a DOUBLE division by a constant into a multiply by
+    # its reciprocal (an ulp off), and an operand is divided by, as IEEE
+    # 754 and op-by-op evaluation of the static IR do
+    static = (lambda b: eval_expr(expr, b))
+    want_d, want_v = (static if name == "double" else jax.jit(static))(batch)
+    template, values = ir.parametrise(expr)
+    values = jax.tree_util.tree_map(jnp.asarray, values)
+    assert not any(isinstance(n, ir.Literal) and n.value is not None
+                   and n.dtype.kind.value != "varchar"
+                   for n in ir.walk(template)), template
+    got_d, got_v = jax.jit(
+        lambda b, v: eval_expr(template, b, v))(batch, values)
+    assert got_d.dtype == want_d.dtype and got_d.shape == want_d.shape
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    np.testing.assert_array_equal(
+        np.asarray(got_d).view(np.uint8), np.asarray(want_d).view(np.uint8))
+    # and through the jitted operator, filter and projection together
+    if expr.dtype == BOOLEAN:
+        (tf, te), vals = ir.parametrise((expr, (col(0),)))
+        out = filter_project(batch, jax.tree_util.tree_map(
+            jnp.asarray, vals), tf, te)
+        np.testing.assert_array_equal(
+            np.asarray(out.live),
+            np.asarray(batch.live & want_d & want_v))
+
+
+def test_folded_scalar_subquery_is_a_slot():
+    """fold_scalars turns an uncorrelated scalar subquery into a Literal
+    before parametrise runs, so its value is a slot like any other: two
+    statements whose subqueries give different values share a template,
+    and the operand gives what the constant gave."""
+    from trino_tpu.exec.session import Session
+    from trino_tpu.planner import logical as L
+    from trino_tpu.sql.parser import parse
+    s = Session(default_schema="tiny")
+    ex = s.executor
+
+    def folded(threshold):
+        rel = s.planner().plan_query(parse(
+            "SELECT n_nationkey FROM nation WHERE n_nationkey > "
+            f"(SELECT max(r_regionkey) + {threshold} FROM region)"))
+        stack, node = [rel.node], None
+        while stack:
+            n = stack.pop()
+            if isinstance(n, L.FilterNode) and any(
+                    isinstance(e, ir.ScalarSubqueryRef)
+                    for e in ir.walk(n.predicate)):
+                node = n
+            stack.extend(L.children(n))
+        assert node is not None
+        return node, ex.fold_scalars(node.predicate)
+
+    node, pred = folded(3)
+    assert not any(isinstance(e, ir.ScalarSubqueryRef)
+                   for e in ir.walk(pred))
+    template, values = ir.parametrise(pred)
+    assert values[0].tolist() == [7]            # max(r_regionkey) = 4
+    template2, values2 = ir.parametrise(folded(10)[1])
+    assert template2 == template and values2[0].tolist() == [14]
+    import jax
+    import jax.numpy as jnp
+    child = ex.run(node.child)
+    want = eval_expr(pred, child)
+    got = eval_expr(template, child,
+                    jax.tree_util.tree_map(jnp.asarray, values))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    rows = s.execute("SELECT count(*) FROM nation WHERE n_nationkey > "
+                     "(SELECT max(r_regionkey) + 3 FROM region)").rows
+    assert rows[0][0] == 17                      # keys 8..24
+
+
+def _template_pairs():
+    from trino_tpu.types import VARCHAR
+    c, code = col(0), col(1, VARCHAR)
+    dec = col(2, decimal(12, 2))
+    base = ir.Compare('<', c, lit(5))
+    return {
+        # what must stay in the template: (a, b, same template?)
+        "values_only": (base, ir.Compare('<', c, lit(6)), True),
+        "decimal_digits": (ir.Compare('<', dec, lit(9, decimal(1, 2))),
+                           ir.Compare('<', dec, lit(10, decimal(2, 2))),
+                           True),
+        "in_values": (ir.InList(c, (lit(1), lit(2))),
+                      ir.InList(c, (lit(3), lit(9))), True),
+        "lut_values": (ir.DictPredicate(code, (True, False, False)),
+                       ir.DictPredicate(code, (False, False, True)), True),
+        "value_map_values": (ir.DictValueMap(code, (1, 2, 3), BIGINT),
+                             ir.DictValueMap(code, (4, 5, 6), BIGINT),
+                             True),
+        "null": (ir.Logical('or', (base, lit(True, BOOLEAN))),
+                 ir.Logical('or', (base, lit(None, BOOLEAN))), False),
+        "in_length": (ir.InList(c, (lit(1), lit(2))),
+                      ir.InList(c, (lit(1), lit(2), lit(3))), False),
+        "scalar_func_param": (
+            ir.ScalarFunc("round", (col(0, DOUBLE),), DOUBLE, params=(1,)),
+            ir.ScalarFunc("round", (col(0, DOUBLE),), DOUBLE, params=(2,)),
+            False),
+        "varchar_literal": (
+            ir.Compare('=', code, ir.Literal("a", VARCHAR)),
+            ir.Compare('=', code, ir.Literal("b", VARCHAR)), False),
+        "pool_length": (ir.DictPredicate(code, (True, False)),
+                        ir.DictPredicate(code, (True, False, False)),
+                        False),
+        "literal_type": (base, ir.Compare('<', c, lit(5, DOUBLE)), False),
+        "decimal_scale": (ir.Compare('<', dec, lit(5, decimal(3, 2))),
+                          ir.Compare('<', dec, lit(5, decimal(3, 1))),
+                          False),
+        "operator": (base, ir.Compare('<=', c, lit(5)), False),
+        "cast_target": (ir.Cast(lit(5), DOUBLE),
+                        ir.Cast(lit(5), decimal(12, 2)), False),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_template_pairs()))
+def test_template_holds_shape_not_values(name):
+    a, b, same = _template_pairs()[name]
+    ta, va = ir.parametrise((a, (a, col(0))))
+    tb, vb = ir.parametrise((b, (b, col(0))))
+    assert (ta == tb) is same
+    if same:
+        assert hash(ta) == hash(tb)
+        assert ir.slot_count(va) == ir.slot_count(vb) > 0
+        flat_a = [np.asarray(x).tolist() for x in (va[0], va[1], *va[2])
+                  if x is not None]
+        flat_b = [np.asarray(x).tolist() for x in (vb[0], vb[1], *vb[2])
+                  if x is not None]
+        assert flat_a != flat_b
+
+
+def test_parametrise_leaves_no_slots_where_nothing_to_bind():
+    exprs = (None, (col(0), ir.arith('+', col(0), col(1))))
+    template, values = ir.parametrise(exprs)
+    assert template == exprs and values == (None, None, ())
+    assert ir.slot_count(values) == 0

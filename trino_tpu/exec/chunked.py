@@ -207,7 +207,7 @@ def compile_fused_chunk(executor, target: L.PlanNode,
 
             def run_project(chunk, b, l, g, _child=child, _exprs=exprs):
                 bt, st = _child(chunk, b, l, g)
-                return filter_project(bt, None, _exprs), st
+                return filter_project(bt, None, None, _exprs), st
             return run_project
         if isinstance(node, L.JoinNode):
             if not _fused_join_ok(node):

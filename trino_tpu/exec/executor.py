@@ -57,6 +57,9 @@ class ExecStats:
     chunk_lut_joins: int = 0         # sync-free reused-LUT probes
     value_puts: int = 0              # ValuesNodes put on the device
                                      # (run_values calls)
+    literal_slots: int = 0           # literals and lookup tables bound as
+                                     # operands of a filter/project
+                                     # program (bound_exprs memo fills)
     fused_chunk_pipelines: int = 0   # whole-chunk-path single programs
     pallas_gather_calls: int = 0     # probe sites dispatched with the
                                      # tiled-gather kernel enabled
@@ -142,6 +145,10 @@ class Executor:
         self.pool = MemoryPool(parse_bytes(env_limit) if env_limit
                                else (64 << 30))
         self._node_bytes: Dict[int, int] = {}
+        # id(plan node) -> (node, template, device values) of the
+        # filter/project program it dispatches (bound_exprs); per-query
+        # state, dropped where _node_bytes is
+        self._bound_exprs: Dict[int, tuple] = {}
         # host-spill survival chain (exec/spill.py): when a join/agg
         # reservation cannot fit even after revocation, the operator
         # retries partition-wise through the host/disk tier
@@ -344,9 +351,7 @@ class Executor:
         self.strategy_decisions = {}
         # release reservations surviving from the previous query (the root
         # batch lives until its results are drained)
-        for b in self._node_bytes.values():
-            self.pool.free(b)
-        self._node_bytes.clear()
+        self.release_all_reservations()
         self._subst.clear()
         self._subst_opaque.clear()
         self._skey_memo.clear()
@@ -650,10 +655,13 @@ class Executor:
     def release_all_reservations(self) -> None:
         """Free every per-node reservation (the distributed scheduler's
         merge path runs plan nodes without execute()'s per-query cleanup
-        — under a small pool those leaked bytes starve later queries)."""
+        — under a small pool those leaked bytes starve later queries),
+        and with them the plan nodes' bound expressions: both are keyed
+        by the identity of nodes that go with the query or the task."""
         for b in self._node_bytes.values():
             self.pool.free(b)
         self._node_bytes.clear()
+        self._bound_exprs.clear()
 
     def release_path_reservations(self, node: L.PlanNode, keep) -> None:
         """Free reservations of `node`'s subtree (chunked mode: the
@@ -669,19 +677,23 @@ class Executor:
             return self.run_scan(node)
         if isinstance(node, L.FilterNode):
             # fuse Filter over Project/Scan chains into one jit call
-            pred = self.fold_scalars(node.predicate)
             if isinstance(node.child, L.ProjectNode):
+                (pred, exprs), values = self.bound_exprs(
+                    node, node.predicate, node.child.exprs)
                 child = self.run(node.child.child)
-                return filter_project_fused(
-                    child, self.fold_scalars_tuple(node.child.exprs), pred)
-            return apply_filter(self.run(node.child), pred)
+                return filter_project_fused(child, values, exprs, pred)
+            return apply_filter(self.run(node.child),
+                                self.fold_scalars(node.predicate))
         if isinstance(node, L.ProjectNode):
-            exprs = self.fold_scalars_tuple(node.exprs)
             if isinstance(node.child, L.FilterNode):
+                (pred, exprs), values = self.bound_exprs(
+                    node, node.child.predicate, node.exprs)
                 child = self.run(node.child.child)
-                return filter_project(
-                    child, self.fold_scalars(node.child.predicate), exprs)
-            return filter_project(self.run(node.child), None, exprs)
+            else:
+                (pred, exprs), values = self.bound_exprs(
+                    node, None, node.exprs)
+                child = self.run(node.child)
+            return filter_project(child, values, pred, exprs)
         if isinstance(node, L.AggregateNode):
             return self.run_aggregate(node)
         if isinstance(node, L.JoinNode):
@@ -1265,6 +1277,26 @@ class Executor:
     def fold_scalars_tuple(self, exprs):
         return tuple(self.fold_scalars(e) for e in exprs)
 
+    def bound_exprs(self, node: L.PlanNode, predicate, exprs):
+        """((predicate, exprs) as a template, its values on the device)
+        for the filter/project program `node` dispatches, made once a
+        plan node: subqueries folded, literals taken out as operands
+        (`ir.parametrise`) and put on the device. A task's 240 splits
+        dispatch the same node, so they pass device arrays: no IR walk
+        and no host-to-device transfer a split."""
+        hit = self._bound_exprs.get(id(node))
+        if hit is None:
+            template, values = ir.parametrise(
+                (self.fold_scalars(predicate),
+                 self.fold_scalars_tuple(exprs)))
+            self.stats.literal_slots += ir.slot_count(values)
+            # uncommitted arrays: a mesh executor's sharded batch places
+            # the program, the operands follow it
+            values = jax.tree_util.tree_map(jnp.asarray, values)
+            # the node reference keeps its id from being reused
+            hit = self._bound_exprs[id(node)] = (node, template, values)
+        return hit[1], hit[2]
+
     def scalar_value(self, ref: ir.ScalarSubqueryRef):
         # keyed by the ref itself (hashes by plan identity) so the cache
         # keeps the plan object alive — id() reuse cannot alias entries
@@ -1781,11 +1813,12 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
     return lines
 
 
-@recorded_jit(static_argnums=(1, 2))
-def filter_project_fused(batch: Batch, exprs, predicate) -> Batch:
-    """Project-then-filter in one jit (Filter over Project)."""
-    projected = project(batch, exprs)
-    return apply_filter(projected, predicate)
+@recorded_jit(static_argnums=(2, 3))
+def filter_project_fused(batch: Batch, values, exprs, predicate) -> Batch:
+    """Project-then-filter in one jit (Filter over Project), keyed by
+    the expressions' shape and fed their literals as `filter_project`."""
+    projected = project(batch, exprs, values)
+    return apply_filter(projected, predicate, values)
 
 
 def remap_codes(batch: Batch, remaps) -> Batch:

@@ -30,8 +30,14 @@ compile duration, into:
 
 A compile is `shape`-keyed when the site has not seen the arguments'
 array shapes and dtypes before (a new capacity bucket) and `literal`-keyed
-when it has and only static arguments differ (`filter_project`'s IR with
-new literals): two different repairs, told apart here.
+when it has and only static arguments differ: two different repairs, told
+apart here. `filter_project` takes its literals as operands
+(`ir.parametrise`), so what is left `literal`-keyed is a static capacity
+or domain that followed the data (`join.dense_join_compacted`), a
+filter/project template that differs in shape proper (a NULL or VARCHAR
+literal, an IN list's length, a function parameter) and the sites that
+still take whole IR as a static argument: a join residual
+(`ops/join.py`, `filter_mask`) and the chunked driver's fused program.
 
 Design constraints: recording must never change execution (a wrapper
 failure falls through to the raw call), must cost ~a cache-size probe

@@ -274,6 +274,7 @@ class Session:
         parse/plan and land here directly."""
         self._apply_executor_properties(t0)
         with self.tracer.span("execute") as sp:
+            slots0 = self.executor.stats.literal_slots
             batch = self.executor.execute(root)
             names, arrays, valids = self.executor.result_to_host(root,
                                                                  batch)
@@ -282,7 +283,9 @@ class Session:
                 sp.attributes.update(
                     residentBytes=resident.total_bytes(),
                     residentEntries=len(resident),
-                    scanPutBytes=self.executor.scan_put_bytes)
+                    scanPutBytes=self.executor.scan_put_bytes,
+                    literalSlots=self.executor.stats.literal_slots
+                    - slots0)
                 if self.executor.profile:
                     ns = [v for v in self.executor.node_stats.values()
                           if len(v) >= 5]

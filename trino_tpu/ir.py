@@ -14,6 +14,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+import numpy as np
+
 from .types import (BIGINT, BOOLEAN, DATE, DOUBLE, DataType, TypeKind,
                     common_super_type, decimal)
 
@@ -33,6 +35,28 @@ class ColumnRef(Expr):
 class Literal(Expr):
     value: object       # python int/float/bool/str/None; DECIMAL as scaled int
     dtype: DataType
+
+
+@dataclass(frozen=True)
+class Param(Expr):
+    """A literal's place in a parametrised expression (`parametrise`):
+    the value is a traced operand, element `slot` of the values' integer
+    vector (BOOLEAN, integer kinds, DATE, TIMESTAMP, short DECIMAL as its
+    scaled int) or of their float64 vector (DOUBLE), cast to `dtype`."""
+    slot: int
+    dtype: DataType
+
+
+@dataclass(frozen=True)
+class ArrayParam:
+    """A per-code lookup table's place (`DictPredicate.lut`,
+    `DictValueMap.values`): array operand `slot`. The pool's length is
+    the program's shape and stays in the template."""
+    slot: int
+    length: int
+
+    def __len__(self) -> int:
+        return self.length
 
 
 @dataclass(frozen=True)
@@ -297,7 +321,7 @@ def remap_columns(expr: Expr, mapping) -> Expr:
     `mapping` (used by the column-pruning optimizer pass)."""
     if isinstance(expr, ColumnRef):
         return ColumnRef(mapping[expr.index], expr.dtype, expr.name)
-    if isinstance(expr, (Literal, ArrayConst)):
+    if isinstance(expr, (Literal, Param, ArrayConst)):
         return expr
     if isinstance(expr, Arith):
         return Arith(expr.op, remap_columns(expr.left, mapping),
@@ -381,3 +405,78 @@ def _transform_value(v, fn):
         if any(a is not b for a, b in zip(items, v)):
             return items
     return v
+
+
+# --------------------------------------------------------------------------
+# Literals as operands: the jit key of a filter/project program is the
+# expression's shape, the literal values are traced arguments
+# --------------------------------------------------------------------------
+
+_INT_SLOT_KINDS = (TypeKind.BOOLEAN, TypeKind.INTEGER, TypeKind.BIGINT,
+                   TypeKind.DATE, TypeKind.TIMESTAMP, TypeKind.DECIMAL)
+
+
+def parametrise(exprs):
+    """(template, values) of `exprs`: an Expr, None, or a nested tuple of
+    them (a filter and a projection list together share one slot space).
+
+    The template is `exprs` with every value-carrying leaf whose value
+    decides neither Python control flow nor an array shape replaced by a
+    slot: a non-NULL `Literal` of a numeric, boolean or temporal type
+    (the members of an `InList` too; its length stays) by `Param`, the
+    lookup table of a `DictPredicate` / `DictValueMap` by `ArrayParam`.
+    NULL and VARCHAR literals, `ScalarFunc.params`, `DerivedDict` pools,
+    `ArrayConst`, cast targets, scales and operators are shape and stay.
+    Two expressions that differ only in slot values have equal templates,
+    so they share one compiled program.
+
+    `values` is `(ints, floats, arrays)`: an int64 vector, a float64
+    vector (None where the template has no such slot) and a tuple of
+    lookup arrays, all numpy; the caller puts them on the device once.
+    Subquery refs must be folded first (Executor.fold_scalars)."""
+    ints, floats, arrays = [], [], []
+
+    def leaf(e):
+        if isinstance(e, Literal):
+            v = e.value
+            if e.dtype.kind in _INT_SLOT_KINDS and \
+                    isinstance(v, (int, np.integer)) and \
+                    -2 ** 63 <= v < 2 ** 63:
+                ints.append(int(v))
+                t = e.dtype
+                if t.kind is TypeKind.DECIMAL and t.precision <= 18:
+                    # 0.09 is decimal(1,2) and 0.10 decimal(2,2): the
+                    # digits of a value are not the program's shape
+                    t = decimal(18, t.scale)
+                return Param(len(ints) - 1, t)
+            if e.dtype.kind is TypeKind.DOUBLE and \
+                    isinstance(v, (int, float, np.number)):
+                floats.append(float(v))
+                return Param(len(floats) - 1, e.dtype)
+            return e        # NULL, VARCHAR, anything unusual: static
+        if isinstance(e, (DictPredicate, DictValueMap)):
+            table = e.lut if isinstance(e, DictPredicate) else e.values
+            if len(table):      # an empty pool is a branch of its own
+                arrays.append(np.asarray(table, dtype=e.dtype.np_dtype))
+                return type(e)(transform(e.arg, leaf),
+                               ArrayParam(len(arrays) - 1, len(table)),
+                               e.dtype)
+        return None
+
+    def over(x):
+        if isinstance(x, tuple):
+            return tuple(over(i) for i in x)
+        return None if x is None else transform(x, leaf)
+
+    template = over(exprs)
+    values = (np.asarray(ints, dtype=np.int64) if ints else None,
+              np.asarray(floats, dtype=np.float64) if floats else None,
+              tuple(arrays))
+    return template, values
+
+
+def slot_count(values) -> int:
+    """Slots bound by `parametrise`'s `values` (a lookup array is one)."""
+    ints, floats, arrays = values
+    return (0 if ints is None else len(ints)) + \
+        (0 if floats is None else len(floats)) + len(arrays)

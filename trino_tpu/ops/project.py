@@ -152,8 +152,27 @@ def civil_from_days(days: jax.Array):
 # --------------------------------------------------------------------------
 
 
-def eval_expr(expr: ir.Expr, batch: Batch):
-    """Evaluate an IR expression over a batch. Returns (data, valid)."""
+def _operand(param: ir.Param, values) -> jax.Array:
+    """The scalar a slot holds, at the dtype its literal had."""
+    ints, floats, _ = values
+    vec = floats if param.dtype.kind is TypeKind.DOUBLE else ints
+    return vec[param.slot].astype(param.dtype.np_dtype)
+
+
+def _lookup_table(table, values, dtype) -> jax.Array:
+    """A per-code table: an array operand, or the static tuple."""
+    if isinstance(table, ir.ArrayParam):
+        return values[2][table.slot]
+    return jnp.asarray(table, dtype=dtype)
+
+
+def eval_expr(expr: ir.Expr, batch: Batch, values=None):
+    """Evaluate an IR expression over a batch. Returns (data, valid).
+
+    `values` are the operands of a parametrised expression
+    (`ir.parametrise`): where a literal stood, an `ir.Param` reads its
+    slot at the literal's dtype, so the arithmetic is what the constant
+    gave. An expression with no slots needs none."""
     n = batch.capacity
 
     if isinstance(expr, ir.ColumnRef):
@@ -172,9 +191,13 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         v = jnp.full(n, expr.value, dtype=expr.dtype.np_dtype)
         return v, jnp.ones(n, dtype=jnp.bool_)
 
+    if isinstance(expr, ir.Param):
+        return (jnp.broadcast_to(_operand(expr, values), n),
+                jnp.ones(n, dtype=jnp.bool_))
+
     if isinstance(expr, ir.Arith):
-        ld, lv = eval_expr(expr.left, batch)
-        rd, rv = eval_expr(expr.right, batch)
+        ld, lv = eval_expr(expr.left, batch, values)
+        rd, rv = eval_expr(expr.right, batch, values)
         valid = lv & rv
         out = expr.dtype
         lt, rt = expr.left.dtype, expr.right.dtype
@@ -226,13 +249,13 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         return res, valid
 
     if isinstance(expr, ir.Negate):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         return -d, v
 
     if isinstance(expr, ir.Compare):
         target = ir.comparable(expr.left, expr.right)
-        ld, lv = eval_expr(expr.left, batch)
-        rd, rv = eval_expr(expr.right, batch)
+        ld, lv = eval_expr(expr.left, batch, values)
+        rd, rv = eval_expr(expr.right, batch, values)
         op = expr.op
         if target.kind is TypeKind.DECIMAL:
             # exact scaled-int comparison without upscaling either side
@@ -250,7 +273,7 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         return _apply_cmp(op, l, r), lv & rv
 
     if isinstance(expr, ir.Logical):
-        parts = [eval_expr(a, batch) for a in expr.args]
+        parts = [eval_expr(a, batch, values) for a in expr.args]
         d, v = parts[0]
         for (d2, v2) in parts[1:]:
             if expr.op == 'and':
@@ -264,19 +287,22 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         return d, v
 
     if isinstance(expr, ir.Not):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         return ~d, v
 
     if isinstance(expr, ir.IsNull):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         res = v if expr.negated else ~v
         return res, jnp.ones_like(v)
 
     if isinstance(expr, ir.InList):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         res = jnp.zeros_like(v)
         for lit in expr.values:
-            res = res | (d == jnp.asarray(lit.value, dtype=d.dtype))
+            member = _operand(lit, values).astype(d.dtype) \
+                if isinstance(lit, ir.Param) \
+                else jnp.asarray(lit.value, dtype=d.dtype)
+            res = res | (d == member)
         return res, v
 
     if isinstance(expr, ir.Between):
@@ -287,27 +313,27 @@ def eval_expr(expr: ir.Expr, batch: Batch):
             ir.Compare('>=', expr.arg, expr.low),
             ir.Compare('<=', expr.arg, expr.high),
         ))
-        return eval_expr(lowered, batch)
+        return eval_expr(lowered, batch, values)
 
     if isinstance(expr, ir.Case):
         default = expr.default
         if default is not None:
-            acc_d, acc_v = eval_expr(default, batch)
+            acc_d, acc_v = eval_expr(default, batch, values)
             acc_d = acc_d.astype(expr.dtype.np_dtype)
         else:
             acc_d = jnp.zeros(n, dtype=expr.dtype.np_dtype)
             acc_v = jnp.zeros(n, dtype=jnp.bool_)
         # reverse order: first matching WHEN wins
         for cond, val in reversed(expr.whens):
-            cd, cv = eval_expr(cond, batch)
-            vd, vv = eval_expr(val, batch)
+            cd, cv = eval_expr(cond, batch, values)
+            vd, vv = eval_expr(val, batch, values)
             take = cd & cv
             acc_d = jnp.where(take, vd.astype(expr.dtype.np_dtype), acc_d)
             acc_v = jnp.where(take, vv, acc_v)
         return acc_d, acc_v
 
     if isinstance(expr, ir.Cast):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         src, dst = expr.arg.dtype, expr.dtype
         if src == dst:
             return d, v
@@ -345,7 +371,7 @@ def eval_expr(expr: ir.Expr, batch: Batch):
                 jnp.ones(n, dtype=jnp.bool_))
 
     if isinstance(expr, ir.DerivedDict):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         lut = jnp.asarray(expr.lut, dtype=jnp.int32)
         codes = jnp.clip(d.astype(jnp.int32), 0, len(expr.lut) - 1)
         out = lut[codes]
@@ -355,22 +381,22 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         return out, v
 
     if isinstance(expr, ir.DictPredicate):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         if len(expr.lut) == 0:      # empty pool: no code can match
             return jnp.zeros_like(d, dtype=jnp.bool_), v
-        lut = jnp.asarray(expr.lut, dtype=jnp.bool_)
+        lut = _lookup_table(expr.lut, values, jnp.bool_)
         codes = jnp.clip(d.astype(jnp.int32), 0, len(expr.lut) - 1)
         return lut[codes], v
 
     if isinstance(expr, ir.DecimalAvg):
         from .aggregate import avg_decimal_finalize
-        sd, sv = eval_expr(expr.sum, batch)
-        cd, cv = eval_expr(expr.count, batch)
+        sd, sv = eval_expr(expr.sum, batch, values)
+        cd, cv = eval_expr(expr.count, batch, values)
         res = avg_decimal_finalize(sd, cd, xp=jnp)
         return res, sv & cv & (cd != 0)
 
     if isinstance(expr, ir.ExtractField):
-        d, v = eval_expr(expr.arg, batch)
+        d, v = eval_expr(expr.arg, batch, values)
         is_ts = expr.arg.dtype.kind is TypeKind.TIMESTAMP
         micros_in_day = 86_400_000_000
         if expr.part.startswith('trunc_'):
@@ -411,22 +437,22 @@ def eval_expr(expr: ir.Expr, batch: Batch):
         return res.astype(jnp.int64), v
 
     if isinstance(expr, ir.DictValueMap):
-        d, v = eval_expr(expr.arg, batch)
-        lut = jnp.asarray(expr.values, dtype=expr.dtype.np_dtype)
+        d, v = eval_expr(expr.arg, batch, values)
+        lut = _lookup_table(expr.values, values, expr.dtype.np_dtype)
         codes = jnp.clip(d.astype(jnp.int32), 0, len(expr.values) - 1)
         return lut[codes], v
 
     if isinstance(expr, ir.ScalarFunc):
-        return eval_scalar_func(expr, batch)
+        return eval_scalar_func(expr, batch, values)
 
     raise NotImplementedError(f"eval of {type(expr).__name__}")
 
 
-def eval_scalar_func(expr: ir.ScalarFunc, batch: Batch):
+def eval_scalar_func(expr: ir.ScalarFunc, batch: Batch, values=None):
     """Built-in scalar functions (reference: operator/scalar/ — MathFunctions,
     ConditionalFunctions), branch-free with three-valued logic."""
     name = expr.name
-    parts = [eval_expr(a, batch) for a in expr.args]
+    parts = [eval_expr(a, batch, values) for a in expr.args]
 
     if name == "coalesce":
         d, v = parts[-1]
@@ -581,31 +607,35 @@ def _hll_hash64(d):
     return x
 
 
-def filter_mask(expr: ir.Expr, batch: Batch) -> jax.Array:
+def filter_mask(expr: ir.Expr, batch: Batch, values=None) -> jax.Array:
     """WHERE semantics: NULL -> excluded."""
-    d, v = eval_expr(expr, batch)
+    d, v = eval_expr(expr, batch, values)
     return d & v
 
 
-def apply_filter(batch: Batch, expr: ir.Expr) -> Batch:
+def apply_filter(batch: Batch, expr: ir.Expr, values=None) -> Batch:
     """Filter = AND into the live mask; no data movement (the TPU analog of
     Trino's SelectedPositions, operator/project/SelectedPositions.java)."""
-    return batch.with_live(batch.live & filter_mask(expr, batch))
+    return batch.with_live(batch.live & filter_mask(expr, batch, values))
 
 
-def project(batch: Batch, exprs) -> Batch:
+def project(batch: Batch, exprs, values=None) -> Batch:
     """Evaluate projection list into a new Batch (same capacity/live)."""
     cols = []
     for e in exprs:
-        d, v = eval_expr(e, batch)
+        d, v = eval_expr(e, batch, values)
         cols.append(Column(data=d, valid=v))
     return Batch(columns=tuple(cols), live=batch.live)
 
 
-@recorded_jit(static_argnums=(1, 2))
-def filter_project(batch: Batch, filter_expr, project_exprs) -> Batch:
+@recorded_jit(static_argnums=(2, 3))
+def filter_project(batch: Batch, values, filter_expr,
+                   project_exprs) -> Batch:
     """Jitted fused filter+project — the PageProcessor equivalent
-    (operator/project/PageProcessor.java:99). Expressions are static
-    (hashable IR), so each distinct plan compiles once and is cached."""
-    b = apply_filter(batch, filter_expr) if filter_expr is not None else batch
-    return project(b, project_exprs)
+    (operator/project/PageProcessor.java:99). The expressions are static
+    (hashable IR) and parametrised (`ir.parametrise`): the program is
+    keyed by their shape, the literals arrive in `values`, so a statement
+    with new literals runs the program the last one compiled."""
+    b = apply_filter(batch, filter_expr, values) \
+        if filter_expr is not None else batch
+    return project(b, project_exprs, values)

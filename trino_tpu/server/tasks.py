@@ -929,6 +929,7 @@ class TaskManager:
                 ex.deadline = task.deadline
                 self._current_task_id = task.task_id
                 puts0 = ex.stats.value_puts
+                slots0 = ex.stats.literal_slots
                 saved_profile = ex.profile
                 saved_node_stats = ex.node_stats
                 if profiling:
@@ -979,9 +980,7 @@ class TaskManager:
                     self._current_task_id = None
                     ex._subst.clear()
                     ex._subst_opaque.clear()
-                    for b in ex._node_bytes.values():
-                        ex.pool.free(b)
-                    ex._node_bytes.clear()
+                    ex.release_all_reservations()
                     if held is not None:
                         held.close()    # what a failure or a cancel left
                     if wspan is not None:
@@ -989,6 +988,11 @@ class TaskManager:
                         # if a split ever puts its build again
                         wspan.attributes["valuePuts"] = \
                             ex.stats.value_puts - puts0
+                        # literals and lookup tables bound as operands,
+                        # once a plan node: `splits` times that if a
+                        # split ever binds its own
+                        wspan.attributes["literalSlots"] = \
+                            ex.stats.literal_slots - slots0
                         # that the fold engaged: a folding task holds
                         # every split and stages 1 page, more if it
                         # flushed; any other a page a split
@@ -1174,9 +1178,7 @@ class TaskManager:
                 self._current_task_id = None
                 ex._subst.clear()
                 ex._subst_opaque.clear()
-                for b in ex._node_bytes.values():
-                    ex.pool.free(b)
-                ex._node_bytes.clear()
+                ex.release_all_reservations()
         if writer is not None:
             self._stage_write(task, writer, arrs, vals)
             return
