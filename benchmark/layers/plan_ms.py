@@ -1,8 +1,8 @@
 """Parse, plan and admission: timeline phases `queued` + `plan`
 (GET /v1/query/{id}/timeline), median per statement, in ms. The `plan`
-phase is read from the coordinator's plan spans; a statement that has
-none (the single-node path writes none as the tree stands) gives nothing
-to read."""
+phase is read from the coordinator's plan spans, which every route
+writes (split-streamed or whole on the coordinator's device); a
+statement that has none gives nothing to read."""
 
 import statistics
 

@@ -71,8 +71,10 @@ def main(argv=None) -> int:
             cell.dep.clear_spool()
             run = cell.window(seed, args.seconds)
             out = cell.report(run, setup_s)
-            correct = cell.check(run)
-            line = dict(seed=seed, correct=correct, **out)
+            checks = cell.check(run)
+            harness.print_checks(checks)
+            correct = harness.passed(checks)
+            line = dict(seed=seed, correct=correct, **out, checks=checks)
             if args.control:
                 line["control_mismatched_cells"] = control(cell, run)
                 bad += line["control_mismatched_cells"] == 0

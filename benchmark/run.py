@@ -192,15 +192,24 @@ class Cell:
 
         def loop(client_no: int) -> None:
             # whole rotations: a client starts another round of its
-            # templates while the clock has not passed `seconds`, and the
-            # round in flight is read to its end and counted. A window
-            # cut between a 3 s and a 7 s template would make the rate
-            # jump with whichever got one statement more
-            n = 0
-            while time.monotonic() - t_open < seconds:
+            # templates while the clock has not passed `seconds` and its
+            # stream has a round left, and the round in flight is read to
+            # its end and counted. A window cut between a 3 s and a 7 s
+            # template would make the rate jump with whichever got one
+            # statement more; a second pass over a spent TPC-H domain
+            # would re-send texts, and a re-sent text finds more than
+            # its programs cached
+            stream, n = streams[client_no], 0
+            while time.monotonic() - t_open < seconds and \
+                    stream.round_left():
                 for _ in self.templates:
-                    one(client_no, n, streams[client_no])
+                    one(client_no, n, stream)
                     n += 1
+            spent = stream.spent()
+            say(f"client {client_no}: window ended after {n} statements "
+                f"in {time.monotonic() - t_open:.1f}s, "
+                + ("by the clock" if spent is None else
+                   f"by {spent[0]}'s domain: all {spent[1]} sets drawn"))
 
         threads = [threading.Thread(target=loop, args=(c,),
                                     name=f"bench-client-{c}")
@@ -259,20 +268,20 @@ class Cell:
             f"{run['seed']}:check").sample(rest, min(CHECK_SAMPLE,
                                                      len(rest)))
 
-    def check(self, run: dict) -> bool:
-        """Every number compared, printed beside its limit (all 0)."""
+    def check(self, run: dict) -> dict:
+        """Every number compared, beside its limit (all 0), as
+        {name: [value, limit]}: see `passed` and `print_checks`."""
         done = run["statements"]
-        numbers = []
+        checks = {}
         off = [s for s in done if not on_device(
             s["info"], bool(self.config["deployment"]["workers"]))]
-        numbers.append(("off_device_statements", len(off)))
+        checks["off_device_statements"] = [len(off), 0]
         for s in off[:3]:
             say(f"check: {s['template']} {s['params']} not on the device "
                 f"path: {route_of(s['info'])}")
         texts = [s["sql"] for s in done + run["failed"]]
-        numbers.append(("repeated_statements",
-                        len(texts) - len(set(texts))))
-        numbers.append(("windows_without_a_completion", int(not done)))
+        checks["repeated_statements"] = [len(texts) - len(set(texts)), 0]
+        checks["windows_without_a_completion"] = [int(not done), 0]
         sample = self.sample(run)
         by_name = {t.NAME: t for t in self.templates}
         t0 = time.monotonic()
@@ -280,15 +289,15 @@ class Cell:
             t = by_name[s["template"]]
             want = t.reference(self.tables, s["params"])
             n, first = compare.mismatched_cells(s["rows"], want, t.COLUMNS)
-            numbers.append((f"mismatched_cells {t.NAME} "
-                            f"{json.dumps(s['params'])}", n))
+            # named by the statement: `statement <client>.<n>` above
+            # gives its parameters
+            checks[f"mismatched_cells.{t.NAME}.{s['client']}.{s['n']}"] = \
+                [n, 0]
             if first:
                 say(f"check: {t.NAME} {s['params']}: {first}")
         say(f"references: {len(sample)} statements in "
             f"{time.monotonic() - t0:.1f}s")
-        for name, value in numbers:
-            say(f"check {name}: {value} (limit 0)")
-        return all(v == 0 for _, v in numbers)
+        return checks
 
     def report(self, run: dict, setup_s: float) -> dict:
         done = run["statements"]
@@ -377,6 +386,18 @@ def on_device(info: dict, has_workers: bool) -> bool:
     return not info.get("distributed") and info.get("route") == "device"
 
 
+def passed(checks: dict) -> bool:
+    return all(value == limit for value, limit in checks.values())
+
+
+def print_checks(checks: dict) -> None:
+    """Each number compared beside its limit: a run's last lines on
+    standard error (the result line carries them too, under `checks`)."""
+    for name, (value, limit) in checks.items():
+        print(f"check {name}: {value} (limit {limit})", file=sys.stderr,
+              flush=True)
+
+
 def route_of(info: dict) -> str:
     return (f"distributed={info.get('distributed')} "
             f"route={info.get('route')!r} "
@@ -401,10 +422,12 @@ def main(argv=None) -> int:
             f"seed {args.seed}")
         run = cell.window(args.seed, args.seconds)
         out = cell.report(run, setup_s)
-        correct = cell.check(run)
+        checks = cell.check(run)
     finally:
         cell.close()
-    print(json.dumps(dict(correct=correct, **out)), flush=True)
+    print_checks(checks)
+    print(json.dumps(dict(correct=passed(checks), **out, checks=checks)),
+          flush=True)
     return 0
 
 

@@ -212,8 +212,9 @@ def test_rehearsal_reads_all_nine(traced_rehearsal):
     phases = sum(m[f"split_{p}_ms"]
                  for p in ("read", "put", "run", "fetch", "emit"))
     assert 0 < phases <= m["split_wall_ms"]
-    # new literals in every statement, the shapes warmed up
-    assert m["literal_keyed_compile_ms"] > 0
+    # new literals in every statement, the shapes warmed up: since PR 30
+    # neither compiles
+    assert m["literal_keyed_compile_ms"] == 0
     assert m["shape_keyed_compile_ms"] == 0
 
 

@@ -1,9 +1,13 @@
 """The parameter draw: domains per TPC-H 2.4, no repeats, set by the
 seed, never the warm-up's set."""
 
+import json
+import os
+
 import pytest
 
 import traffic
+from conftest import HERE
 
 MIX = {"loop": "closed", "clients": 1, "templates": ["q6", "q1"]}
 
@@ -91,13 +95,44 @@ def test_stream_is_set_by_the_seed_and_never_repeats():
     assert [s[0].NAME for s in third] == ["q6", "q1"] * 20
 
 
-def test_stream_fails_when_the_domain_runs_out():
+@pytest.mark.parametrize("clients,each", [(1, 60), (3, 20)])
+def test_stream_has_no_round_left_when_a_domain_is_spent(clients, each):
+    """q1's 60 sets (TPC-H 2.4.1.3, the validation set left out) end the
+    rounds of q6 + q1 though q6 has 79; three clients take 20 each."""
+    mix = dict(MIX, clients=clients)
+    for c in range(clients):
+        s = traffic.Stream(mix, 2147483659, "tpch.sf10", c)
+        rounds = 0
+        while s.round_left():
+            assert s.spent() is None
+            for _ in mix["templates"]:
+                next(s)
+            rounds += 1
+        assert rounds == each
+        assert s.spent() == ("q1", each)
+
+
+def test_next_past_the_domain_still_fails():
     s = traffic.Stream({"loop": "closed", "clients": 1,
                         "templates": ["q1"]}, 1, "tpch.tiny")
     for _ in range(60):
+        assert s.round_left()
         next(s)
+    assert not s.round_left()
     with pytest.raises(RuntimeError, match="domain exhausted"):
         next(s)
+
+
+@pytest.mark.parametrize("mix", ["scan", "join"])
+@pytest.mark.parametrize("seed", [7, 2147483659, 3000000019])
+def test_first_40_statements_are_the_accepted_benchmarks(mix, seed):
+    """Byte for byte the texts that traffic.py of the benchmark accepted
+    at PR 30 yields (tests/data/golden_statements.json, written from
+    that file): the window rule does not touch the order of draws."""
+    with open(os.path.join(HERE, "data", "golden_statements.json")) as f:
+        golden = json.load(f)[f"{mix}:{seed}"]
+    s = traffic.Stream(traffic.load_mix(mix), seed, "tpch.sf10")
+    assert [next(s)[2] for _ in range(40)] == golden
 
 
 def test_clients_take_disjoint_statements():

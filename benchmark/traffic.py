@@ -5,14 +5,18 @@
 and optionally "parameters": {"<template>": [<parameter set>, ...]}, the
 part of a template's domain the mix draws from before the rest, and
 "literal_keyed_sites": [<compile site>, ...], the sites that new
-literals are known to compile (run.py names any other that fires).
+literals are known to compile (none since PR 30; run.py names any site
+that fires in a window and is not listed).
 
 Each closed-loop client sends its next statement when the last one's
 answer has been read to the end. A client's stream is its templates in
 rotation; each statement takes the template's next parameter set from a
 seeded order of the template's whole TPC-H domain, so none is drawn
 twice in a run, and the validation set (the warm-up's) is never drawn.
-Clients share one order and take disjoint slices of it.
+Clients share one order and take disjoint slices of it. TPC-H's domains
+are what they are (q1: 60 sets, q18: 4): a window ends when the template
+with the fewest sets has none left for another round (`round_left`),
+never by a second pass over a domain.
 
 The order is a seeded shuffle, then spread: the next set is the one
 whose slot values have been used least so far (the warm-up's count),
@@ -73,9 +77,10 @@ def draws(template, seed: int, first=()):
 
 
 class Stream:
-    """One client's statements: (template, params, sql), endless until a
-    template's share of the domain runs out (an error: the window is
-    then longer than the domain allows)."""
+    """One client's statements: (template, params, sql), until a
+    template's share of the domain runs out. The caller asks
+    `round_left()` before each round; `next()` past the end is an
+    error."""
 
     def __init__(self, mix: dict, seed: int, schema: str, client: int = 0):
         self.schema = schema
@@ -91,6 +96,19 @@ class Stream:
 
     def __iter__(self):
         return self
+
+    def round_left(self) -> bool:
+        """Whether every template has a set left for this client: one
+        more statement of each, a round, can be drawn."""
+        return self.spent() is None
+
+    def spent(self):
+        """(template name, sets drawn) of the first template with none
+        left, or None while a round is left."""
+        for t, n, pool in zip(self.templates, self.taken, self.pools):
+            if n >= len(pool):
+                return t.NAME, n
+        return None
 
     def __next__(self):
         i = self.turn % len(self.templates)
