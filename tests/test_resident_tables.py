@@ -38,13 +38,13 @@ def bench_module(name):
     return importlib.import_module(name)
 
 
-def reference_tables(session, templates):
+def reference_tables(session, templates, schema="tiny"):
     """The connector's generated columns, as benchmark/deploy.py hands
     them to the references."""
     out = {}
     for t in templates:
         for table, names in t.TABLES.items():
-            data = session.catalog.get_table("tpch", "tiny", table)
+            data = session.catalog.get_table("tpch", schema, table)
             entry = out.setdefault(table, {"columns": {}, "dictionary": {}})
             for name in names:
                 i = data.schema.index_of(name)

@@ -147,6 +147,24 @@ def bucket_capacity(n: int) -> int:
     return 1 << k
 
 
+# a compaction never shrinks a batch by more than 2^13: see below
+COMPACT_FLOOR_SHIFT = 13
+
+
+def compaction_capacity(live: int, source_capacity: int) -> int:
+    """Capacity for the `live` rows compacted out of a batch of
+    `source_capacity`: their bucket, but never less than the bucket of
+    1/8192 of the source. Below that floor a smaller batch saves
+    nothing that shows beside the program that produced it (a 60M-row
+    probe), while every lattice point under it is another set of
+    programs to compile: TPC-H Q18's join keeps 4,662 of 60M rows with
+    its validation parameter and 483 to 826 with the four a run draws,
+    and with the floor all five land on 8,192. A source of up to 8M rows
+    has the lattice's own floor, 1,024."""
+    return max(bucket_capacity(live),
+               bucket_capacity(source_capacity >> COMPACT_FLOOR_SHIFT))
+
+
 def live_first_order(mask: jax.Array, new_capacity: int) -> jax.Array:
     """Row indices with `mask` rows first, each side in its original
     order — exactly `jnp.argsort(~mask, stable=True)[:new_capacity]`,

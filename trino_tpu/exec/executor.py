@@ -28,7 +28,7 @@ import numpy as np
 
 from .. import ir
 from ..batch import (Batch, Column, batch_from_numpy, batch_to_numpy,
-                     bucket_capacity)
+                     bucket_capacity, compaction_capacity)
 from ..catalog import Catalog
 from ..ops.aggregate import (AggSpec, direct_group_aggregate,
                              global_aggregate, sort_group_aggregate)
@@ -133,6 +133,12 @@ class Executor:
         # host-to-device bytes of this statement's scans (`scanPutBytes`
         # on the `execute` span)
         self.scan_put_bytes = 0
+        # operator spans (`aggregate`, `join`, `sort`) of a traced
+        # statement that runs whole on this executor: on only inside
+        # execute(), so a worker's split loop opens none; the stack
+        # holds (context manager, span) of those still open
+        self._operator_spans = False
+        self._open_operators: List[tuple] = []
         self._scalar_cache: Dict[object, object] = {}
         self.stats = ExecStats()
         self.profile = False           # EXPLAIN ANALYZE per-node timing
@@ -362,8 +368,10 @@ class Executor:
                 out = execute_chunked(self, root)
                 if out is not None:
                     return out
+            self._operator_spans = tracing.current().enabled
             return self.run(root.child)
         finally:
+            self._operator_spans = False
             self.save_decisions()
 
     # TRINO_TPU_TRACE_NODES=1 prints per-node dispatch timings to stderr
@@ -384,7 +392,7 @@ class Executor:
         from .memory import ExceededMemoryLimitError, MemoryKilledError, \
             batch_bytes
         try:
-            out = self._dispatch_timed(node)
+            out = self._dispatch_spanned(node)
             b = batch_bytes(out)
             self.pool.reserve(b)
         except MemoryKilledError:
@@ -429,6 +437,38 @@ class Executor:
         if out is None:
             raise
         return out
+
+    def operator_span(self, name: str, **attributes) -> None:
+        """Open the `name` span of the plan node being dispatched, once
+        its children have run: the span is the operator's own wall, not
+        its subtree's. `_dispatch_spanned` closes it behind the node's
+        fence, so under `enable_profiling` that wall holds the
+        operator's device time; with tracing alone it is the dispatch
+        and whatever the operator fetched. A no-op unless a traced
+        statement runs whole on this executor (`execute`)."""
+        if self._operator_spans:
+            cm = tracing.current().span(name, **{
+                k: v for k, v in attributes.items() if v is not None})
+            self._open_operators.append((cm, cm.__enter__()))
+
+    def stamp_operator(self, **attributes) -> None:
+        """Attributes for the innermost open operator span, if any."""
+        if self._open_operators:
+            self._open_operators[-1][1].attributes.update(attributes)
+
+    def _known_rows(self, node: L.PlanNode) -> Optional[int]:
+        """Live rows `node` gave, where the profiled dispatch counted
+        them (`node_stats`); a span never pays a device sync for one."""
+        stat = self.node_stats.get(id(node)) if self.profile else None
+        return stat[1] if stat else None
+
+    def _dispatch_spanned(self, node: L.PlanNode) -> Batch:
+        depth = len(self._open_operators)
+        try:
+            return self._dispatch_timed(node)
+        finally:
+            while len(self._open_operators) > depth:
+                self._open_operators.pop()[0].__exit__(None, None, None)
 
     def _dispatch_timed(self, node: L.PlanNode) -> Batch:
         if self.TRACE:
@@ -704,9 +744,12 @@ class Executor:
             keys = tuple((k.index, k.ascending, k.nulls_first)
                          for k in node.keys)
             child = self.run(node.child)
+            self.operator_span("sort", capacity=child.capacity,
+                               rows=self._known_rows(node.child),
+                               limit=node.limit)
             # at scale, pack ORDER BY keys into one int64 so the sort
             # stays 2-operand (see SORT_SMALL_ROWS)
-            if keys and child.capacity > SORT_SMALL_ROWS:
+            if keys and child.capacity > SORT_GENERAL_ROWS:
                 from ..ops.sort import sort_batch_packed, sort_pack_plan
                 plan = sort_pack_plan(
                     child, keys,
@@ -1045,6 +1088,8 @@ class Executor:
             a.distinct)
             for a in node.aggs)
         child = self.run(node.child)
+        self.operator_span("aggregate", inputCapacity=child.capacity,
+                           inputRows=self._known_rows(node.child))
         return self.aggregate_batch(node, child, aggs)
 
     def gather_mode(self) -> str:
@@ -1058,6 +1103,7 @@ class Executor:
         per-query EXPLAIN/operator_stats surface plus the
         {agg,join}_strategy_decisions counter families."""
         self.strategy_decisions[op] = strategy
+        self.stamp_operator(strategy=strategy)
         from ..metrics import (AGG_STRATEGY_DECISIONS,
                                JOIN_STRATEGY_DECISIONS)
         if kind == "agg":
@@ -1114,34 +1160,19 @@ class Executor:
             return direct_group_aggregate(child, node.group_keys,
                                           node.key_domains, aggs)
         capacity = min(node.out_capacity, child.capacity)   # groups <= rows
-        # planner NDV products overestimate real group counts by orders
-        # of magnitude on join outputs, and the sorted kernel's key
-        # readback gathers scale with OUT capacity — so once a run has
-        # measured the true group count, later runs size the output
-        # tightly from the decision cache (one recompile, then every
-        # re-execution gathers at the real G instead of the estimate)
-        if self.decisions_cacheable(node):
-            skey = self.memo_structure_key(node)
-            if skey is not None and not self._decision_loaded:
-                self._load_decisions()
-            known = self._decision_cache.get(
-                ("aggfinal", skey, self._decision_salt())) \
-                if skey is not None else None
-            if known is not None:
-                capacity = max(1024, bucket_capacity(known[0]))
         # big inputs: pack all keys into one int64 so the sort has 2
         # operands — the general kernel's 2-per-key operand count makes
         # XLA TPU compiles explode at scale (see SORT_COMPILE_BUDGET)
         pack = None
         # pack when rows are big: the general kernel sorts ~2 operands
         # per key and XLA TPU sort compiles explode in operand count
-        # past SORT_SMALL_ROWS (q10's 7-key GROUP BY was a >900s compile
-        # at 131k rows). Below it even 16 operands compile in a second
-        # or two, and the general kernel's statics do not depend on the
-        # data — a packed layout's key bits do, so packing small
-        # per-split batches compiled one program per split (q18)
+        # with the rows (q10's 7-key GROUP BY was a >900s compile at
+        # 131k rows). Up to SORT_GENERAL_ROWS they finish, and the
+        # general kernel's statics do not depend on the data — a packed
+        # layout's key bits do, so packing small per-split batches
+        # compiled one program per split (q18)
         if not any(a.distinct for a in aggs) and node.group_keys and \
-                child.capacity > SORT_SMALL_ROWS:
+                child.capacity > SORT_GENERAL_ROWS:
             from ..ops.aggregate import (key_pack_plan_words,
                                          packed_sort_group_aggregate)
             live = []
@@ -1152,6 +1183,8 @@ class Executor:
                 live.append(int(vals[0]))
                 return vals[1:]
             pack = key_pack_plan_words(child, node.group_keys, fetch=fetch)
+            if live:
+                self.stamp_operator(inputRows=live[0])
             if live and live[0] <= SORT_SMALL_ROWS:
                 # a mostly dead batch (a selective join's split): the
                 # few live rows move to a small batch and take the
@@ -1161,6 +1194,7 @@ class Executor:
                 pack = None
         self._note_strategy("AggregateNode", "sort", "agg")
         gm = self.gather_mode()
+        retries = self.stats.agg_capacity_retries
         while True:
             if pack is not None:
                 kmins, bits, splits = pack
@@ -1176,12 +1210,9 @@ class Executor:
                 break
             capacity *= 4
             self.stats.agg_capacity_retries += 1
-        if self.decisions_cacheable(node):
-            skey = self.memo_structure_key(node)
-            if skey is not None:
-                self._decision_cache[
-                    ("aggfinal", skey, self._decision_salt())] = (n_groups,)
-                self._decision_dirty = True
+        self.stamp_operator(
+            groups=n_groups, capacity=capacity,
+            capacityRetries=self.stats.agg_capacity_retries - retries)
         if n_groups == 0 and not node.group_keys:
             # zero-key sort aggregation (global DISTINCT) over an empty
             # input: SQL still requires one output row (0 counts / NULL
@@ -1245,7 +1276,10 @@ class Executor:
         NULL when the subquery produced one (x IN S is NULL for unmatched
         x when S contains NULL)."""
         if ref not in self._scalar_cache:
-            batch = self.run(ref.plan)
+            # the members come to the host: the live ones, not the
+            # subquery's whole capacity (Q18's HAVING keeps hundreds of
+            # 16.7M group slots)
+            batch = self.maybe_compact(self.run(ref.plan), node=ref.plan)
             arrays, valids = batch_to_numpy(batch)
             vals, has_null = [], False
             arg_t = ref.arg.dtype
@@ -1334,7 +1368,7 @@ class Executor:
                 return batch          # the chunked loop stays sync-free
             live = self.fetch_ints(node, "complive",
                                    jnp.sum(batch.live))[0]
-        new_cap = bucket_capacity(live)
+        new_cap = compaction_capacity(live, batch.capacity)
         if new_cap * self.COMPACT_SHRINK <= batch.capacity:
             self.stats.dynamic_filter_compactions += 1
             return compact_batch(batch, new_cap)
@@ -1353,6 +1387,12 @@ class Executor:
                 if out is not None:
                     return out
         build = self.run(node.right)
+        self.operator_span("join", kind=node.kind,
+                           probeCapacity=probe.capacity,
+                           probeRows=self._known_rows(node.left),
+                           buildCapacity=build.capacity,
+                           buildRows=self._known_rows(node.right),
+                           domain=node.build_key_domain)
         # >2-column keys (or values past 2^31) overflow the kernels'
         # fixed 32-bit-per-column packing: range-compress both sides'
         # keys into ONE appended int64 column (shared min/max so equality
@@ -1541,7 +1581,7 @@ class Executor:
                     if dup != 0:
                         return None
                     self._note_strategy("JoinNode", "dense-lut", "join")
-                    new_cap = bucket_capacity(live)
+                    new_cap = compaction_capacity(live, probe.capacity)
                     if new_cap * self.COMPACT_SHRINK <= probe.capacity:
                         self.stats.dynamic_filter_compactions += 1
                         return dense_join_compacted(
@@ -1647,7 +1687,7 @@ class Executor:
             # row-count round trip)
             live = self.fetch_ints(node, "dflive",
                                    jnp.sum(probe.live))[0]
-            new_cap = bucket_capacity(live)
+            new_cap = compaction_capacity(live, probe.capacity)
             if new_cap * 4 <= probe.capacity:
                 self.stats.dynamic_filter_compactions += 1
                 probe = compact_batch(probe, new_cap)
@@ -1755,7 +1795,7 @@ class Executor:
         if batch.columns and batch.capacity >= probe_floor:
             live = self.fetch_ints(root, "resultlive",
                                    jnp.sum(batch.live))[0]
-            new_cap = bucket_capacity(live)
+            new_cap = compaction_capacity(live, batch.capacity)
             if new_cap * 2 <= batch.capacity:
                 batch = compact_batch(batch, new_cap)
         arrays, valids = batch_to_numpy(batch)
@@ -1855,6 +1895,15 @@ MAX_SORT_OPERANDS = 12
 # 534 s at 1M. A (packed key, index) sort costs 16-48 s from 25,600 rows
 # up and is nearly flat in rows after that.
 SORT_SMALL_ROWS = 1 << 11
+# input capacity up to which an aggregate and an ORDER BY take the
+# general kernels all the same: their statics are the shapes alone,
+# where a packed kernel's key bits are read from the data, so two
+# statements of one template that keep other rows can need two programs
+# (ROADMAP S11). It is the capacity a compaction out of a 60M-row batch
+# lands on at the least (`batch.compaction_capacity`), so a selective
+# join's few thousand rows meet one aggregate and one sort whatever the
+# statement's literals kept. The price is the compile, once a shape.
+SORT_GENERAL_ROWS = 1 << 13
 
 
 def compact_batch(batch: Batch, new_capacity: int) -> Batch:

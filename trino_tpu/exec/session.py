@@ -46,6 +46,12 @@ def _bool(v):
     return str(v).lower() in ("true", "1")
 
 
+def _spilled_operators(stats) -> int:
+    """Operators that went through the host-spill tier so far."""
+    return stats.spilled_joins + stats.spilled_aggregations + \
+        stats.spilled_sorts
+
+
 def _default_query_max_memory_mb() -> int:
     """TRINO_TPU_QUERY_MAX_MEMORY (bytes, B/kB/MB/GB suffixes) overrides
     the 64 GiB per-query default for every session in the process."""
@@ -274,7 +280,10 @@ class Session:
         parse/plan and land here directly."""
         self._apply_executor_properties(t0)
         with self.tracer.span("execute") as sp:
-            slots0 = self.executor.stats.literal_slots
+            stats = self.executor.stats
+            slots0 = stats.literal_slots
+            retries0 = stats.agg_capacity_retries
+            spilled0 = _spilled_operators(stats)
             batch = self.executor.execute(root)
             names, arrays, valids = self.executor.result_to_host(root,
                                                                  batch)
@@ -284,8 +293,11 @@ class Session:
                     residentBytes=resident.total_bytes(),
                     residentEntries=len(resident),
                     scanPutBytes=self.executor.scan_put_bytes,
-                    literalSlots=self.executor.stats.literal_slots
-                    - slots0)
+                    literalSlots=stats.literal_slots - slots0,
+                    aggCapacityRetries=stats.agg_capacity_retries
+                    - retries0,
+                    spilledOperators=_spilled_operators(stats)
+                    - spilled0)
                 if self.executor.profile:
                     ns = [v for v in self.executor.node_stats.values()
                           if len(v) >= 5]

@@ -162,6 +162,11 @@ class ScalarSubqueryRef(Expr):
     def __eq__(self, other):
         return self is other
 
+    def __repr__(self):
+        # never the plan's own repr: its scans carry their tables'
+        # schemas, dictionaries and all (EXPLAIN prints the sub-plan)
+        return f"ScalarSubqueryRef(plan=<{type(self.plan).__name__}>)"
+
 
 @dataclass(frozen=True)
 class DerivedDict(Expr):
@@ -202,6 +207,11 @@ class InSubqueryRef(Expr):
 
     def __eq__(self, other):
         return self is other
+
+    def __repr__(self):
+        # as ScalarSubqueryRef's: the sub-plan is EXPLAIN's to print
+        return (f"InSubqueryRef(arg={self.arg!r}, "
+                f"plan=<{type(self.plan).__name__}>)")
 
 
 @dataclass(frozen=True)

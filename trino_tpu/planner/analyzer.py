@@ -20,6 +20,7 @@ from ..batch import Field
 from ..types import (BIGINT, BOOLEAN, DATE, DOUBLE, VARCHAR, DataType,
                      TypeKind, common_super_type, decimal)
 from ..sql import ast_nodes as A
+from .optimizer import prune_plan
 
 EPOCH = datetime.date(1970, 1, 1)
 
@@ -351,7 +352,10 @@ class ExpressionLowerer:
             if len(sub.scope.columns) != 1:
                 raise AnalysisError("IN subquery must return one column")
             arg_field = self.planner.field_for(arg, self.scope)
-            ref = ir.InSubqueryRef(arg, sub.node, arg_field,
+            # pruned here: the statement's own prune_plan never sees a
+            # plan held inside an expression, and an unpruned scan puts
+            # every column of its table on the device
+            ref = ir.InSubqueryRef(arg, prune_plan(sub.node), arg_field,
                                    sub.scope.columns[0].field)
             return ir.Not(ref) if node.negated else ref
 
@@ -362,7 +366,8 @@ class ExpressionLowerer:
             sub = self.planner.plan_query(node.query)   # raises if correlated
             if len(sub.scope.columns) != 1:
                 raise AnalysisError("scalar subquery must return one column")
-            return ir.ScalarSubqueryRef(sub.node, sub.scope.columns[0].dtype)
+            return ir.ScalarSubqueryRef(prune_plan(sub.node),
+                                        sub.scope.columns[0].dtype)
 
         raise AnalysisError(f"unsupported expression {type(node).__name__}")
 

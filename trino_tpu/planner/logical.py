@@ -285,6 +285,21 @@ def replace_nodes(root: PlanNode, mapping) -> PlanNode:
     return _dc.replace(root, **changes) if changes else root
 
 
+def subquery_plans(node: PlanNode) -> list:
+    """Plans of the uncorrelated subqueries `node`'s own expressions
+    hold (`ir.InSubqueryRef`, `ir.ScalarSubqueryRef`), in order."""
+    from .. import ir
+    exprs = []
+    for name in ("predicate", "residual"):
+        e = getattr(node, name, None)
+        if isinstance(e, ir.Expr):
+            exprs.append(e)
+    exprs.extend(e for e in getattr(node, "exprs", ())
+                 if isinstance(e, ir.Expr))
+    return [r.plan for e in exprs for r in ir.walk(e)
+            if isinstance(r, (ir.InSubqueryRef, ir.ScalarSubqueryRef))]
+
+
 def explain_text(node: PlanNode, indent: int = 0, annotate=None) -> str:
     """EXPLAIN rendering (textual plan like Trino's PlanPrinter).
     `annotate(node) -> str` appends per-node runtime stats
@@ -340,5 +355,8 @@ def explain_text(node: PlanNode, indent: int = 0, annotate=None) -> str:
         extra = annotate(node)
         if extra:
             line = f"{line}   {extra}"
-    return "\n".join([line] + [explain_text(c, indent + 1, annotate)
-                               for c in children(node)])
+    subplans = [f"{pad}  Subquery\n" + explain_text(p, indent + 2, annotate)
+                for p in subquery_plans(node)]
+    return "\n".join([line] + subplans +
+                     [explain_text(c, indent + 1, annotate)
+                      for c in children(node)])
