@@ -114,17 +114,38 @@ def test_sort_aggregate_edge_keys_match_oracle(agg_env, name):
     assert ran(session, "AggregateNode") == "sort"
 
 
-def test_more_groups_than_capacity_retries_and_matches_oracle():
+@pytest.mark.parametrize("form,rows,scale", [
+    ("permutation", 70_000, 1 << 40), ("carried", 300_000, 1)])
+def test_more_groups_than_capacity_retries_and_matches_oracle(
+        form, rows, scale, monkeypatch):
     """An expression key has no NDV statistics, so the plan sizes the
     output at the default; 70,000 groups overflow it and the executor
-    grows the capacity and sorts again."""
+    grows the capacity and sorts again. On the permutation form of the
+    packed sort aggregate (a sum argument too wide to share the keys'
+    sort word), and on the value-carrying form where the default is far
+    enough under the input's capacity that the groups are read back
+    dense; in place (70,000 rows of narrow values) nothing can overflow
+    and nothing retries."""
+    import trino_tpu.ops.aggregate as aggregate
+    # the default capacity (65,536) is dense for 300,000 rows at 4,
+    # which the chip's timings would only ask for past 8 million
+    monkeypatch.setattr(aggregate, "IN_PLACE_FACTOR", 4)
     n = 70_000
     rng = np.random.default_rng(3)
     session, oracle = session_over([table("wide", {
-        "k": rng.permutation(n) * 7919, "v": rng.integers(0, 9, n)})])
+        "k": rng.permutation(rows) % n * 7919,
+        "v": rng.integers(0, 9, rows) * scale})])
     check(session, oracle,
           "SELECT k + 1, sum(v), count(*) FROM wide GROUP BY k + 1")
     assert session.executor.stats.agg_capacity_retries > 0
+    if form == "carried":
+        # the second capacity is within IN_PLACE_FACTOR of the input's
+        assert session.executor.stats.agg_capacity_retries == 1
+        session, oracle = session_over([table("wide", {
+            "k": rng.permutation(n) * 7919, "v": rng.integers(0, 9, n)})])
+        check(session, oracle,
+              "SELECT k + 1, sum(v), count(*) FROM wide GROUP BY k + 1")
+        assert session.executor.stats.agg_capacity_retries == 0
 
 
 @pytest.fixture(scope="module")

@@ -145,6 +145,52 @@ def test_q1_stage_compiles_at_60m_rows(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# the packed sort aggregate with Q18's statics (one 28-bit key, a decimal
+# sum's two limbs): what the chip's compiler makes of its three forms. At
+# 16,384 rows each compiles in 4 to 6 s here; the same program took 141 s
+# at 262,144 rows and 113 s at 60M, which no test should pay
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("form", ["in-place", "dense", "permutation"])
+def test_packed_sort_aggregate_gathers_by_form(one_chip, form):
+    import re
+
+    from trino_tpu.batch import Batch, Column
+    from trino_tpu.ops.aggregate import (AggSpec,
+                                         packed_sort_group_aggregate)
+    n, capacity = 16_384, 2_048
+
+    def shape(dtype, length=n):
+        return jax.ShapeDtypeStruct((length,), dtype, sharding=one_chip)
+    batch = Batch(tuple(Column(shape(jnp.int64), shape(jnp.bool_))
+                        for _ in range(3)), shape(jnp.bool_))
+    aggs = (AggSpec("sum", 1), AggSpec("sum", 2))
+    carried = form != "permutation"
+
+    def program(batch, kmins, vmins):
+        return packed_sort_group_aggregate(
+            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),), "off",
+            vmins if carried else None, (2, 16) if carried else None,
+            form == "in-place")
+    text = jax.jit(program).lower(
+        batch, shape(jnp.int64, 1), shape(jnp.int64, 2)).compile().as_text()
+    lengths = [int(m) for m in re.findall(
+        r"= \w+\[(\d+)\]\S* gather\(", text)]
+    scatters = len(re.findall(r" scatter\(", text))
+    if form == "in-place":
+        assert (lengths, scatters) == ([], 0)
+    elif form == "dense":
+        # the groups' read-back, a plane each, and nothing at the input's
+        assert lengths and set(lengths) == {capacity} and scatters == 0
+    else:
+        # what the value-carrying form is rid of: planes fetched through
+        # the sort's permutation at the input's length, then at the
+        # group capacity, and the segment-start scatter
+        assert lengths.count(n) >= 6 and lengths.count(capacity) >= 13
+        assert scatters == 1
+
+
+# ---------------------------------------------------------------------------
 # filter_project with its literals as operands: q6's filter at a 250,000-row
 # split's capacity, and a decimal comparison whose LITERAL has the larger
 # scale (the traced scalar is the side _decimal_compare floor-divides)

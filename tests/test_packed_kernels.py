@@ -331,3 +331,194 @@ def test_key_span_measures_combined_packed_key():
                            np.array([1, 2, 1000], dtype=np.int64)],
                           valids=[None, np.array([True, True, False])])
     assert int(_key_span(b2, (0, 1))) == 2
+
+
+# ---- the value-carrying form of the packed sort aggregate -----------------
+# (key bits + argument bits fit one sort word: the aggregates' inputs ride
+# the sort, nothing is gathered through a permutation)
+
+CARRIED_ROWS = 12_000         # over SORT_GENERAL_ROWS, where it engages
+ALL_FUNCS = (AggSpec("sum", 1), AggSpec("count", 1), AggSpec("min", 1),
+             AggSpec("max", 1), AggSpec("count_star", None))
+
+
+def numpy_group_by(arrays, valids, live, keys, aggs):
+    """{key tuple (None = NULL): state tuple (None = NULL)}, in Python
+    integers: the plain answer."""
+    groups = {}
+    for i in np.nonzero(live)[0]:
+        k = tuple(int(arrays[j][i]) if valids[j][i] else None for j in keys)
+        groups.setdefault(k, []).append(i)
+    out = {}
+    for k, rows in groups.items():
+        state = []
+        for spec in aggs:
+            if spec.func == "count_star":
+                state.append(len(rows))
+                continue
+            vals = [int(arrays[spec.arg_index][i]) for i in rows
+                    if valids[spec.arg_index][i]]
+            if spec.func == "count":
+                state.append(len(vals))
+            elif not vals:
+                state.append(None)
+            else:
+                state.append({"sum": sum, "min": min,
+                              "max": max}[spec.func](vals))
+        out[k] = tuple(state)
+    return out
+
+
+def carried_case(name):
+    """(arrays, valids, live, key columns, aggs, carried?) of one case."""
+    rng = np.random.default_rng(len(name))
+    n = CARRIED_ROWS
+    k = rng.integers(100, 400, n)
+    v = rng.integers(0, 5000, n)
+    w = rng.integers(0, 90, n)
+    ok = [np.ones(n, bool) for _ in range(3)]
+    live = np.ones(n, bool)
+    keys, aggs, carried = (0,), ALL_FUNCS, True
+    if name == "null_keys":
+        ok[0] = rng.random(n) > 0.2
+    elif name == "null_arguments":
+        # keys 100-119 see no valid argument: sum NULL, count 0
+        ok[1] = (rng.random(n) > 0.3) & (k >= 120)
+    elif name == "dead_rows_interleaved":
+        live = rng.random(n) > 0.4
+        ok[0] = rng.random(n) > 0.1
+        ok[1] = rng.random(n) > 0.1
+    elif name == "all_dead":
+        live = np.zeros(n, bool)
+    elif name == "negative_values":
+        k, v = k - 1000, v - 4000
+    elif name == "two_aggregates_two_columns":
+        ok[1], ok[2] = rng.random(n) > 0.2, rng.random(n) > 0.2
+        aggs = (AggSpec("sum", 1), AggSpec("max", 2), AggSpec("count", 2),
+                AggSpec("min", 1), AggSpec("sum", 2))
+    elif name == "two_keys_count_star_alone":
+        keys, aggs = (0, 2), (AggSpec("count_star", None),)
+        ok[2] = rng.random(n) > 0.2
+    elif name in ("bits_63_taken", "bits_64_falls_back"):
+        # 16 bits of key (rounded from 14 measured; 15 where the room is
+        # short) under 47 or 48 of argument: the word has 63
+        k = rng.integers(0, 10_000, n)
+        top = (1 << 46) if name == "bits_63_taken" else (1 << 47)
+        v = rng.integers(0, 300, n) * (top // 300)
+        v[:2] = 0, top
+        ok[1] = rng.random(n) > 0.1
+        aggs = (AggSpec("sum", 1), AggSpec("count", 1), AggSpec("max", 1))
+        carried = name == "bits_63_taken"
+    else:
+        assert name == "plain"
+    return [k, v, w], ok, live, keys, aggs, carried
+
+
+@pytest.mark.parametrize("form", ["in-place", "dense"])
+@pytest.mark.parametrize("name", [
+    "plain", "null_keys", "null_arguments", "dead_rows_interleaved",
+    "all_dead", "negative_values", "two_aggregates_two_columns",
+    "two_keys_count_star_alone", "bits_63_taken", "bits_64_falls_back"])
+def test_carried_aggregate_matches_numpy_and_the_general_kernel(name, form):
+    from trino_tpu.ops.aggregate import key_pack_plan_words
+    arrays, valids, live, keys, aggs, carried = carried_case(name)
+    b = batch_from_numpy(arrays, valids=valids)
+    live_d = np.zeros(b.capacity, bool)
+    live_d[:len(live)] = live
+    b = Batch(b.columns, jnp.asarray(live_d))
+    kmins, bits, splits, values = key_pack_plan_words(b, keys, aggs=aggs)
+    assert (values is not None) == carried
+    if name == "bits_63_taken":
+        assert sum(bits) + sum(values[1]) == 63
+    vmins, value_bits = values or (None, None)
+    # 1,024 is over every case's group count but those of the two-key
+    # case and of bits_*'s 10,000 keys, which overflow the dense form
+    in_place = carried and form == "in-place"
+    capacity = b.capacity if form == "in-place" else 1024
+    got = packed_sort_group_aggregate(
+        b, jnp.asarray(kmins), keys, bits, aggs, capacity, splits, "off",
+        None if vmins is None else jnp.asarray(vmins), value_bits, in_place)
+    assert got.capacity == (b.capacity if in_place else capacity)
+    permuted = packed_sort_group_aggregate(
+        b, jnp.asarray(kmins), keys, bits, aggs, capacity, splits)
+    n_keys = len(keys)
+    answer = {r[:n_keys]: r[n_keys:] for r in rows_of(got)}
+    assert len(answer) == int(np.asarray(got.live).sum())
+    want = numpy_group_by(arrays, valids, live, keys, aggs)
+    if len(want) <= capacity:
+        assert answer == want
+        # the same rows in the same (key) order as the permutation form
+        assert rows_of(got) == rows_of(permuted)
+        general = sort_group_aggregate(b, keys, aggs, capacity)
+        assert sorted(rows_of(general), key=repr) == \
+            sorted(rows_of(got), key=repr)
+    else:
+        # groups past the capacity are dropped and the live count says
+        # so (the executor retries); what the value-carrying form keeps
+        # is right (the permutation form's last kept group is not)
+        assert len(answer) == capacity == \
+            int(np.asarray(permuted.live).sum())
+        assert not carried or all(want[key] == state
+                                  for key, state in answer.items())
+    if name == "null_arguments":
+        assert answer[(100,)][:2] == (None, 0)
+    # dtypes are the permutation form's, plane by plane
+    for c, p in zip(got.columns, permuted.columns):
+        assert (c.data.dtype, c.valid.dtype) == (p.data.dtype, p.valid.dtype)
+
+
+def jaxpr_equations(jaxpr):
+    """Every equation of `jaxpr`, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from jaxpr_equations(inner)
+
+
+def addressed_reads(form, capacity):
+    """[(primitive, number of indices)] of the gather and scatter
+    equations the packed sort aggregate traces to, Q18's statics."""
+    import jax
+    n = 16_384
+    b = batch_from_numpy([np.arange(n) // 4, np.arange(n) % 50 * 100])
+    aggs = (AggSpec("sum", 1),)
+    carried = (None, None) if form == "permutation" else \
+        (jnp.zeros(1, jnp.int64), (16,))
+    jaxpr = jax.make_jaxpr(
+        lambda batch, kmins, vmins: packed_sort_group_aggregate(
+            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),), "off",
+            vmins, carried[1], form == "in-place"))(
+        b, jnp.zeros(1, jnp.int64), carried[0])
+    out = []
+    for eqn in jaxpr_equations(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            out.append((name, int(np.prod(eqn.invars[1].aval.shape[:-1]))))
+    return out
+
+
+def test_carried_aggregate_addresses_nothing_through_a_permutation():
+    """The property the value-carrying form exists for, read off the
+    traced program: in place it has no gather and no scatter at all;
+    dense it gathers at the group capacity only. The same walk over the
+    permutation form finds its gathers at the input's length and its
+    scatter, so it looks in the right place."""
+    n, groups = 16_384, 2_048
+    assert addressed_reads("in-place", groups) == []
+    dense = addressed_reads("dense", groups)
+    assert dense and all(kind == "gather" and indices == groups
+                         for kind, indices in dense)
+    permuted = addressed_reads("permutation", groups)
+    by_length = {}
+    for kind, indices in permuted:
+        by_length.setdefault((kind.split("-")[0], indices), []).append(kind)
+    # live[perm], w[perm], data[perm], valid[perm]: four arrays at the
+    # input's length (six 32-bit planes on the chip); the start_lut
+    # scatter; eight and more arrays at the group capacity
+    assert len(by_length[("gather", n)]) == 4
+    assert len(by_length[("scatter", n)]) == 1
+    assert len(by_length[("gather", groups)]) >= 8

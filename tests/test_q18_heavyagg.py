@@ -264,3 +264,64 @@ def test_explain_prints_the_in_subquery_as_a_sub_plan():
     scans = [ln for ln in lines if "TableScan[tpch.tiny.lineitem]" in ln]
     assert len(scans) == 2 and all(
         "-> [l_orderkey, l_quantity]" in ln for ln in scans)
+
+
+# a Q18-shaped statement (GROUP BY the fact table's key, HAVING on a
+# decimal sum, as an IN subquery) and a q3-shaped one (three keys, a sum
+# of a product) over all of lineitem: 60,104 rows take the packed sort
+# aggregate; the first one's keys and argument share one sort word, the
+# second one's 36 + 32 bits do not
+CARRIED_SQL = (
+    "SELECT o_orderkey, o_totalprice FROM {s}orders WHERE o_orderkey IN ("
+    "SELECT l_orderkey FROM {s}lineitem GROUP BY l_orderkey "
+    "HAVING sum(l_quantity) > 240) ORDER BY o_totalprice DESC, o_orderkey")
+PERMUTED_SQL = (
+    "SELECT l_orderkey, l_partkey, l_suppkey, "
+    "sum(l_extendedprice * (1 - l_discount)) AS revenue FROM {s}lineitem "
+    "GROUP BY l_orderkey, l_partkey, l_suppkey "
+    "ORDER BY revenue DESC, l_orderkey, l_partkey, l_suppkey LIMIT 10")
+
+
+@pytest.mark.parametrize("sql,carried", [(CARRIED_SQL, True),
+                                         (PERMUTED_SQL, False)],
+                         ids=["q18_shaped", "q3_shaped"])
+def test_the_aggregate_span_says_what_rode_the_sort(single, sql, carried):
+    from oracle import assert_rows_match, load_oracle, oracle_query
+    conn = single.session.catalog.connector("tpch")
+    oracle = load_oracle([conn.get_table("tiny", t)
+                          for t in ("orders", "lineitem")])
+    single.client.execute("SET SESSION enable_tracing = true")
+    try:
+        rows, info, spans = single.run(sql.format(s="tpch.tiny."))
+    finally:
+        single.client.execute("SET SESSION enable_tracing = false")
+    assert info["route"] == "device" and not info.get("distributed")
+    want = oracle_query(oracle, sql.format(s=""))
+    assert len(want) > 5
+    assert_rows_match(rows, want, rel_tol=1e-12, abs_tol=0.005,
+                      ordered=True)
+    aggregate = min((sp for sp in spans if sp["name"] == "aggregate"),
+                    key=lambda sp: sp["startTimeUnixNano"])["attributes"]
+    assert aggregate["strategy"] == "sort"
+    assert aggregate["inputCapacity"] > 8192
+    if carried:
+        # under l_orderkey's 16 bits the two limbs of the decimal sum:
+        # 2 bits for the high one (NULL and 0), 16 (13 measured) for
+        # the low one
+        assert aggregate["valueBits"] == 18
+        assert aggregate["outputForm"] == "in-place"
+        assert aggregate["capacity"] == aggregate["inputCapacity"]
+        assert aggregate["groups"] == 15_000
+    else:
+        assert aggregate["valueBits"] == 0
+        assert aggregate["outputForm"] == "dense"
+    assert aggregate["capacityRetries"] == 0
+
+
+def test_operations_guide_lists_the_aggregate_span_attributes():
+    import os
+    path = os.path.join(os.path.dirname(__file__), "..", "docs",
+                        "operations.md")
+    with open(path) as f:
+        row = next(ln for ln in f if ln.startswith("| `aggregate`, `join`"))
+    assert "`valueBits`" in row and "`outputForm`" in row

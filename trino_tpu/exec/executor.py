@@ -640,8 +640,10 @@ class Executor:
                 hit = self._decision_cache.get(key)
                 if hit is not None:
                     return hit
-        out = tuple(int(v) for v in np.asarray(jnp.stack(
-            [jnp.asarray(v).astype(jnp.int64) for v in vals])))
+        # one device vector (a plan's measurements) comes as it is
+        flat = vals[0] if len(vals) == 1 and jnp.ndim(vals[0]) == 1 else \
+            jnp.stack([jnp.asarray(v).astype(jnp.int64) for v in vals])
+        out = tuple(int(v) for v in np.asarray(flat))
         if key is not None:
             if len(self._decision_cache) >= 4096:
                 self._decision_cache.clear()
@@ -753,7 +755,7 @@ class Executor:
                 from ..ops.sort import sort_batch_packed, sort_pack_plan
                 plan = sort_pack_plan(
                     child, keys,
-                    fetch=lambda *v: self.fetch_ints(node, "sortpack", *v))
+                    fetch=lambda v: self.fetch_ints(node, "sortpackv", v))
                 if plan is not None:
                     kmins, bits, splits = plan
                     return sort_batch_packed(child, jnp.asarray(kmins),
@@ -1173,16 +1175,17 @@ class Executor:
         # compiled one program per split (q18)
         if not any(a.distinct for a in aggs) and node.group_keys and \
                 child.capacity > SORT_GENERAL_ROWS:
-            from ..ops.aggregate import (key_pack_plan_words,
+            from ..ops.aggregate import (in_place_output,
+                                         key_pack_plan_words,
                                          packed_sort_group_aggregate)
             live = []
 
-            def fetch(*stats):      # the live count rides the same fetch
-                vals = self.fetch_ints(node, "aggpack",
-                                       jnp.sum(child.live), *stats)
-                live.append(int(vals[0]))
-                return vals[1:]
-            pack = key_pack_plan_words(child, node.group_keys, fetch=fetch)
+            def fetch(stats):       # the live count leads the vector
+                vals = self.fetch_ints(node, "aggpackv", stats)
+                live.append(vals[0])
+                return vals
+            pack = key_pack_plan_words(child, node.group_keys, fetch=fetch,
+                                       aggs=aggs)
             if live:
                 self.stamp_operator(inputRows=live[0])
             if live and live[0] <= SORT_SMALL_ROWS:
@@ -1195,24 +1198,36 @@ class Executor:
         self._note_strategy("AggregateNode", "sort", "agg")
         gm = self.gather_mode()
         retries = self.stats.agg_capacity_retries
+        # where the plan found room for the aggregates' arguments in the
+        # keys' sort word, the kernel carries them through its sort
+        carried = pack[3] if pack is not None else None
+        vmins, value_bits = (jnp.asarray(carried[0]), carried[1]) \
+            if carried else (None, None)
         while True:
             if pack is not None:
-                kmins, bits, splits = pack
+                kmins, bits, splits = pack[:3]
                 out = packed_sort_group_aggregate(
                     child, jnp.asarray(kmins), node.group_keys, bits,
-                    aggs, capacity, splits, gm)
+                    aggs, capacity, splits, gm, vmins, value_bits,
+                    carried is not None and
+                    in_place_output(capacity, child.capacity))
             else:
                 out = sort_group_aggregate(child, node.group_keys, aggs,
                                            capacity, gm)
             n_groups = self.fetch_ints(node, f"agggroups{capacity}",
                                        jnp.sum(out.live))[0]
-            if n_groups < capacity or capacity >= child.capacity:
+            # in place (the value-carrying form, where the capacity is
+            # near the input's) nothing can have been dropped
+            if n_groups < capacity or out.capacity >= child.capacity:
                 break
             capacity *= 4
             self.stats.agg_capacity_retries += 1
         self.stamp_operator(
-            groups=n_groups, capacity=capacity,
-            capacityRetries=self.stats.agg_capacity_retries - retries)
+            groups=n_groups, capacity=out.capacity,
+            capacityRetries=self.stats.agg_capacity_retries - retries,
+            valueBits=sum(carried[1]) if carried else 0,
+            outputForm="in-place" if carried and
+            out.capacity == child.capacity else "dense")
         if n_groups == 0 and not node.group_keys:
             # zero-key sort aggregation (global DISTINCT) over an empty
             # input: SQL still requires one output row (0 counts / NULL
@@ -1881,9 +1896,13 @@ def remap_codes(batch: Batch, remaps) -> Batch:
 # 385s — and operand count alone: a 1.57M x 22-operand sort ran past 8
 # MINUTES while a 22-argument non-sort kernel compiled in 1.4s. So big
 # sorts must stay under an operand-element budget AND a hard operand
-# cap; above either, sort the minimum (keys + index) and move payload
-# columns with gathers (~1.6s per 60M column at runtime, compile in
-# seconds).
+# cap; above either, sort the minimum. A gather through the sort's
+# permutation is the expensive way to move a payload (1.33 s a 60M-row
+# column whatever it holds, against 0.17 s for a one-operand 60M-row
+# int64 sort: PERF.md, PR 33), so what fits beside the keys in ONE
+# int64 word rides the sort (`live_first_order`, `lsd_word_sort`'s row
+# position, the value-carrying form of `packed_sort_group_aggregate`)
+# and only what does not is gathered.
 SORT_COMPILE_BUDGET = 1 << 26
 MAX_SORT_OPERANDS = 12
 # rows below which a multi-operand sort still compiles in seconds;

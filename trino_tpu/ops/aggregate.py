@@ -13,12 +13,23 @@ masked reduction over the same rows costs ~0.1ms):
   mixed-radix code; each (group, aggregate) cell is a *masked full
   reduction*. XLA fuses the G x A reductions over one data pass; no scatter,
   no hash table. (The analog of BigintGroupByHash's dense mode.)
-- **sort** (general keys): lexicographic multi-column `lax.sort` (dead rows
-  last), segment boundaries by adjacent-difference, then per-aggregate:
-  sums/counts via `cumsum` + boundary differencing, min/max via a segmented
-  associative scan; group results land via `searchsorted` *gathers*, never
-  scatters. Exact (sorts real key values, no hash collisions), static
-  shapes throughout.
+- **sort** (general keys): sort (dead rows last), segment boundaries by
+  adjacent-difference, then per-aggregate: sums/counts via `cumsum` +
+  boundary differencing, min/max via a segmented scan. Exact (sorts real
+  key values, no hash collisions), static shapes throughout. Three
+  kernels by what is sorted. `sort_group_aggregate` (small batches):
+  one multi-operand lexicographic `lax.sort` of the key columns and the
+  row index. `packed_sort_group_aggregate`, permutation form: the keys
+  range-compressed into int64 words, each sorted with the row position
+  in its low bits; arguments and group results are *gathered* through
+  the permutation and the segment extents come from one scatter: on the
+  chip a gather costs 22 ns an index and 32-bit plane whatever it
+  fetches, 16.4 of the 17.45 s of TPC-H Q18's aggregate at SF10
+  (PERF.md section 5). Value-carrying form
+  (where keys and arguments fit one 63-bit word): the word carries the
+  arguments where it carried the position, every sorted column is a
+  shift and a mask of the sorted word, and a group's totals are read at
+  its segment's last row: no gather, no scatter.
 
 Both paths produce *partial aggregate states* (sum/count/min/max); AVG is
 decomposed by the planner into (sum, count) and finalized in the
@@ -39,7 +50,7 @@ import numpy as np
 from ..exec.profiler import recorded_jit
 from jax import lax
 
-from ..batch import Batch, Column
+from ..batch import Batch, Column, live_first_order
 
 AGG_FUNCS = ("sum", "count", "count_star", "min", "max")
 
@@ -142,7 +153,7 @@ def direct_group_aggregate(batch: Batch, key_indices: tuple,
 
 
 # --------------------------------------------------------------------------
-# sort-based general strategy — cumsum / segmented scan, gather-only
+# sort-based general strategy — cumsum / segmented scan
 # --------------------------------------------------------------------------
 
 def _segmented_scan(vals: jax.Array, boundary: jax.Array, op):
@@ -353,7 +364,7 @@ def key_pack_plan(batch: Batch, key_indices: tuple, fetch=None):
 
 
 def key_pack_plan_words(batch: Batch, key_indices: tuple, fetch=None,
-                        max_words: int = 3):
+                        max_words: int = 3, aggs=None):
     """key_pack_plan generalized to MULTIPLE packed words: keys are
     assigned IN ORDER to words of <=62 bits each, and the sort becomes
     an LSD-radix sequence of stable 2-operand sorts (least-significant
@@ -370,11 +381,30 @@ def key_pack_plan_words(batch: Batch, key_indices: tuple, fetch=None,
     the partitions of one spill — share one compiled program instead of
     compiling one each. Where the rounding would cost a sort (one more
     word, or a word that no longer fits lsd_word_sort's one-operand
-    form at this capacity), the measured bits stay."""
-    plan = _measure_key_bits(batch, key_indices, fetch)
+    form at this capacity), the measured bits stay.
+
+    With `aggs` the result has a fourth element, the layout of the
+    aggregates' arguments in the sort word, `(vmins, value_bits)` over
+    `value_columns(aggs)`, or None. Their [min, max] ride the keys' fetch
+    and their bits are rounded as the keys' are, slot 0 for NULL. They
+    are given where the sort word has room for them below the keys
+    (one word, key bits + value bits <= 63), every argument is an
+    integer or a boolean, and a 64-bit running sum of each field over
+    the batch cannot wrap (bits + log2 capacity <= 62): then
+    packed_sort_group_aggregate carries the values through its sort and
+    gathers nothing through a permutation."""
+    values = value_columns(aggs or ())
+    plan = _measure_key_bits(batch, key_indices + values, fetch)
+    if plan is None and values:
+        # an argument that is not an integer (nothing was fetched yet):
+        # the keys alone
+        values = None
+        plan = _measure_key_bits(batch, key_indices, fetch)
     if plan is None:
         return None
-    kmins, bits = plan
+    n_keys = len(key_indices)
+    kmins, bits = plan[0][:n_keys], plan[1][:n_keys]
+    vmins, value_bits = plan[0][n_keys:], plan[1][n_keys:]
     if max(bits) > 62:
         return None
     idx_bits = max(1, (batch.capacity - 1).bit_length())
@@ -397,26 +427,49 @@ def key_pack_plan_words(batch: Batch, key_indices: tuple, fetch=None,
     splits = words(bits)[0]
     if len(splits) > max_words:
         return None
-    return kmins, bits, splits
+    if aggs is None:
+        return kmins, bits, splits
+    carried = None
+    if values is not None and len(splits) == 1:
+        # two bits say "one value" to the kernel and stay two
+        rounded = tuple(b if b == 2 else -(-b // 4) * 4
+                        for b in value_bits)
+        for vb in (rounded, value_bits):
+            if sum(bits) + sum(vb) <= 63 and \
+                    max(vb, default=0) + idx_bits <= 62:
+                carried = (vmins, vb)
+                break
+    return kmins, bits, splits, carried
+
+
+@recorded_jit(static_argnums=(1,))
+def _column_ranges(batch: Batch, indices: tuple) -> jax.Array:
+    """int64 [live rows, min 0, max 0, min 1, max 1, ...] of columns
+    `indices` over their live valid rows: ONE program and one vector to
+    fetch, where a dozen eager reductions a column cost a worker's
+    250,000-row split a millisecond and a half of dispatch."""
+    big = jnp.iinfo(jnp.int64)
+    stats = [jnp.sum(batch.live, dtype=jnp.int64)]
+    for i in indices:
+        col = batch.columns[i]
+        m = batch.live & col.valid
+        data = col.data.astype(jnp.int64)
+        stats.append(jnp.min(jnp.where(m, data, big.max)))
+        stats.append(jnp.max(jnp.where(m, data, big.min)))
+    return jnp.stack(stats)
 
 
 def _measure_key_bits(batch: Batch, key_indices: tuple, fetch=None):
     """Shared measurement: per-key [min, max] -> (kmins, bits) with no
-    total-width cap (key_pack_plan applies the single-word cap)."""
-    import numpy as np
-    stats = []
+    total-width cap (key_pack_plan applies the single-word cap).
+    `fetch` takes the device vector of _column_ranges (the batch's live
+    count leads it) and gives it back on the host."""
     for ki in key_indices:
-        col = batch.columns[ki]
-        if not jnp.issubdtype(col.data.dtype, jnp.integer) and \
-                col.data.dtype != jnp.bool_:
+        dtype = batch.columns[ki].data.dtype
+        if not jnp.issubdtype(dtype, jnp.integer) and dtype != jnp.bool_:
             return None
-        m = batch.live & col.valid
-        data = col.data.astype(jnp.int64)
-        big = jnp.iinfo(jnp.int64)
-        stats.append(jnp.min(jnp.where(m, data, big.max)))
-        stats.append(jnp.max(jnp.where(m, data, big.min)))
-    vals = fetch(*stats) if fetch is not None else \
-        np.asarray(jnp.stack(stats))
+    stats = _column_ranges(batch, tuple(key_indices))
+    vals = (fetch(stats) if fetch is not None else np.asarray(stats))[1:]
     kmins, bits = [], []
     for i in range(len(key_indices)):
         lo, hi = int(vals[2 * i]), int(vals[2 * i + 1])
@@ -454,21 +507,60 @@ def lsd_word_sort(words, word_bits) -> jax.Array:
     return perm
 
 
-@recorded_jit(static_argnums=(2, 3, 4, 5, 6, 7))
+def value_columns(aggs) -> tuple:
+    """The columns `aggs` read, each once, in the order their fields
+    take in a value-carrying sort word (two aggregates over one column
+    share its bits; count(*) needs none)."""
+    return tuple(sorted({s.arg_index for s in aggs
+                         if s.arg_index is not None}))
+
+
+# The value-carrying form leaves its groups where their segments end, in
+# a batch of the input's capacity, unless the plan's group capacity is
+# more than this factor below it: then they are read back to the group
+# capacity (dense). From two timings at 60,011,520 rows (my chip runs,
+# PR 33; the kernel itself 0.445 s): the read-back is 0.074 s for the
+# mask's sort and 0.225 us a group slot (ten 32-bit planes at 22 ns an
+# index): 3.77 s at 16,777,216 slots, 0.100 s at 131,072; left in
+# place, Q18's HAVING filter and the compaction after it read 60M rows
+# and not 16.7M, which costs them 0.15 s. The two meet near n / 128.
+IN_PLACE_FACTOR = 128
+
+
+def in_place_output(out_capacity: int, capacity: int) -> bool:
+    """Whether the value-carrying form keeps its groups in place for an
+    input of `capacity` rows and a plan that expects `out_capacity`
+    groups."""
+    return out_capacity * IN_PLACE_FACTOR >= capacity
+
+
+@recorded_jit(static_argnums=(2, 3, 4, 5, 6, 7, 9, 10))
 def packed_sort_group_aggregate(batch: Batch, kmins, key_indices: tuple,
                                 key_bits: tuple, aggs: tuple,
                                 out_capacity: int,
                                 word_splits: tuple = None,
-                                gather_mode: str = "off") -> Batch:
+                                gather_mode: str = "off",
+                                vmins=None,
+                                value_bits: tuple = None,
+                                in_place: bool = False) -> Batch:
     """sort_group_aggregate with all keys packed into int64 words (see
     key_pack_plan / key_pack_plan_words). One word sorts directly;
     multiple words run an LSD radix (lsd_word_sort): stable sorts from
     the least-significant word up, so even 7-key GROUP BYs never exceed
     two sort operands per pass (XLA TPU sort compile cost is
     operand-count bound). Dead rows pack to int64.max in every word so
-    they sort last; group keys are read back from representative rows (gathers at
-    G positions, not N). No DISTINCT support (callers route distinct to
-    the general kernel)."""
+    they sort last. No DISTINCT support (callers route distinct to the
+    general kernel).
+
+    Two forms, chosen by what the plan measured. With `value_bits` (the
+    keys' one word has room for the aggregates' arguments below it) the
+    word carries them through the sort and nothing is gathered through a
+    permutation: see _carried_group_aggregate; `in_place` leaves the
+    groups at their segments' ends in a batch of the input's capacity
+    and `out_capacity` is not read. Without, the word carries
+    the row's position, the sort gives the row permutation, and keys,
+    arguments and group results are gathered through it
+    (_grouped_reduce)."""
     n = batch.capacity
     if word_splits is None:
         word_splits = ((0, len(key_indices)),)
@@ -480,8 +572,14 @@ def packed_sort_group_aggregate(batch: Batch, kmins, key_indices: tuple,
             norm = col.data.astype(jnp.int64) - kmins[j] + 1
             norm = jnp.where(col.valid, norm, 0)      # NULL slot
             w = (w << key_bits[j]) | norm
-        words.append(jnp.where(batch.live, w,
-                               jnp.iinfo(jnp.int64).max))
+        words.append(w)
+    if value_bits is not None:
+        assert len(words) == 1
+        return _carried_group_aggregate(
+            batch, words[0], kmins, key_indices, key_bits, aggs,
+            None if in_place else out_capacity, vmins, value_bits)
+    words = [jnp.where(batch.live, w, jnp.iinfo(jnp.int64).max)
+             for w in words]
     perm = lsd_word_sort(words, [sum(key_bits[s:e])
                                  for (s, e) in word_splits])
     live_s = batch.live[perm]
@@ -494,6 +592,113 @@ def packed_sort_group_aggregate(batch: Batch, kmins, key_indices: tuple,
     boundary = live_s & (first | diff)
     return _grouped_reduce(batch, key_indices, aggs, out_capacity, perm,
                            live_s, boundary, {}, gather_mode)
+
+
+def _carried_group_aggregate(batch: Batch, key_word, kmins,
+                             key_indices: tuple, key_bits: tuple,
+                             aggs: tuple, out_capacity: int, vmins,
+                             value_bits: tuple) -> Batch:
+    """The value-carrying form of packed_sort_group_aggregate (traced
+    inside its jit). The sort word is `keys << value bits | arguments`,
+    each argument range-compressed as a key is (`- vmin + 1`, slot 0 for
+    NULL), dead rows int64.max: ONE int64 operand, unstable, since equal
+    words are equal rows. Sorted keys, arguments, validity and the live
+    mask are shifts and masks of the sorted word.
+
+    A group's totals are read at its segment's LAST row: a cumulative
+    sum less its value before the segment's first row, which a
+    cumulative max carries forward (the summed fields are >= 0, so the
+    running sums never fall); min and max are a cumulative max of the
+    field under the segment's first position. No gather, no scatter.
+
+    The output is the input's capacity with `live` at the segment ends
+    (in place: `out_capacity` None), or those rows brought to
+    `out_capacity` in key order by one live_first_order of the
+    segment-end mask and a gather a plane (dense: groups past it are
+    dropped and the caller retries, as on the permutation form).
+    in_place_output says which the executor asks for."""
+    n = batch.capacity
+    big = jnp.iinfo(jnp.int64).max
+    columns = value_columns(aggs)
+    w = key_word
+    for j, vi in enumerate(columns):
+        col = batch.columns[vi]
+        norm = col.data.astype(jnp.int64) - vmins[j] + 1
+        w = (w << value_bits[j]) | jnp.where(col.valid, norm, 0)
+    (ws,) = jax.lax.sort((jnp.where(batch.live, w, big),), num_keys=1,
+                         is_stable=False)
+
+    def field(shift: int, bits: int):
+        return (ws >> shift) & ((1 << bits) - 1)
+
+    live_s = ws != big
+    ks = ws >> sum(value_bits)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    # a dead row's key part is above every live one's: the last live
+    # row ends its segment against it
+    boundary = live_s & ((pos == 0) | (ks != jnp.roll(ks, 1)))
+    last = live_s & ((pos == n - 1) | (ks != jnp.roll(ks, -1)))
+
+    # the segment's first position, carried to its every row
+    start = lax.cummax(jnp.where(boundary, pos, 0))
+
+    def seg_total(x):
+        """Each segment's total of x >= 0, at the segment's last row."""
+        cs = jnp.cumsum(x)
+        return cs - lax.cummax(jnp.where(boundary, cs - x, 0))
+
+    def seg_max(x, bits: int):
+        """Each segment's running maximum of 0 <= x < 2^bits: `start`
+        never falls, so above x it makes a cumulative max segmented.
+        (One pass; lax.associative_scan alone at 60M rows ended the
+        process on the chip with SIGSEGV: PERF.md, PR 33.)"""
+        packed = (start.astype(jnp.int64) << bits) | x
+        return lax.cummax(packed) & ((1 << bits) - 1)
+
+    out_cols = []
+    shift = sum(value_bits) + sum(key_bits)
+    for j, ki in enumerate(key_indices):
+        shift -= key_bits[j]
+        f = field(shift, key_bits[j])
+        valid = last & (f != 0)
+        data = jnp.where(valid, f + kmins[j] - 1, 0)
+        out_cols.append(Column(
+            data=data.astype(batch.columns[ki].data.dtype), valid=valid))
+
+    fields, counts = {}, {}
+    for j, vi in enumerate(columns):
+        shift -= value_bits[j]
+        f = jnp.where(live_s, field(shift, value_bits[j]), 0)
+        fields[vi] = (f, value_bits[j], vmins[j] - 1)
+        counts[vi] = seg_total((f != 0).astype(jnp.int32)).astype(jnp.int64)
+    for spec in aggs:
+        if spec.func == "count_star":
+            cnt = (pos + 1 - start).astype(jnp.int64)
+            out_cols.append(Column(data=cnt, valid=last))
+            continue
+        cnt = counts[spec.arg_index]
+        if spec.func == "count":
+            out_cols.append(Column(data=cnt, valid=last))
+            continue
+        f, bits, base = fields[spec.arg_index]
+        if spec.func == "sum":
+            # two bits hold NULL and ONE value (a decimal sum's high
+            # limb): its fields add up to the count
+            state = (cnt if bits == 2 else seg_total(f)) + cnt * base
+        else:
+            # min is the max of the field counted down from 2^bits
+            top = 1 << bits
+            state = seg_max(f, bits) if spec.func == "max" else \
+                top - seg_max(jnp.where(f != 0, top - f, 0), bits)
+            state = (state + base).astype(
+                batch.columns[spec.arg_index].data.dtype)
+        out_cols.append(Column(data=state, valid=last & (cnt > 0)))
+    out = Batch(columns=tuple(out_cols), live=last)
+    if out_capacity is None:
+        return out
+    idx = live_first_order(last, out_capacity)
+    return Batch(tuple(Column(c.data[idx], c.valid[idx])
+                       for c in out.columns), last[idx])
 
 
 # --------------------------------------------------------------------------
