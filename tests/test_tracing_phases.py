@@ -24,10 +24,10 @@ from trino_tpu.utils.tracing import NOOP, Tracer
 # every span of docs/operations.md's catalogue that a split-streamed
 # aggregation opens (build-stage, pin-builds' children and the write and
 # exchange spans belong to other plan shapes)
-Q6_SPANS = {"query", "exec-lock-wait", "plan-distributed", "stage-prepare",
-            "source-stage", "spool-lookup", "stage-wait", "task-create",
-            "task-drain", "task-record",
-            "task-decode", "worker-task", "pin-builds", "split-read",
+Q6_SPANS = {"query", "exec-lock-wait", "exec-lock-held", "plan-distributed",
+            "stage-prepare", "source-stage", "spool-lookup", "stage-wait",
+            "task-create", "task-drain", "task-record", "task-decode",
+            "task-lock-wait", "worker-task", "pin-builds", "split-read",
             "split-put", "split", "split-fetch", "split-emit", "compile",
             "task-merge", "task-emit", "final-stage", "merge-decode", "merge-partials", "merge-run",
             "result-fetch", "decode-rows"}
@@ -333,7 +333,8 @@ def test_traced_q6_yields_every_phase_span(cluster):
         return ids[sp["parentSpanId"]]
 
     # who hangs under whom
-    want = {"exec-lock-wait": {"query"}, "stage-prepare": {"query"},
+    want = {"exec-lock-wait": {"query"}, "exec-lock-held": {"query"},
+            "task-lock-wait": {"source-stage"}, "stage-prepare": {"query"},
             "source-stage": {"query"}, "final-stage": {"query"},
             "task-create": {"source-stage"}, "task-drain": {"source-stage"},
             "task-record": {"source-stage"}, "task-decode": {"source-stage"},

@@ -25,6 +25,7 @@ import json
 import threading
 import time
 import traceback
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -154,6 +155,63 @@ class RegisteredNode:
         self.tasks: Optional[list] = None
 
 
+class ArrivalOrderLock:
+    """The device lock: re-entrant like `threading.RLock`, and waiters
+    get it in the order they asked. An RLock promises no order (whoever
+    the OS wakes runs next), so with several sessions a statement's
+    latency was the host scheduler's to decide; Trino's root resource
+    group starts queued queries first in, first out (`schedulingPolicy`
+    `fair`), and statements admitted under its concurrency limit queue
+    here instead. `release` hands the lock to the oldest waiter
+    directly: nobody who asks later can slip in between."""
+
+    def __init__(self):
+        self._mutex = threading.Lock()
+        self._owner: Optional[int] = None
+        self._depth = 0
+        self._waiters: deque = deque()     # (thread ident, its gate)
+
+    def acquire(self) -> int:
+        """Blocks until the lock is this thread's. Returns how many
+        were ahead when it asked: the holder and the waiters before it,
+        0 for a free lock or a re-entrant acquire."""
+        me = threading.get_ident()
+        with self._mutex:
+            if self._owner == me:
+                self._depth += 1
+                return 0
+            if self._owner is None:
+                self._owner, self._depth = me, 1
+                return 0
+            gate = threading.Event()
+            self._waiters.append((me, gate))
+            ahead = len(self._waiters)
+        gate.wait()
+        return ahead
+
+    def release(self) -> None:
+        with self._mutex:
+            if self._owner != threading.get_ident():
+                raise RuntimeError("release of a lock this thread "
+                                   "does not hold")
+            self._depth -= 1
+            if self._depth:
+                return
+            if self._waiters:
+                self._owner, gate = self._waiters.popleft()
+                self._depth = 1
+                gate.set()
+            else:
+                self._owner = None
+
+    def __enter__(self) -> "ArrivalOrderLock":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
 class Dispatcher:
     """Admission + async execution (DispatchManager + SqlQueryManager).
 
@@ -170,9 +228,10 @@ class Dispatcher:
         self.tracker = tracker
         self.pool = ThreadPoolExecutor(max_workers=max_concurrency,
                                        thread_name_prefix="dispatch")
-        # RLock: traced attempts hold it across the whole attempt while
-        # the serving layer re-acquires for its device-path execution
-        self.exec_lock = threading.RLock()
+        # re-entrant: traced attempts hold it across the whole attempt
+        # while the serving layer re-acquires for its device-path
+        # execution. Waiters are served in arrival order
+        self.exec_lock = ArrivalOrderLock()
         self.failure_injector = None      # FailureInjector (tests/ops)
         # retry-policy QUERY (admin/fault-tolerant-execution.md): rerun the
         # whole query on failure; deterministic kernels + the dedup of
@@ -605,14 +664,22 @@ class Dispatcher:
     @contextmanager
     def _exec_locked(self):
         """The one place the dispatcher takes the exec lock; the wait
-        for it is a span of its own (`exec-lock-wait`)."""
+        for it is a span of its own (`exec-lock-wait`, with `ahead`:
+        the statements that had asked before and not yet released), and
+        so is the time the statement then holds it (`exec-lock-held`, a
+        sibling recorded after the release, so it is nobody's parent)."""
         from ..utils import tracing
-        with tracing.current().span("exec-lock-wait"):
-            self.exec_lock.acquire()
+        tracer = tracing.current()
+        with tracer.span("exec-lock-wait") as wait:
+            ahead = self.exec_lock.acquire()
+            if wait is not None:
+                wait.attributes["ahead"] = ahead
+        t_held = time.monotonic()
         try:
             yield
         finally:
             self.exec_lock.release()
+            tracer.record("exec-lock-held", t_held, time.monotonic())
 
     def _execute_attempt(self, tq: TrackedQuery) -> None:
         """One execution attempt under the exec lock: cluster path first,
@@ -714,8 +781,18 @@ class Dispatcher:
                 with self._exec_locked():
                     result = self.scheduler.execute(tq.sql,
                                                     query_id=tq.query_id)
-                tq.fallback_reason = self.scheduler.fallback_reason \
-                    if result is None else None
+                    # the scheduler keeps one statement's state on
+                    # itself: read this one's while the lock is still
+                    # held, or the next statement's `execute` may have
+                    # replaced it
+                    if result is None:
+                        tq.fallback_reason = self.scheduler.fallback_reason
+                    else:
+                        tq.fallback_reason = None
+                        # per-query stage/task rollup for events +
+                        # system.runtime tables + /v1/query info
+                        tq.stage_stats = getattr(self.scheduler,
+                                                 "last_query", None)
             except TaskFailedError as te:
                 from .scheduler import (RetryBudgetExhaustedError,
                                         TaskTimeoutError)
@@ -730,11 +807,6 @@ class Dispatcher:
                 if fair is not None:
                     fair.device_end(getattr(tq, "tenant", "default"))
             tq.distributed = result is not None
-            if tq.distributed:
-                # per-query stage/task rollup for events +
-                # system.runtime tables + /v1/query info
-                tq.stage_stats = getattr(self.scheduler,
-                                         "last_query", None)
         if result is None and getattr(
                 self.session, "properties", {}).get(
                 "require_distributed") and \

@@ -24,6 +24,7 @@ import logging
 import threading
 import time
 import traceback
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -860,6 +861,17 @@ class TaskManager:
                 sp.attributes.update(rows=len(arrs[0]) if arrs else 0,
                                      bytes=task.bytes_out - bytes0)
 
+    @contextmanager
+    def _exec_locked(self, tracer: Tracer):
+        """The one place a task takes this worker's executor lock; the
+        wait for it is a span of its own (`task-lock-wait`)."""
+        with tracer.span("task-lock-wait"):
+            self._exec_lock.acquire()
+        try:
+            yield
+        finally:
+            self._exec_lock.release()
+
     def _run(self, task: WorkerTask) -> None:
         with task.lock:
             if task.state != "PENDING":   # canceled before the thread ran
@@ -914,7 +926,7 @@ class TaskManager:
             # serialized by the chip anyway (Trino's analog: one lookup
             # source per build, drivers share it under memory context
             # locking).
-            with self._exec_lock, \
+            with self._exec_locked(tracer), \
                     tracer.span("worker-task", taskId=task.task_id,
                                 node=self.node_id,
                                 splits=len(task.splits)) as wspan:
@@ -1140,7 +1152,7 @@ class TaskManager:
         from ..batch import batch_to_numpy
         names = {id(n): type(n).__name__
                  for n in _subtree_nodes_all(root)} if tracer.enabled else {}
-        with self._exec_lock:
+        with self._exec_locked(tracer):
             ex = self._executor
             ex._subst.clear()
             ex._subst_opaque.clear()
