@@ -90,6 +90,33 @@ def test_packed_sort_matches_general(asc, nf, wide):
     assert rows_of(got) == rows_of(want)
 
 
+@pytest.mark.parametrize("top", [(1 << 32) - 100, (1 << 32) + 100,
+                                 1 << 20])
+@pytest.mark.parametrize("tie", [False, True])
+def test_order_by_layout_does_not_follow_the_measures_span(top, tie):
+    """A measure of 32 bits, of 33 and of 21 before a 9-bit key, 4,096
+    rows: one layout (62 - 12 - 12 = 38 bits and 12), one program, and
+    the general sort's order. With a 45-bit tie-breaker behind them
+    (q3's shape: the keys take two words) each word's first key takes
+    its word's room."""
+    rng = np.random.default_rng(35)
+    measure = rng.integers(0, top, 4096)
+    measure[7] = top
+    b = batch_from_numpy(
+        [measure, rng.integers(0, 400, 4096),
+         rng.integers(0, 1 << 45, 4096)],
+        [rng.random(4096) > 0.02, None, None])
+    keys = ((0, False, False), (1, True, False))
+    want = ((38, 12), ((0, 2),))
+    if tie:
+        keys += ((2, True, False),)
+        want = ((38, 12, 50), ((0, 2), (2, 3)))
+    kmins, bits, splits = sort_pack_plan(b, keys)
+    assert (bits, splits) == want
+    got = sort_batch_packed(b, jnp.asarray(kmins), keys, bits, 10, splits)
+    assert rows_of(got) == rows_of(sort_batch(b, keys, 10))
+
+
 def test_pack_plan_refuses_wide_domains():
     n = 64
     b = batch_from_numpy(

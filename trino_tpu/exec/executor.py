@@ -1540,11 +1540,13 @@ class Executor:
 
     def try_unique_join(self, node: L.JoinNode, probe: Batch,
                         build: Batch, domain) -> Optional[Batch]:
-        """Unique-build fast paths. inner/left take the gather-free
-        sort-merge kernel (the fastest primitive on TPU is the sort
-        network); dense LUT / sorted probing remain for membership and
-        wide-row fallbacks. None = build had duplicate keys (caller
-        expands)."""
+        """Unique-build fast paths. Small inner/left joins take the
+        gather-free sort-merge kernel (the fastest primitive on TPU is
+        the sort network); a large inner join finds its build rows
+        first (one merge sort of both sides, or the dense LUT's gather)
+        and compacts before it gathers payloads; dense LUT / sorted
+        probing remain for the rest. None = build had duplicate keys
+        (caller expands)."""
         # Compile-cost gate for the multi-operand merge sort, measured in
         # SORT OPERAND-ELEMENTS (rows x sort operands, where each column
         # contributes data+valid operands). Measured on v5e: ~240M
@@ -1580,46 +1582,72 @@ class Executor:
                 self._note_strategy("JoinNode", "sort-merge", "join")
                 return self.maybe_compact(out, live=live)
             return None
-        if domain is not None:
-            if node.kind == "inner" and probe.capacity > SORT_SMALL_ROWS:
-                # two-phase: probe the LUT, THEN decide — a selective
-                # join compacts matched rows before paying per-column
-                # build gathers at full probe capacity (gathers are the
-                # dense join's whole cost)
-                from ..ops.join import dense_join_compacted, dense_probe
-                src, matched, dup, oob, live = dense_probe(
-                    probe, build, node.left_keys, node.right_keys,
-                    domain)
-                dup, oob, live = self.fetch_ints(
-                    node, f"jdense2:{domain}", dup, oob, live)
-                if oob == 0:
-                    if dup != 0:
-                        return None
-                    self._note_strategy("JoinNode", "dense-lut", "join")
+        if node.kind == "inner" and probe.capacity > SORT_SMALL_ROWS and \
+                (domain is not None or not self.chunk_mode):
+            # two-phase: find each probe row's build row, THEN decide —
+            # a selective join compacts matched rows before paying
+            # per-column build gathers at full probe capacity (gathers
+            # are the dense join's whole cost). Phase 1 is one merge
+            # sort of both sides where its word fits and the capacities
+            # say it beats the LUT's gather, else the LUT. (A chunked
+            # loop's join with no domain stays on the sorted kernel: its
+            # chunks keep one shape.)
+            from ..ops.join import (dense_join_compacted, dense_probe,
+                                    merge_probe, merge_probe_form)
+            word_bits = merge_probe_form(probe.capacity, build.capacity,
+                                         domain)
+            phase1 = None
+            if word_bits is not None:
+                phase1 = merge_probe(probe, build, node.left_keys,
+                                     node.right_keys)
+                tag, strategy = "jmerge2", "sort-probe"
+            elif domain is not None:
+                phase1 = dense_probe(probe, build, node.left_keys,
+                                     node.right_keys, domain)
+                tag, strategy = f"jdense2:{domain}", "dense-lut"
+            if phase1 is not None:
+                # escaped: build keys outside the domain (the LUT) or
+                # wider than the word's key field (the merge)
+                words, rows, *counts = phase1
+                dup, escaped, live = self.fetch_ints(node, tag, *counts)
+                if escaped != 0:
+                    self.stats.join_domain_fallbacks += 1
+                    domain = None
+                elif dup != 0:
+                    return None
+                else:
                     new_cap = compaction_capacity(live, probe.capacity)
                     if new_cap * self.COMPACT_SHRINK <= probe.capacity:
+                        self._note_strategy("JoinNode", strategy, "join")
+                        if word_bits is not None:
+                            self.stamp_operator(wordBits=word_bits,
+                                                sortRows=words.shape[0])
                         self.stats.dynamic_filter_compactions += 1
                         return dense_join_compacted(
-                            probe, src, matched, build, node.left_keys,
+                            probe, words, rows, build, node.left_keys,
                             node.right_keys, new_cap, gm)
-                    out, dup2, oob2 = join_unique_build_dense(
-                        probe, build, node.left_keys, node.right_keys,
-                        node.kind, domain, gm)
-                    return out
-                self.stats.join_domain_fallbacks += 1
-            else:
-                out, dup, oob = join_unique_build_dense(
-                    probe, build, node.left_keys, node.right_keys,
-                    node.kind, domain, gm)
-                dup, oob, live = self.fetch_ints(
-                    node, f"jdense:{domain}", dup, oob,
-                    jnp.sum(out.live))
-                if oob == 0:
-                    if dup != 0:
-                        return None
-                    self._note_strategy("JoinNode", "dense-lut", "join")
-                    return self.maybe_compact(out, live=live)
-                self.stats.join_domain_fallbacks += 1
+                    if word_bits is None:
+                        # unselective, and the LUT form has vouched for
+                        # the domain: one shot at the probe's capacity
+                        self._note_strategy("JoinNode", "dense-lut",
+                                            "join")
+                        return join_unique_build_dense(
+                            probe, build, node.left_keys,
+                            node.right_keys, node.kind, domain, gm)[0]
+                    # unselective after a merge: the one-shot kernels
+                    # below, which check the domain themselves
+        if domain is not None:
+            out, dup, oob = join_unique_build_dense(
+                probe, build, node.left_keys, node.right_keys,
+                node.kind, domain, gm)
+            dup, oob, live = self.fetch_ints(
+                node, f"jdense:{domain}", dup, oob, jnp.sum(out.live))
+            if oob == 0:
+                if dup != 0:
+                    return None
+                self._note_strategy("JoinNode", "dense-lut", "join")
+                return self.maybe_compact(out, live=live)
+            self.stats.join_domain_fallbacks += 1
         out, dup = join_unique_build(probe, build, node.left_keys,
                                      node.right_keys, node.kind)
         dup, live = self.fetch_ints(node, "jsorted", dup,

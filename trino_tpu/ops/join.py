@@ -19,6 +19,13 @@ at 60M probe / 15M build rows and have not been re-measured:
   searchsorted lowers to ~24 sequential gather rounds (30s at 60M probes)
   — usable for small/medium probes, pathological at scale, hence the LUT.
 
+A selective unique-build inner join over a large probe runs in two
+phases: find every probe row's build row (`dense_probe`: the LUT's one
+gather, 22 ns an index on a v5e; or `merge_probe`: probe and build
+through ONE sort word, no gather, where `merge_probe_form` says the word
+fits and the capacities favour it), then compact the matched rows and
+gather payloads at the compacted capacity only (`dense_join_compacted`).
+
 Output-row mapping in the expansion kernels uses scatter + cummax
 (associative scan) instead of a second searchsorted for the same reason.
 
@@ -41,7 +48,7 @@ import jax.numpy as jnp
 
 from ..exec.profiler import recorded_jit
 
-from ..batch import Batch, Column, live_first_order
+from ..batch import Batch, Column
 from . import pallas_gather
 
 _SENTINEL = jnp.iinfo(jnp.int64).max
@@ -389,13 +396,28 @@ def dense_join_packed(probe: Batch, lut: jax.Array, probe_keys: tuple,
     return Batch(columns=probe.columns + tuple(build_cols), live=live)
 
 
+def _match_words(pos, idx, matched, idx_bits: int) -> jax.Array:
+    """One int64 a probe row for dense_join_compacted: `position << idx
+    bits | idx` where the row matched, int64.max where it did not; `idx`
+    is where phase 1's `rows` holds the row's build row. The words of
+    matched rows all differ and sort into the probe's row order."""
+    word = (pos.astype(jnp.int64) << idx_bits) | idx.astype(jnp.int64)
+    return jnp.where(matched, word, _SENTINEL)
+
+
+def _index_bits(capacity: int) -> int:
+    return max(1, (capacity - 1).bit_length())
+
+
 @recorded_jit(static_argnums=(2, 3, 4))
 def dense_probe(probe: Batch, build: Batch, probe_keys: tuple,
                 build_keys: tuple, domain: int):
-    """Phase 1 of the two-phase dense join: LUT build + probe lookup
-    only. Returns (src row indices, matched mask, dup, oob, match
-    count) — ONE gather at probe capacity; the caller decides whether
-    to compact before paying the per-column build gathers (phase 2)."""
+    """Phase 1 of the two-phase dense join, the LUT form: LUT build +
+    probe lookup only, ONE gather at probe capacity. Returns (match
+    words, rows, dup, oob, match count): a word a probe row and the
+    build row of each, as dense_join_compacted reads them; the caller
+    decides whether to compact before paying the per-column build
+    gathers (phase 2). merge_probe is the form without the gather."""
     pk, pk_valid = _combined_key(probe, probe_keys)
     bk, bk_valid = _combined_key(build, build_keys)
     b_ok = build.live & bk_valid
@@ -403,31 +425,172 @@ def dense_probe(probe: Batch, build: Batch, probe_keys: tuple,
     lut, dup = _dense_row_lut(bk, b_ok, domain)
     p_idx = jnp.where(pk_valid, jnp.clip(pk, 0, domain - 1), domain)
     src = lut[p_idx]
+    # the key-validity and range checks belong to the mask: the LUT's
+    # dead-row sink slot holds a real row id, so `src >= 0` alone would
+    # join NULL-key probes
     matched = (src >= 0) & pk_valid & probe.live & \
         (pk >= 0) & (pk < domain)
-    return src, matched, dup, oob, jnp.sum(matched, dtype=jnp.int64)
+    pos = jnp.arange(probe.capacity, dtype=jnp.int32)
+    words = _match_words(pos, pos, matched, _index_bits(probe.capacity))
+    return words, src, dup, oob, jnp.sum(matched, dtype=jnp.int64)
+
+
+# What the two forms of phase 1 cost on a v5e, from traced runs at
+# 60,011,520 rows (PR 33's and PR 35's): XLA's gather 22 ns an index
+# and 32-bit plane (at 60M indices and at 16.7M alike, whatever the
+# table's order: the LUT probe read 1.33-1.46 s), a one-operand int64
+# `lax.sort` 0.1647 s. The merge form sorts probe and build together
+# once and sorts the match words once more to compact them; between the
+# sorts it scans once (the int32 `cummax` read 0.022 s) and builds and
+# reads two arrays of words, which SCAN_NS_PER_WORD allows for together
+# (an int64 scan's time, 0.100 s). The LUT form gathers once a probe
+# row. The two meet near a build of twice its probe's capacity.
+GATHER_NS_PER_INDEX = 22.0
+SORT_NS_PER_WORD = 2.7
+SCAN_NS_PER_WORD = 1.7
+# merge_probe's scan carries a sorted word's index and one flag in an
+# int32
+MERGE_MAX_WORDS = 1 << 30
+
+
+def merge_probe_wins(probe_capacity: int, build_capacity: int) -> bool:
+    """Whether phase 1 as one merge (merge_probe) costs less than as
+    one LUT gather (dense_probe), from the capacities alone."""
+    words = probe_capacity + build_capacity
+    return (2 * SORT_NS_PER_WORD + SCAN_NS_PER_WORD) * words < \
+        GATHER_NS_PER_INDEX * probe_capacity
+
+
+def merge_probe_word_bits(probe_capacity: int, build_capacity: int,
+                          key_span: int = None) -> int:
+    """Bits of merge_probe's sort word for a build whose keys take at
+    most `key_span` consecutive values: a key field that keeps its top
+    value free (a live word is never int64.max), one tag bit, and a row
+    position of either side. With no `key_span` (nothing is known of
+    the keys) the key field is all the room an int64 leaves, and the
+    program says whether the build's keys fit it (`wide`). The form
+    needs 63 bits or fewer."""
+    if key_span is None:
+        return 63
+    return max(1, int(key_span).bit_length()) + 1 + \
+        _index_bits(max(probe_capacity, build_capacity))
+
+
+def merge_probe_form(probe_capacity: int, build_capacity: int,
+                     key_span: int = None):
+    """The sort word's bits where a unique-build inner join takes phase
+    1 as merge_probe, None where it keeps dense_probe: the word has to
+    fit an int64 and the capacities have to say the merge wins."""
+    bits = merge_probe_word_bits(probe_capacity, build_capacity, key_span)
+    if bits <= 63 and merge_probe_wins(probe_capacity, build_capacity) \
+            and probe_capacity + build_capacity < MERGE_MAX_WORDS:
+        return bits
+    return None
+
+
+@recorded_jit(static_argnums=(2, 3))
+def merge_probe(probe: Batch, build: Batch, probe_keys: tuple,
+                build_keys: tuple):
+    """Phase 1 of the two-phase join with no LUT and no gather: probe
+    and build merge through ONE sort word, the way
+    aggregate._carried_group_aggregate carries an aggregate's arguments.
+
+    A word a row over build ++ probe: `key - kmin` (kmin the build's
+    least live key) in the high bits, then a tag bit (build 0, probe 1),
+    then the row's own position. Dead rows, NULL keys and probe keys
+    outside the build's range are int64.max. After one unstable
+    one-operand sort the words of one key stand together, its build
+    word first, so a probe word matched iff its key's run starts with a
+    build word, and that word's low field is its build row. A
+    cumulative max of "this word's index and whether it is a build
+    word, where a run starts, else -1" hands every word its run's
+    start: an int32 scan (the int64 one over the words themselves
+    compiles for 125 s at 60M words where this one takes 16, and moves
+    two planes). `lax.cummax`, never `lax.associative_scan`: at 60M
+    rows that one ended the process (PERF.md, PR 33).
+
+    The key field is the room the tag and the position leave, so the
+    program's statics are the two shapes and the key columns alone.
+    Returns (match words, rows, dup, wide, match count), words and rows
+    in key order with the build's slots among them (probe + build
+    capacity of each): a matched probe row's word is `its position <<
+    idx bits | the index of its run's start`, and `rows` there is its
+    build row, as dense_join_compacted reads them; `dup` counts build
+    words that follow a build word of their key; `wide` counts build
+    keys the key field cannot hold (the caller takes another path, as
+    after `oob`)."""
+    n, m = probe.capacity, build.capacity
+    assert n + m < MERGE_MAX_WORDS
+    pos_bits = _index_bits(max(n, m))
+    key_bits = 62 - pos_bits
+    pk, pk_valid = _combined_key(probe, probe_keys)
+    bk, bk_valid = _combined_key(build, build_keys)
+    b_ok = build.live & bk_valid
+    kmin = jnp.min(jnp.where(b_ok, bk, _SENTINEL))
+    top = (1 << key_bits) - 1           # never a live key's offset
+    b_off, p_off = bk - kmin, pk - kmin
+    # `>= 0`: a span past 2^63 wraps the difference to a negative
+    b_fits = b_ok & (b_off >= 0) & (b_off < top)
+    wide = jnp.sum(b_ok & ~b_fits, dtype=jnp.int64)
+    p_ok = probe.live & pk_valid & (pk >= kmin) & (p_off >= 0) & \
+        (p_off < top)
+    tag = jnp.int64(1) << pos_bits
+    b_word = (b_off << (pos_bits + 1)) | jnp.arange(m, dtype=jnp.int64)
+    p_word = (p_off << (pos_bits + 1)) | tag | \
+        jnp.arange(n, dtype=jnp.int64)
+    (ws,) = jax.lax.sort((jnp.concatenate([
+        jnp.where(b_fits, b_word, _SENTINEL),
+        jnp.where(p_ok, p_word, _SENTINEL)]),), num_keys=1,
+        is_stable=False)
+
+    # the sentinel has its tag bit set: it is nobody's build word, and
+    # its key field is no live key's, so its run starts with a sentinel
+    is_build = (ws & tag) == 0
+    differs = ws[1:] ^ ws[:-1]          # from the word before
+    starts = jnp.concatenate([
+        jnp.ones(1, dtype=bool), (differs >> (pos_bits + 1)) != 0])
+    j = jnp.arange(n + m, dtype=jnp.int32)
+    start = jax.lax.cummax(jnp.where(
+        starts, (j << 1) | is_build.astype(jnp.int32), -1))
+    matched = ~is_build & ((start & 1) == 1)
+    # key and tag of the word before: a build word again, of this key
+    dup = jnp.sum(is_build[1:] & ((differs >> pos_bits) == 0))
+    low = tag - 1
+    words = _match_words(ws & low, start >> 1, matched,
+                         _index_bits(n + m))
+    return (words, (ws & low).astype(jnp.int32), dup, wide,
+            jnp.sum(matched, dtype=jnp.int64))
 
 
 @recorded_jit(static_argnums=(4, 5, 6, 7))
-def dense_join_compacted(probe: Batch, src: jax.Array,
-                         matched: jax.Array, build: Batch,
-                         probe_keys: tuple, build_keys: tuple,
-                         new_capacity: int,
+def dense_join_compacted(probe: Batch, words: jax.Array, rows: jax.Array,
+                         build: Batch, probe_keys: tuple,
+                         build_keys: tuple, new_capacity: int,
                          gather_mode: str = "off") -> Batch:
-    """Phase 2 (selective inner join): compact matched probe rows first
-    (argsort of the match mask), then gather probe AND build payload
-    columns at the compacted capacity only. For a 60M-capacity probe
-    with a few-percent match rate this replaces several 60M-row gathers
-    with ~matched-size ones — gathers are the whole cost of the dense
-    join on TPU.
+    """Phase 2 (selective inner join): compact the matched probe rows
+    first, then gather probe AND build payload columns at the compacted
+    capacity only. For a 60M-capacity probe with a few-percent match
+    rate this replaces several 60M-row gathers with ~matched-size ones
+    — gathers are the whole cost of the dense join on TPU.
 
-    `matched` MUST be phase 1's mask: it carries the key-validity and
-    domain-range checks (src >= 0 alone is not sufficient — the LUT's
-    dead-row sink slot holds a real row id, so NULL-key probes would
-    join spuriously and overflow new_capacity)."""
-    order = live_first_order(matched, new_capacity)
-    live = matched[order]
-    src_c = jnp.clip(src[order], 0, build.capacity - 1)
+    `words` and `rows` are phase 1's (dense_probe's or merge_probe's):
+    a word is `position << idx bits | idx` a matched probe row and
+    int64.max otherwise, in any order, and `rows[idx]` is the row's
+    build row. ONE unstable one-operand sort of the words, sliced to
+    `new_capacity`, is the compaction: a word's high field is the probe
+    row to read (`order`, in the probe's row order) and its low field
+    finds the build row (`src_c`, one gather at `new_capacity`);
+    nothing is gathered at the probe's capacity. Slots past the matched
+    rows are dead and read row 0 of both sides."""
+    idx_bits = _index_bits(words.shape[0])
+    assert _index_bits(probe.capacity) + idx_bits <= 62
+    (ordered,) = jax.lax.sort((words,), num_keys=1, is_stable=False)
+    ordered = ordered[:new_capacity]
+    live = ordered != _SENTINEL
+    ordered = jnp.where(live, ordered, 0)
+    order = (ordered >> idx_bits).astype(jnp.int32)
+    idx = (ordered & ((1 << idx_bits) - 1)).astype(jnp.int32)
+    src_c = jnp.where(live, rows[idx], 0)
 
     cols = []
     for c in probe.columns:
