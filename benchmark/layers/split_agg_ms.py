@@ -1,0 +1,11 @@
+"""Worker tasks and executor, inside a split: the partial aggregation of
+the split's fragment (fenced in the traced run): summed wall of the
+statement's `aggregate` spans that carry `split`, over its count of
+`split` spans, median per statement, in ms a split. Nothing to read on a
+program that names no operator inside a split."""
+
+from layers import _split_ops
+
+
+def read(run):
+    return _split_ops.operator_ms(run, "aggregate")

@@ -267,14 +267,15 @@ def test_heartbeat_off_no_threads_and_identical_wire_format(monkeypatch):
                        for th in threading.enumerate())
         w_off.announce_once()
         w_on.announce_once()
-        # heartbeats off -> the announce body is byte-identical to the
-        # legacy wire format: exactly the five pre-round-21 keys
+        # heartbeats off -> the announce body has no heartbeat key: the
+        # five pre-round-21 keys and the span clock (PR 38: which clock
+        # pair the node's spans are stamped on, and its reading)
         assert set(bodies["zo-off"]) == \
-            {"nodeId", "uri", "state", "now", "tasks"}
+            {"nodeId", "uri", "state", "now", "spanClock", "tasks"}
         # heartbeats on -> same keys plus the live piggyback
         assert set(bodies["zo-on"]) == \
-            {"nodeId", "uri", "state", "now", "tasks", "liveStats",
-             "memory"}
+            {"nodeId", "uri", "state", "now", "spanClock", "tasks",
+             "liveStats", "memory"}
         assert set(bodies["zo-on"]["liveStats"]) == \
             {"seq", "tasks", "busy", "utilization"}
     finally:

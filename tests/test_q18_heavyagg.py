@@ -230,18 +230,37 @@ def test_tracing_off_builds_no_operator_span(single, monkeypatch):
         assert CountingAnnotation.names.count(name) == n
 
 
-def test_a_worker_task_opens_no_operator_span(worker_cluster):
-    """240 splits times three operators would move the split phases: the
-    spans belong to the coordinator's whole-statement route alone."""
+def test_a_worker_task_opens_operator_spans_beside_its_splits(
+        worker_cluster):
+    """A traced task's operators have spans too: in a split they hang
+    under `worker-task` beside the `split` lap (or under the operator
+    they run inside), say which split they are and nothing else, and are
+    nobody's `compile` parent. tests/test_tracing_phases.py holds the
+    split loop's spans to more."""
     worker_cluster.client.execute("SET SESSION enable_tracing = true")
     try:
         _, info, spans = worker_cluster.run(
             q18.render({"quantity": 201}, "tpch.tiny"))
     finally:
         worker_cluster.client.execute("SET SESSION enable_tracing = false")
-    names = {sp["name"] for sp in spans}
-    assert info["distributed"] and "worker-task" in names
-    assert not names & {"aggregate", "join", "sort"}
+    ids = {sp["spanId"]: sp for sp in spans}
+    assert info["distributed"] and "worker-task" in {
+        sp["name"] for sp in spans}
+    operators = [sp for sp in spans if sp["name"] in (
+        "aggregate", "join", "sort", "filter-project", "dynamic-filter")]
+    in_splits = [sp for sp in operators if "split" in sp["attributes"]]
+    assert {"aggregate", "join"} <= {sp["name"] for sp in in_splits}
+    for sp in in_splits:
+        assert list(sp["attributes"]) == ["split"]
+        assert ids[sp["parentSpanId"]]["name"] in (
+            "worker-task", "join", "aggregate", "filter-project")
+    taken = {sp["spanId"] for sp in operators}
+    assert not [sp for sp in spans if sp["name"] == "compile"
+                and sp["parentSpanId"] in taken and
+                "split" in ids[sp["parentSpanId"]]["attributes"]]
+    ex = worker_cluster.workers[0].task_manager._executor
+    assert ex._operator_spans is False and ex._operator_split is None
+    assert ex._open_operators == []
 
 
 def test_explain_prints_the_in_subquery_as_a_sub_plan():

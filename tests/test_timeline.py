@@ -29,6 +29,7 @@ from trino_tpu.server.timeline import (PHASES, attribute_phases,
                                        breakdown_line, critical_path,
                                        dominant_phase)
 from trino_tpu.server.worker import WorkerServer
+from trino_tpu.utils import tracing
 from trino_tpu.utils.tracing import Tracer
 
 
@@ -351,6 +352,22 @@ def test_announce_now_estimates_clock_offset(cluster):
         # a real worker's offset is ~zero (same host clock)
         real = coord.state.nodes[workers[0].node_id]
         assert abs(real.clock_offset) < 1.0
+        # spans are rebased by the offset of the SPAN clocks (each
+        # process's one clock pair). A worker of this process announces
+        # this process's pair: exactly 0, whatever the request took; an
+        # announce without a span clock falls back on the wall clock's
+        assert real.span_offset == 0.0
+        assert -2.5 < coord.state.nodes["tl-skewed"].span_offset < -1.5
+        here = tracing.unix_ns(time.monotonic())
+        coord.state.announce("tl-skewed", "http://127.0.0.1:1",
+                             state="DRAINING", now=time.time(),
+                             span_clock=["another-process", here + 3 * 10**9])
+        node = coord.state.nodes["tl-skewed"]
+        assert 2.9 < node.span_offset <= 3.0 and abs(node.clock_offset) < 0.5
+        coord.state.announce("tl-skewed", "http://127.0.0.1:1",
+                             state="DRAINING", now=time.time() + 9.0,
+                             span_clock=[tracing.CLOCK_ID, here])
+        assert node.span_offset == 0.0 and node.clock_offset > 8.5
     finally:
         coord.state.announce("tl-skewed", "", state="LEFT")
 

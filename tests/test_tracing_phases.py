@@ -4,6 +4,7 @@ merge, `compile` spans that tell literal-keyed from shape-keyed
 (exec/profiler.py), and tracing that no longer implies fences.
 """
 
+import json
 import threading
 import time
 
@@ -102,10 +103,9 @@ def test_span_parent_argument_and_record():
     assert by["late"]["parentSpanId"] == b.span_id
     assert by["late"]["attributes"] == {"site": "x"}
     assert 9.0 <= by["late"]["durationMs"] < 200.0
-    # its wall-clock start lies inside its parent's interval
-    assert by["b"]["startTimeUnixNano"] - 2e6 <= \
-        by["late"]["startTimeUnixNano"] <= \
-        by["b"]["startTimeUnixNano"] + by["b"]["durationMs"] * 1e6 + 2e6
+    # on the exported clock it lies inside its parent, as it did on the
+    # monotonic one
+    assert _inside(by["late"], by["b"])
     assert by["c"]["parentSpanId"] == b.span_id
     assert by["late-explicit"]["parentSpanId"] == a.span_id
 
@@ -135,7 +135,7 @@ def test_laps_leave_no_moment_unnamed():
     # each phase starts exactly where the one before ended
     for a, b in zip(phases, phases[1:]):
         end = a["startTimeUnixNano"] + a["durationMs"] * 1e6
-        assert abs(end - b["startTimeUnixNano"]) <= 2e3     # rounding
+        assert abs(end - b["startTimeUnixNano"]) <= ROUNDING_NS
     # off: the same code runs and builds nothing
     with NOOP.laps() as lap:
         assert lap("read", index=0) is None
@@ -150,6 +150,130 @@ def test_laps_close_the_open_phase_on_an_error():
             raise ValueError("boom")
     assert t.current_span() is None
     assert [s["name"] for s in t.export()] == ["read"]
+
+
+def test_spans_of_one_process_share_one_clock_pair(monkeypatch):
+    """Two tracers, `record()` and `laps()` while another thread takes
+    the GIL wherever it can: on the exported clock a child lies inside
+    its parent and laps touch, exactly (a wall-clock read per span put a
+    thread switch, 5 ms here, between a span and its parent). And the
+    wall clock is read once a process: while these spans are made the
+    tracer cannot read it at all."""
+    import types
+    monkeypatch.setattr(tracing, "time",
+                        types.SimpleNamespace(monotonic=time.monotonic))
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(2000))
+
+    spinner = threading.Thread(target=spin)
+    spinner.start()
+    a, b = Tracer(service="coordinator"), Tracer(service="worker")
+    try:
+        for i in range(300):
+            with a.span("outer", n=i) as outer:
+                with b.span("inner", parent=outer.span_id) as inner:
+                    t0 = time.monotonic()
+                    sum(range(200))
+                    b.record("late", t0, time.monotonic(),
+                             parent=inner.span_id)
+                    with b.laps() as lap:
+                        lap("read")
+                        lap("run")
+                a.record("held", outer.start, time.monotonic(),
+                         parent=outer.span_id)
+    finally:
+        stop.set()
+        spinner.join()
+    spans = a.export() + b.export()
+    ids = {s["spanId"]: s for s in spans}
+    assert len(spans) == 300 * 6
+    for s in spans:
+        if s["parentSpanId"] is not None:
+            assert _inside(s, ids[s["parentSpanId"]]), \
+                (s, ids[s["parentSpanId"]])
+    # a start is its monotonic reading through the process's one pair
+    for t in (a, b):
+        for sp, d in zip(t.spans, t.export()):
+            assert d["startTimeUnixNano"] == tracing.unix_ns(sp.start)
+    laps = sorted((s for s in spans if s["name"] in ("read", "run")),
+                  key=lambda s: s["startTimeUnixNano"])
+    for read, run in zip(laps[::2], laps[1::2]):
+        assert (read["name"], run["name"]) == ("read", "run")
+        assert abs(_interval(read)[1] - run["startTimeUnixNano"]) <= \
+            ROUNDING_NS
+    # `held` began when `outer` did: the same reading, the same stamp
+    for s in spans:
+        if s["name"] == "held":
+            assert s["startTimeUnixNano"] == \
+                ids[s["parentSpanId"]]["startTimeUnixNano"]
+
+
+def test_split_spans_are_compact_until_a_trace_is_read():
+    """A split loop's operator spans: nobody's context, five integers
+    each where a task ships them, ordinary span dicts where the trace
+    is read; rebased as one where they are adopted with an offset."""
+    t = Tracer(service="worker")
+    with t.span("task") as task:
+        with t.laps() as lap:
+            for i in range(2):
+                split = lap("split", index=i)
+                with t.split_span("join", task.span_id, i) as join:
+                    assert t.current_span() is split
+                    t.record("compile", time.monotonic() - 0.001,
+                             time.monotonic())
+                    with t.split_span("dynamic-filter", task.span_id, i,
+                                      depth=1):
+                        with t.span("inner"):
+                            pass
+                with t.split_span("aggregate", task.span_id, i):
+                    pass
+                assert t.current_span() is split and join[4] is not None
+    shipped = t.export(compact=True)
+    block, = [d for d in shipped if "splitSpans" in d]
+    assert len(shipped) == 1 + 2 * 3 + 1
+    assert block["parentSpanId"] == task.span_id
+    assert len(block["splitSpans"]) == 6 * 5
+    assert all(type(v) is int for v in block["splitSpans"])
+    spans = t.export()
+    assert len(spans) == 1 + 2 * 3 + 6 and \
+        len({s["spanId"] for s in spans}) == len(spans)
+    ids = {s["spanId"]: s for s in spans}
+    laps = {s["attributes"]["index"]: s for s in spans
+            if s["name"] == "split"}
+    for s in spans:
+        assert set(s) == set(spans[0]) and s["traceId"] == t.trace_id
+        if s["name"] in ("join", "aggregate", "dynamic-filter"):
+            assert list(s["attributes"]) == ["split"]
+            assert s["service"] == "worker"
+            assert _inside(s, laps[s["attributes"]["split"]])
+            up = ids[s["parentSpanId"]]
+            assert up["name"] == ("join" if s["name"] == "dynamic-filter"
+                                  else "task") and _inside(s, up)
+        elif s["name"] in ("compile", "inner"):
+            assert ids[s["parentSpanId"]]["name"] == "split"
+    # adopted (the coordinator's side, off the wire): still compact,
+    # and every span 5 s earlier, to the nanosecond
+    coord = Tracer()
+    coord.adopt(json.loads(json.dumps(shipped)), offset_s=5.0)
+    assert sum("splitSpans" in d for d in coord.export(compact=True)) == 1
+
+    def facts(exported, shift):
+        return sorted((s["name"], s["startTimeUnixNano"] + shift,
+                       s["durationMs"], s["attributes"].get("split"))
+                      for s in exported)
+
+    assert facts(coord.export(), 5 * 10**9) == facts(spans, 0)
+    # a block that skips a level (another program's) is still read
+    odd = dict(block, splitSpans=[0, 7, 2, 0, 1], names=["join"])
+    late, = tracing._expand(odd)
+    assert late["parentSpanId"] == task.span_id
+    # off: nothing is built
+    with NOOP.split_span("join", "p", 0) as none:
+        assert none is None
+    assert NOOP.export(compact=True) == []
 
 
 class CountingAnnotation:
@@ -267,13 +391,9 @@ def cluster():
     session = Session(default_schema="tiny")
     coord = CoordinatorServer(session).start()
     coord.state.scheduler.split_rows = 8192
-    # one process, one clock: the worker's offset IS 0. The estimate made
-    # at announce is minus that request's latency (8 ms seen under
-    # `-n 6`), and adopted spans are rebased by it, so the stamp is
-    # dropped here and the containment bounds below stay at 3 ms
-    # (tests/test_timeline.py holds the estimate itself)
-    announce = coord.state.announce
-    coord.state.announce = lambda *a, **kw: announce(*a, **{**kw, "now": None})
+    # one process, one clock pair: the worker's announce says so
+    # (`spanClock`), its spans are adopted with no offset, and a child
+    # lies inside its parent to the rounding of `durationMs`
     worker = WorkerServer("phase-w0", coord.uri, announce_interval_s=0.1,
                           catalog=session.catalog).start()
     deadline = time.time() + 5
@@ -284,9 +404,19 @@ def cluster():
     worker.stop()
 
 
+# what is left between two spans of one process: `durationMs` is rounded
+# to a microsecond, at either end of a comparison
+ROUNDING_NS = 2e3
+
+
 def _interval(sp):
     s0 = sp["startTimeUnixNano"]
     return s0, s0 + sp["durationMs"] * 1e6
+
+
+def _inside(inner, outer):
+    (i0, i1), (o0, o1) = _interval(inner), _interval(outer)
+    return o0 <= i0 and i1 <= o1 + ROUNDING_NS
 
 
 def _covered_ms(intervals, lo, hi):
@@ -349,19 +479,15 @@ def test_traced_q6_yields_every_phase_span(cluster):
     for s in spans:
         if s["name"] in want:
             assert parent(s)["name"] in want[s["name"]], s
-    # children inside their parents. One process, one clock: adopted
-    # worker spans (rebased by the announce offset, ~0 here) too
+    # children inside their parents. One process, one clock pair: the
+    # worker's spans, adopted with no offset here, too
     for s in spans:
         if s["parentSpanId"] in ids:
-            p0, p1 = _interval(parent(s))
-            s0, s1 = _interval(s)
-            assert p0 - 3e6 <= s0 and s1 <= p1 + 3e6, (s, parent(s))
+            assert _inside(s, parent(s)), (s, parent(s))
     stage = next(s for s in spans if s["name"] == "source-stage")
-    lo, hi = _interval(stage)
     for s in spans:
         if s["name"] in SPLIT_PHASES:
-            s0, s1 = _interval(s)
-            assert lo - 3e6 <= s0 and s1 <= hi + 3e6, s
+            assert _inside(s, stage), s
     # five spans a split
     n_splits = stage["attributes"]["splits"]
     for n in SPLIT_PHASES:
@@ -436,6 +562,169 @@ def test_tracing_alone_does_not_fence(cluster):
     task = next(s for s in spans if s["name"] == "worker-task")
     assert task["attributes"]["deviceMs"] >= 0
     assert "hostMs" in task["attributes"]
+
+
+# ---------------------------------------------------------------------------
+# inside a split: operator spans beside the `split` lap, and its dispatches
+# ---------------------------------------------------------------------------
+
+OPERATORS = ("filter-project", "join", "aggregate", "sort", "dynamic-filter")
+
+
+def _split_operators(spans):
+    """{(worker-task id, split index): [top-level operator spans]} and
+    the `split` laps under the same keys."""
+    ops, laps = {}, {}
+    for s in spans:
+        if s["name"] == "split":
+            laps[(s["parentSpanId"], s["attributes"]["index"])] = s
+        elif s["name"] in OPERATORS and "split" in s["attributes"]:
+            ops.setdefault((s["parentSpanId"], s["attributes"]["split"]),
+                           []).append(s)
+    return ops, laps
+
+
+@pytest.mark.parametrize("profiling", [False, True],
+                         ids=["tracing-alone", "fenced"])
+def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling):
+    coord, worker, session = cluster
+    sql = orders_join("1996-02-1" + str(int(profiling)))
+    spans, _, _ = _traced(coord, sql, profiling=profiling)
+    ids = {s["spanId"]: s for s in spans}
+    ops, laps = _split_operators(spans)
+    task, pin = _join_task(spans)
+    mine = {k: v for k, v in laps.items() if k[0] == task["spanId"]}
+    assert sorted(i for _, i in mine) == list(range(8))
+    for key, lap in laps.items():
+        # every split of every task, the probing task's with its join
+        names = [s["name"] for s in sorted(ops[key], key=_interval)]
+        assert names == (["join", "filter-project", "aggregate"]
+                         if key in mine else ["filter-project"]), \
+            (key, names)
+        for s in ops[key]:
+            # under the task, beside the lap, inside it on the clock,
+            # and saying which split and nothing else
+            assert ids[s["parentSpanId"]]["name"] == "worker-task"
+            assert s["attributes"] == {"split": key[1]}
+            assert _inside(s, lap), (s, lap)
+        own = sorted(_interval(s) for s in ops[key])
+        for (_, end), (start, _) in zip(own, own[1:]):
+            assert start >= end - ROUNDING_NS      # each its own wall
+    # the eager ops on the build's key range: inside their join
+    filters = [s for s in spans if s["name"] == "dynamic-filter"]
+    assert len(filters) == 8
+    for s in filters:
+        join = ids[s["parentSpanId"]]
+        assert join["name"] == "join" and _inside(s, join)
+        assert s["attributes"] == join["attributes"]
+    # the lap keeps what it had: `compile` under `split`, never an
+    # operator's child; no operator span is a lap's child
+    taken = {s["spanId"] for v in ops.values() for s in v} | \
+        {s["spanId"] for s in filters}
+    assert not [s for s in spans if s["parentSpanId"] in taken
+                and s["name"] != "dynamic-filter"]
+    lap_ids = {s["spanId"] for s in laps.values()}
+    assert {s["name"] for s in spans
+            if s["parentSpanId"] in lap_ids} <= {"compile"}
+    ex = worker.task_manager._executor
+    assert (ex._operator_spans, ex._operator_split,
+            ex._open_operators) == (False, None, [])
+
+
+def test_a_compile_in_an_operator_still_hangs_under_the_split(cluster):
+    coord, worker, session = cluster
+    # an IN list's length is shape proper: this filter compiles
+    spans, before, after = _traced(
+        coord, q6("24") + " AND l_linenumber IN (1, 2, 3, 5, 6)")
+    assert after["compiles"] > before["compiles"]
+    ids = {s["spanId"]: s for s in spans}
+    ops, laps = _split_operators(spans)
+    inside_an_operator = 0
+    for c in (s for s in spans if s["name"] == "compile"):
+        lap = ids[c["parentSpanId"]]
+        if lap["name"] != "split":
+            continue
+        key = (lap["parentSpanId"], lap["attributes"]["index"])
+        inside_an_operator += any(_inside(c, op) for op in ops[key])
+    assert inside_an_operator >= 1
+
+
+def test_dispatches_is_the_recorders_call_delta_of_the_split(
+        cluster, monkeypatch):
+    coord, worker, session = cluster
+    calls = []
+    record = RECORDER.record
+
+    def spy(*args, **kwargs):
+        calls.append(tracing.unix_ns(time.monotonic()))
+        return record(*args, **kwargs)
+
+    monkeypatch.setattr(RECORDER, "record", spy)
+    spans, before, after = _traced(coord, orders_join("1996-03-05"))
+    counted = [s for s in spans if s["name"] == "split"]
+    assert len(counted) == 10
+    for s in counted:
+        lo, hi = _interval(s)
+        assert s["attributes"]["dispatches"] == \
+            sum(lo <= at <= hi + ROUNDING_NS for at in calls), s
+    # a probing split dispatches its join, its aggregate and the
+    # expression under them; the task's first builds the LUT besides
+    task, _ = _join_task(spans)
+    probing = sorted((s for s in counted
+                      if s["parentSpanId"] == task["spanId"]),
+                     key=lambda s: s["attributes"]["index"])
+    per_split = [s["attributes"]["dispatches"] for s in probing]
+    assert per_split[0] > per_split[1] >= 2
+    assert len(set(per_split[1:])) == 1
+    # hits and misses: all of the statement's, the coordinator's too
+    total = (after["hits"] + after["compiles"]) - \
+        (before["hits"] + before["compiles"])
+    assert len(calls) == total
+    assert sum(s["attributes"]["dispatches"] for s in counted) <= total
+    # a count, nothing else: no event, no lock taken for it
+    rec = CompileRecorder()
+    assert rec.thread_calls() == 0
+    rec.record("site", "fp", 0.0, True)
+    rec.record("site", "fp", 0.1, False)
+    assert rec.thread_calls() == 2
+    seen = []
+    th = threading.Thread(target=lambda: seen.append(rec.thread_calls()))
+    th.start()
+    th.join()
+    assert seen == [0]                  # per thread
+
+
+def test_tracing_off_a_task_builds_no_operator_span(cluster, monkeypatch):
+    import jax.profiler
+    coord, worker, session = cluster
+    CountingAnnotation.names = []
+    built = []
+
+    class CountingSpan(tracing.Span):
+        def __init__(self, *args, **kwargs):
+            built.append(args[0])
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    monkeypatch.setattr(tracing, "Span", CountingSpan)
+    coord.state.scheduler.spool.clear()
+    client = Client(coord.uri, user="phases")
+    res = client.execute(orders_join("1996-04-07"))
+    info = client.query_info(res.query_id)
+    assert info["distributed"] and info["stageStats"]["tasks"] >= 2
+    assert built == [] and CountingAnnotation.names == []
+    ex = worker.task_manager._executor
+    assert ex._operator_spans is False and ex._operator_split is None
+    ex.operator_span("join")
+    assert ex._open_operators == [] and built == []
+    # and on: each of a task's operator spans has its `tt:` twin
+    spans, _, _ = _traced(coord, orders_join("1996-04-08"))
+    for name in ("join", "aggregate", "filter-project", "dynamic-filter"):
+        n = sum(s["name"] == name for s in spans)
+        assert n >= 8 and CountingAnnotation.names.count("tt:" + name) == n
+    # in the split loop without a `Span` each: a row of five numbers
+    assert not set(built) & {"join", "aggregate", "filter-project",
+                             "dynamic-filter"}
 
 
 # ---------------------------------------------------------------------------

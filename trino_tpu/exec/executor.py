@@ -133,12 +133,18 @@ class Executor:
         # host-to-device bytes of this statement's scans (`scanPutBytes`
         # on the `execute` span)
         self.scan_put_bytes = 0
-        # operator spans (`aggregate`, `join`, `sort`) of a traced
-        # statement that runs whole on this executor: on only inside
-        # execute(), so a worker's split loop opens none; the stack
-        # holds (context manager, span) of those still open
+        # operator spans (`filter-project`, `aggregate`, `join`, `sort`,
+        # `dynamic-filter`) of a traced statement: on inside execute()
+        # and while a worker's traced task pins its builds and runs its
+        # splits (server/tasks.py); the stack holds (context manager,
+        # span) of those still open
         self._operator_spans = False
         self._open_operators: List[tuple] = []
+        # in a worker's split loop: (the `worker-task` span's id, the
+        # split's index). The spans then hang under the task, BESIDE the
+        # `split` lap (whose self time and `compile` children stay what
+        # they were), and carry `split` and nothing else
+        self._operator_split: Optional[tuple] = None
         self._scalar_cache: Dict[object, object] = {}
         self.stats = ExecStats()
         self.profile = False           # EXPLAIN ANALYZE per-node timing
@@ -374,12 +380,6 @@ class Executor:
             self._operator_spans = False
             self.save_decisions()
 
-    # TRINO_TPU_TRACE_NODES=1 prints per-node dispatch timings to stderr
-    # (async dispatch time; sync waits inside a node attribute to it) —
-    # the printf tier of EXPLAIN ANALYZE, usable when a query never
-    # finishes
-    TRACE = bool(os.environ.get("TRINO_TPU_TRACE_NODES"))
-
     def run(self, node: L.PlanNode) -> Batch:
         # bind this executor's stats to the dispatch thread so the
         # compile recorder attributes fresh XLA compiles here
@@ -445,15 +445,28 @@ class Executor:
         fence, so under `enable_profiling` that wall holds the
         operator's device time; with tracing alone it is the dispatch
         and whatever the operator fetched. A no-op unless a traced
-        statement runs whole on this executor (`execute`)."""
-        if self._operator_spans:
+        statement runs whole on this executor (`execute`) or a traced
+        worker task runs (`_operator_split` says how its split loop's
+        spans differ)."""
+        if not self._operator_spans:
+            return
+        if self._operator_split is None:
             cm = tracing.current().span(name, **{
                 k: v for k, v in attributes.items() if v is not None})
-            self._open_operators.append((cm, cm.__enter__()))
+        else:
+            parent, index = self._operator_split
+            cm = tracing.current().split_span(
+                name, parent, index, depth=len(self._open_operators))
+        self._open_operators.append((cm, cm.__enter__()))
+
+    def _close_operators(self, depth: int) -> None:
+        while len(self._open_operators) > depth:
+            self._open_operators.pop()[0].__exit__(None, None, None)
 
     def stamp_operator(self, **attributes) -> None:
-        """Attributes for the innermost open operator span, if any."""
-        if self._open_operators:
+        """Attributes for the innermost open operator span, if any (none
+        in a split loop: hundreds of splits say the same)."""
+        if self._open_operators and self._operator_split is None:
             self._open_operators[-1][1].attributes.update(attributes)
 
     def _known_rows(self, node: L.PlanNode) -> Optional[int]:
@@ -467,21 +480,10 @@ class Executor:
         try:
             return self._dispatch_timed(node)
         finally:
-            while len(self._open_operators) > depth:
-                self._open_operators.pop()[0].__exit__(None, None, None)
+            self._close_operators(depth)
 
     def _dispatch_timed(self, node: L.PlanNode) -> Batch:
-        if self.TRACE:
-            import sys
-            import time as _t
-            t0 = _t.monotonic()
-            print(f"[trace] > {type(node).__name__}", file=sys.stderr,
-                  flush=True)
-            out = self.dispatch(node)
-            print(f"[trace] < {type(node).__name__} "
-                  f"{_t.monotonic() - t0:.1f}s", file=sys.stderr,
-                  flush=True)
-        elif self.profile:
+        if self.profile:
             import time
             from .profiler import RECORDER
             c0 = RECORDER.thread_compile_seconds()
@@ -723,9 +725,11 @@ class Executor:
                 (pred, exprs), values = self.bound_exprs(
                     node, node.predicate, node.child.exprs)
                 child = self.run(node.child.child)
+                self.operator_span("filter-project")
                 return filter_project_fused(child, values, exprs, pred)
-            return apply_filter(self.run(node.child),
-                                self.fold_scalars(node.predicate))
+            child = self.run(node.child)
+            self.operator_span("filter-project")
+            return apply_filter(child, self.fold_scalars(node.predicate))
         if isinstance(node, L.ProjectNode):
             if isinstance(node.child, L.FilterNode):
                 (pred, exprs), values = self.bound_exprs(
@@ -735,6 +739,7 @@ class Executor:
                 (pred, exprs), values = self.bound_exprs(
                     node, None, node.exprs)
                 child = self.run(node.child)
+            self.operator_span("filter-project")
             return filter_project(child, values, pred, exprs)
         if isinstance(node, L.AggregateNode):
             return self.run_aggregate(node)
@@ -1712,6 +1717,17 @@ class Executor:
             return probe
         if node.kind in ("anti", "left", "mark") or node.null_aware:
             return probe
+        # eager ops on the build's key range, in every split of a
+        # worker's task: a span of their own inside `join`
+        depth = len(self._open_operators)
+        self.operator_span("dynamic-filter")
+        try:
+            return self._dynamic_filter(node, probe, build)
+        finally:
+            self._close_operators(depth)
+
+    def _dynamic_filter(self, node: L.JoinNode, probe: Batch,
+                        build: Batch) -> Batch:
         for pk_i, bk_i in zip(node.left_keys, node.right_keys):
             bk = build.columns[bk_i]
             m = build.live & bk.valid

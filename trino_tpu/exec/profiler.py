@@ -25,6 +25,7 @@ compile duration, into:
   compiles to the executor whose dispatch triggered them),
 - a per-thread compile-seconds accumulator the profiled dispatch path
   reads to split operator wall into device/host/compile components,
+  and beside it a per-thread count of recorded calls (`thread_calls`),
 - a `compile` span of the thread's active tracer (utils/tracing.py), so
   a traced query shows each compile inside the span that paid for it.
 
@@ -142,6 +143,13 @@ class CompileRecorder:
         the compile component of an operator's wall."""
         return getattr(self._tl, "compile_s", 0.0)
 
+    def thread_calls(self) -> int:
+        """Recorded calls made on this thread so far, hits and misses:
+        the programs it dispatched through `recorded_jit`. A split loop
+        diffs this around `ex.run(root)` (`dispatches` on the `split`
+        span, server/tasks.py)."""
+        return getattr(self._tl, "calls", 0)
+
     @contextmanager
     def site_context(self, prefix: str):
         """Prefix every site recorded on this thread inside the block —
@@ -176,7 +184,9 @@ class CompileRecorder:
         None where the caller did not compute one (a hit under a fixed
         fingerprint: nothing to learn; a miss: the fingerprint stands
         in)."""
-        prefix = getattr(self._tl, "site_prefix", None)
+        tl = self._tl
+        tl.calls = getattr(tl, "calls", 0) + 1
+        prefix = getattr(tl, "site_prefix", None)
         if prefix:
             site = f"{prefix}:{site}"
         if shape is None and not hit:

@@ -145,10 +145,15 @@ class RegisteredNode:
         # (system.runtime.nodes surface)
         self.device: Optional[dict] = None
         # estimated clock skew (worker clock minus coordinator clock),
-        # refreshed from the `now` stamp each announce carries; adopted
-        # worker spans are rebased by it so stitched-trace intervals
-        # cannot go negative under skewed wall clocks
+        # refreshed from the `now` stamp each announce carries: a task's
+        # deadline is shipped on the worker's wall clock through it
         self.clock_offset: float = 0.0
+        # the same for the clock SPANS are stamped on (each process's
+        # one clock pair, utils/tracing.py), from the announce's
+        # `spanClock`; exactly 0 for a worker of this process. Adopted
+        # worker spans are rebased by it, so stitched-trace intervals
+        # cannot go negative under skewed clocks
+        self.span_offset: float = 0.0
         # live task inventory from the last announce ([{taskId, state}])
         # — a promoted coordinator reconciles the ledger against this
         # before deciding re-attach vs re-execute
@@ -667,7 +672,9 @@ class Dispatcher:
         for it is a span of its own (`exec-lock-wait`, with `ahead`:
         the statements that had asked before and not yet released), and
         so is the time the statement then holds it (`exec-lock-held`, a
-        sibling recorded after the release, so it is nobody's parent)."""
+        sibling recorded after the release, so it is nobody's parent;
+        its end is read BEFORE the release, so two statements' holds
+        never overlap on the spans' clock)."""
         from ..utils import tracing
         tracer = tracing.current()
         with tracer.span("exec-lock-wait") as wait:
@@ -678,8 +685,9 @@ class Dispatcher:
         try:
             yield
         finally:
+            t_released = time.monotonic()
             self.exec_lock.release()
-            tracer.record("exec-lock-held", t_held, time.monotonic())
+            tracer.record("exec-lock-held", t_held, t_released)
 
     def _execute_attempt(self, tq: TrackedQuery) -> None:
         """One execution attempt under the exec lock: cluster path first,
@@ -1114,6 +1122,7 @@ class CoordinatorState:
     def announce(self, node_id: str, uri: str,
                  state: str = "ACTIVE",
                  now: Optional[float] = None,
+                 span_clock: Optional[list] = None,
                  tasks: Optional[list] = None,
                  live_stats: Optional[dict] = None,
                  memory: Optional[dict] = None) -> None:
@@ -1138,12 +1147,24 @@ class CoordinatorState:
         # clock-skew estimate: the worker stamped `now` at send time and
         # we read our clock at receive time — the send/recv midpoint of a
         # sub-millisecond local POST, so offset ≈ worker_clock - ours.
-        # Adopted worker spans are rebased by it (utils/tracing.py).
         offset = (now - time.time()) if now is not None else None
+        # the same on the clock spans are stamped on, by which adopted
+        # worker spans are rebased (utils/tracing.py): the worker sent
+        # what its clock pair read; a worker on THIS process's pair is
+        # off by nothing, whatever the request took
+        span_offset = offset
+        if span_clock is not None:
+            from ..utils import tracing
+            clock_id, sent_ns = span_clock
+            span_offset = 0.0 if clock_id == tracing.CLOCK_ID else \
+                (sent_ns - tracing.unix_ns(time.monotonic())) / 1e9
         with self.nodes_lock:
             node = self.nodes.get(node_id)
-            if offset is not None and node is not None and state != "LEFT":
-                node.clock_offset = offset
+            if node is not None and state != "LEFT":
+                if offset is not None:
+                    node.clock_offset = offset
+                if span_offset is not None:
+                    node.span_offset = span_offset
             if state == "LEFT":
                 if node is not None:
                     del self.nodes[node_id]
@@ -1154,6 +1175,8 @@ class CoordinatorState:
                     state if state in ("DRAINING", "DRAINED") else "ACTIVE"
                 if offset is not None:
                     self.nodes[node_id].clock_offset = offset
+                if span_offset is not None:
+                    self.nodes[node_id].span_offset = span_offset
                 changed = True
                 state = self.nodes[node_id].state
             else:
@@ -1406,6 +1429,7 @@ class _Handler(BaseHTTPRequestHandler):
                     body.get("uri", ""),
                     state=body.get("state", "ACTIVE"),
                     now=body.get("now"),
+                    span_clock=body.get("spanClock"),
                     tasks=body.get("tasks"),
                     live_stats=body.get("liveStats"),
                     memory=body.get("memory"))

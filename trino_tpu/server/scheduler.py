@@ -596,11 +596,11 @@ class StageScheduler:
                     acc["compile_ms"] += float(d.get("compileMs", 0.0))
                     if d.get("strategy"):
                         acc["strategy"] = d["strategy"]
-        # rebase the worker's span stamps onto the coordinator clock
-        # using the offset estimated at announce (skew satellite)
+        # rebase the worker's span stamps onto the coordinator's span
+        # clock by the offset measured at announce (0 in one process)
         self._tracer().adopt(
             st.get("spans") or [],
-            offset_s=getattr(task.node, "clock_offset", 0.0))
+            offset_s=getattr(task.node, "span_offset", 0.0))
 
     # -- eligibility + planning -------------------------------------------
 

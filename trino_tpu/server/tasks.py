@@ -30,6 +30,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..exec.profiler import RECORDER
 from ..exec.spill import PartialState
 from ..metrics import TASK_OUTPUT_BYTES, TASK_OUTPUT_ROWS
 from ..utils import tracing
@@ -738,7 +739,8 @@ class TaskManager:
             if task.manifest is not None:
                 task.stats["manifest"] = task.manifest
             if tracer.enabled:
-                task.spans = tracer.export()
+                # the split loop's operator spans in their compact form
+                task.spans = tracer.export(compact=True)
         self._executor.flush_metrics()
 
     def _run_splits(self, task: WorkerTask, ex, root, driver_scan,
@@ -798,10 +800,20 @@ class TaskManager:
             ex._subst[id(driver_scan)] = chunk
             ex._subst_opaque.add(id(driver_scan))
             sp_t0 = time.monotonic()
-            lap("split", index=si, rows=split.count)
+            sp = lap("split", index=si, rows=split.count)
+            if sp is not None:
+                # the operators' spans hang beside this lap, under its
+                # parent (`worker-task`), and say which split they are
+                ex._operator_split = (sp.parent_id, si)
+                calls0 = RECORDER.thread_calls()
             try:
                 out = ex.run(root)
             finally:
+                if sp is not None:
+                    ex._operator_split = None
+                    # programs this split dispatched, cached or not
+                    sp.attributes["dispatches"] = \
+                        RECORDER.thread_calls() - calls0
                 ex._subst.pop(id(driver_scan), None)
                 ex._subst_opaque.discard(id(driver_scan))
                 # per-split outputs die here; pinned builds keep their
@@ -947,6 +959,9 @@ class TaskManager:
                 if profiling:
                     ex.profile = True
                     ex.node_stats = {}
+                # a traced task's operators get spans, as a traced
+                # statement's do in Executor.execute
+                ex._operator_spans = tracer.enabled
                 # the coordinator says when the root is the stage's merge
                 # aggregate; a partition spec routes rows by key, so
                 # those tasks keep a page a split
@@ -985,6 +1000,7 @@ class TaskManager:
                         self._emit_held(task, tracer, held)
                 finally:
                     ex.exit_chunk_mode()
+                    ex._operator_spans = False
                     ex.profile = saved_profile
                     ex.node_stats = saved_node_stats
                     ex.deadline = None

@@ -27,6 +27,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.request import Request, urlopen
 
+from ..utils import tracing
 from .routes import STAR, dispatch, register_routes
 
 SERVER_NAME = "worker"
@@ -465,6 +466,10 @@ class WorkerServer:
                    "uri": self.uri,
                    "state": state or self.state,
                    "now": time.time(),
+                   # the clock this node's spans are stamped on: which
+                   # pair, and what it reads now (utils/tracing.py)
+                   "spanClock": [tracing.CLOCK_ID,
+                                 tracing.unix_ns(time.monotonic())],
                    "tasks": self.task_manager.inventory()}
             if hb is not None:
                 doc["liveStats"] = hb

@@ -24,6 +24,23 @@ with `use(tracer, parent=span_id)`. While a tracer is enabled every live
 span is also a `jax.profiler.TraceAnnotation("tt:<name>")`, so a JAX
 profiler trace taken meanwhile shows the program's spans on the
 profiler's own clock, above the device's operations.
+
+One clock pair a process: a span is timed on `time.monotonic()` and its
+exported `startTimeUnixNano` is that reading carried onto the wall clock
+through ONE `(time.time_ns(), time.monotonic())` pair read at import
+(`unix_ns`). Spans of one process therefore nest and touch on the
+exported clock exactly as they did on the monotonic one, whichever
+tracer or thread made them, to the microsecond `durationMs` is rounded
+to. Spans of another process come through `adopt(offset_s)`: the offset
+between the two processes' pairs, which a worker's announce measures
+(`CLOCK_ID` tells the coordinator a worker of its own process: 0).
+
+Spans of a task's split loop (`split_span`: an operator's wall inside
+one split, hundreds a task, each saying which split and nothing else)
+are kept and shipped as five integers each (`export(compact=True)`, one
+block a parent span) and become span dicts only where a trace is read
+(`export()`): as dicts they were 40% of a task's status JSON and 7 ms of
+a 240-split stage's hand-over.
 """
 
 from __future__ import annotations
@@ -36,6 +53,15 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 _ROOT_SPAN_ID = "0" * 16
+# the process's one clock pair (see above): a pair read per span puts a
+# thread switch between its two reads, and with it milliseconds between
+# a span and its own parent
+_UNIX0_NS, _MONO0 = time.time_ns(), time.monotonic()
+# names the pair: two tracers that see the same id stamp on one clock
+CLOCK_ID = os.urandom(8).hex()
+# integers a `split_span` is shipped as: index into the block's names,
+# split, depth, start (ns after the block's), duration (us)
+_ROW = 5
 # profiler annotations of the program's spans; `bench:` belongs to the
 # benchmark's anchors (benchmark/trace_reduce.py)
 ANNOTATION_PREFIX = "tt:"
@@ -47,6 +73,12 @@ def new_trace_id() -> str:
 
 def new_span_id() -> str:
     return os.urandom(8).hex()
+
+
+def unix_ns(mono: float) -> int:
+    """A `time.monotonic()` reading of this process on the wall clock,
+    in ns, through the process's clock pair."""
+    return _UNIX0_NS + round((mono - _MONO0) * 1e9)
 
 
 def format_traceparent(trace_id: str, span_id: str) -> str:
@@ -79,7 +111,6 @@ class Span:
     # spans, so a name link is ambiguous); None = trace root
     parent_id: Optional[str] = None
     service: str = "trino-tpu"
-    start_unix: float = 0.0            # time.time() at start
 
     @property
     def duration_ms(self) -> float:
@@ -91,7 +122,7 @@ class Span:
                 "spanId": self.span_id,
                 "parentSpanId": self.parent_id,
                 "service": self.service,
-                "startTimeUnixNano": int(self.start_unix * 1e9),
+                "startTimeUnixNano": unix_ns(self.start),
                 "durationMs": round(self.duration_ms, 3),
                 "attributes": self.attributes}
 
@@ -115,6 +146,9 @@ class Tracer:
         self.remote_parent = parent_span_id
         self.service = service
         self.spans: List[Span] = []
+        # split-loop spans by parent span: [name, split, depth, start,
+        # end] in the order they opened (a parent before its children)
+        self._split_spans: Dict[str, List[list]] = {}
         self._foreign: List[dict] = []
         self._local = threading.local()
         self._lock = threading.Lock()
@@ -170,7 +204,7 @@ class Tracer:
         s = Span(name, time.monotonic(), attributes=attributes,
                  trace_id=self.trace_id, span_id=new_span_id(),
                  parent_id=parent or self._context_parent(),
-                 service=self.service, start_unix=time.time())
+                 service=self.service)
         stack = self._stack()
         stack.append(s)
         try:
@@ -182,6 +216,27 @@ class Tracer:
             with self._lock:
                 self.spans.append(s)
 
+    @contextmanager
+    def split_span(self, name: str, parent: str, split: int,
+                   depth: int = 0):
+        """A live span of a task's split loop, in the compact form (see
+        the module's docstring): a child of `parent` (depth 0) or of the
+        split span last opened one level up, with `split` its one
+        attribute. It is not the thread's context: what opens or is
+        recorded meanwhile hangs where it would have (an operator's
+        span lies BESIDE the `split` lap it runs in)."""
+        if not self.enabled:
+            yield None
+            return
+        row = [name, split, depth, time.monotonic(), None]
+        with self._lock:
+            self._split_spans.setdefault(parent, []).append(row)
+        try:
+            with _annotation(name):
+                yield row
+        finally:
+            row[4] = time.monotonic()
+
     def record(self, name: str, start: float, end: float,
                parent: Optional[str] = None, **attributes) -> None:
         """A span known only after the fact (a call turned out to have
@@ -191,8 +246,7 @@ class Tracer:
         s = Span(name, start, end, attributes=attributes,
                  trace_id=self.trace_id, span_id=new_span_id(),
                  parent_id=parent or self._context_parent(),
-                 service=self.service,
-                 start_unix=time.time() - (time.monotonic() - start))
+                 service=self.service)
         with self._lock:
             self.spans.append(s)
 
@@ -210,7 +264,6 @@ class Tracer:
             return
         stack = self._stack()
         parent = self._context_parent()
-        unix0, mono0 = time.time(), time.monotonic()
         current = None                 # (span, its annotation)
 
         def close(now: float) -> None:
@@ -230,8 +283,7 @@ class Tracer:
             close(now)
             s = Span(name, now, attributes=attributes,
                      trace_id=self.trace_id, span_id=new_span_id(),
-                     parent_id=parent, service=self.service,
-                     start_unix=unix0 + (now - mono0))
+                     parent_id=parent, service=self.service)
             stack.append(s)
             note = _annotation(name)
             note.__enter__()
@@ -249,36 +301,97 @@ class Tracer:
         too — a mis-stitched span is more diagnosable than a dropped
         one.
 
-        `offset_s` is the remote node's estimated clock offset (remote
-        clock minus local clock, measured at announce time): remote
-        `startTimeUnixNano` stamps are rebased onto the local clock so
-        cross-node timeline intervals cannot go negative when a worker's
-        wall clock is skewed. Spans are copied, not mutated in place."""
+        `offset_s` is the remote process's span clock minus this one's
+        (both through their clock pairs, measured at announce time; 0
+        for a node of this process): remote `startTimeUnixNano` stamps
+        are rebased onto the local clock so cross-node timeline
+        intervals cannot go negative when a worker's wall clock is
+        skewed. A block of split-loop spans is rebased as one span.
+        Spans are copied, not mutated in place."""
         if not self.enabled or not span_dicts:
             return
-        adopted = []
+        adopted, offset_ns = [], round(offset_s * 1e9)
         for d in span_dicts:
             if not isinstance(d, dict):
                 continue
-            if offset_s and "startTimeUnixNano" in d:
+            if offset_ns and "startTimeUnixNano" in d:
                 d = dict(d)
-                d["startTimeUnixNano"] = int(
-                    d["startTimeUnixNano"] - offset_s * 1e9)
+                d["startTimeUnixNano"] = \
+                    int(d["startTimeUnixNano"]) - offset_ns
             adopted.append(d)
         with self._lock:
             self._foreign.extend(adopted)
 
-    def export(self) -> List[dict]:
+    def _split_blocks(self) -> List[dict]:
+        """This tracer's split-loop spans as shipped: one block a
+        parent span, `_ROW` integers a span."""
+        blocks = []
+        for parent, rows in self._split_spans.items():
+            names = sorted({r[0] for r in rows})
+            t0 = unix_ns(rows[0][3])
+            flat = []
+            for name, split, depth, start, end in rows:
+                flat += (names.index(name), split, depth,
+                         unix_ns(start) - t0,
+                         round(((end or start) - start) * 1e6))
+            blocks.append({"name": "split-spans",
+                           "splitSpans": flat, "names": names,
+                           "traceId": self.trace_id,
+                           "spanId": new_span_id(),
+                           "parentSpanId": parent,
+                           "service": self.service,
+                           "startTimeUnixNano": t0})
+        return blocks
+
+    def export(self, compact: bool = False) -> List[dict]:
+        """The trace's spans as dicts. `compact` leaves split-loop
+        spans in their blocks (what a task ships; `adopt` takes them)."""
         with self._lock:
-            return [s.to_dict() for s in self.spans] + list(self._foreign)
+            out = [s.to_dict() for s in self.spans] + \
+                self._split_blocks() + list(self._foreign)
+        if compact:
+            return out
+        spans = []
+        for d in out:
+            if "splitSpans" in d:
+                spans.extend(_expand(d))
+            else:
+                spans.append(d)
+        return spans
 
     def clear(self) -> None:
         with self._lock:
             self.spans.clear()
+            self._split_spans.clear()
             self._foreign.clear()
 
 
 NOOP = Tracer(enabled=False)
+
+
+def _expand(block: dict) -> List[dict]:
+    """A block of split-loop spans as span dicts. Ids follow the
+    block's own, one a row; a row's parent is the row last seen one
+    level up (the deepest there is, should a block skip a level), the
+    block's parent at depth 0."""
+    flat, names = block["splitSpans"], block["names"]
+    id0, t0 = int(block["spanId"], 16), block["startTimeUnixNano"]
+    parents, spans = [block["parentSpanId"]], []
+    for i in range(0, len(flat), _ROW):
+        name, split, depth, start, micros = flat[i:i + _ROW]
+        span_id = f"{(id0 + i // _ROW) % (1 << 64):016x}"
+        depth = min(depth, len(parents) - 1)
+        del parents[depth + 1:]
+        spans.append({"name": names[name],
+                      "traceId": block["traceId"],
+                      "spanId": span_id,
+                      "parentSpanId": parents[depth],
+                      "service": block["service"],
+                      "startTimeUnixNano": t0 + start,
+                      "durationMs": micros / 1000,
+                      "attributes": {"split": split}})
+        parents.append(span_id)
+    return spans
 
 
 def _annotation(name: str):
