@@ -293,6 +293,9 @@ def test_boot_replay_resumes_killed_query(tmp_path, upto, mode):
         while not tq.state_machine.is_done() and time.time() < deadline:
             time.sleep(0.02)
         assert tq.state == "FINISHED"
+        # `is_done` turns true before the terminal listeners (the
+        # ledger's record among them) have run; `settled` is set after
+        assert tq.state_machine.settled.wait(10)
         assert [list(r) for r in tq.result.rows] == EXPECT
         # the resumed run's ledger records landed under the new epoch
         view, _ = coord.state.ledger.replay()

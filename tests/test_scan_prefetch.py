@@ -2,7 +2,7 @@
 
 exec/chunked.py overlaps host decode+stage of chunk k+1 with device
 compute of chunk k through a bounded double-buffered worker
-(_PrefetchPipeline). The contracts under test:
+(exec/prefetch.py PrefetchPipeline). The contracts under test:
 
 - prefetch_depth=0 recovers the serial loop exactly (bit-exact rows);
 - staged buffers are REVOCABLE memory-pool reservations tagged
@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from trino_tpu.batch import batch_from_numpy
-from trino_tpu.exec.chunked import _PrefetchPipeline
+from trino_tpu.exec.prefetch import PrefetchPipeline
 from trino_tpu.exec.session import Session
 from trino_tpu.server.failureinjector import (RAISE, SCAN_PREFETCH,
                                               FailureInjector,
@@ -83,7 +83,7 @@ def test_staged_buffers_revocable_under_pressure(session):
         return batch_from_numpy([np.arange(start, start + 8,
                                            dtype=np.int64)])
 
-    pipe = _PrefetchPipeline(ex, starts, decode, depth=len(starts))
+    pipe = PrefetchPipeline(ex, starts, decode, depth=len(starts))
     try:
         deadline = time.time() + 5
         while len(pipe._staged) < len(starts) and time.time() < deadline:

@@ -184,27 +184,40 @@ def live_first_order(mask: jax.Array, new_capacity: int) -> jax.Array:
 def batch_from_numpy(arrays: Sequence[np.ndarray],
                      valids: Optional[Sequence[Optional[np.ndarray]]] = None,
                      capacity: Optional[int] = None,
-                     pad_multiple: int = 1024) -> Batch:
-    """Build a device Batch from host numpy columns, padding to capacity."""
+                     pad_multiple: int = 1024,
+                     live: Optional[jax.Array] = None) -> Batch:
+    """Build a device Batch from host numpy columns, padding to capacity.
+
+    One transfer call a batch. A column without a null mask takes the
+    batch's `live` as its `valid` (the same device array: both are
+    `arange(capacity) < n`), so only a column that has nulls sends a
+    mask. `live`, where given, is that mask already on the device (a
+    batch of the same `capacity` and `n` put before): then no mask is
+    sent at all. The pad is a host copy into zeroed arrays."""
     n = len(arrays[0]) if len(arrays) else 0
     for a in arrays:
         assert len(a) == n, "ragged columns"
     cap = capacity if capacity is not None else pad_capacity(n, pad_multiple)
     assert cap >= n
-    cols = []
-    for i, a in enumerate(arrays):
-        a = np.asarray(a)
-        data = np.zeros(cap, dtype=a.dtype)
-        data[:n] = a
-        v = np.zeros(cap, dtype=np.bool_)
-        if valids is not None and valids[i] is not None:
-            v[:n] = valids[i]
-        else:
-            v[:n] = True
-        cols.append(Column(data=jnp.asarray(data), valid=jnp.asarray(v)))
-    live = np.zeros(cap, dtype=np.bool_)
-    live[:n] = True
-    return Batch(columns=tuple(cols), live=jnp.asarray(live))
+
+    def padded(a, dtype=None) -> np.ndarray:
+        a = np.asarray(a, dtype=dtype)
+        out = np.zeros(cap, dtype=a.dtype)
+        out[:n] = a
+        return out
+
+    host = [padded(a) for a in arrays]
+    masked = [i for i in range(len(arrays))
+              if valids is not None and valids[i] is not None]
+    host += [padded(valids[i], np.bool_) for i in masked]
+    if live is None:
+        host.append(padded(True, np.bool_))
+    put = jax.device_put(host)
+    if live is None:
+        live = put.pop()
+    own = dict(zip(masked, put[len(arrays):]))
+    return Batch(columns=tuple(Column(data=put[i], valid=own.get(i, live))
+                               for i in range(len(arrays))), live=live)
 
 
 def batch_to_numpy(batch: Batch) -> tuple:

@@ -34,7 +34,6 @@ BUILD side fall back to single-shot execution.
 from __future__ import annotations
 
 import functools
-import queue
 import threading
 import time
 from typing import Dict, List, Optional
@@ -46,6 +45,7 @@ import numpy as np
 from ..batch import (Batch, Column, batch_from_numpy, batch_to_numpy,
                      bucket_capacity)
 from ..planner import logical as L
+from .prefetch import PrefetchPipeline
 from .profiler import instrument, recorded_jit
 
 
@@ -579,131 +579,6 @@ from .device_cache import prof as _prof
 from .device_cache import profile_enabled as _profile_enabled
 
 
-class _PrefetchPipeline:
-    """Bounded double-buffered decode->stage pipeline for the chunked
-    driver ("Revisiting Co-Processing..." overlap, PAPERS.md): a worker
-    thread decodes chunk k+1 from host columns and stages its device
-    transfer (batch_from_numpy -> jnp.asarray, the same
-    device_cache-warmed path) while the device computes chunk k.
-
-    Every staged chunk holds a REVOCABLE reservation in the memory pool,
-    so arbitration/backpressure see the prefetch buffer and can reclaim
-    it under pressure: a revoked chunk is simply re-decoded inline by the
-    consumer — correctness never depends on staging. Faults injected at
-    the SCAN_PREFETCH chaos point raise out of next() on the consumer
-    thread, surfacing as an ordinary retryable query/task failure.
-    `depth` bounds how many chunks may sit decoded-but-unconsumed."""
-
-    def __init__(self, executor, starts, decode, depth: int):
-        self.executor = executor
-        self.pool = executor.pool
-        self.decode = decode
-        self.decode_s = 0.0
-        self.served = 0                     # chunks consumed from staging
-        self._staged: Dict[int, tuple] = {}
-        self._lock = threading.Lock()
-        self._slots = threading.Semaphore(depth)
-        self._queue: "queue.Queue[tuple]" = queue.Queue()
-        self._stop = False
-        self._revocation = self.pool.register_revocation(
-            self._revoke, tag="scan-prefetch")
-        self._thread = threading.Thread(
-            target=self._run, args=(list(starts),),
-            name="scan-prefetch", daemon=True)
-        self._thread.start()
-
-    def _gauge(self) -> None:
-        from ..metrics import SCAN_PREFETCH_BUFFERS
-        SCAN_PREFETCH_BUFFERS.set(len(self._staged))
-
-    def _revoke(self, target_bytes: int) -> int:
-        """Memory-pool revocation callback: drop staged chunks (newest
-        kept longest would not matter — the consumer re-decodes any
-        missing chunk inline)."""
-        freed = 0
-        with self._lock:
-            for s in list(self._staged):
-                if freed >= target_bytes:
-                    break
-                _, b = self._staged.pop(s)
-                self.pool.free_revocable(b, tag="scan-prefetch")
-                freed += b
-            self._gauge()
-        return freed
-
-    def _run(self, starts) -> None:
-        from .memory import batch_bytes
-        try:
-            for s in starts:
-                self._slots.acquire()
-                if self._stop:
-                    return
-                inj = self.executor.failure_injector
-                if inj is not None:
-                    from ..server.failureinjector import SCAN_PREFETCH
-                    inj.maybe_fail(SCAN_PREFETCH, f"chunk@{s}")
-                t0 = time.monotonic()
-                batch = self.decode(s)
-                self.decode_s += time.monotonic() - t0
-                b = batch_bytes(batch)
-                self.pool.reserve_revocable(b, tag="scan-prefetch")
-                with self._lock:
-                    self._staged[s] = (batch, b)
-                    self._gauge()
-                _prof(f"prefetch: chunk@{s} staged")
-                self._queue.put(("chunk", s))
-            self._queue.put(("done", None))
-        except BaseException as e:          # surfaces in next()
-            self._queue.put(("error", e))
-
-    def next(self, expected_start: int) -> Batch:
-        from ..metrics import SCAN_PREFETCH_STALL_SECONDS
-        import queue as _q
-        t0 = time.monotonic()
-        while True:
-            # bounded waits so a stuck prefetch worker (chaos HANG, dead
-            # source) can't pin a canceled query on the exec lock — the
-            # cooperative check raises and close() reaps the thread
-            try:
-                kind, val = self._queue.get(timeout=0.25)
-                break
-            except _q.Empty:
-                self.executor.check_cancel()
-        wait = time.monotonic() - t0
-        if wait > 1e-4:
-            self.executor.stats.scan_prefetch_stalls += 1
-            SCAN_PREFETCH_STALL_SECONDS.inc(wait)
-        if kind == "error":
-            raise val
-        assert kind == "chunk" and val == expected_start, \
-            f"prefetch out of order: {kind} {val} != {expected_start}"
-        with self._lock:
-            hit = self._staged.pop(expected_start, None)
-            self._gauge()
-        self._slots.release()
-        if hit is None:                     # revoked under pressure
-            t0 = time.monotonic()
-            batch = self.decode(expected_start)
-            self.decode_s += time.monotonic() - t0
-            return batch
-        batch, b = hit
-        self.pool.free_revocable(b, tag="scan-prefetch")
-        self.executor.stats.scan_prefetched_chunks += 1
-        self.served += 1
-        return batch
-
-    def close(self) -> None:
-        self._stop = True
-        self._slots.release()               # unblock a waiting worker
-        self._thread.join(timeout=10)
-        with self._lock:
-            for s in list(self._staged):
-                _, b = self._staged.pop(s)
-                self.pool.free_revocable(b, tag="scan-prefetch")
-            self._gauge()
-        self.pool.unregister_revocation(self._revocation)
-
-
 def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
     """Run `root` with the driver scan streamed in chunks. Returns None if
     the plan shape doesn't support chunking (caller falls back)."""
@@ -864,11 +739,9 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
     # ---- prefetch pipeline: overlap host decode+stage with compute -------
     # depth 0 (or a device-resident fact table, which decodes nothing)
     # keeps the serial loop exactly
-    depth = int(executor.prefetch_depth or 0)
-    pipeline = None
-    if fact is None and depth > 0 and len(starts_list) > 1:
-        pipeline = _PrefetchPipeline(executor, starts_list, _decode_chunk,
-                                     depth)
+    depth = int(executor.prefetch_depth or 0) \
+        if fact is None and len(starts_list) > 1 else 0
+    pipeline = PrefetchPipeline(executor, starts_list, _decode_chunk, depth)
 
     # ---- compile warm: overlap the fused XLA compile with chunk-0 decode -
     # a zero-row dummy at the shared capacity has the identical trace
@@ -877,7 +750,7 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
     # output is discarded — bit-exactness is untouched — and the recorder
     # books the compile to the prewarm context so the loop's first call
     # counts as a prewarm hit.
-    if fused is not None and pipeline is not None and \
+    if fused is not None and depth > 0 and \
             getattr(executor, "prewarm_chunks", False):
         from .profiler import RECORDER
 
@@ -897,7 +770,6 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
                          daemon=True).start()
 
     chunk_stats: List[object] = []
-    decode_s = 0.0
     compute_s = 0.0
     t_loop = time.monotonic()
     executor.enter_chunk_mode()
@@ -911,12 +783,8 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
                     cap, fact_wide, fact_datas, fact_valids, start,
                     min(start + chunk_rows, plan.driver_rows),
                     plan.driver_rows)
-            elif pipeline is not None:
-                chunk = pipeline.next(start)
             else:
-                t0 = time.monotonic()
-                chunk = _decode_chunk(start)
-                decode_s += time.monotonic() - t0
+                chunk = pipeline.next(start)
             t0 = time.monotonic()
             if fused is not None:
                 out, stats_vec = fused[0](chunk, fused[1], fused[2],
@@ -953,19 +821,17 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
             partial_state.close()       # drop revocable reservations
         raise
     finally:
-        if pipeline is not None:
-            decode_s += pipeline.decode_s
-            pipeline.close()
+        pipeline.close()
         executor.exit_chunk_mode()
         # per-run span attribution for the overlap proof (bench.py
         # --scan-micro compares pipelined wall against the serial run's
         # decode+compute span sum)
         executor.chunk_spans = {
             "chunks": len(starts_list),
-            "decode_s": decode_s,
+            "decode_s": pipeline.decode_s,
             "compute_s": compute_s,
             "wall_s": time.monotonic() - t_loop,
-            "prefetched": pipeline.served if pipeline is not None else 0,
+            "prefetched": pipeline.served,
         }
 
     if plan.merge_agg is None:

@@ -211,8 +211,9 @@ class Executor:
         self.enable_zone_map_pruning = True
         from .zonemap import DEFAULT_ZONE_ROWS
         self.zone_map_rows = DEFAULT_ZONE_ROWS
-        # chunked-driver prefetch pipeline depth: how many decoded+staged
-        # chunks may sit ahead of the device (0 = the serial loop)
+        # prefetch pipeline depth (exec/prefetch.py): how many decoded+staged
+        # chunks of the chunked driver, or splits of a worker task, may sit
+        # ahead of the device (0 = the serial loop)
         self.prefetch_depth = 2
         self.prewarm_chunks = False
         # seeded FailureInjector (server/failureinjector.py) for chaos

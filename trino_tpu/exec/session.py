@@ -115,8 +115,9 @@ SESSION_PROPERTY_DEFAULTS = {
     "enable_zone_map_pruning": (True, _bool),
     # zone granularity in rows (split-level pruning quantum)
     "zone_map_rows": (65536, int),
-    # chunked-driver prefetch pipeline: how many decoded+staged chunks
-    # may run ahead of the device (0 = today's serial loop, exactly)
+    # prefetch pipeline (the chunked driver's; a worker task's split loop
+    # takes its executor's): how many decoded+staged chunks may run ahead
+    # of the device (0 = the serial loop, exactly)
     "prefetch_depth": (2, int),
     # chunked-driver compile warm: overlap the fused program's XLA compile
     # with chunk-0 decode via a discarded zero-row call (exec/prewarm.py

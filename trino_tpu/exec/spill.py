@@ -577,7 +577,9 @@ class PartialState:
                     break
                 batch = self.device.pop(0)
                 b = self._device_bytes.pop(0)
-            self.host.append(batch_to_numpy(batch))
+                # under the lock: a merge on another thread snapshots
+                # both lists, and must find the partial in one of them
+                self.host.append(batch_to_numpy(batch))
             self.executor.pool.free_revocable(b, tag=self.tag)
             freed += b
         if freed:

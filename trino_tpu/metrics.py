@@ -417,12 +417,13 @@ SCAN_ZONES_PRUNED = REGISTRY.counter(
     "(exec/zonemap.py)")
 SCAN_PREFETCH_BUFFERS = REGISTRY.gauge(
     "trino_tpu_scan_prefetch_buffers_in_use",
-    "Decoded+staged chunks currently held by the chunked-driver "
-    "prefetch pipeline (revocable reservations)")
+    "Decoded+staged chunks (a worker task's: splits) currently held by "
+    "a prefetch pipeline (revocable reservations)")
 SCAN_PREFETCH_STALL_SECONDS = REGISTRY.counter(
     "trino_tpu_scan_prefetch_stall_seconds",
-    "Seconds the chunked-driver consumer spent waiting on a chunk the "
-    "prefetch worker had not staged yet")
+    "Seconds a prefetch pipeline's consumer (the chunked driver, a "
+    "worker task's split loop) spent waiting on a chunk the prefetch "
+    "worker had not staged yet")
 
 # elastic cluster membership (server/worker.py lifecycle state machine,
 # server/coordinator.py announce protocol, server/scheduler.py drain

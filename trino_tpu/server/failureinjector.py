@@ -43,8 +43,8 @@ EXCHANGE_DRAIN = "EXCHANGE_DRAIN"      # coordinator pulling result pages
 SPOOL_READ = "SPOOL_READ"              # durable exchange get()
 SPOOL_WRITE = "SPOOL_WRITE"            # durable exchange put()
 HEARTBEAT_PING = "HEARTBEAT_PING"      # failure detector /v1/status probe
-SCAN_PREFETCH = "SCAN_PREFETCH"        # chunked-driver prefetch worker,
-                                       # per staged chunk (exec/chunked.py)
+SCAN_PREFETCH = "SCAN_PREFETCH"        # prefetch worker, per staged chunk or
+                                       # split (exec/prefetch.py)
 WRITE_STAGE = "WRITE_STAGE"            # write task staging an attempt file
 WRITE_COMMIT = "WRITE_COMMIT"          # coordinator journaling the commit
 WRITE_PUBLISH = "WRITE_PUBLISH"        # per-file atomic rename publish

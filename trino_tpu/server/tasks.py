@@ -30,6 +30,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..exec.prefetch import PrefetchPipeline
 from ..exec.profiler import RECORDER
 from ..exec.spill import PartialState
 from ..metrics import TASK_OUTPUT_BYTES, TASK_OUTPUT_ROWS
@@ -743,15 +744,51 @@ class TaskManager:
                 task.spans = tracer.export(compact=True)
         self._executor.flush_metrics()
 
+    def _split_decoder(self, task: WorkerTask, driver_scan, cap: int):
+        """`decode(si)` of the task's pipeline: split `si`'s columns
+        sliced from the connector's table and put on the device at
+        `cap`. It runs on the pipeline's thread (on the loop's at depth
+        0 and for a revoked batch) and touches the catalog, numpy and
+        the transfer, never the executor. TPC-H has no nulls and a
+        task's splits but its last have one size, so the mask of the
+        batch put last is handed to the next put: a split then sends its
+        data columns and nothing else."""
+        from ..batch import batch_from_numpy
+        last = (None, None)     # rows of the batch put last, its `live`
+
+        def decode(si: int):
+            nonlocal last
+            split = task.splits[si]
+            data = self.catalog.get_table(
+                split.catalog, split.schema_name, split.table)
+            rows = slice(split.start, split.start + split.count)
+            arrays = [np.asarray(data.columns[i])[rows]
+                      for i in driver_scan.column_indices]
+            valids = None
+            if data.valids is not None:
+                valids = [None if data.valids[i] is None else
+                          np.asarray(data.valids[i])[rows]
+                          for i in driver_scan.column_indices]
+            count, live = last   # one read: two threads may decode
+            chunk = batch_from_numpy(
+                arrays, valids=valids, capacity=cap,
+                live=live if count == split.count else None)
+            last = (split.count, chunk.live)
+            return chunk
+        return decode
+
     def _run_splits(self, task: WorkerTask, ex, root, driver_scan,
-                    cap: int, lap, held: Optional[_HeldPartials],
+                    pipeline: PrefetchPipeline, lap,
+                    held: Optional[_HeldPartials],
                     names: Optional[Dict[int, str]],
                     op_agg: Dict[str, list], live_prev: tuple) -> tuple:
         """The split loop of one task. Five spans a split (`lap`,
         utils/tracing.py), each starting where the last one ended, so
         every moment of the loop has a name; benchmark/layers/
         split_*_ms.py read them. `names` is set when the fragment is
-        profiled (fenced).
+        profiled (fenced). A split's input comes from `pipeline`, which
+        decodes and puts `ex.prefetch_depth` splits ahead of the loop on
+        a thread of its own: `split-put` is the loop's wait for it.
 
         What a page is. With `held` (the fragment's root is the stage's
         merge aggregate, no partition spec) a split stages nothing: its
@@ -763,7 +800,7 @@ class TaskManager:
         and the loop goes on. Without `held` (concat, sorted runs, a
         partition spec) a page is one split's output, as it always
         was."""
-        from ..batch import batch_from_numpy, batch_to_numpy
+        from ..batch import batch_to_numpy
         for si, split in enumerate(task.splits):
             lap("split-read", index=si, rows=split.count)
             if task.state in ("CANCELED", "ABANDONED"):
@@ -782,21 +819,15 @@ class TaskManager:
                 # target)
                 self.injector.maybe_fail("WORKER_TASK_RUN",
                                          f"{task.task_id}:{si}")
-            data = self.catalog.get_table(
-                split.catalog, split.schema_name, split.table)
-            arrays = [np.asarray(data.columns[i])
-                      [split.start:split.start + split.count]
-                      for i in driver_scan.column_indices]
-            valids = None
-            if data.valids is not None:
-                valids = [None if data.valids[i] is None else
-                          np.asarray(data.valids[i])
-                          [split.start:split.start + split.count]
-                          for i in driver_scan.column_indices]
             sp = lap("split-put", index=si)
+            served, stalls = pipeline.served, ex.stats.scan_prefetch_stalls
+            chunk = pipeline.next(si)
             if sp is not None:
-                sp.attributes["bytes"] = sum(a.nbytes for a in arrays)
-            chunk = batch_from_numpy(arrays, valids=valids, capacity=cap)
+                sp.attributes["bytes"] = split.count * sum(
+                    c.data.dtype.itemsize for c in chunk.columns)
+                # staged when the loop asked for it
+                sp.attributes["ahead"] = pipeline.served > served and \
+                    ex.stats.scan_prefetch_stalls == stalls
             ex._subst[id(driver_scan)] = chunk
             ex._subst_opaque.add(id(driver_scan))
             sp_t0 = time.monotonic()
@@ -969,6 +1000,11 @@ class TaskManager:
                     ex, root, f"task-partials:{task.task_id}") \
                     if fragment.get("merge_agg") and \
                     task.partition is None else None
+                stalls0 = ex.stats.scan_prefetch_stalls
+                pipeline = PrefetchPipeline(
+                    ex, range(len(task.splits)),
+                    self._split_decoder(task, driver_scan, cap),
+                    ex.prefetch_depth)
                 try:
                     # pin maximal driver-free subtrees ONCE per task (join
                     # build sides, HashBuilderOperator's build-once-probe-
@@ -991,14 +1027,15 @@ class TaskManager:
                     ex.enter_chunk_mode()
                     with tracer.laps() as lap:
                         live_prev = self._run_splits(
-                            task, ex, root, driver_scan, cap, lap, held,
-                            names if profiling else None, op_agg,
+                            task, ex, root, driver_scan, pipeline, lap,
+                            held, names if profiling else None, op_agg,
                             live_prev)
                     # a cancelled task stages nothing
                     if held is not None and held.count() and \
                             task.state == "RUNNING":
                         self._emit_held(task, tracer, held)
                 finally:
+                    pipeline.close()    # nothing staged outlives the task
                     ex.exit_chunk_mode()
                     ex._operator_spans = False
                     ex.profile = saved_profile
@@ -1031,6 +1068,15 @@ class TaskManager:
                             held.folded_splits if held is not None else 0
                         wspan.attributes["flushes"] = \
                             held.flushes if held is not None else 0
+                        # how often the input was there before the loop
+                        # asked: splits served from staging, the loop's
+                        # waits over 0.1 ms, the decodes' summed wall
+                        wspan.attributes["prefetchedSplits"] = \
+                            pipeline.served
+                        wspan.attributes["prefetchStalls"] = \
+                            ex.stats.scan_prefetch_stalls - stalls0
+                        wspan.attributes["stageMs"] = round(
+                            pipeline.decode_s * 1000, 3)
                     if wspan is not None and op_agg:
                         # fenced split totals ride the worker-task span
                         # so the stitched trace carries device time, not
