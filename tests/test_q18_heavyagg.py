@@ -19,11 +19,14 @@ from trino_tpu.server.coordinator import CoordinatorServer
 from trino_tpu.server.worker import WorkerServer
 
 from test_resident_tables import bench_module, reference_tables
-from test_tracing_phases import CountingAnnotation
+from test_tracing_phases import (ROUNDING_NS, SPLIT_PHASES,
+                                 CountingAnnotation, _inside, _interval)
 
 # at `tiny` TPC-H's own 312-315 keep no order; these keep 3,404, 799, 54,
 # 1 and 0 (21,246, 5,343, 376, 7 and 0 lineitems)
 QUANTITIES = (150, 200, 250, 300, 313)
+# through a worker also these, which keep 1,860, 227, 12 and 1 orders
+WORKER_QUANTITIES = QUANTITIES + (175, 225, 275, 299)
 
 q18 = bench_module("queries.q18")
 compare = bench_module("compare")
@@ -94,15 +97,16 @@ def test_single_node_route_matches_the_reference(single, tiny_tables,
     assert len(rows) == want_rows and (want_rows > 0) == (quantity < 313)
 
 
-@pytest.mark.parametrize("quantity", QUANTITIES)
+@pytest.mark.parametrize("quantity", WORKER_QUANTITIES)
 def test_worker_route_matches_the_reference(tiny_tables, quantity,
                                             worker_cluster):
     rows, info, _ = worker_cluster.run(q18.render({"quantity": quantity},
                                                   "tpch.tiny"))
     assert info["distributed"] and not (
         info.get("fallbackReason") or "").startswith("task failure")
-    (n, first), _ = mismatched(rows, tiny_tables, quantity)
+    (n, first), want_rows = mismatched(rows, tiny_tables, quantity)
     assert (n, first) == (0, None)
+    assert len(rows) == want_rows and (want_rows > 0) == (quantity < 313)
 
 
 @pytest.fixture(scope="module")
@@ -261,6 +265,211 @@ def test_a_worker_task_opens_operator_spans_beside_its_splits(
     ex = worker_cluster.workers[0].task_manager._executor
     assert ex._operator_spans is False and ex._operator_split is None
     assert ex._open_operators == []
+
+
+def by_name(spans):
+    out = {}
+    for sp in spans:
+        out.setdefault(sp["name"], []).append(sp)
+    return out
+
+
+def traced(served, quantity, profiling=False, sql=None):
+    """-> (rows, spans) of a Q18 no other test sends (or of `sql`),
+    traced."""
+    served.client.execute("SET SESSION enable_tracing = true")
+    if profiling:
+        served.client.execute("SET SESSION enable_profiling = true")
+    try:
+        rows, info, spans = served.run(
+            sql or q18.render({"quantity": quantity}, "tpch.tiny"))
+    finally:
+        served.client.execute("SET SESSION enable_profiling = false")
+        served.client.execute("SET SESSION enable_tracing = false")
+    assert bool(info.get("distributed")) == bool(served.workers)
+    assert served.workers or info["route"] == "device"
+    return rows, spans
+
+
+@pytest.mark.parametrize("profiling", [False, True],
+                         ids=["tracing", "fenced"])
+def test_a_worker_folds_the_subquery_under_one_span_beside_its_split(
+        worker_cluster, tiny_tables, profiling):
+    """The IN subquery is folded by the WORKER's executor, inside the
+    first split of the `orders` task: one `subquery-fold` a statement,
+    under that task's `worker-task`, beside the `split` lap it ran in,
+    the subquery's own operators under it."""
+    quantity = 247 if profiling else 248
+    kept = len(q18.reference(tiny_tables, {"quantity": quantity}))
+    assert 0 < kept < 100        # every order it keeps is in the answer
+    # a worker's executor keeps what it scans whole (the subquery's
+    # columns; a split's rows come through the task's pipeline): as a
+    # worker's first Q18 meets it
+    ex = worker_cluster.workers[0].task_manager._executor
+    ex.resident.clear()
+    rows, spans = traced(worker_cluster, quantity, profiling)
+    assert len(rows) == kept
+    ids = {sp["spanId"]: sp for sp in spans}
+    by = by_name(spans)
+    (fold,) = by["subquery-fold"]
+    task = ids[fold["parentSpanId"]]
+    # 15,000 orders in splits of 8,192; lineitem's tasks have 8, customer's 1
+    assert task["name"] == "worker-task" and \
+        task["attributes"]["splits"] == 2 and \
+        task["attributes"]["literalSlots"] == 1
+    assert fold["attributes"] == dict(
+        fold["attributes"], kind="in", split=0, inputRows=60_104,
+        members=kept)
+    # tiny's lineitem padded to 60,416: two 8-byte columns and the mask
+    assert fold["attributes"]["putBytes"] >= 2 * 8 * 60_104
+    assert sorted(fold["attributes"]) == ["inputRows", "kind", "members",
+                                          "putBytes", "split"]
+    # beside the lap, not under it: the lap's parent is the fold's, the
+    # lap covers it
+    (lap,) = [sp for sp in by["split"]
+              if sp["parentSpanId"] == task["spanId"]
+              and sp["attributes"]["index"] == 0]
+    assert _inside(fold, lap) and _inside(fold, task)
+    # the subquery's plan under it, whole-statement form: its scan of
+    # lineitem, its 15,000-group aggregate
+    under = by_name(sp for sp in spans
+                    if sp["parentSpanId"] == fold["spanId"])
+    (scan,) = under["scan"]
+    assert scan["attributes"]["table"] == "lineitem" and \
+        scan["attributes"]["putBytes"] == fold["attributes"]["putBytes"]
+    (aggregate,) = under["aggregate"]
+    assert aggregate["attributes"]["groups"] == 15_000 and \
+        aggregate["attributes"]["inputRows"] == 60_104
+    assert "split" not in aggregate["attributes"]
+    assert all(_inside(sp, fold) for sps in under.values() for sp in sps)
+    # the five laps of every split still touch exactly, in every task
+    for wt in by["worker-task"]:
+        laps = sorted((sp for n in SPLIT_PHASES for sp in by[n]
+                       if sp["parentSpanId"] == wt["spanId"]),
+                      key=lambda sp: sp["startTimeUnixNano"])
+        assert len(laps) == 5 * wt["attributes"]["splits"]
+        assert [sp["name"] for sp in laps[:5]] == [
+            "split-read", "split-put", "split", "split-fetch", "split-emit"]
+        for a, b in zip(laps, laps[1:]):
+            assert abs(_interval(a)[1] - _interval(b)[0]) <= ROUNDING_NS
+    assert ex._operator_split is None and ex._open_operators == []
+    # the next statement finds the columns where this one put them
+    _, spans = traced(worker_cluster, quantity - 10, profiling)
+    (fold,) = by_name(spans)["subquery-fold"]
+    assert fold["attributes"]["putBytes"] == 0 and \
+        fold["attributes"]["inputRows"] == 60_104
+
+
+def test_the_single_node_route_folds_under_execute(single, tiny_tables):
+    kept = len(q18.reference(tiny_tables, {"quantity": 246}))
+    assert 0 < kept < 100
+    rows, spans = traced(single, 246)
+    assert len(rows) == kept
+    by = by_name(spans)
+    (fold,) = by["subquery-fold"]
+    (execute,) = by["execute"]
+    assert fold["parentSpanId"] == execute["spanId"] and \
+        _inside(fold, execute)
+    assert fold["attributes"] == dict(
+        fold["attributes"], kind="in", inputRows=60_104, members=kept)
+    assert "split" not in fold["attributes"]
+    # what the fold's scans put is part of what the statement's did;
+    # resident columns cost neither
+    assert 0 <= fold["attributes"]["putBytes"] <= \
+        execute["attributes"]["scanPutBytes"]
+    (aggregate,) = [sp for sp in by["aggregate"]
+                    if sp["parentSpanId"] == fold["spanId"]]
+    assert aggregate["attributes"]["groups"] == 15_000
+    # the filter that holds the IN list opens behind the fold
+    assert not [sp for sp in by["filter-project"]
+                if _inside(fold, sp) and sp["spanId"] != fold["spanId"]
+                and sp["parentSpanId"] == execute["spanId"]]
+
+
+def test_a_scalar_subquery_folds_under_the_same_span(single):
+    rows, spans = traced(
+        single, None,
+        sql="SELECT count(*) FROM tpch.tiny.orders WHERE o_totalprice > "
+            "(SELECT avg(o_totalprice) FROM tpch.tiny.orders "
+            "WHERE o_custkey < 1399)")
+    assert 0 < rows[0][0] < 15_000
+    (fold,) = by_name(spans)["subquery-fold"]
+    assert fold["attributes"] == dict(
+        fold["attributes"], kind="scalar", members=1, inputRows=15_000)
+
+
+def test_tracing_off_builds_no_fold_span(single, worker_cluster,
+                                         monkeypatch):
+    import jax.profiler
+    CountingAnnotation.names = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    for served, quantity in ((single, 245), (worker_cluster, 245)):
+        _, _, spans = served.run(q18.render({"quantity": quantity},
+                                            "tpch.tiny"))
+        assert spans == []
+    assert CountingAnnotation.names == []
+    # and on: the span has its twin on the profiler's clock, one a statement
+    for served, quantity in ((single, 244), (worker_cluster, 244)):
+        traced(served, quantity)
+    assert CountingAnnotation.names.count("tt:subquery-fold") == 2
+
+
+def test_the_fold_runs_once_a_task_not_once_a_split(worker_cluster,
+                                                    monkeypatch):
+    """Three `orders` splits bind the task's IN list once
+    (`bound_exprs`), so the subquery's scan reads lineitem once."""
+    from trino_tpu.exec.executor import Executor
+    folds = []
+    fold_span = Executor._fold_span
+
+    def counted(self, kind):
+        folds.append(kind)
+        return fold_span(self, kind)
+    monkeypatch.setattr(Executor, "_fold_span", counted)
+    scheduler = worker_cluster.coord.state.scheduler
+    monkeypatch.setattr(scheduler, "split_rows", 5000)
+    _, spans = traced(worker_cluster, 243)
+    by = by_name(spans)
+    (fold,) = by["subquery-fold"]
+    task = next(sp for sp in by["worker-task"]
+                if sp["spanId"] == fold["parentSpanId"])
+    assert task["attributes"]["splits"] == 3
+    assert folds == ["in"] and fold["attributes"]["inputRows"] == 60_104
+
+
+def test_folded_answers_go_with_the_task_and_the_statement(
+        single, worker_cluster):
+    """An executor keeps a folded subquery's answer (keyed by the ref,
+    which holds the subquery's plan) no longer than the plan nodes'
+    bound expressions: a worker's executor, which lives as long as the
+    worker, holds none between tasks."""
+    worker_cluster.run(q18.render({"quantity": 242}, "tpch.tiny"))
+    ex = worker_cluster.workers[0].task_manager._executor
+    assert ex._scalar_cache == {} and ex._bound_exprs == {}
+    # the coordinator's executor ran the final stage
+    ex = worker_cluster.session.executor
+    assert ex._scalar_cache == {} and ex._bound_exprs == {}
+    # whole on one executor the answer serves the statement (bound once
+    # a plan node) and goes where the next statement begins
+    ex = single.session.executor
+    single.run(q18.render({"quantity": 242}, "tpch.tiny"))
+    assert len(ex._scalar_cache) == 1
+    single.run("SELECT count(*) FROM tpch.tiny.region")
+    assert ex._scalar_cache == {}
+    ex._scalar_cache["ref"] = ((1,), False)
+    ex.release_all_reservations()
+    assert ex._scalar_cache == {}
+
+
+def test_operations_guide_lists_the_fold_span():
+    import os
+    path = os.path.join(os.path.dirname(__file__), "..", "docs",
+                        "operations.md")
+    with open(path) as f:
+        row = next(ln for ln in f if ln.startswith("| `subquery-fold`"))
+    for attribute in ("`kind`", "`inputRows`", "`members`", "`putBytes`",
+                      "`split`"):
+        assert attribute in row
 
 
 def test_explain_prints_the_in_subquery_as_a_sub_plan():

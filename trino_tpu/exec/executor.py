@@ -20,6 +20,7 @@ from __future__ import annotations
 import math
 import os
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -701,12 +702,16 @@ class Executor:
         """Free every per-node reservation (the distributed scheduler's
         merge path runs plan nodes without execute()'s per-query cleanup
         — under a small pool those leaked bytes starve later queries),
-        and with them the plan nodes' bound expressions: both are keyed
-        by the identity of nodes that go with the query or the task."""
+        and with them the plan nodes' bound expressions and the folded
+        subqueries' answers: all keyed by the identity of nodes that go
+        with the query or the task (a subquery's key holds its whole
+        plan: a worker's executor would keep one alive for every Q18 it
+        has ever run)."""
         for b in self._node_bytes.values():
             self.pool.free(b)
         self._node_bytes.clear()
         self._bound_exprs.clear()
+        self._scalar_cache.clear()
 
     def release_path_reservations(self, node: L.PlanNode, keep) -> None:
         """Free reservations of `node`'s subtree (chunked mode: the
@@ -729,8 +734,10 @@ class Executor:
                 self.operator_span("filter-project")
                 return filter_project_fused(child, values, exprs, pred)
             child = self.run(node.child)
+            # a subquery of the predicate has a span of its own
+            predicate = self.fold_scalars(node.predicate)
             self.operator_span("filter-project")
-            return apply_filter(child, self.fold_scalars(node.predicate))
+            return apply_filter(child, predicate)
         if isinstance(node, L.ProjectNode):
             if isinstance(node.child, L.FilterNode):
                 (pred, exprs), values = self.bound_exprs(
@@ -1291,17 +1298,51 @@ class Executor:
             return None
         return ir.transform(expr, fn)
 
+    @contextmanager
+    def _fold_span(self, kind: str):
+        """The `subquery-fold` span of a subquery this executor runs to
+        fold it (not of a memoised answer): everything from the
+        subquery's plan to its values on the host. `inputRows` and
+        `putBytes` are what its scans read and put on the device (0: the
+        columns were resident); the caller stamps `members`. On a whole
+        statement it lies under `execute`. In a worker's split loop it
+        hangs under `worker-task` BESIDE the `split` lap it ran in, as
+        the operators' spans do, says which (`split`), and is the
+        thread's context meanwhile: the subquery's own `scan`,
+        `aggregate` and `compile` spans nest under it, whole-statement
+        form (it runs once a task, not once a split)."""
+        tracer = tracing.current()
+        if not tracer.enabled:
+            yield None
+            return
+        split, self._operator_split = self._operator_split, None
+        where = {} if split is None else {"split": split[1]}
+        rows0, put0 = self.stats.rows_scanned, self.scan_put_bytes
+        try:
+            with tracer.span("subquery-fold", split and split[0],
+                             kind=kind, **where) as sp:
+                yield sp
+                sp.attributes.update(
+                    inputRows=self.stats.rows_scanned - rows0,
+                    putBytes=self.scan_put_bytes - put0)
+        finally:
+            self._operator_split = split
+
     def fold_in_subquery(self, ref: ir.InSubqueryRef) -> ir.Expr:
         """Execute the subquery and fold x IN (...) to an InList, mapping
         varchar values into the probe's dictionary and injecting Kleene
         NULL when the subquery produced one (x IN S is NULL for unmatched
         x when S contains NULL)."""
         if ref not in self._scalar_cache:
-            # the members come to the host: the live ones, not the
-            # subquery's whole capacity (Q18's HAVING keeps hundreds of
-            # 16.7M group slots)
-            batch = self.maybe_compact(self.run(ref.plan), node=ref.plan)
-            arrays, valids = batch_to_numpy(batch)
+            with self._fold_span("in") as sp:
+                # the members come to the host: the live ones, not the
+                # subquery's whole capacity (Q18's HAVING keeps hundreds
+                # of 16.7M group slots)
+                batch = self.maybe_compact(self.run(ref.plan),
+                                           node=ref.plan)
+                arrays, valids = batch_to_numpy(batch)
+                if sp is not None:
+                    sp.attributes["members"] = len(arrays[0])
             vals, has_null = [], False
             arg_t = ref.arg.dtype
             from ..types import TypeKind as TK
@@ -1356,8 +1397,11 @@ class Executor:
         # keyed by the ref itself (hashes by plan identity) so the cache
         # keeps the plan object alive — id() reuse cannot alias entries
         if ref not in self._scalar_cache:
-            batch = self.run(ref.plan)
-            arrays, valids = batch_to_numpy(batch)
+            with self._fold_span("scalar") as sp:
+                batch = self.run(ref.plan)
+                arrays, valids = batch_to_numpy(batch)
+                if sp is not None:
+                    sp.attributes["members"] = len(arrays[0])
             if len(arrays[0]) > 1:
                 raise RuntimeError(
                     "scalar subquery returned more than one row")
