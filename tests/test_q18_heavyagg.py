@@ -314,16 +314,20 @@ def test_a_worker_folds_the_subquery_under_one_span_beside_its_split(
     (fold,) = by["subquery-fold"]
     task = ids[fold["parentSpanId"]]
     # 15,000 orders in splits of 8,192; lineitem's tasks have 8, customer's 1
+    # bound once a task: the Project's slot, and the Filter's member set
+    # with its count, tested once a split in one program
     assert task["name"] == "worker-task" and \
         task["attributes"]["splits"] == 2 and \
-        task["attributes"]["literalSlots"] == 1
+        task["attributes"]["literalSlots"] == 3 and \
+        task["attributes"]["inSetProbes"] == 2 and \
+        task["attributes"]["inSetCapacity"] == 1024
     assert fold["attributes"] == dict(
         fold["attributes"], kind="in", split=0, inputRows=60_104,
         members=kept)
     # tiny's lineitem padded to 60,416: two 8-byte columns and the mask
     assert fold["attributes"]["putBytes"] >= 2 * 8 * 60_104
-    assert sorted(fold["attributes"]) == ["inputRows", "kind", "members",
-                                          "putBytes", "split"]
+    assert sorted(fold["attributes"]) == [
+        "fetchedSlots", "inputRows", "kind", "members", "putBytes", "split"]
     # beside the lap, not under it: the lap's parent is the fold's, the
     # lap covers it
     (lap,) = [sp for sp in by["split"]
@@ -373,6 +377,11 @@ def test_the_single_node_route_folds_under_execute(single, tiny_tables):
     assert fold["attributes"] == dict(
         fold["attributes"], kind="in", inputRows=60_104, members=kept)
     assert "split" not in fold["attributes"]
+    # tiny's 60,416 group slots are under the floor a compaction pays
+    # from; the members are tested once, in one program, at one capacity
+    assert fold["attributes"]["fetchedSlots"] == 60_416
+    assert execute["attributes"]["inSetProbes"] == 1 and \
+        execute["attributes"]["inSetCapacity"] == 1024
     # what the fold's scans put is part of what the statement's did;
     # resident columns cost neither
     assert 0 <= fold["attributes"]["putBytes"] <= \
@@ -380,7 +389,7 @@ def test_the_single_node_route_folds_under_execute(single, tiny_tables):
     (aggregate,) = [sp for sp in by["aggregate"]
                     if sp["parentSpanId"] == fold["spanId"]]
     assert aggregate["attributes"]["groups"] == 15_000
-    # the filter that holds the IN list opens behind the fold
+    # the filter that tests the member set opens behind the fold
     assert not [sp for sp in by["filter-project"]
                 if _inside(fold, sp) and sp["spanId"] != fold["spanId"]
                 and sp["parentSpanId"] == execute["spanId"]]
@@ -437,6 +446,50 @@ def test_the_fold_runs_once_a_task_not_once_a_split(worker_cluster,
     assert folds == ["in"] and fold["attributes"]["inputRows"] == 60_104
 
 
+def test_a_task_fetches_the_live_members_and_tests_them_in_one_program(
+        worker_cluster, tiny_tables, monkeypatch):
+    """A task's split loop is a chunked loop, and the fold compacts
+    before it fetches all the same (one sync a task): what comes to the
+    host has the compacted capacity, not the subquery's. The members
+    are one operand of one program a split, so an `orders` split
+    dispatches as much with a dozen members as with over a hundred, and
+    the answer still goes with its task."""
+    from trino_tpu.batch import compaction_capacity
+    ex = worker_cluster.workers[0].task_manager._executor
+    # tiny's subquery answers in 60,416 slots, under the floor a
+    # compaction pays from: lowered for this executor, as sf10's 67M
+    # slots are over it
+    monkeypatch.setattr(ex, "COMPACT_MIN_ROWS", 1024)
+    seen = {}
+    for quantity in (271, 231):
+        rows, spans = traced(worker_cluster, quantity)
+        ids = {sp["spanId"]: sp for sp in spans}
+        (fold,) = by_name(spans)["subquery-fold"]
+        members = fold["attributes"]["members"]
+        # every order the subquery keeps is in the answer, a top-100
+        assert min(members, 100) == len(rows) == len(
+            q18.reference(tiny_tables, {"quantity": quantity}))
+        assert fold["attributes"]["fetchedSlots"] == \
+            compaction_capacity(members, 60_416) == 1024
+        task = ids[fold["parentSpanId"]]
+        laps = sorted((sp for sp in spans if sp["name"] == "split"
+                       and sp["parentSpanId"] == task["spanId"]),
+                      key=lambda sp: sp["attributes"]["index"])
+        assert task["attributes"]["inSetProbes"] == len(laps) == 2 and \
+            task["attributes"]["inSetCapacity"] == 1024
+        seen[quantity] = (members,
+                          [sp["attributes"]["dispatches"] for sp in laps])
+        # no other task of the statement tests a set
+        assert [wt["attributes"]["inSetProbes"]
+                for wt in by_name(spans)["worker-task"]
+                if wt is not task] == [0]
+        assert ex._scalar_cache == {} and ex._bound_exprs == {}
+    (few, laps_few), (many, laps_many) = seen[271], seen[231]
+    assert few < 30 < 100 < many and laps_few == laps_many
+    # the filter, the join and the projection: three programs a split
+    assert laps_few[1] == 3
+
+
 def test_folded_answers_go_with_the_task_and_the_statement(
         single, worker_cluster):
     """An executor keeps a folded subquery's answer (keyed by the ref,
@@ -468,8 +521,11 @@ def test_operations_guide_lists_the_fold_span():
     with open(path) as f:
         row = next(ln for ln in f if ln.startswith("| `subquery-fold`"))
     for attribute in ("`kind`", "`inputRows`", "`members`", "`putBytes`",
-                      "`split`"):
+                      "`split`", "`fetchedSlots`"):
         assert attribute in row
+    with open(path) as f:
+        row = next(ln for ln in f if ln.startswith("| `worker-task`"))
+    assert "`inSetProbes`" in row and "`inSetCapacity`" in row
 
 
 def test_explain_prints_the_in_subquery_as_a_sub_plan():

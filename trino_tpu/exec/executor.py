@@ -36,7 +36,8 @@ from ..ops.aggregate import (AggSpec, direct_group_aggregate,
 from ..batch import live_first_order, pad_capacity
 from ..ops.join import (join_expand, join_mark, join_unique_build,
                         join_unique_build_dense, join_unique_build_merge)
-from ..ops.project import apply_filter, filter_project, project
+from ..ops.project import (apply_filter, filter_project, filter_rows,
+                           project)
 from ..ops.sort import limit_batch, sort_batch
 from ..planner import logical as L
 from ..utils import tracing
@@ -61,6 +62,9 @@ class ExecStats:
     literal_slots: int = 0           # literals and lookup tables bound as
                                      # operands of a filter/project
                                      # program (bound_exprs memo fills)
+    in_set_probes: int = 0           # dispatches of a filter/project
+                                     # program that tests a folded IN
+                                     # subquery's member set (ir.InSet)
     fused_chunk_pipelines: int = 0   # whole-chunk-path single programs
     pallas_gather_calls: int = 0     # probe sites dispatched with the
                                      # tiled-gather kernel enabled
@@ -735,9 +739,9 @@ class Executor:
                 return filter_project_fused(child, values, exprs, pred)
             child = self.run(node.child)
             # a subquery of the predicate has a span of its own
-            predicate = self.fold_scalars(node.predicate)
+            (pred, _), values = self.bound_exprs(node, node.predicate, ())
             self.operator_span("filter-project")
-            return apply_filter(child, predicate)
+            return filter_rows(child, values, pred)
         if isinstance(node, L.ProjectNode):
             if isinstance(node.child, L.FilterNode):
                 (pred, exprs), values = self.bound_exprs(
@@ -1304,7 +1308,8 @@ class Executor:
         fold it (not of a memoised answer): everything from the
         subquery's plan to its values on the host. `inputRows` and
         `putBytes` are what its scans read and put on the device (0: the
-        columns were resident); the caller stamps `members`. On a whole
+        columns were resident); the caller stamps `members` and
+        `fetchedSlots` (the capacity of what came to the host). On a whole
         statement it lies under `execute`. In a worker's split loop it
         hangs under `worker-task` BESIDE the `split` lap it ran in, as
         the operators' spans do, says which (`split`), and is the
@@ -1329,20 +1334,30 @@ class Executor:
             self._operator_split = split
 
     def fold_in_subquery(self, ref: ir.InSubqueryRef) -> ir.Expr:
-        """Execute the subquery and fold x IN (...) to an InList, mapping
-        varchar values into the probe's dictionary and injecting Kleene
-        NULL when the subquery produced one (x IN S is NULL for unmatched
-        x when S contains NULL)."""
+        """Execute the subquery and fold x IN (...) to an `ir.InSet` over
+        its distinct members (`member_set`), mapping varchar values into
+        the probe's dictionary and injecting Kleene NULL when the
+        subquery produced one (x IN S is NULL for unmatched x when S
+        contains NULL)."""
         if ref not in self._scalar_cache:
             with self._fold_span("in") as sp:
                 # the members come to the host: the live ones, not the
                 # subquery's whole capacity (Q18's HAVING keeps hundreds
-                # of 16.7M group slots)
-                batch = self.maybe_compact(self.run(ref.plan),
-                                           node=ref.plan)
+                # of 67M group slots). In a task's split loop too: the
+                # fold runs once a task and ends in this fetch anyway,
+                # so the live count's one sync, which chunk mode spares
+                # a per-chunk loop, costs nothing here
+                batch = self.run(ref.plan)
+                live = None
+                if self.chunk_mode and \
+                        batch.capacity >= self.COMPACT_MIN_ROWS:
+                    (live,) = self.fetch_ints(ref.plan, "complive",
+                                              jnp.sum(batch.live))
+                batch = self.maybe_compact(batch, live, node=ref.plan)
                 arrays, valids = batch_to_numpy(batch)
                 if sp is not None:
-                    sp.attributes["members"] = len(arrays[0])
+                    sp.attributes.update(members=len(arrays[0]),
+                                         fetchedSlots=batch.capacity)
             vals, has_null = [], False
             arg_t = ref.arg.dtype
             from ..types import TypeKind as TK
@@ -1362,8 +1377,7 @@ class Executor:
                 vals.append(v)
             self._scalar_cache[ref] = (tuple(sorted(set(vals))), has_null)
         vals, has_null = self._scalar_cache[ref]
-        folded: ir.Expr = ir.InList(
-            ref.arg, tuple(ir.Literal(v, ref.arg.dtype) for v in vals))
+        folded: ir.Expr = member_set(ref.arg, vals)
         if has_null:
             from ..types import BOOLEAN
             folded = ir.Logical("or", (folded,
@@ -1389,9 +1403,23 @@ class Executor:
             # uncommitted arrays: a mesh executor's sharded batch places
             # the program, the operands follow it
             values = jax.tree_util.tree_map(jnp.asarray, values)
+            set_capacity = max(
+                (len(e.members) for t in (template[0],) + template[1]
+                 if t is not None for e in ir.walk(t)
+                 if isinstance(e, ir.InSet)), default=0)
             # the node reference keeps its id from being reused
-            hit = self._bound_exprs[id(node)] = (node, template, values)
-        return hit[1], hit[2]
+            hit = self._bound_exprs[id(node)] = (node, template, values,
+                                                 set_capacity)
+        _, template, values, set_capacity = hit
+        if set_capacity:
+            self.stats.in_set_probes += 1
+        return template, values
+
+    def in_set_capacity(self) -> int:
+        """The largest member capacity among the set programs bound for
+        the running statement or task (`inSetCapacity`); 0: none."""
+        return max((capacity for *_, capacity
+                    in self._bound_exprs.values()), default=0)
 
     def scalar_value(self, ref: ir.ScalarSubqueryRef):
         # keyed by the ref itself (hashes by plan identity) so the cache
@@ -1401,7 +1429,8 @@ class Executor:
                 batch = self.run(ref.plan)
                 arrays, valids = batch_to_numpy(batch)
                 if sp is not None:
-                    sp.attributes["members"] = len(arrays[0])
+                    sp.attributes.update(members=len(arrays[0]),
+                                         fetchedSlots=batch.capacity)
             if len(arrays[0]) > 1:
                 raise RuntimeError(
                     "scalar subquery returned more than one row")
@@ -1417,6 +1446,8 @@ class Executor:
     # still pays full price in the join's random gathers, while compaction
     # itself is cheap (ascending-index gathers are quasi-sequential HBM)
     COMPACT_SHRINK = 2
+    # a batch under this is too small for a compaction to pay
+    COMPACT_MIN_ROWS = 1 << 16
 
     def maybe_compact(self, batch: Batch,
                       live: Optional[int] = None,
@@ -1427,8 +1458,8 @@ class Executor:
         matters to end-to-end latency. `node` keys
         the cross-run decision cache when the count must be fetched."""
         if live is None:
-            if batch.capacity < (1 << 16):
-                return batch          # too small for compaction to pay
+            if batch.capacity < self.COMPACT_MIN_ROWS:
+                return batch
             if self.chunk_mode:
                 return batch          # the chunked loop stays sync-free
             live = self.fetch_ints(node, "complive",
@@ -1955,6 +1986,18 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
 
     walk(root)
     return lines
+
+
+def member_set(arg: ir.Expr, vals) -> ir.InSet:
+    """arg IN vals (distinct, ascending, in arg's physical rep) as one
+    array operand of a program keyed by their bucket capacity, not by
+    their number: Q18's 69 to 666 members share one, and so does no
+    member at all."""
+    from ..types import BIGINT
+    vals = tuple(vals)
+    pad = bucket_capacity(len(vals)) - len(vals)
+    return ir.InSet(arg, vals + (vals[-1:] or (0,)) * pad,
+                    ir.Literal(len(vals), BIGINT))
 
 
 @recorded_jit(static_argnums=(2, 3))

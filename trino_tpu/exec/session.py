@@ -283,6 +283,7 @@ class Session:
         with self.tracer.span("execute") as sp:
             stats = self.executor.stats
             slots0 = stats.literal_slots
+            probes0 = stats.in_set_probes
             retries0 = stats.agg_capacity_retries
             spilled0 = _spilled_operators(stats)
             batch = self.executor.execute(root)
@@ -295,6 +296,8 @@ class Session:
                     residentEntries=len(resident),
                     scanPutBytes=self.executor.scan_put_bytes,
                     literalSlots=stats.literal_slots - slots0,
+                    inSetProbes=stats.in_set_probes - probes0,
+                    inSetCapacity=self.executor.in_set_capacity(),
                     aggCapacityRetries=stats.agg_capacity_retries
                     - retries0,
                     spilledOperators=_spilled_operators(stats)

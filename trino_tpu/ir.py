@@ -50,8 +50,9 @@ class Param(Expr):
 @dataclass(frozen=True)
 class ArrayParam:
     """A per-code lookup table's place (`DictPredicate.lut`,
-    `DictValueMap.values`): array operand `slot`. The pool's length is
-    the program's shape and stays in the template."""
+    `DictValueMap.values`) or a member set's (`InSet.members`): array
+    operand `slot`. The pool's length, or the set's capacity, is the
+    program's shape and stays in the template."""
     slot: int
     length: int
 
@@ -109,6 +110,23 @@ class IsNull(Expr):
 class InList(Expr):
     arg: Expr
     values: tuple       # tuple[Literal, ...] coerced to arg's physical rep
+    dtype: DataType = BOOLEAN
+
+
+@dataclass(frozen=True)
+class InSet(Expr):
+    """x IN <the members a folded subquery gave> (`InSubqueryRef` after
+    `Executor.fold_in_subquery`; never made by the planner). Where an
+    `InList`'s length is the SQL text's shape, a set's follows the data,
+    so the members are one array operand, tested in one expression:
+    `count` distinct values in arg's physical rep, ascending, padded to
+    a `batch.bucket_capacity` by repeating the last (a duplicate cannot
+    change membership; zeros under a count of 0, which matches no row),
+    so sets of different length, the empty one too, share a program.
+    Never NULL: the fold handles a NULL member."""
+    arg: Expr
+    members: object     # tuple of scalars, or ArrayParam once parametrised
+    count: Expr         # BIGINT Literal, or its Param
     dtype: DataType = BOOLEAN
 
 
@@ -311,6 +329,8 @@ def walk(expr: Expr):
         children = expr.args
     elif isinstance(expr, InList):
         children = (expr.arg,)
+    elif isinstance(expr, InSet):
+        children = (expr.arg, expr.count)
     elif isinstance(expr, Between):
         children = (expr.arg, expr.low, expr.high)
     elif isinstance(expr, Case):
@@ -350,6 +370,9 @@ def remap_columns(expr: Expr, mapping) -> Expr:
         return IsNull(remap_columns(expr.arg, mapping), expr.negated)
     if isinstance(expr, InList):
         return InList(remap_columns(expr.arg, mapping), expr.values)
+    if isinstance(expr, InSet):
+        return InSet(remap_columns(expr.arg, mapping), expr.members,
+                     expr.count)
     if isinstance(expr, Between):
         return Between(remap_columns(expr.arg, mapping),
                        remap_columns(expr.low, mapping),
@@ -434,7 +457,8 @@ def parametrise(exprs):
     decides neither Python control flow nor an array shape replaced by a
     slot: a non-NULL `Literal` of a numeric, boolean or temporal type
     (the members of an `InList` too; its length stays) by `Param`, the
-    lookup table of a `DictPredicate` / `DictValueMap` by `ArrayParam`.
+    lookup table of a `DictPredicate` / `DictValueMap` and the members
+    of an `InSet` (their capacity stays) by `ArrayParam`.
     NULL and VARCHAR literals, `ScalarFunc.params`, `DerivedDict` pools,
     `ArrayConst`, cast targets, scales and operators are shape and stay.
     Two expressions that differ only in slot values have equal templates,
@@ -471,6 +495,12 @@ def parametrise(exprs):
                 return type(e)(transform(e.arg, leaf),
                                ArrayParam(len(arrays) - 1, len(table)),
                                e.dtype)
+        if isinstance(e, InSet) and not isinstance(e.members, ArrayParam):
+            arrays.append(np.asarray(e.members,
+                                     dtype=e.arg.dtype.np_dtype))
+            return InSet(transform(e.arg, leaf),
+                         ArrayParam(len(arrays) - 1, len(e.members)),
+                         transform(e.count, leaf))
         return None
 
     def over(x):

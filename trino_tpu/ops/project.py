@@ -166,6 +166,34 @@ def _lookup_table(table, values, dtype) -> jax.Array:
     return jnp.asarray(table, dtype=dtype)
 
 
+# What `d IN members` costs on a v5e, int64, rows x capacity (chip run
+# of PR 42, PERF.md section 6). Every pair compared in one fused
+# reduction: 1.8 ps a pair whatever the live count (262,144 x 1,024 in
+# 1.03 ms, 16.7M x 1,024 in 31.3 ms, 16.7M x 4,096 in 117 ms). Rows and
+# members merged through one sort, then one gather: 28-40 ns a row
+# whatever the capacity (7.5 ms and 666 ms). A binary search pays the
+# gather's 22 ns an index at every step: 150-170 ns a row. The first
+# two meet near 20,000 members.
+IN_SET_PAIR_NS = 0.0018
+IN_SET_MERGE_NS_PER_ROW = 40.0
+
+
+def in_set(d: jax.Array, members: jax.Array, count) -> jax.Array:
+    """d IN members[:count] (`ir.InSet`: ascending; past `count` a
+    member repeats, or none exists). Every pair compared in one
+    expression (XLA fuses the compare into the reduction, rows x members
+    is never in memory), up to the capacity at which one merge of rows
+    and members costs less: a capacity is a shape, so the form is the
+    program's and not the data's."""
+    capacity = members.shape[0]
+    if capacity * IN_SET_PAIR_NS <= IN_SET_MERGE_NS_PER_ROW:
+        found = jnp.any(d[:, None] == members[None, :], axis=1)
+    else:
+        at = jnp.searchsorted(members, d, method="sort")
+        found = members[jnp.minimum(at, capacity - 1)] == d
+    return found & (count > 0)
+
+
 def eval_expr(expr: ir.Expr, batch: Batch, values=None):
     """Evaluate an IR expression over a batch. Returns (data, valid).
 
@@ -304,6 +332,13 @@ def eval_expr(expr: ir.Expr, batch: Batch, values=None):
                 else jnp.asarray(lit.value, dtype=d.dtype)
             res = res | (d == member)
         return res, v
+
+    if isinstance(expr, ir.InSet):
+        d, v = eval_expr(expr.arg, batch, values)
+        members = _lookup_table(expr.members, values, d.dtype)
+        count = _operand(expr.count, values) \
+            if isinstance(expr.count, ir.Param) else expr.count.value
+        return in_set(d, members.astype(d.dtype), count), v
 
     if isinstance(expr, ir.Between):
         # x BETWEEN lo AND hi == (x >= lo) AND (x <= hi) with Kleene AND
@@ -626,6 +661,13 @@ def project(batch: Batch, exprs, values=None) -> Batch:
         d, v = eval_expr(e, batch, values)
         cols.append(Column(data=d, valid=v))
     return Batch(columns=tuple(cols), live=batch.live)
+
+
+@recorded_jit(static_argnums=(2,))
+def filter_rows(batch: Batch, values, filter_expr) -> Batch:
+    """Jitted filter alone (a Filter over no Project: a join's filtered
+    probe side), keyed and fed as `filter_project`."""
+    return apply_filter(batch, filter_expr, values)
 
 
 @recorded_jit(static_argnums=(2, 3))

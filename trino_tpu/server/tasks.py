@@ -985,6 +985,7 @@ class TaskManager:
                 self._current_task_id = task.task_id
                 puts0 = ex.stats.value_puts
                 slots0 = ex.stats.literal_slots
+                probes0 = ex.stats.in_set_probes
                 saved_profile = ex.profile
                 saved_node_stats = ex.node_stats
                 if profiling:
@@ -1045,6 +1046,7 @@ class TaskManager:
                     self._current_task_id = None
                     ex._subst.clear()
                     ex._subst_opaque.clear()
+                    set_capacity = ex.in_set_capacity()
                     ex.release_all_reservations()
                     if held is not None:
                         held.close()    # what a failure or a cancel left
@@ -1058,6 +1060,12 @@ class TaskManager:
                         # split ever binds its own
                         wspan.attributes["literalSlots"] = \
                             ex.stats.literal_slots - slots0
+                        # dispatches of a program that tests a folded IN
+                        # subquery's members (one a split and node), and
+                        # the members' capacity, the program's shape
+                        wspan.attributes["inSetProbes"] = \
+                            ex.stats.in_set_probes - probes0
+                        wspan.attributes["inSetCapacity"] = set_capacity
                         # that the fold engaged: a folding task holds
                         # every split and stages 1 page, more if it
                         # flushed; any other a page a split
