@@ -186,7 +186,7 @@ def test_default_budget_is_derived_from_the_device(monkeypatch):
     else:                       # the CPU reports no limit: still finite
         assert here == device_cache.HOST_RESIDENT_BYTES < 1 << 40
     monkeypatch.setattr(profiler, "device_memory_stats",
-                        lambda: {"bytesLimit": 16 << 30})
+                        lambda device=None: {"bytesLimit": 16 << 30})
     assert device_cache.default_resident_bytes() == 8 << 30
     # the session's default defers to it; an explicit value overrides
     s = Session(default_schema="tiny")

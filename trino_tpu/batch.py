@@ -185,10 +185,13 @@ def batch_from_numpy(arrays: Sequence[np.ndarray],
                      valids: Optional[Sequence[Optional[np.ndarray]]] = None,
                      capacity: Optional[int] = None,
                      pad_multiple: int = 1024,
-                     live: Optional[jax.Array] = None) -> Batch:
+                     live: Optional[jax.Array] = None,
+                     device=None) -> Batch:
     """Build a device Batch from host numpy columns, padding to capacity.
 
-    One transfer call a batch. A column without a null mask takes the
+    One transfer call a batch, onto `device` where given (the batch is
+    then committed there and programs follow it), else the default
+    device. A column without a null mask takes the
     batch's `live` as its `valid` (the same device array: both are
     `arange(capacity) < n`), so only a column that has nulls sends a
     mask. `live`, where given, is that mask already on the device (a
@@ -212,7 +215,7 @@ def batch_from_numpy(arrays: Sequence[np.ndarray],
     host += [padded(valids[i], np.bool_) for i in masked]
     if live is None:
         host.append(padded(True, np.bool_))
-    put = jax.device_put(host)
+    put = jax.device_put(host, device)
     if live is None:
         live = put.pop()
     own = dict(zip(masked, put[len(arrays):]))

@@ -32,13 +32,14 @@ import numpy as np
 HOST_RESIDENT_BYTES = 8 << 30
 
 
-def default_resident_bytes() -> int:
-    """Half of the device's own `memory_stats()["bytes_limit"]`: the
+def default_resident_bytes(device=None) -> int:
+    """Half of `device`'s own (the process's first device's, where none
+    is given) `memory_stats()["bytes_limit"]`: the
     other half is a statement's (its projected columns, join LUTs, sort
     operands — TPC-H q18 at SF10 peaked 8.6 GB above its tables on a
     16 GB v5e, q3 2.2 GB)."""
     from .profiler import device_memory_stats
-    limit = device_memory_stats().get("bytesLimit") or 0
+    limit = device_memory_stats(device).get("bytesLimit") or 0
     return limit // 2 if limit else HOST_RESIDENT_BYTES
 
 
@@ -50,15 +51,18 @@ class ResidentSet:
     version by the identity of the connector's TableData held in the
     value), never which statement asked for it.
 
-    `max_bytes` None = `default_resident_bytes()`, resolved at first
-    use (asking the device initialises the backend). An entry larger
+    `max_bytes` None = `default_resident_bytes(device)`, resolved at
+    first use (asking the device initialises the backend); `device` is
+    the executor's own chip (`Executor.put_device`), None the process's
+    first. An entry larger
     than the whole budget is not kept: accounted bytes never exceed it.
     Eviction drops the set's references; the buffers go back to the
     allocator as soon as no running statement holds them either.
     """
 
-    def __init__(self, max_bytes: Optional[int] = None):
+    def __init__(self, max_bytes: Optional[int] = None, device=None):
         self._max_bytes = max_bytes
+        self.device = device
         # key -> (value, nbytes, on_evict)
         self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
         self._total = 0
@@ -69,7 +73,7 @@ class ResidentSet:
     @property
     def max_bytes(self) -> int:
         if self._max_bytes is None:
-            self._max_bytes = default_resident_bytes()
+            self._max_bytes = default_resident_bytes(self.device)
         return self._max_bytes
 
     @max_bytes.setter
@@ -423,10 +427,12 @@ class FactTableCache:
             if total > self.max_bytes:
                 return None
             t1 = _time.monotonic()
-            dev_payload = jax.device_put(np.ascontiguousarray(payload))
+            dev_payload = jax.device_put(np.ascontiguousarray(payload),
+                                         self.resident.device)
             d = decode_transfer(enc, dev_payload, cm)
             dv = None if valid_np is None else \
-                jax.device_put(np.ascontiguousarray(valid_np))
+                jax.device_put(np.ascontiguousarray(valid_np),
+                               self.resident.device)
             if prof_on:
                 jax.block_until_ready(d)
                 print(f"[ingest] col {i}: {payload.nbytes/1e6:.0f}MB "

@@ -20,10 +20,11 @@ from __future__ import annotations
 import math
 import os
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -128,13 +129,32 @@ def _subtree_nodes(node: "L.PlanNode"):
 
 
 class Executor:
-    def __init__(self, catalog: Catalog):
+    def __init__(self, catalog: Catalog, device=None):
         self.catalog = catalog
+        # the one local device this executor computes on (a worker's
+        # chip, server/tasks.py), or None for the process's default
+        # device (a session's executor): every host-to-device transfer
+        # is committed there and programs follow their operands; what a
+        # thread creates on the device without an operand (`on_device`)
+        # lands there too
+        self.device = device
+        from .profiler import device_label
+        self.device_label = device_label(device)
+        # where transfers are committed and this executor's threads are
+        # pointed: nowhere for the process's first device, which is
+        # where everything lands unasked. A worker on device 0 (every
+        # one-worker process) then dispatches exactly as an executor
+        # bound to none does: a commit and a thread's default device
+        # are no identity to the persistent compile cache (another key
+        # for the same program: a cold set-up once a cell) and perhaps
+        # not to `jit`'s dispatch (PERF.md section 6, PR 43)
+        self.put_device = None if device is None or \
+            device == jax.local_devices()[0] else device
         # everything this executor keeps on the device across statements
         # (exec/device_cache.ResidentSet): scanned table columns, the
         # chunked driver's fact tables and pinned builds — one budget
         from .device_cache import FactTableCache, ResidentSet
-        self.resident = ResidentSet()
+        self.resident = ResidentSet(device=self.put_device)
         # host-to-device bytes of this statement's scans (`scanPutBytes`
         # on the `execute` span)
         self.scan_put_bytes = 0
@@ -269,6 +289,24 @@ class Executor:
 
     # ------------------------------------------------------------------
 
+    def on_device(self):
+        """Context of a thread that works for this executor (a worker's
+        task thread, its split feeder): arrays made without an operand
+        (`jnp.asarray`, `jnp.zeros`, a bare `jax.device_put`) and
+        programs without a committed one land on `self.device` and not
+        on the process's first. `jax.default_device` is the thread's
+        own; with no device, or the process's first, it is the null
+        context (`put_device`)."""
+        if self.put_device is None:
+            return nullcontext()
+        return jax.default_device(self.put_device)
+
+    def bind_recorder(self) -> None:
+        """Compiles recorded on this thread are this executor's, on its
+        device (exec/profiler.py)."""
+        from .profiler import RECORDER
+        RECORDER.bind_stats(self.stats, self.device_label)
+
     def _revoke_caches(self, target_bytes: int) -> int:
         """Revocation callback: evict cached build batches (revocable
         reservations) until the target is met. Evicted builds re-run on
@@ -362,8 +400,7 @@ class Executor:
 
     def execute(self, root: L.OutputNode) -> Batch:
         assert isinstance(root, L.OutputNode)
-        from .profiler import RECORDER
-        RECORDER.bind_stats(self.stats)
+        self.bind_recorder()
         self._kill_reason = None
         self._cancel_reason = None
         self.strategy_decisions = {}
@@ -389,8 +426,7 @@ class Executor:
     def run(self, node: L.PlanNode) -> Batch:
         # bind this executor's stats to the dispatch thread so the
         # compile recorder attributes fresh XLA compiles here
-        from .profiler import RECORDER
-        RECORDER.bind_stats(self.stats)
+        self.bind_recorder()
         sub = self._subst.get(id(node))
         if sub is not None:
             return sub
@@ -828,7 +864,8 @@ class Executor:
         if node.ordinality:
             out_arrays.append((within + 1).astype(np.int64))
             out_valids.append(np.ones(len(row_idx), dtype=np.bool_))
-        return batch_from_numpy(out_arrays, valids=out_valids)
+        return batch_from_numpy(out_arrays, valids=out_valids,
+                                device=self.put_device)
 
     def run_values(self, node: L.ValuesNode) -> Batch:
         # a materialised broadcast build arrives as a ValuesNode with a
@@ -840,11 +877,11 @@ class Executor:
         if node.arrays:
             return batch_from_numpy(list(node.arrays),
                                     valids=list(node.valids),
-                                    capacity=cap)
+                                    capacity=cap, device=self.put_device)
         # zero-column values (SELECT without FROM): live mask only
         live = np.zeros(cap, dtype=np.bool_)
         live[:node.num_rows] = True
-        return Batch(columns=(), live=jnp.asarray(live))
+        return Batch(columns=(), live=self._place(live))
 
     def run_setop(self, node: L.SetOpNode) -> Batch:
         left = remap_codes(self.run(node.left), node.left_remaps)
@@ -917,8 +954,8 @@ class Executor:
         if not arrays:
             live = np.zeros(pad_capacity(len(out)), dtype=np.bool_)
             live[:len(out)] = True
-            return Batch(columns=(), live=jnp.asarray(live))
-        return batch_from_numpy(arrays, valids=valids)
+            return Batch(columns=(), live=self._place(live))
+        return batch_from_numpy(arrays, valids=valids, device=self.put_device)
 
     # ------------------------------------------------------------------
 
@@ -989,13 +1026,12 @@ class Executor:
             "resident": "miss" if put else "hit", "zonesPruned": pruned,
             "putBytes": put}
 
-    @staticmethod
-    def _host_batch(data, column_indices) -> Batch:
+    def _host_batch(self, data, column_indices) -> Batch:
         """The connector's rows put whole and kept nowhere."""
         arrays = [data.columns[i] for i in column_indices]
         valids = None if data.valids is None else \
             [data.valids[i] for i in column_indices]
-        return batch_from_numpy(arrays, valids=valids)
+        return batch_from_numpy(arrays, valids=valids, device=self.put_device)
 
     def _scan_capacity(self, rows: int) -> int:
         """Padded capacity of a table's device copy."""
@@ -1003,7 +1039,7 @@ class Executor:
 
     def _place(self, host: np.ndarray):
         """A host array onto this executor's device(s)."""
-        return jnp.asarray(host)
+        return jax.device_put(host, self.put_device)
 
     def _resident_column(self, table: tuple, index, data, cap: int, live):
         """-> (table column `index` as a device Column at `cap`, bytes
@@ -1941,7 +1977,6 @@ class Executor:
 
 
 import functools
-import jax
 
 from .profiler import recorded_jit
 

@@ -77,6 +77,12 @@ class PrefetchPipeline:
         return batch
 
     def _run(self, starts) -> None:
+        # the feeder works for its executor: what it puts lands on that
+        # executor's device (the thread's own default device)
+        with self.executor.on_device():
+            self._feed(starts)
+
+    def _feed(self, starts) -> None:
         from .memory import batch_bytes
         try:
             for s in starts:

@@ -131,11 +131,20 @@ class CompileRecorder:
 
     # -- per-thread attribution --------------------------------------------
 
-    def bind_stats(self, stats) -> None:
+    def bind_stats(self, stats, device=None) -> None:
         """Attribute compiles recorded on THIS thread to `stats`
         (ExecStats.jit_compiles). The executor binds its stats object at
-        dispatch entry; worker task threads each bind their own."""
+        dispatch entry; worker task threads each bind their own.
+        `device` is the label of the executor's own (`device_label`;
+        None: the default device): a program is compiled once a device,
+        so the fingerprints of calls recorded on this thread say which
+        (`thread_device`)."""
         self._tl.stats = stats
+        self._tl.device = device
+
+    def thread_device(self) -> Optional[str]:
+        """The label of the device this thread's executor is bound to."""
+        return getattr(self._tl, "device", None)
 
     def thread_compile_seconds(self) -> float:
         """Cumulative compile seconds recorded on this thread — the
@@ -360,6 +369,12 @@ def instrument(jitted: Callable, site: str,
                 # miss still learns what keyed it
                 fp = fingerprint
                 shape = None if hit else _arg_fingerprint(args, kwargs)[1]
+            dev = rec.thread_device()
+            if dev is not None:
+                # one compile a device: a shape met on a second chip is
+                # a new shape there, not a new literal
+                fp = f"{fp}@{dev}"
+                shape = shape and f"{shape}@{dev}"
             ev = rec.record(site, fp, dt, hit, shape)
             if not hit:
                 # the span lands inside whatever span of this thread's
@@ -394,13 +409,20 @@ def recorded_jit(site: Optional[str] = None, static_argnums=None,
     return deco
 
 
-def device_memory_stats() -> dict:
-    """Live device/HBM stats of this process's first accelerator, in the
-    /v1/status heartbeat shape. TPU/GPU backends report allocator stats;
-    CPU returns platform-only (the fields read 0)."""
+def device_label(device) -> Optional[str]:
+    """`<platform>:<id>` of a device (`worker-task.device`, the compile
+    recorder's fingerprints); None for None."""
+    return None if device is None else f"{device.platform}:{device.id}"
+
+
+def device_memory_stats(device=None) -> dict:
+    """Live device/HBM stats of `device` (a worker's own chip), of this
+    process's first accelerator where none is given, in the /v1/status
+    heartbeat shape. TPU/GPU backends report allocator stats; CPU
+    returns platform-only (the fields read 0)."""
     try:
         import jax
-        d = jax.local_devices()[0]
+        d = device if device is not None else jax.local_devices()[0]
         stats = None
         if hasattr(d, "memory_stats"):
             try:
@@ -408,6 +430,8 @@ def device_memory_stats() -> dict:
             except Exception:    # noqa: BLE001 — backend-dependent
                 stats = None
         out = {"platform": d.platform, "deviceCount": jax.local_device_count()}
+        if device is not None:
+            out["device"] = device_label(device)
         if stats:
             out["bytesInUse"] = int(stats.get("bytes_in_use", 0))
             out["bytesLimit"] = int(stats.get("bytes_limit", 0))

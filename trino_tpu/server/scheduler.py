@@ -133,6 +133,26 @@ def _merge_sorted_runs(sort_node, pages):
     return [a[order] for a in arrays], [v[order] for v in valids]
 
 
+# a node's memory pressure is read in this many steps of its pool
+PLACEMENT_PRESSURE_STEPS = 8
+
+
+def _placement_key(node) -> tuple:
+    """Order of the workers a stage's splits are dealt over: the node's
+    reported memory pressure in eighths of its pool, then its id. The
+    report is as old as the last heartbeat, and between two stages one
+    worker's says what it held a moment ago and another's says 0: bytes
+    compared as they stand would let the heartbeat's timing decide who
+    gets a stage's odd split, so which chip meets which fold's shape
+    (and compiles it inside a statement). Workers within a step of each
+    other are peers and keep one order."""
+    memory = getattr(node, "memory", None) or {}
+    reserved, limit = memory.get("reserved", 0), memory.get("limit", 0)
+    steps = reserved * PLACEMENT_PRESSURE_STEPS // limit if limit else \
+        int(reserved > 0)
+    return steps, node.node_id
+
+
 class _HedgedUnit:
     """One work unit (a node's split group) in a drain round. A unit may
     carry several concurrent attempts once hedged; `pages` is set exactly
@@ -703,8 +723,13 @@ class StageScheduler:
         for f in frags[:-1]:
             plan_f = self._bind_remotes(f.root, materialized)
             self._current_stage = f"build-{f.id}"
-            with tracer.span("build-stage", fragment=f.id):
+            hedges0 = self.stats["hedged_tasks"]
+            with tracer.span("build-stage", fragment=f.id) as bspan:
                 materialized[f.id] = self._run_build_stage(plan_f)
+                if bspan is not None:
+                    # its `source-stage`'s own count, seen from here
+                    bspan.attributes["hedges"] = \
+                        self.stats["hedged_tasks"] - hedges0
             if self.failure_injector is not None:
                 self.failure_injector.maybe_fail("STAGE_BOUNDARY", sql)
         self._current_stage = "source"
@@ -1312,13 +1337,10 @@ class StageScheduler:
             use_spool = analysis.driver.catalog in ("tpch", "tpcds")
             splits = self._make_splits(analysis)
             # memory-aware placement: order workers by heartbeat-reported
-            # reserved bytes so the round-robin lands extra splits on the
-            # least-pressured nodes first (UniformNodeSelector weighted by
-            # the ClusterMemoryManager's per-node view)
-            workers = sorted(
-                workers,
-                key=lambda w: (getattr(w, "memory", None) or {}).get(
-                    "reserved", 0))
+            # memory pressure so the round-robin lands extra splits on
+            # the least-pressured nodes first (UniformNodeSelector
+            # weighted by the ClusterMemoryManager's per-node view)
+            workers = sorted(workers, key=_placement_key)
             # uniform assignment (UniformNodeSelector's round-robin core)
             assignment: Dict[str, List[Split]] = {
                 w.node_id: [] for w in workers}
@@ -1338,12 +1360,16 @@ class StageScheduler:
                               self.retry_backoff_max_s,
                               max_attempts=self.max_task_retries + 2
                               ).delays()
+        hedges0 = self.stats["hedged_tasks"]
         with self._tracer().span("source-stage", splits=len(splits),
                                  workers=len(workers)) as stage:
             pages = self._drain_rounds(pending, by_id, blob, use_spool,
                                        backoff)
             if stage is not None:
                 stage.attributes["pages"] = len(pages)
+                # twins started for this stage's stragglers
+                stage.attributes["hedges"] = \
+                    self.stats["hedged_tasks"] - hedges0
         return pages
 
     def _drain_rounds(self, pending, by_id, blob, use_spool,
@@ -1424,11 +1450,12 @@ class StageScheduler:
         DRAINING workers) contributes to the migrated count and its
         nodes keep clean detector records.
 
-        Hedging: once enough units complete to establish a median drain
-        time, any unit still running past max(hedge_min_s, multiplier *
-        median) gets a second, speculative attempt on a node it has not
-        tried. The first successful attempt wins — a unit's attempts all
-        compute the same deterministic split set, drains are
+        Hedging: once half the round's units have completed, which
+        establishes a median drain time, any unit still running past
+        max(hedge_min_s, multiplier * median) gets a second, speculative
+        attempt on a node it has not tried. The first successful
+        attempt wins — a unit's attempts all compute the same
+        deterministic split set, drains are
         all-or-nothing, and only the winning attempt's pages are kept
         (the spool's work-key dedup gives later query attempts the same
         guarantee) — so hedging can duplicate WORK but never RESULTS."""
@@ -1584,7 +1611,15 @@ class StageScheduler:
                               if u.pages is None and u.live > 0]
                 if not unresolved:
                     break
-                med = statistics.median(durations) if durations else None
+                # a straggler is behind the median of its stage: half
+                # its peers have finished. One finished unit of four is
+                # no median: where one worker finds its programs
+                # compiled and three compile them (a chip each, a
+                # process's first statement), four times the one's wall
+                # passes while they do, and a twin that wins cancels a
+                # compile the next statement has to make again
+                med = statistics.median(durations) \
+                    if 2 * len(durations) >= len(units) else None
             # drain-aware hedging: a unit whose attempt is running on a
             # node the inventory now shows DRAINING hedges immediately —
             # the drain deadline may cut that attempt off, so a

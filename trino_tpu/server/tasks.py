@@ -357,10 +357,16 @@ class TaskManager:
     returns immediately (the reference's updateTask is async the same
     way)."""
 
-    def __init__(self, catalog, injector=None, node_id: str = "worker"):
+    def __init__(self, catalog, injector=None, node_id: str = "worker",
+                 device=None):
         import os
         self.catalog = catalog
         self.node_id = node_id            # span service attribution
+        # the ONE local device this worker computes on (WorkerServer
+        # deals them out in the order the process starts its workers);
+        # None: the process's default device, for a TaskManager that
+        # stands alone
+        self.device = device
         self.tasks: Dict[str, WorkerTask] = {}
         self._lock = threading.Lock()
         self.injector = injector          # FailureInjector hook
@@ -389,10 +395,11 @@ class TaskManager:
         # target: a DELETE for it interrupts the running split
         # cooperatively via the executor's check_cancel points)
         self._current_task_id: Optional[str] = None
-        # one Executor per worker: kernels are jitted process-wide anyway;
-        # the lock serializes device use within this worker
+        # one Executor per worker, bound to the worker's device: kernels
+        # are jitted process-wide and compiled once a device; the lock
+        # serializes device use within this worker
         from ..exec.executor import Executor
-        self._executor = Executor(catalog)
+        self._executor = Executor(catalog, device=device)
         # executor-side chaos points (e.g. SCAN_PREFETCH in the chunked
         # driver's prefetch worker) share this worker's injector, so the
         # same seeded schedule covers threads the task manager spawns
@@ -772,7 +779,8 @@ class TaskManager:
             count, live = last   # one read: two threads may decode
             chunk = batch_from_numpy(
                 arrays, valids=valids, capacity=cap,
-                live=live if count == split.count else None)
+                live=live if count == split.count else None,
+                device=self._executor.put_device)
             last = (split.count, chunk.live)
             return chunk
         return decode
@@ -925,8 +933,10 @@ class TaskManager:
         self.tasks_run += 1
         tracer = self._tracer_for(task)
         # the task's thread carries its tracer: the compile recorder
-        # (exec/profiler.py) finds it with tracing.current()
-        with tracing.use(tracer):
+        # (exec/profiler.py) finds it with tracing.current(); and its
+        # executor's device, so whatever the thread puts or runs without
+        # a committed operand is on this worker's chip
+        with tracing.use(tracer), self._executor.on_device():
             self._run_traced(task, tracer)
 
     def _run_traced(self, task: WorkerTask, tracer: Tracer) -> None:
@@ -939,7 +949,8 @@ class TaskManager:
                 self.injector.maybe_fail("WORKER_TASK_RUN", task.task_id)
             if task.sources is not None:
                 with tracer.span("worker-task", taskId=task.task_id,
-                                 node=self.node_id, kind="exchange"):
+                                 node=self.node_id, kind="exchange",
+                                 device=self._executor.device_label):
                     self._run_exchange_consumer(task, tracer, op_agg)
                 # final stats/spans land BEFORE the terminal state so a
                 # status fetch racing the transition never sees partials
@@ -972,7 +983,9 @@ class TaskManager:
             with self._exec_locked(tracer), \
                     tracer.span("worker-task", taskId=task.task_id,
                                 node=self.node_id,
-                                splits=len(task.splits)) as wspan:
+                                splits=len(task.splits),
+                                device=self._executor.device_label) \
+                    as wspan:
                 ex = self._executor
                 ex._subst.clear()
                 ex._subst_opaque.clear()
@@ -1217,7 +1230,8 @@ class TaskManager:
             nodes = by_fid.get(fid)
             arrs, vals = concat_pages(
                 pages, nodes[0].output if nodes else ())
-            batches[fid] = batch_from_numpy(arrs, valids=vals)
+            batches[fid] = batch_from_numpy(arrs, valids=vals,
+                                            device=self._executor.put_device)
 
         from ..batch import batch_to_numpy
         names = {id(n): type(n).__name__

@@ -19,6 +19,7 @@ into the query trace.
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -135,7 +136,8 @@ class _WorkerHandler(BaseHTTPRequestHandler):
                        self.worker.task_manager.memory_info(),
                    # live accelerator/HBM allocator stats (zeros
                    # off-TPU) — surfaced in system.runtime.nodes
-                   "device": device_memory_stats(),
+                   "device": device_memory_stats(
+                       self.worker.task_manager.device),
                    # persistent compile-cache report: operators verify
                    # cache-dir sharing across workers from here
                    "compileCache": compile_cache_stats()}
@@ -336,6 +338,19 @@ class _WorkerHandler(BaseHTTPRequestHandler):
         self._send(200, {"state": self.worker.state})
 
 
+# how many workers this process has started: the k-th computes on local
+# device k modulo their number, so the first is on device 0 (what every
+# one-worker process always used) and four workers on a four-chip host
+# take a chip each
+_WORKERS_STARTED = itertools.count()
+
+
+def _next_local_device():
+    import jax
+    devices = jax.local_devices()
+    return devices[next(_WORKERS_STARTED) % len(devices)]
+
+
 class WorkerServer:
     """One worker process stand-in: HTTP status endpoint + announcer loop.
 
@@ -387,7 +402,8 @@ class WorkerServer:
         from ..catalog import default_catalog
         from .tasks import TaskManager
         self.catalog = catalog if catalog is not None else default_catalog()
-        self.task_manager = TaskManager(self.catalog, node_id=node_id)
+        self.task_manager = TaskManager(self.catalog, node_id=node_id,
+                                        device=_next_local_device())
         self.task_manager.on_terminal = self._task_terminal
         handler = type("BoundWorkerHandler", (_WorkerHandler,),
                        {"worker": self})
