@@ -234,7 +234,6 @@ def test_durable_exchange_resumes_from_spool(cluster):
     injector = FailureInjector()
     injector.inject("STAGE_BOUNDARY", times=1)
     sched.failure_injector = injector
-    ran_before = sum(w.task_manager.tasks_run for w in workers)
     want = _local_rows(session, Q1)
     try:
         client = Client(coord.uri, user="test")
@@ -246,10 +245,14 @@ def test_durable_exchange_resumes_from_spool(cluster):
     assert r.state == "FINISHED"
     assert [tuple(row) for row in r.rows] == \
         [tuple(_json_vals(row)) for row in want]
-    # the retry consumed spooled outputs: no new task executions
-    ran_after = sum(w.task_manager.tasks_run for w in workers)
-    first_attempt_tasks = ran_after - ran_before
-    assert sched.stats["spool_hits"] >= first_attempt_tasks >= 1
+    # the retry consumed spooled outputs: every unit of its stage (one a
+    # worker) was answered by the spool, and the attempt that finished
+    # the query drained no task. Task RUNS on the workers are no measure
+    # of that: a straggler of the FIRST attempt (one worker compiling on
+    # a loaded host while its peers are done) gets a hedge twin, one more
+    # run for the same unit and the same spool entry
+    assert 1 <= sched.stats["spool_hits"] <= len(workers)
+    assert sched.last_query["tasks"] == []
 
 
 PART_Q = """

@@ -9,15 +9,47 @@ distributed execution layers on top in planner/physical.py.
 
 from __future__ import annotations
 
-from typing import Dict
+import hashlib
+import threading
+from typing import Dict, Optional
 
 from .connectors.tpch.connector import TpchConnector
+
+
+class PoolMismatchError(RuntimeError):
+    """A plan fragment named a string pool of this catalog by handle and
+    the pool held here is not the one the sender hashed: the strings
+    behind the codes would differ, so the task must not run."""
+
+
+def _pool_digest(pool: tuple) -> Optional[str]:
+    """sha256 over the pool's strings, each with its length; None for a
+    pool that holds anything but strings."""
+    try:
+        text = "".join(pool).encode("utf-8", "surrogatepass")
+    except TypeError:
+        return None
+    import numpy as np
+    h = hashlib.sha256()
+    h.update(np.fromiter(map(len, pool), dtype=np.int64,
+                         count=len(pool)).tobytes())
+    h.update(text)
+    return h.hexdigest()
 
 
 class Catalog:
     def __init__(self):
         self._connectors: Dict[str, object] = {}
         self._stats_cache: Dict[tuple, object] = {}
+        # string pools this catalog can name on the wire: id(pool) ->
+        # (the pool, (catalog, schema, table, column)), noted as tables
+        # of generator connectors are handed out; the entry holds the
+        # pool, so a recycled id never aliases. Digests are made on first
+        # use, once a pool object
+        self._pool_names: Dict[int, tuple] = {}
+        self._pool_digests: Dict[int, tuple] = {}
+        self._noted_schemas: Dict[int, object] = {}
+        self._pool_lock = threading.Lock()
         # monotonic catalog version: bumped on every DDL/write that goes
         # through the session (CREATE/DROP/INSERT/UPDATE/DELETE/MERGE).
         # The serving layer stamps every cached plan and result page with
@@ -42,7 +74,57 @@ class Catalog:
     def get_table(self, catalog: str, schema: str, table: str):
         if schema == "information_schema":
             return self.information_schema_table(catalog, table)
-        return self.connector(catalog).get_table(schema, table)
+        conn = self.connector(catalog)
+        data = conn.get_table(schema, table)
+        if hasattr(conn, "scale_for_schema") and \
+                self._noted_schemas.get(id(data.schema)) is not data.schema:
+            # generator connectors: every node makes the same table from
+            # the schema's scale, so a fragment may name its pools
+            self._noted_schemas[id(data.schema)] = data.schema
+            for f in data.schema.fields:
+                if f.dictionary is not None:
+                    self._pool_names[id(f.dictionary)] = (
+                        f.dictionary, (catalog, schema, table, f.name))
+        return data
+
+    def _digest_of(self, pool: tuple) -> Optional[str]:
+        hit = self._pool_digests.get(id(pool))
+        if hit is not None and hit[0] is pool:
+            return hit[1]
+        with self._pool_lock:          # four tasks decode at once
+            hit = self._pool_digests.get(id(pool))
+            if hit is None or hit[0] is not pool:
+                hit = (pool, _pool_digest(pool))
+                self._pool_digests[id(pool)] = hit
+        return hit[1]
+
+    def pool_handle(self, pool: tuple) -> Optional[tuple]:
+        """(catalog, schema, table, column, digest) if `pool` IS the pool
+        of a table column every node's catalog holds, else None: what a
+        plan fragment writes in the pool's place (server/serde.py)."""
+        hit = self._pool_names.get(id(pool))
+        if hit is None or hit[0] is not pool:
+            return None
+        digest = self._digest_of(pool)
+        return None if digest is None else hit[1] + (digest,)
+
+    def resolve_pool(self, catalog: str, schema: str, table: str,
+                     column: str, digest: str) -> tuple:
+        """This catalog's own pool for a handle, checked against the
+        sender's digest: never a silently different string."""
+        name = f"{catalog}.{schema}.{table}.{column}"
+        try:
+            pool = self.get_table(catalog, schema, table) \
+                .schema.field(column).dictionary
+        except KeyError as e:
+            raise PoolMismatchError(
+                f"string pool {name}: not in this node's catalog "
+                f"({e})") from e
+        if pool is None or self._digest_of(pool) != digest:
+            raise PoolMismatchError(
+                f"string pool {name}: this node's differs from the "
+                f"coordinator's (digest {digest[:12]} expected)")
+        return pool
 
     def get_table_stats(self, catalog: str, schema: str, table: str):
         """TableStats for an already-materialized table, else None —

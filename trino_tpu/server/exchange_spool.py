@@ -39,10 +39,18 @@ class ExchangeSpool:
         self.write_skips = 0              # best-effort puts that failed
 
     @staticmethod
-    def work_key(fragment_blob: str, splits) -> str:
-        """Digest of the task's deterministic work identity."""
+    def fragment_key(fragment_blob: bytes) -> bytes:
+        """Digest of a stage's fragment bytes, made once a stage: equal
+        fragments encode to equal bytes, and a string pool written as a
+        handle is there by its digest."""
+        return hashlib.sha256(fragment_blob).digest()
+
+    @staticmethod
+    def work_key(fragment_key: bytes, splits) -> str:
+        """Digest of the task's deterministic work identity: the stage's
+        `fragment_key` and the unit's splits."""
         h = hashlib.sha256()
-        h.update(fragment_blob.encode())
+        h.update(fragment_key)
         for s in splits:
             h.update(f"{s.catalog}.{s.schema_name}.{s.table}"
                      f":{s.start}+{s.count}".encode())

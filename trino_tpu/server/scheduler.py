@@ -43,7 +43,8 @@ from ..utils import tracing
 from .failureinjector import InjectedFailure
 from .pageserde import PageChecksumError, verify_page
 from .retrypolicy import RetryPolicy
-from .tasks import Split, decode_columns, encode_fragment
+from .tasks import (TASK_MEDIA_TYPE, Split, decode_columns, encode_fragment,
+                    task_body)
 
 log = logging.getLogger("trino_tpu.scheduler")
 
@@ -184,7 +185,7 @@ class _HedgedUnit:
 class RemoteTask:
     """Coordinator's proxy of one worker task (HttpRemoteTask.java:135)."""
 
-    def __init__(self, node, task_id: str, fragment_blob: str,
+    def __init__(self, node, task_id: str, fragment_blob: bytes,
                  splits: List[Split], http_timeout_s: float = 30.0,
                  partition: Optional[dict] = None,
                  sources: Optional[dict] = None, injector=None,
@@ -211,11 +212,12 @@ class RemoteTask:
         return f"{self.node.uri}/v1/task/{self.task_id}{suffix}"
 
     def _request(self, url: str, data: Optional[bytes] = None,
-                 method: str = "GET", accept: str = ""):
+                 method: str = "GET", accept: str = "",
+                 content_type: str = "application/json"):
         """JSON request; with `accept` = the binary pages media type the
         response may instead be a raw page frame (returned as bytes)."""
         from .security import internal_headers
-        headers = {"Content-Type": "application/json",
+        headers = {"Content-Type": content_type,
                    **internal_headers()}
         if accept:
             headers["Accept"] = accept
@@ -229,11 +231,11 @@ class RemoteTask:
                 return bytes(body)
             return json.loads(body.decode()) if body else {}
 
-    def start(self) -> None:
-        payload = {
-            "fragment": self.fragment_blob,
-            "splits": [vars(s) for s in self.splits],
-        }
+    def start(self) -> int:
+        """POST the task: what differs by task as a small envelope, then
+        the stage's fragment bytes as they were built, once a stage.
+        Returns the bytes posted."""
+        payload = {"splits": [vars(s) for s in self.splits]}
         if self.partition is not None:
             payload["partition"] = self.partition
         if self.sources is not None:
@@ -244,8 +246,10 @@ class RemoteTask:
             # deadline so a skewed worker enforces the same instant
             payload["deadline"] = self.deadline + \
                 getattr(self.node, "clock_offset", 0.0)
-        body = json.dumps(payload).encode()
-        self._request(self._url(), data=body, method="POST")
+        body = task_body(payload, self.fragment_blob)
+        self._request(self._url(), data=body, method="POST",
+                      content_type=TASK_MEDIA_TYPE)
+        return len(body)
 
     def wait_finished(self, deadline: float) -> None:
         """Poll task status until FINISHED (producer stages whose buffers
@@ -886,7 +890,7 @@ class StageScheduler:
         traceparent = self._tracer().traceparent()
         splits = self._make_splits(analysis)
         blob = encode_fragment({"root": src_root,
-                                "driver": analysis.driver})
+                                "driver": analysis.driver}, sess.catalog)
         src_tasks = []
         live: Dict[int, list] = {}
         _os.makedirs(table_dir, exist_ok=True)
@@ -928,9 +932,9 @@ class StageScheduler:
                         table_dir=table_dir, fmt=conn.fmt, query_id=qid,
                         stage=1, partition=p, attempt=tid,
                         fields=tuple(out_fields), output=(("rows", BIGINT),))
-                    wblob = encode_fragment({"root": node,
-                                             "timeout_s":
-                                                 self.task_timeout_s})
+                    wblob = encode_fragment(
+                        {"root": node, "timeout_s": self.task_timeout_s},
+                        sess.catalog)
                     sources = {"1": [{"uri": t.node.uri, "taskId": t.task_id,
                                       "buffer": p} for t in src_tasks]}
                     task = RemoteTask(w, tid, wblob, [], sources=sources,
@@ -1329,12 +1333,16 @@ class StageScheduler:
                 # unprofiled spooled output). Tracing alone does not:
                 # spans cost spans.
                 frag["profile"] = True
-            blob = encode_fragment(frag)
+            stats: Dict[str, int] = {}
+            blob = encode_fragment(frag, self.session.catalog, stats)
             # the work key hashes (fragment, splits) but not data
             # contents: only deterministic generator sources may reuse
             # spooled outputs (a memory-connector table can change
             # between attempts)
             use_spool = analysis.driver.catalog in ("tpch", "tpcds")
+            # hashed once a stage; a unit's key adds its splits
+            fragment_key = self.spool.fragment_key(blob) \
+                if use_spool else b""
             splits = self._make_splits(analysis)
             # memory-aware placement: order workers by heartbeat-reported
             # memory pressure so the round-robin lands extra splits on
@@ -1348,7 +1356,8 @@ class StageScheduler:
             for i, s in enumerate(splits):
                 assignment[workers[i % len(workers)].node_id].append(s)
             if prep is not None:
-                prep.attributes.update(bytes=len(blob), splits=len(splits))
+                prep.attributes.update(bytes=len(blob), splits=len(splits),
+                                       **stats)
 
         pages: List[dict] = []
         pending = {nid: sp for nid, sp in assignment.items() if sp}
@@ -1363,8 +1372,8 @@ class StageScheduler:
         hedges0 = self.stats["hedged_tasks"]
         with self._tracer().span("source-stage", splits=len(splits),
                                  workers=len(workers)) as stage:
-            pages = self._drain_rounds(pending, by_id, blob, use_spool,
-                                       backoff)
+            pages = self._drain_rounds(pending, by_id, blob, fragment_key,
+                                       use_spool, backoff)
             if stage is not None:
                 stage.attributes["pages"] = len(pages)
                 # twins started for this stage's stragglers
@@ -1372,8 +1381,8 @@ class StageScheduler:
                     self.stats["hedged_tasks"] - hedges0
         return pages
 
-    def _drain_rounds(self, pending, by_id, blob, use_spool,
-                      backoff) -> List[bytes]:
+    def _drain_rounds(self, pending, by_id, blob, fragment_key,
+                      use_spool, backoff) -> List[bytes]:
         pages: List[bytes] = []
         retries = 0
         migration_rounds = 0
@@ -1383,14 +1392,12 @@ class StageScheduler:
                 raise QueryTerminatedError(
                     "query terminated during stage drain")
             units: List[_HedgedUnit] = []
-            # the work key hashes the whole fragment, broadcast build
-            # included: tens of milliseconds for a 35 MB one
             with self._tracer().span("spool-lookup", units=len(pending)):
                 for nid, sp in list(pending.items()):
                     # durable-exchange hit: a prior attempt already
                     # produced this work's output — consume the spool,
                     # skip dispatch
-                    key = self.spool.work_key(blob, sp)
+                    key = self.spool.work_key(fragment_key, sp)
                     spooled = self.spool.get(key) if use_spool else None
                     if spooled is not None:
                         pages.extend(spooled)
@@ -1439,7 +1446,7 @@ class StageScheduler:
             pending = {nid: sp for nid, sp in redo.items() if sp}
         return pages
 
-    def _drain_units(self, units: List["_HedgedUnit"], by_id, blob: str,
+    def _drain_units(self, units: List["_HedgedUnit"], by_id, blob: bytes,
                      use_spool: bool, pages: List[bytes]
                      ) -> Tuple[List[Split], Set[str], int]:
         """Dispatch and drain one round of work units CONCURRENTLY with
@@ -1491,8 +1498,10 @@ class StageScheduler:
             losers: List[RemoteTask] = []
             try:
                 with tracer.span("task-create", taskId=tid,
-                                 splits=len(unit.splits)):
-                    task.start()
+                                 splits=len(unit.splits)) as create:
+                    posted = task.start()
+                    if create is not None:
+                        create.attributes["bytes"] = posted
                     self._ledger_assign(task)
                     self._livestats_register(task)
                 self.stats["tasks"] += 1
@@ -1802,7 +1811,8 @@ class StageScheduler:
         traceparent = self._tracer().traceparent()
 
         def stage_tasks(side_root, driver, keys):
-            blob = encode_fragment({"root": side_root, "driver": driver})
+            blob = encode_fragment({"root": side_root, "driver": driver},
+                                   self.session.catalog)
             rows = self.session.catalog.get_table(
                 driver.catalog, driver.schema_name, driver.table).num_rows
             splits = [Split(driver.catalog, driver.schema_name,
@@ -1840,7 +1850,8 @@ class StageScheduler:
         c_root = L.replace_nodes(
             merge_agg, {id(join.left): rs_a, id(join.right): rs_b})
         blob_c = encode_fragment({"root": c_root,
-                                  "timeout_s": self.task_timeout_s})
+                                  "timeout_s": self.task_timeout_s},
+                                 self.session.catalog)
         c_tasks = []
         for p in range(P):
             sources = {

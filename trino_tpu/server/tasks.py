@@ -20,7 +20,9 @@ PartitionedOutputBuffer.java:42) reduced to its sequential-consumer core.
 from __future__ import annotations
 
 import base64
+import json
 import logging
+import struct
 import threading
 import time
 import traceback
@@ -95,17 +97,54 @@ def concat_pages(pages, out_types) -> tuple:
     return arrs, [np.zeros(0, dtype=np.bool_) for _ in arrs]
 
 
-def encode_fragment(root) -> str:
-    """Plan subtree -> wire form: a data-only JSON serde (server/serde.py),
-    the analog of the reference's Jackson-serialized PlanFragment — a
-    crafted POST body can at worst build a malformed plan, never run code."""
+def encode_fragment(root, catalog=None, stats: dict = None) -> bytes:
+    """Plan subtree -> wire form: a data-only serde (server/serde.py), the
+    analog of the reference's Jackson-serialized PlanFragment — a crafted
+    POST body can at worst build a malformed plan, never run code. One
+    body of bytes (version 2), built once a stage and posted to every
+    worker as it is; string pools `catalog` holds go as handles."""
     from . import serde
-    return serde.dumps(root)
+    return serde.dumps_bytes(root, pools=catalog, stats=stats)
 
 
-def decode_fragment(blob: str):
+def decode_fragment(blob, catalog=None, stats: dict = None):
+    """Inverse of `encode_fragment`; also takes version 1's JSON text (as
+    `str` or as its bytes), which older coordinators and tests post."""
     from . import serde
-    return serde.loads(blob)
+    if serde.is_bytes_form(blob):
+        return serde.loads_bytes(blob, pools=catalog, stats=stats)
+    return serde.loads(blob if isinstance(blob, str)
+                       else bytes(blob).decode())
+
+
+# POST /v1/task body, media type TASK_MEDIA_TYPE: what differs by task
+# (splits, partition, sources, deadline) as a small JSON envelope, then
+# the stage's fragment bytes untouched:
+#   u32 envelope length | envelope | padding to 64 | fragment
+TASK_MEDIA_TYPE = "application/x-trino-task"
+
+
+def task_body(envelope: dict, fragment: bytes) -> bytes:
+    from .serde import pad
+    head = json.dumps(envelope).encode()
+    return b"".join([struct.pack("<I", len(head)), head,
+                     bytes(pad(4 + len(head))), fragment])
+
+
+def split_task_body(body: bytes) -> tuple:
+    """(envelope, fragment): the fragment a view of `body`, not a copy."""
+    from .serde import pad
+    view = memoryview(body)
+    if len(view) < 4:
+        raise ValueError("truncated task body")
+    (n,) = struct.unpack_from("<I", view)
+    start = 4 + n + pad(4 + n)
+    if start > len(view):
+        raise ValueError("truncated task body")
+    envelope = json.loads(bytes(view[4:4 + n]))
+    if not isinstance(envelope, dict):
+        raise ValueError("task envelope is not an object")
+    return envelope, view[start:]
 
 
 def _subtree_nodes_all(root):
@@ -288,7 +327,9 @@ class WorkerTask:
     from upstream tasks on other workers (worker<->worker data plane,
     DirectExchangeClient.java:56)."""
     task_id: str
-    fragment_blob: str
+    # the stage's fragment as posted: version 2's bytes (a view of the
+    # POST body) or version 1's text
+    fragment_blob: object
     splits: List[Split]
     # {"keys": [out col idx, ...], "count": P} -> partitioned output
     partition: Optional[dict] = None
@@ -960,9 +1001,12 @@ class TaskManager:
                         task.state = "FINISHED"
                 return
             with tracer.span("task-decode",
-                             bytes=len(task.fragment_blob)):
-                # broadcast builds ride inside the fragment
-                fragment = decode_fragment(task.fragment_blob)
+                             bytes=len(task.fragment_blob)) as dspan:
+                # broadcast builds ride inside the fragment; the string
+                # pools this worker's catalog holds are linked in from it
+                fragment = decode_fragment(
+                    task.fragment_blob, self.catalog,
+                    dspan.attributes if dspan is not None else None)
             root, driver_scan = fragment["root"], fragment["driver"]
             cap = bucket_capacity(max(s.count for s in task.splits)) \
                 if task.splits else 1024
@@ -1196,7 +1240,7 @@ class TaskManager:
 
         from ..batch import batch_from_numpy
         from ..planner import logical as L
-        fragment = decode_fragment(task.fragment_blob)
+        fragment = decode_fragment(task.fragment_blob, self.catalog)
         root = fragment["root"]
         writer = None
         if isinstance(root, L.TableWriterNode):

@@ -292,14 +292,23 @@ class _WorkerHandler(BaseHTTPRequestHandler):
             self._send(409, {"error": f"node is {self.worker.state}",
                              "errorName": "NODE_DRAINING"})
             return
-        n = int(self.headers.get("Content-Length", 0))
-        body = json.loads(self.rfile.read(n).decode())
         from .failureinjector import InjectedFailure
-        from .tasks import Split
+        from .tasks import TASK_MEDIA_TYPE, Split, split_task_body
+        n = int(self.headers.get("Content-Length", 0))
+        raw = self.rfile.read(n)
+        if self.headers.get("Content-Type", "").startswith(TASK_MEDIA_TYPE):
+            # envelope, then the stage's fragment bytes: read once, the
+            # task decodes its arrays as views of this body
+            body, fragment = split_task_body(raw)
+        else:
+            # the JSON form (older coordinators): the fragment is version
+            # 1's text, a string of the document
+            body = json.loads(raw.decode())
+            fragment = body["fragment"]
         splits = [Split(**s) for s in body.get("splits", [])]
         try:
             task = self.worker.task_manager.create_or_update(
-                parts[2], body["fragment"], splits,
+                parts[2], fragment, splits,
                 partition=body.get("partition"),
                 sources=body.get("sources"),
                 traceparent=self.headers.get("traceparent"),
