@@ -418,7 +418,8 @@ def test_strategy_decision_metrics_move():
     # pre-initialized families (lint also enforces this)
     for strat in ("global", "direct", "mxu", "sort"):
         assert AGG_STRATEGY_DECISIONS.has_sample(strategy=strat)
-    joins = ("dense-lut", "sort-probe", "sort-merge", "sorted", "expand")
+    joins = ("dense-lut", "dense-lut-packed", "sort-probe", "sort-merge",
+             "sorted", "expand")
     for strat in joins:
         assert JOIN_STRATEGY_DECISIONS.has_sample(strategy=strat)
     s = Session(default_schema="tiny")

@@ -191,6 +191,48 @@ def test_packed_sort_aggregate_gathers_by_form(one_chip, form):
 
 
 # ---------------------------------------------------------------------------
+# a split's join over a pinned build, at worker.join's shapes: a 250,000-row
+# lineitem split probing the 60M-key LUT of a 1,572,864-row `orders` build
+# with two payload columns. The row-id form gathers once for the row and
+# once for each payload plane; the packed form gathers its word
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("form", ["packed-int32", "packed-int64", "rows"])
+def test_a_split_join_gathers_once_when_its_lut_is_packed(one_chip, form):
+    import re
+
+    from trino_tpu.batch import Batch, Column, bucket_capacity
+    from trino_tpu.ops.join import dense_join_packed, dense_join_with_lut
+    n, domain, build_rows = bucket_capacity(250_000), 60_000_000, 1_572_864
+
+    def shape(dtype, length):
+        return jax.ShapeDtypeStruct((length,), dtype, sharding=one_chip)
+
+    def batch(length, *dtypes):
+        return Batch(tuple(Column(shape(d, length), shape(jnp.bool_, length))
+                           for d in dtypes), shape(jnp.bool_, length))
+    probe = batch(n, jnp.int64, jnp.int64, jnp.int64)
+    if form == "rows":
+        build = batch(build_rows, jnp.int64, jnp.int32, jnp.int32)
+        text = dense_join_with_lut.__wrapped__.lower(
+            probe, build, shape(jnp.int32, domain + 1), (0,), (0,),
+            "inner").compile().as_text()
+    else:
+        word = jnp.int32 if form == "packed-int32" else jnp.int64
+        meta = ((1, 16, 1, 17), (2, 8, 18, 26))
+        text = dense_join_packed.__wrapped__.lower(
+            probe, shape(word, domain + 1), shape(jnp.int64, 2), (0,),
+            meta, 0, ("int64", "int32", "int32"), "inner").compile().as_text()
+    gathers = re.findall(r"= \w+\[(\d+)\]\S* gather\(", text)
+    assert set(gathers) == {str(n)}
+    # the compiler gathers a 32-bit plane at a time: an int64 word is
+    # two, and the row-id form's five are the row, the validity word's
+    # two planes and the two columns
+    assert len(gathers) == {"packed-int32": 1, "packed-int64": 2,
+                            "rows": 5}[form]
+
+
+# ---------------------------------------------------------------------------
 # filter_project with its literals as operands: q6's filter at a 250,000-row
 # split's capacity, and a decimal comparison whose LITERAL has the larger
 # scale (the traced scalar is the side _decimal_compare floor-divides)

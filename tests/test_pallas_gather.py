@@ -174,25 +174,25 @@ def test_windowed_join_site_parity():
     bk = rng.permutation(domain)[:nb].astype(np.int64)
     bval = rng.integers(-500, 500, nb).astype(np.int64)
     build = batch_from_numpy([bk, bval])
-    meta = ((1, -500, 10, 1, 11),)
+    meta, los = ((1, 16, 1, 17),), jnp.asarray([-500])
     lut, exp, oob, occ = dense_build_packed_lut(build, (0,), domain,
-                                                meta, "int32")
+                                                meta, "int32", los)
     probe = batch_from_numpy(
         [np.sort(rng.integers(0, domain, np_)).astype(np.int64),
          rng.integers(0, 9, np_).astype(np.int64)])
     out_dtypes = ("int64", "int64")
     planes = pg.prepare_word_planes(lut)
     o_xla, e_xla, s_xla = dense_join_packed_windowed(
-        probe, lut, (0,), meta, 0, out_dtypes, "inner", 8192)
+        probe, lut, los, (0,), meta, 0, out_dtypes, "inner", 8192)
     o_pal, e_pal, s_pal = dense_join_packed_windowed(
-        probe, lut, (0,), meta, 0, out_dtypes, "inner", 8192,
+        probe, lut, los, (0,), meta, 0, out_dtypes, "inner", 8192,
         word_dtype="int32", gather_mode="interpret", lut_planes=planes)
     assert int(e_xla) == 0 and int(e_pal) == 0
     assert int(s_xla) == int(s_pal)
     assert rows_of(o_xla) == rows_of(o_pal)
     # and both agree with the full-table probe
-    o_full = dense_join_packed(probe, lut, (0,), meta, 0, out_dtypes,
-                               "inner", "interpret")
+    o_full = dense_join_packed(probe, lut, los, (0,), meta, 0,
+                               out_dtypes, "inner", "interpret")
     assert rows_of(o_full) == rows_of(o_pal)
 
 

@@ -238,8 +238,8 @@ def test_a_worker_task_opens_operator_spans_beside_its_splits(
         worker_cluster):
     """A traced task's operators have spans too: in a split they hang
     under `worker-task` beside the `split` lap (or under the operator
-    they run inside), say which split they are and nothing else, and are
-    nobody's `compile` parent. tests/test_tracing_phases.py holds the
+    they run inside), say which split they are and, a join, the form of
+    its LUT, and are nobody's `compile` parent. tests/test_tracing_phases.py holds the
     split loop's spans to more."""
     worker_cluster.client.execute("SET SESSION enable_tracing = true")
     try:
@@ -255,7 +255,15 @@ def test_a_worker_task_opens_operator_spans_beside_its_splits(
     in_splits = [sp for sp in operators if "split" in sp["attributes"]]
     assert {"aggregate", "join"} <= {sp["name"] for sp in in_splits}
     for sp in in_splits:
-        assert list(sp["attributes"]) == ["split"]
+        said = dict(sp["attributes"])
+        assert isinstance(said.pop("split"), int)
+        if sp["name"] == "join":
+            # every lap says which LUT it probed, a refusal also why
+            form, bits = said.pop("lutForm"), said.pop("wordBits")
+            assert bits in ((8, 16, 32, 64) if form == "packed" else (32,))
+            assert (form == "rows") == ("packRefused" in said)
+            said.pop("packRefused", None)
+        assert not said
         assert ids[sp["parentSpanId"]]["name"] in (
             "worker-task", "join", "aggregate", "filter-project")
     taken = {sp["spanId"] for sp in operators}

@@ -92,22 +92,28 @@ def test_join_query_distributes(cluster):
 def test_split_loop_builds_the_join_lut_once_per_task(cluster):
     """A task's split loop is a chunked loop: the pinned build side's
     dense LUT is built once and every split probes it (before, each
-    split re-scattered a LUT the size of the key domain)."""
+    split re-scattered a LUT the size of the key domain). Whichever
+    form the LUT takes: the row-id one, or the one whose word carries
+    the build's payload, which q3's builds fit."""
     from trino_tpu.exec.profiler import RECORDER
     coord, workers, _session = cluster
 
     def lut_builds():
         return sum(e["compiles"] + e["hits"] for e in RECORDER.snapshot()
-                   if e["site"] == "join.dense_build_lut")
+                   if e["site"] in ("join.dense_build_lut",
+                                    "join.dense_build_packed_lut"))
+
+    def probes(counter):
+        return sum(getattr(w.task_manager._executor.stats, counter)
+                   for w in workers)
     builds0, tasks0 = lut_builds(), \
         sum(w.task_manager.tasks_run for w in workers)
     r = Client(coord.uri, user="test").execute(Q3)
     assert r.state == "FINISHED"
     tasks = sum(w.task_manager.tasks_run for w in workers) - tasks0
-    probes = sum(w.task_manager._executor.stats.chunk_lut_joins
-                 for w in workers)
     # tiny lineitem is 8 splits of 8,192 rows over 3 workers
-    assert probes > tasks >= 3
+    assert probes("chunk_lut_joins") > tasks >= 3
+    assert 0 < probes("packed_lut_joins") <= probes("chunk_lut_joins")
     assert 0 < lut_builds() - builds0 <= tasks
     for w in workers:
         assert not w.task_manager._executor.chunk_mode

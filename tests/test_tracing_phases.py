@@ -603,9 +603,13 @@ def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling):
             (key, names)
         for s in ops[key]:
             # under the task, beside the lap, inside it on the clock,
-            # and saying which split and nothing else
+            # and saying which split and, a join, the form of its LUT:
+            # the one-column payload (five priorities) rides in the
+            # LUT's word, 1 + 8 + 1 bits in an int16
             assert ids[s["parentSpanId"]]["name"] == "worker-task"
-            assert s["attributes"] == {"split": key[1]}
+            assert s["attributes"] == dict(
+                {"split": key[1]}, **({"lutForm": "packed", "wordBits": 16}
+                                      if s["name"] == "join" else {}))
             assert _inside(s, lap), (s, lap)
         own = sorted(_interval(s) for s in ops[key])
         for (_, end), (start, _) in zip(own, own[1:]):
@@ -616,7 +620,7 @@ def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling):
     for s in filters:
         join = ids[s["parentSpanId"]]
         assert join["name"] == "join" and _inside(s, join)
-        assert s["attributes"] == join["attributes"]
+        assert s["attributes"] == {"split": join["attributes"]["split"]}
     # the lap keeps what it had: `compile` under `split`, never an
     # operator's child; no operator span is a lap's child
     taken = {s["spanId"] for v in ops.values() for s in v} | \
@@ -731,7 +735,8 @@ def test_tracing_off_a_task_builds_no_operator_span(cluster, monkeypatch):
 # the broadcast build's hand-over: once a task, at a lattice capacity
 # ---------------------------------------------------------------------------
 
-JOIN_SITES = ("join.dense_build_lut", "join.dense_join_with_lut")
+JOIN_SITES = ("join.payload_ranges", "join.dense_build_packed_lut",
+              "join.dense_join_packed")
 
 
 def orders_join(before: str) -> str:

@@ -319,8 +319,11 @@ def test_what_a_task_puts_pins_and_holds_is_on_its_device(monkeypatch):
                    *rest)
         seen["held"] = [leaf.devices() for b in held.state.device
                         for leaf in jax.tree_util.tree_leaves(b)]
-        seen["luts"] = [v.devices() for v in ex._chunk_lut_cache.values()
-                        if v is not False]
+        # the LUT and, of a packed one, the offsets beside it
+        seen["luts"] = [leaf.devices()
+                        for lut, packed, _ in ex._chunk_lut_cache.values()
+                        for leaf in jax.tree_util.tree_leaves((lut, packed))
+                        if isinstance(leaf, jax.Array)]
         return out
 
     monkeypatch.setattr(TaskManager, "_split_decoder", spied_decoder)

@@ -106,38 +106,6 @@ def _spine_joins(target: L.PlanNode, driver: L.ScanNode) \
     return joins if walk(target) else None
 
 
-# value-packing caps: at most this many payload columns, packed word
-# must fit int64 with the sign bit untouched
-_PACK_MAX_COLS = 4
-_PACK_MAX_BITS = 62
-
-
-def _plan_packing(build: Batch, node: L.JoinNode, mins, maxs):
-    """Static packing meta for a build whose payload values fit one
-    word: ((col_idx, lo, width, val_off, valid_off), ...), word dtype
-    name. None when not packable (caller keeps the row-id LUT)."""
-    bkey = node.right_keys[0] if len(node.right_keys) == 1 else None
-    payload = [i for i in range(len(build.columns)) if i != bkey]
-    if len(payload) > _PACK_MAX_COLS:
-        return None
-    meta = []
-    off = 1                                   # bit0 = presence
-    for j, i in enumerate(payload):
-        if not jnp.issubdtype(build.columns[i].data.dtype, jnp.integer):
-            return None
-        lo, hi = int(mins[j]), int(maxs[j])
-        if hi < lo:
-            lo, hi = 0, 0
-        width = max(1, int(hi - lo + 1).bit_length())
-        meta.append((i, lo, width, off, off + width))
-        off += width + 1
-    if off > _PACK_MAX_BITS:
-        return None
-    word_dtype = "int8" if off <= 7 else "int16" if off <= 15 else \
-        "int32" if off <= 31 else "int64"
-    return tuple(meta), word_dtype
-
-
 def compile_fused_chunk(executor, target: L.PlanNode,
                         driver: L.ScanNode, lut_specs=None, adapt=None,
                         gather_mode: str = "off"):
@@ -153,7 +121,9 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     `lut_specs` maps id(join node) -> spec from _fused_luts: ("rows",)
     joins gather per payload column off a row-id LUT; ("packed", meta,
     word_dtype, bkey, out_dtypes) joins decode everything from ONE
-    value-packed gather.
+    value-packed gather, and their entry of `luts` is the pair (LUT,
+    the word's offsets `los`): the spec holds the schema's statics, the
+    operands what follows the data.
 
     `adapt` applies a previous run's measurements (AdaptivePlanner.java:87's
     role, replayed through the cross-run decision cache): {join_idx: W}
@@ -228,15 +198,16 @@ def compile_fused_chunk(executor, target: L.PlanNode,
                 esc = jnp.int64(0)
                 if _spec is not None and _spec[0] == "packed":
                     _, meta, _wd, bkey, out_dtypes = _spec
+                    lut, los = l[_idx]
                     if _win is not None:
                         gp = g[_idx] if _idx < len(g) else None
                         out, esc, span = dense_join_packed_windowed(
-                            bt, l[_idx], _lk, meta, bkey, out_dtypes,
+                            bt, lut, los, _lk, meta, bkey, out_dtypes,
                             _kind, _win, word_dtype=_wd,
                             gather_mode=gather_mode, lut_planes=gp)
                     else:
                         out = dense_join_packed(
-                            bt, l[_idx], _lk, meta, bkey, out_dtypes,
+                            bt, lut, los, _lk, meta, bkey, out_dtypes,
                             _kind, gather_mode)
                         span = _key_span(bt, _lk)
                 else:
@@ -310,7 +281,9 @@ def _fused_luts(executor, joins) -> Optional[tuple]:
     replay). Uncacheable builds fuse all stats into one device fetch and
     all validations into a second. Any violation aborts the fused path
     (the per-node loop has the graceful fallbacks)."""
-    from ..ops.join import dense_build_lut, dense_build_packed_lut
+    from ..ops.join import (dense_build_lut, dense_build_packed_lut,
+                            pack_refusal, packed_word_dtype,
+                            payload_ranges, plan_packed_word)
     n = len(joins)
     builds = [executor.run(j.right) for j in joins]
     luts: List[object] = [None] * n
@@ -335,37 +308,23 @@ def _fused_luts(executor, joins) -> Optional[tuple]:
         # j.right. A FRESH process then replays with zero device syncs.
         # Uncacheable builds keep the old behavior: ALL their stats fuse
         # into one fetch and all their validations into a second.
-        big = 1 << 62
-
-        def minmax_parts(k):
-            b, j = builds[k], joins[k]
-            bkey = j.right_keys[0]
-            parts = []
-            for i in range(len(b.columns)):
-                if i == bkey:
-                    continue
-                col = b.columns[i]
-                if jnp.issubdtype(col.data.dtype, jnp.integer):
-                    m = b.live & col.valid
-                    d = col.data.astype(jnp.int64)
-                    parts.append(jnp.min(jnp.where(m, d, big)))
-                    parts.append(jnp.max(jnp.where(m, d, -big)))
-                else:
-                    parts.append(jnp.full((), big, jnp.int64))
-                    parts.append(jnp.full((), -big, jnp.int64))
-            return parts
-
         def build_one(k, mins, maxs):
             """Build LUT k; returns (dup_signal, oob) device scalars."""
             b, j = builds[k], joins[k]
             if j.kind in ("semi", "anti"):
-                pk = ((), "int8")         # presence bit only
+                # presence bit only
+                plan = ((), np.zeros(0, np.int64), 1)
+            elif pack_refusal(b, j.right_keys) is None:
+                plan = plan_packed_word(b, j.right_keys[0], mins, maxs)
             else:
-                pk = _plan_packing(b, j, mins, maxs)
-            if pk is not None:
-                meta, wd = pk
+                plan = None
+            if plan is not None:
+                meta, los, bits = plan
+                wd = packed_word_dtype(bits)
+                los = executor._place(los)
                 lut, exp, oob, occ = dense_build_packed_lut(
-                    b, j.right_keys, j.build_key_domain, meta, wd)
+                    b, j.right_keys, j.build_key_domain, meta, wd, los)
+                lut = (lut, los)
                 specs[k] = ("packed", meta, wd, j.right_keys[0],
                             tuple(str(c.data.dtype) for c in b.columns))
                 dup_sig = exp - occ           # >0 = duplicate keys
@@ -385,20 +344,18 @@ def _fused_luts(executor, joins) -> Optional[tuple]:
         fused_rest = [k for k in fresh if keys[k] is None]
         for k in cacheable:
             j = joins[k]
-            parts = minmax_parts(k)
             vals = np.asarray(executor.fetch_ints(
-                j.right, join_tag("fusedminmax", j), *parts),
-                dtype=np.int64) if parts else np.zeros(0, np.int64)
+                j.right, join_tag("fusedminmax", j),
+                payload_ranges(builds[k], j.right_keys)), dtype=np.int64)
             dup_sig, oob = build_one(k, vals[0::2], vals[1::2])
             check = executor.fetch_ints(
                 j.right, join_tag("fusedlutcheck", j), dup_sig, oob)
             if check[0] != 0 or check[1] != 0:
                 return None
         if fused_rest:
-            all_parts = [minmax_parts(k) for k in fused_rest]
-            flat = [p for ps in all_parts for p in ps]
-            vals = np.asarray(jnp.stack(flat)) if flat else \
-                np.zeros(0, np.int64)
+            all_parts = [payload_ranges(builds[k], joins[k].right_keys)
+                         for k in fused_rest]
+            vals = np.asarray(jnp.concatenate(all_parts))
             pos, checks = 0, []
             for k, ps in zip(fused_rest, all_parts):
                 vk = vals[pos:pos + len(ps)]
@@ -423,9 +380,9 @@ def _windowed_planes(gmode: str, adapt, specs, luts, k):
     from ..ops import pallas_gather
     if gmode == "off" or k not in (adapt or {}) or specs[k] is None or \
             specs[k][0] != "packed" or \
-            luts[k].shape[0] > pallas_gather.MAX_WINDOWED_ELEMS:
+            luts[k][0].shape[0] > pallas_gather.MAX_WINDOWED_ELEMS:
         return None
-    return pallas_gather.prepare_word_planes(luts[k])
+    return pallas_gather.prepare_word_planes(luts[k][0])
 
 
 # adaptive re-optimization safety margin: windows pad the measured

@@ -58,6 +58,9 @@ class ExecStats:
     mxu_agg_calls: int = 0
     fact_cache_chunks: int = 0       # chunks sliced from device-resident
     chunk_lut_joins: int = 0         # sync-free reused-LUT probes
+    packed_lut_joins: int = 0        # those of them whose LUT's word
+                                     # carries the build's payload (one
+                                     # gather a probe)
     value_puts: int = 0              # ValuesNodes put on the device
                                      # (run_values calls)
     literal_slots: int = 0           # literals and lookup tables bound as
@@ -505,10 +508,14 @@ class Executor:
         while len(self._open_operators) > depth:
             self._open_operators.pop()[0].__exit__(None, None, None)
 
-    def stamp_operator(self, **attributes) -> None:
-        """Attributes for the innermost open operator span, if any (none
-        in a split loop: hundreds of splits say the same)."""
-        if self._open_operators and self._operator_split is None:
+    def stamp_operator(self, in_splits: bool = False,
+                       **attributes) -> None:
+        """Attributes for the innermost open operator span, if any. In a
+        split loop only those a caller marks `in_splits`: hundreds of
+        splits say the same, so what they say has to be little (it is
+        shipped once a kind of span: `Tracer.split_span`)."""
+        if self._open_operators and (self._operator_split is None or
+                                     in_splits):
             self._open_operators[-1][1].attributes.update(attributes)
 
     def _known_rows(self, node: L.PlanNode) -> Optional[int]:
@@ -1777,33 +1784,96 @@ class Executor:
     def _chunk_lut_join(self, node: L.JoinNode, probe: Batch,
                         build: Batch, domain: int) -> Optional[Batch]:
         """Chunk-mode unique-build join: the dense LUT is built and
-        dup/oob-validated ONCE per pinned build side (one device fetch),
-        cached for the life of the chunked loop, and every subsequent
-        probe chunk joins sync-free at probe capacity (no compaction).
-        None = validation failed (caller takes the general fallbacks) or
-        kernel limits don't apply."""
+        validated ONCE per pinned build side, cached for the life of the
+        chunked loop, and every subsequent probe chunk joins sync-free
+        at probe capacity (no compaction). None = validation failed
+        (caller takes the general fallbacks) or kernel limits don't
+        apply.
+
+        The LUT's word carries the build's payload where it fits one
+        (`_packed_chunk_lut`): a probe is then one gather, where the
+        row-id form pays one for the row and one for each payload
+        column and the validity word. The join's span says which form
+        ran (`lutForm`, `wordBits`) and why not the packed one
+        (`packRefused`)."""
         if len(probe.columns) > 63 or len(build.columns) > 63:
             return None
         key = (id(node), domain)
         rec = self._chunk_lut_cache.get(key)
         if rec is None:
-            from ..ops.join import dense_build_lut
-            lut, dup, oob = dense_build_lut(build, node.right_keys,
-                                            domain)
-            dup, oob = (int(v) for v in np.asarray(jnp.stack(
-                (dup.astype(jnp.int64), oob))))
-            rec = lut if dup == 0 and oob == 0 else False
+            rec = self._packed_chunk_lut(node, build, domain)
+            if rec == "validation":
+                # the row-id LUT's own checks would say the same
+                rec = (None, None, {"packRefused": rec})
+            elif isinstance(rec, str):
+                rec = self._row_chunk_lut(node, build, domain, rec)
             self._chunk_lut_cache[key] = rec
-            if rec is False:
-                self.stats.join_domain_fallbacks += oob > 0
-        if rec is False:
+        lut, packed, said = rec
+        self.stamp_operator(in_splits=True, **said)
+        if lut is None:
             return None
-        from ..ops.join import dense_join_with_lut
+        from ..ops.join import dense_join_packed, dense_join_with_lut
         self.stats.chunk_lut_joins += 1
-        self._note_strategy("JoinNode", "dense-lut", "join")
-        return dense_join_with_lut(probe, build, rec, node.left_keys,
-                                   node.right_keys, node.kind,
-                                   self.gather_mode())
+        if packed is None:
+            self._note_strategy("JoinNode", "dense-lut", "join")
+            return dense_join_with_lut(probe, build, lut, node.left_keys,
+                                       node.right_keys, node.kind,
+                                       self.gather_mode())
+        los, meta, out_dtypes = packed
+        self.stats.packed_lut_joins += 1
+        self._note_strategy("JoinNode", "dense-lut-packed", "join")
+        return dense_join_packed(probe, lut, los, node.left_keys, meta,
+                                 node.right_keys[0], out_dtypes, node.kind,
+                                 self.gather_mode())
+
+    def _packed_chunk_lut(self, node: L.JoinNode, build: Batch,
+                          domain: int):
+        """A pinned build's value-packed LUT as `_chunk_lut_cache` keeps
+        it, (lut, (los, meta, out dtypes), what the join's span says),
+        or the first reason there is none (`key`, `columns`, `float`:
+        ops.join.pack_refusal, with no fetch; `bits`: plan_packed_word;
+        `validation`: a duplicate or out-of-domain build key). Two
+        fetches, once a task: the payload's ranges, then the checks."""
+        from ..ops.join import (dense_build_packed_lut, pack_refusal,
+                                packed_word_dtype, payload_ranges,
+                                plan_packed_word)
+        refused = pack_refusal(build, node.right_keys)
+        if refused is not None:
+            return refused
+        ranges = np.asarray(payload_ranges(build, node.right_keys))
+        plan = plan_packed_word(build, node.right_keys[0], ranges[0::2],
+                                ranges[1::2])
+        if plan is None:
+            return "bits"
+        meta, los, bits = plan
+        los = self._place(los)
+        lut, expected, oob, occupied = dense_build_packed_lut(
+            build, node.right_keys, domain, meta, packed_word_dtype(bits),
+            los)
+        expected, oob, occupied = (int(v) for v in np.asarray(
+            jnp.stack((expected, oob, occupied))))
+        if oob != 0 or occupied != expected:
+            self.stats.join_domain_fallbacks += oob > 0
+            return "validation"
+        return (lut, (los, meta,
+                      tuple(str(c.data.dtype) for c in build.columns)),
+                {"lutForm": "packed", "wordBits": lut.dtype.itemsize * 8})
+
+    def _row_chunk_lut(self, node: L.JoinNode, build: Batch, domain: int,
+                       refused: str):
+        """A pinned build's row-id LUT as `_chunk_lut_cache` keeps it,
+        (lut, None, what the join's span says: why it is not the packed
+        one), with no LUT after a duplicate or out-of-domain build key
+        (one fetch)."""
+        from ..ops.join import dense_build_lut
+        lut, dup, oob = dense_build_lut(build, node.right_keys, domain)
+        dup, oob = (int(v) for v in np.asarray(jnp.stack(
+            (dup.astype(jnp.int64), oob))))
+        if dup == 0 and oob == 0:
+            return (lut, None, {"lutForm": "rows", "wordBits": 32,
+                                "packRefused": refused})
+        self.stats.join_domain_fallbacks += oob > 0
+        return (None, None, {"packRefused": refused})
 
     def enter_chunk_mode(self) -> None:
         self.chunk_mode = True

@@ -627,7 +627,8 @@ for _target in ("host", "device"):
     ROUTER_DECISIONS.init_labels(target=_target)
 for _s in ("global", "direct", "mxu", "sort"):
     AGG_STRATEGY_DECISIONS.init_labels(strategy=_s)
-for _s in ("dense-lut", "sort-probe", "sort-merge", "sorted", "expand"):
+for _s in ("dense-lut", "dense-lut-packed", "sort-probe", "sort-merge",
+           "sorted", "expand"):
     JOIN_STRATEGY_DECISIONS.init_labels(strategy=_s)
 for _ls in ("ACTIVE", "DRAINING", "DRAINED", "LEFT", "FAILED"):
     NODE_LIFECYCLE_TRANSITIONS.init_labels(state=_ls)

@@ -45,6 +45,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..exec.profiler import recorded_jit
 
@@ -257,27 +258,112 @@ def build_lut_chunk(lut: jax.Array, chunk: Batch, key_idx: int,
             jnp.sum(ok & ~in_dom, dtype=jnp.int64))
 
 
+# The value-packed LUT's word (dense_build_packed_lut / dense_join_packed):
+# bit 0 says a build row holds the key, then a payload column at a time
+# its value less the column's least (`los`, an operand), in a field of
+# its width CLASS, and one validity bit. The classes and offsets are the
+# programs' statics, so they follow the schema and not the statement: a
+# range that moves inside its class, or a new least value, runs the
+# program there is.
+PACK_MAX_COLS = 4
+# the word fits an int64 with the sign bit untouched
+PACK_MAX_BITS = 62
+# a field's width is rounded up to a multiple of this
+PACK_WIDTH_CLASS = 8
+
+
+def pack_refusal(build: Batch, build_keys: tuple):
+    """Why no packed word can hold this build's payload, whatever its
+    values: `key` (more than one key column), `columns` (over
+    PACK_MAX_COLS payload columns), `float` (a payload column that is
+    no integer); None where its ranges decide (plan_packed_word: over
+    PACK_MAX_BITS is `bits`)."""
+    if len(build_keys) != 1:
+        return "key"
+    payload = [c for i, c in enumerate(build.columns)
+               if i != build_keys[0]]
+    if len(payload) > PACK_MAX_COLS:
+        return "columns"
+    if not all(jnp.issubdtype(c.data.dtype, jnp.integer)
+               for c in payload):
+        return "float"
+    return None
+
+
+def plan_packed_word(build: Batch, bkey: int, mins, maxs):
+    """The packed word's layout for a build that pack_refusal let by:
+    (meta, los, bits), or None where its payload needs over
+    PACK_MAX_BITS. `mins`/`maxs` are the payload columns' least and
+    largest live values, in column order (payload_ranges). meta is
+    ((col_idx, width class, val_off, valid_off), ...), `los` the int64
+    offsets beside it."""
+    meta, los = [], []
+    off = 1                                   # bit0 = presence
+    payload = [i for i in range(len(build.columns)) if i != bkey]
+    for j, i in enumerate(payload):
+        lo, hi = int(mins[j]), int(maxs[j])
+        if hi < lo:
+            lo, hi = 0, 0
+        width = max(1, int(hi - lo + 1).bit_length())
+        width = -(-width // PACK_WIDTH_CLASS) * PACK_WIDTH_CLASS
+        meta.append((i, width, off, off + width))
+        los.append(lo)
+        off += width + 1
+    if off > PACK_MAX_BITS:
+        return None
+    return tuple(meta), np.asarray(los, dtype=np.int64), off
+
+
+def packed_word_dtype(bits: int) -> str:
+    """The narrowest LUT word for a layout of `bits`, the sign bit
+    untouched. On a v5e a gather of 262,144 indices into 60M entries
+    read 2.56 ms for int8 words, 3.46 for int16, 3.87 for int32 and
+    10.1 for int64 (two planes; PR 45's reading): a narrower word is no
+    slower, a wider one is."""
+    return "int8" if bits <= 7 else "int16" if bits <= 15 else \
+        "int32" if bits <= 31 else "int64"
+
+
+@recorded_jit(static_argnums=(1,))
+def payload_ranges(build: Batch, build_keys: tuple) -> jax.Array:
+    """int64[2 * payload columns]: the least and the largest live value
+    of each payload column in column order, (2^62, -2^62) for a column
+    that is no integer or has no live value. One program and one fetch
+    a build (plan_packed_word reads them)."""
+    bkey = build_keys[0] if len(build_keys) == 1 else None
+    big = jnp.int64(1) << 62
+    parts = []
+    for i, col in enumerate(build.columns):
+        if i == bkey:
+            continue
+        if jnp.issubdtype(col.data.dtype, jnp.integer):
+            m = build.live & col.valid
+            d = col.data.astype(jnp.int64)
+            parts += [jnp.min(jnp.where(m, d, big)),
+                      jnp.max(jnp.where(m, d, -big))]
+        else:
+            parts += [big, -big]
+    return jnp.stack(parts) if parts else jnp.zeros(0, jnp.int64)
+
+
 @recorded_jit(static_argnums=(1, 2, 3, 4))
 def dense_build_packed_lut(build: Batch, build_keys: tuple, domain: int,
-                           meta: tuple, word_dtype: str):
+                           meta: tuple, word_dtype: str, los: jax.Array):
     """Value-packed dense LUT: the build row's PAYLOAD values pack into
-    the LUT word itself (bit0 = presence, then per payload column
-    `width` value bits offset by `lo` plus one validity bit), so a probe
-    is ONE gather total instead of a row-id gather plus one gather per
-    payload column. On this backend a 50M-row HBM gather costs ~1s —
-    for a 2-payload join the packed form is ~3x fewer gathers.
+    the LUT word itself (the layout above), so a probe is ONE gather
+    total instead of a row-id gather plus one gather per payload column.
 
-    meta: ((col_idx, lo, width, val_off, valid_off), ...) — static.
-    Returns (lut, expected_rows, oob_rows, occupied_slots); duplicates
-    show up as occupied < expected (unique-build violation), validated
-    by the caller in one fetch."""
+    meta: ((col_idx, width, val_off, valid_off), ...), static; `los`:
+    int64, an offset a meta entry. Returns (lut, expected_rows,
+    oob_rows, occupied_slots); duplicates show up as occupied < expected
+    (unique-build violation), validated by the caller in one fetch."""
     bk, bk_valid = _combined_key(build, build_keys)
     ok = build.live & bk_valid
     in_dom = ok & (bk >= 0) & (bk < domain)
     word = jnp.ones(build.capacity, dtype=jnp.int64)      # presence bit
-    for col_idx, lo, width, val_off, valid_off in meta:
+    for j, (col_idx, width, val_off, valid_off) in enumerate(meta):
         col = build.columns[col_idx]
-        v = (col.data.astype(jnp.int64) - lo) & ((1 << width) - 1)
+        v = (col.data.astype(jnp.int64) - los[j]) & ((1 << width) - 1)
         word = word | (v << val_off) | \
             (col.valid.astype(jnp.int64) << valid_off)
     idx = jnp.where(in_dom, jnp.clip(bk, 0, domain - 1), domain)
@@ -288,8 +374,35 @@ def dense_build_packed_lut(build: Batch, build_keys: tuple, domain: int,
             jnp.sum(ok & ~in_dom, dtype=jnp.int64), occupied)
 
 
+def _unpack_build_columns(probe: Batch, word, matched, pk, meta: tuple,
+                          los, bkey: int, out_dtypes: tuple,
+                          kind: str) -> Batch:
+    """A packed probe's output (traced helper of the two packed
+    kernels): the build's columns decoded from each row's LUT `word` in
+    the build's output order, the key column from the probe key (equal
+    where matched). A NULL or unmatched slot reads 0."""
+    by_idx = {m[0]: (j, m) for j, m in enumerate(meta)}
+    build_cols = []
+    for i, dt in enumerate(out_dtypes):
+        dtype = jnp.dtype(dt)
+        if i == bkey:
+            build_cols.append(Column(
+                data=jnp.where(matched, pk, 0).astype(dtype),
+                valid=matched))
+            continue
+        j, (_, width, val_off, valid_off) = by_idx[i]
+        valid = (((word >> valid_off) & 1) != 0) & matched
+        raw = (word >> val_off) & ((1 << width) - 1)
+        build_cols.append(Column(
+            data=jnp.where(valid, raw + los[j], 0).astype(dtype),
+            valid=valid))
+    live = probe.live & matched if kind == "inner" else probe.live
+    return Batch(columns=probe.columns + tuple(build_cols), live=live)
+
+
 def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
-                               probe_keys: tuple, meta: tuple, bkey: int,
+                               los: jax.Array, probe_keys: tuple,
+                               meta: tuple, bkey: int,
                                out_dtypes: tuple, kind: str, window: int,
                                word_dtype: str = None,
                                gather_mode: str = "off",
@@ -340,34 +453,21 @@ def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
         return probe.with_live(probe.live & matched), escaped, span
     if kind == "anti":
         return probe.with_live(probe.live & ~matched), escaped, span
-    by_idx = {m[0]: m for m in meta}
-    build_cols = []
-    for i, dt in enumerate(out_dtypes):
-        dtype = jnp.dtype(dt)
-        if i == bkey:
-            build_cols.append(Column(
-                data=jnp.where(matched, pk, 0).astype(dtype),
-                valid=matched))
-            continue
-        col_idx, lo_v, width, val_off, valid_off = by_idx[i]
-        raw = (word >> val_off) & ((1 << width) - 1)
-        build_cols.append(Column(
-            data=(raw + lo_v).astype(dtype),
-            valid=(((word >> valid_off) & 1) != 0) & matched))
-    live = probe.live & matched if kind == "inner" else probe.live
-    return (Batch(columns=probe.columns + tuple(build_cols), live=live),
+    return (_unpack_build_columns(probe, word, matched, pk, meta, los,
+                                  bkey, out_dtypes, kind),
             escaped, span)
 
 
-@recorded_jit(static_argnums=(2, 3, 4, 5, 6, 7))
-def dense_join_packed(probe: Batch, lut: jax.Array, probe_keys: tuple,
-                      meta: tuple, bkey: int, out_dtypes: tuple,
-                      kind: str, gather_mode: str = "off") -> Batch:
+@recorded_jit(static_argnums=(3, 4, 5, 6, 7, 8))
+def dense_join_packed(probe: Batch, lut: jax.Array, los: jax.Array,
+                      probe_keys: tuple, meta: tuple, bkey: int,
+                      out_dtypes: tuple, kind: str,
+                      gather_mode: str = "off") -> Batch:
     """Probe a value-packed LUT (see dense_build_packed_lut): one gather
-    yields presence + every payload value. Build columns reconstruct in
-    the build's output order; the key column reconstructs from the probe
-    key (equal where matched). Sync-free, no compaction — the fused
-    chunk pipeline's join step."""
+    yields presence + every payload value. Sync-free, no compaction:
+    the output keeps the probe's capacity with a live mask, as
+    dense_join_with_lut's does — a split loop's and the fused chunk
+    pipeline's join step."""
     domain = lut.shape[0] - 1
     pk, pk_valid = _combined_key(probe, probe_keys)
     p_idx = jnp.where(pk_valid, jnp.clip(pk, 0, domain - 1), domain)
@@ -378,22 +478,8 @@ def dense_join_packed(probe: Batch, lut: jax.Array, probe_keys: tuple,
         return probe.with_live(probe.live & matched)
     if kind == "anti":
         return probe.with_live(probe.live & ~matched)
-    by_idx = {m[0]: m for m in meta}
-    build_cols = []
-    for i, dt in enumerate(out_dtypes):
-        dtype = jnp.dtype(dt)
-        if i == bkey:
-            build_cols.append(Column(
-                data=jnp.where(matched, pk, 0).astype(dtype),
-                valid=matched))
-            continue
-        col_idx, lo, width, val_off, valid_off = by_idx[i]
-        raw = (word >> val_off) & ((1 << width) - 1)
-        build_cols.append(Column(
-            data=(raw + lo).astype(dtype),
-            valid=(((word >> valid_off) & 1) != 0) & matched))
-    live = probe.live & matched if kind == "inner" else probe.live
-    return Batch(columns=probe.columns + tuple(build_cols), live=live)
+    return _unpack_build_columns(probe, word, matched, pk, meta, los,
+                                 bkey, out_dtypes, kind)
 
 
 def _match_words(pos, idx, matched, idx_bits: int) -> jax.Array:

@@ -36,11 +36,13 @@ between the two processes' pairs, which a worker's announce measures
 (`CLOCK_ID` tells the coordinator a worker of its own process: 0).
 
 Spans of a task's split loop (`split_span`: an operator's wall inside
-one split, hundreds a task, each saying which split and nothing else)
+one split, hundreds a task, each saying which split and little else)
 are kept and shipped as five integers each (`export(compact=True)`, one
 block a parent span) and become span dicts only where a trace is read
 (`export()`): as dicts they were 40% of a task's status JSON and 7 ms of
-a 240-split stage's hand-over.
+a 240-split stage's hand-over. What else such a span says (the form of a
+join's LUT) it says in every split alike, so a block ships each distinct
+(name, attributes) once and a span's first integer names the pair.
 """
 
 from __future__ import annotations
@@ -59,8 +61,9 @@ _ROOT_SPAN_ID = "0" * 16
 _UNIX0_NS, _MONO0 = time.time_ns(), time.monotonic()
 # names the pair: two tracers that see the same id stamp on one clock
 CLOCK_ID = os.urandom(8).hex()
-# integers a `split_span` is shipped as: index into the block's names,
-# split, depth, start (ns after the block's), duration (us)
+# integers a `split_span` is shipped as: index into the block's names
+# (and its `attributes`, where it has them), split, depth, start (ns
+# after the block's), duration (us)
 _ROW = 5
 # profiler annotations of the program's spans; `bench:` belongs to the
 # benchmark's anchors (benchmark/trace_reduce.py)
@@ -97,6 +100,15 @@ def parse_traceparent(header: Optional[str]) -> Optional[Tuple[str, str]]:
     except ValueError:
         return None
     return parts[1], parts[2]
+
+
+class _SplitRow(list):
+    """A `split_span` as the tracer keeps it: [name, split, depth,
+    start, end, attributes]."""
+
+    @property
+    def attributes(self) -> dict:
+        return self[5]
 
 
 @dataclass
@@ -147,7 +159,8 @@ class Tracer:
         self.service = service
         self.spans: List[Span] = []
         # split-loop spans by parent span: [name, split, depth, start,
-        # end] in the order they opened (a parent before its children)
+        # end, attributes] in the order they opened (a parent before
+        # its children)
         self._split_spans: Dict[str, List[list]] = {}
         self._foreign: List[dict] = []
         self._local = threading.local()
@@ -221,14 +234,16 @@ class Tracer:
                    depth: int = 0):
         """A live span of a task's split loop, in the compact form (see
         the module's docstring): a child of `parent` (depth 0) or of the
-        split span last opened one level up, with `split` its one
-        attribute. It is not the thread's context: what opens or is
-        recorded meanwhile hangs where it would have (an operator's
-        span lies BESIDE the `split` lap it runs in)."""
+        split span last opened one level up, with `split` its
+        attribute and whatever the caller puts in the yielded row's
+        `attributes` (a few small values that most splits repeat). It is not
+        the thread's context: what opens or is recorded meanwhile hangs
+        where it would have (an operator's span lies BESIDE the `split`
+        lap it runs in)."""
         if not self.enabled:
             yield None
             return
-        row = [name, split, depth, time.monotonic(), None]
+        row = _SplitRow((name, split, depth, time.monotonic(), None, {}))
         with self._lock:
             self._split_spans.setdefault(parent, []).append(row)
         try:
@@ -327,15 +342,20 @@ class Tracer:
         parent span, `_ROW` integers a span."""
         blocks = []
         for parent, rows in self._split_spans.items():
-            names = sorted({r[0] for r in rows})
+            # a kind of span: its name and what it says besides `split`
+            of = [(r[0], tuple(sorted(r[5].items()))) for r in rows]
+            kinds = sorted(set(of), key=repr)
+            index = {kind: i for i, kind in enumerate(kinds)}
             t0 = unix_ns(rows[0][3])
             flat = []
-            for name, split, depth, start, end in rows:
-                flat += (names.index(name), split, depth,
-                         unix_ns(start) - t0,
+            for kind, (_, split, depth, start, end, _) in zip(of, rows):
+                flat += (index[kind], split, depth, unix_ns(start) - t0,
                          round(((end or start) - start) * 1e6))
-            blocks.append({"name": "split-spans",
-                           "splitSpans": flat, "names": names,
+            block = {"attributes": [dict(a) for _, a in kinds]} \
+                if any(a for _, a in kinds) else {}
+            blocks.append({"name": "split-spans", **block,
+                           "splitSpans": flat,
+                           "names": [n for n, _ in kinds],
                            "traceId": self.trace_id,
                            "spanId": new_span_id(),
                            "parentSpanId": parent,
@@ -375,6 +395,7 @@ def _expand(block: dict) -> List[dict]:
     level up (the deepest there is, should a block skip a level), the
     block's parent at depth 0."""
     flat, names = block["splitSpans"], block["names"]
+    attributes = block.get("attributes") or [{}] * len(names)
     id0, t0 = int(block["spanId"], 16), block["startTimeUnixNano"]
     parents, spans = [block["parentSpanId"]], []
     for i in range(0, len(flat), _ROW):
@@ -389,7 +410,8 @@ def _expand(block: dict) -> List[dict]:
                       "service": block["service"],
                       "startTimeUnixNano": t0 + start,
                       "durationMs": micros / 1000,
-                      "attributes": {"split": split}})
+                      "attributes": {"split": split,
+                                     **attributes[name]}})
         parents.append(span_id)
     return spans
 
