@@ -352,90 +352,6 @@ class BenchConnector:
 
 
 # ---------------------------------------------------------------------------
-# --gather-micro: ns/row of the Pallas tiled-gather kernel vs jnp.take
-# ---------------------------------------------------------------------------
-
-def gather_micro(table_sizes=None, probe_rows=None, n_tables=3, runs=3,
-                 out_path="BENCH_gather_micro.json"):
-    """Microbenchmark the dense-probe gather: kernel vs jnp.take ns per
-    gathered row across table sizes, recorded as one JSON artifact so
-    the per-round trajectory toward the ~4 ns/row break-even
-    is measurable.
-
-    On TPU this times the compiled kernel; under JAX_PLATFORMS=cpu it
-    drops to a tiny smoke configuration in Pallas interpret mode (the
-    numbers are meaningless there — the run exists so tier-1 exercises
-    the harness end to end). Returns the record dict it wrote."""
-    import jax
-    import jax.numpy as jnp
-
-    from trino_tpu.ops import pallas_gather as pg
-
-    import functools
-
-    on_tpu = jax.default_backend() == "tpu"
-    mode = "device" if on_tpu else "interpret"
-    if table_sizes is None:
-        table_sizes = [1 << 12, 1 << 14, 1 << 16] if on_tpu else [1 << 12]
-    if probe_rows is None:
-        probe_rows = (1 << 22) if on_tpu else (1 << 13)
-    rng = np.random.default_rng(7)
-
-    def timed(fn):
-        jax.block_until_ready(fn())                # warm (compile)
-        walls = []
-        for _ in range(runs):
-            t0 = time.monotonic()
-            jax.block_until_ready(fn())
-            walls.append(time.monotonic() - t0)
-        return min(walls)
-
-    records = []
-    for w in table_sizes:
-        tables = [jnp.asarray(rng.integers(-(1 << 40), 1 << 40, w))
-                  for _ in range(n_tables)]
-        idx = jnp.asarray(rng.integers(0, w, probe_rows))
-
-        take = jax.jit(lambda ts, ix: [jnp.take(t, ix, axis=0)
-                                       for t in ts])
-        # the pre-jitted kernel entry points route their XLA compiles
-        # through the central recorder (exec/profiler.py), so the
-        # microbench's compile costs land in /v1/jit like every other
-        # jit site's
-        kernel = functools.partial(pg.gather_columns_jit, mode=mode)
-        t_take = timed(lambda: take(tables, idx))
-        t_kernel = timed(lambda: kernel(tables, idx))
-        elems = probe_rows * n_tables
-        rec = {"table_rows": w, "probe_rows": probe_rows,
-               "n_tables": n_tables,
-               "take_ns_per_elem": round(t_take * 1e9 / elems, 3),
-               "kernel_ns_per_elem": round(t_kernel * 1e9 / elems, 3),
-               "kind": "scan"}
-        records.append(rec)
-
-        # windowed kernel on near-sorted indices (the chunked fact-scan
-        # shape): per-probe cost independent of table size
-        idx_s = jnp.sort(idx)
-        planes = pg.prepare_word_planes(tables[0])
-        win = functools.partial(pg.gather_word_windowed_jit,
-                                word_dtype="int64", mode=mode)
-        t_win = timed(lambda: win(planes, idx_s))
-        records.append({
-            "table_rows": w, "probe_rows": probe_rows, "n_tables": 1,
-            "take_ns_per_elem": round(t_take * 1e9 / elems, 3),
-            "kernel_ns_per_elem": round(t_win * 1e9 / probe_rows, 3),
-            "kind": "windowed"})
-
-    out = {"metric": "gather_micro_ns_per_elem",
-           "device": str(jax.devices()[0]), "mode": mode,
-           "smoke": not on_tpu, "records": records}
-    with open(out_path, "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out), flush=True)
-    return out
-
-
-# ---------------------------------------------------------------------------
 # --scan-micro: zone-map pruning + prefetch-pipeline scan-path microbench
 # ---------------------------------------------------------------------------
 
@@ -2472,9 +2388,6 @@ def build_parser():
     mode.add_argument("--memory-pressure", action="store_true",
                       help="concurrent soak at 25%% pool -> "
                            "BENCH_memory.json")
-    mode.add_argument("--gather-micro", action="store_true",
-                      help="Pallas tiled-gather microbench -> "
-                           "BENCH_gather_micro.json")
     mode.add_argument("--scan-micro", action="store_true",
                       help="zone-map pruning + prefetch pipeline "
                            "scan-path microbench across predicate "
@@ -2541,9 +2454,6 @@ def main(argv=None):
         return 0 if rec["passed"] else 1
     if args.memory_pressure:
         memory_pressure_soak()
-        return 0
-    if args.gather_micro:
-        gather_micro()
         return 0
     if args.scan_micro:
         scan_micro()

@@ -6,13 +6,11 @@ One process (a chip belongs to one process): an in-process
 `CoordinatorServer` and `WorkerServer`, a `Client` over HTTP, and TPC-H
 q6, q1, q3 and q18 against the `tpch` connector's own generator at sf10
 (60M-row lineitem). Every session property stays at its default, so
-`auto` picks the kernels the way a user gets them. Each answer is
-compared with a plain numpy reference computed here from the same
-generated columns, independent of the engine. A last phase runs each
-Pallas kernel family that compiles for the chip once at sf10 shapes and
-compares it bit for bit with the XLA path.
+a statement runs the way a user gets it. Each answer is compared with a
+plain numpy reference computed here from the same generated columns,
+independent of the engine.
 
-    python chip_smoke.py            one chip: the four queries + kernels
+    python chip_smoke.py            one chip: the four queries
     python chip_smoke.py --chips 4  four chips: q3 at sf10 over a Mesh of
                                     the four devices vs one device vs
                                     numpy, and nothing else
@@ -347,9 +345,6 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
                 # device again (compiled programs and data stay)
                 sched.spool.clear()
                 before = RECORDER.totals()["compiles"]
-                calls0 = {k: (ex.stats.mxu_agg_calls,
-                              ex.stats.pallas_gather_calls)
-                          for k, ex in executors.items()}
                 t0 = time.monotonic()
                 res = client.execute(sql)
                 secs = time.monotonic() - t0
@@ -372,17 +367,12 @@ def run_served(cache: CacheCounter, sch: str = SCHEMA) -> None:
                 strategies = {k: dict(ex.strategy_decisions)
                               for k, ex in executors.items()
                               if ex.strategy_decisions}
-                calls = {k: tuple(b - a for a, b in zip(
-                    calls0[k], (ex.stats.mxu_agg_calls,
-                                ex.stats.pallas_gather_calls)))
-                         for k, ex in executors.items()}
                 say(f"{name} {sch} {run}: {secs:.2f}s, {len(res.rows)} rows"
                     f" match numpy; route={route}"
                     f" (reason={info.get('routeReason')!r},"
                     f" fallback={fallback!r});"
                     f" compiles={RECORDER.totals()['compiles'] - before};"
                     f" strategies={strategies};"
-                    f" (mxu_agg, pallas_gather) calls={calls};"
                     f" peak_bytes_in_use={peak_bytes()}")
                 expect_strategies(name, strategies)
         say(f"compile cache at {compile_cache_dir()}: "
@@ -399,7 +389,7 @@ def expect_strategies(name: str, strategies: dict) -> None:
     if name == "q6":
         assert "global" in aggs, (name, strategies)
     elif name == "q1":
-        assert aggs & {"direct", "mxu"}, (name, strategies)
+        assert "direct" in aggs, (name, strategies)
     else:
         assert "sort" in aggs, (name, strategies)
         joins = {per_ex.get("JoinNode") for per_ex in strategies.values()}
@@ -409,90 +399,6 @@ def expect_strategies(name: str, strategies: dict) -> None:
 def compile_cache_dir():
     import trino_tpu
     return trino_tpu.COMPILE_CACHE_DIR
-
-
-# ---------------------------------------------------------------------------
-# the Pallas kernel families that compile for the chip, once each at sf10
-# shapes against the XLA path (bit-exact)
-# ---------------------------------------------------------------------------
-
-def run_kernels() -> None:
-    import jax
-    import jax.numpy as jnp
-    from trino_tpu.batch import batch_from_numpy
-    from trino_tpu.ops import pallas_agg, pallas_gather as pg
-    from trino_tpu.ops.aggregate import AggSpec, direct_group_aggregate
-
-    n, words, rows = 1 << 22, 1 << 24, 1 << 23
-    mode = pg.resolve_mode("auto")
-    assert mode == "device", f"gather kernels resolve to {mode!r}"
-    rng = np.random.default_rng(23)
-
-    def custom_calls(jitted, *args, **kw) -> int:
-        n_cc = jitted.lower(*args, **kw).compile().as_text().count(
-            "tpu_custom_call")
-        assert n_cc >= 1, "kernel lowered without a tpu_custom_call"
-        return n_cc
-
-    # scan gather: a 65,536-entry dimension LUT, three payload tables
-    # (two int64, one int32: five int32 planes), 4M probes with misses
-    w = pg.SCAN_MAX_ELEMS
-    tables = [jnp.asarray(rng.integers(-2**62, 2**62, w)),
-              jnp.asarray(rng.integers(-2**62, 2**62, w)),
-              jnp.asarray(rng.integers(0, 2**31 - 1, w, dtype=np.int32))]
-    idx = jnp.asarray(rng.integers(-5, w + 5, n))
-    fills = (-1, 0, -1)
-    t0 = time.monotonic()
-    got = pg.gather_columns_jit(tables, idx, fills=fills, mode=mode)
-    want = pg.gather_columns_jit(tables, idx, fills=fills, mode="off")
-    jax.block_until_ready((got, want))
-    for g, x in zip(got, want):
-        assert g.dtype == x.dtype and bool(jnp.array_equal(g, x))
-    n_cc = custom_calls(jax.jit(pg.gather_columns,
-                                static_argnames=("fills", "mode")),
-                        tables, idx, fills=fills, mode=mode)
-    say(f"kernel scan gather: W={w:,} n={n:,} bit-exact vs jnp.take, "
-        f"tpu_custom_call x{n_cc}, {time.monotonic() - t0:.1f}s")
-
-    # windowed gather: a 16M-word packed LUT (sf10 o_orderkey domain is
-    # 60M; one chunk's window), 4M ascending probe keys, no escapes
-    lut = jnp.asarray(rng.integers(1, 2**62, words))
-    keys = np.sort(rng.integers(0, words, n))
-    t0 = time.monotonic()
-    planes = pg.prepare_word_planes(lut)
-    got, esc = pg.gather_word_windowed_jit(planes, jnp.asarray(keys),
-                                           word_dtype="int64", mode=mode)
-    want = jnp.take(lut, jnp.asarray(keys))
-    assert int(esc) == 0, f"windowed gather: {int(esc)} escapes on " \
-        f"sorted keys"
-    assert bool(jnp.array_equal(got, want))
-    n_cc = custom_calls(jax.jit(pg.gather_word_windowed,
-                                static_argnames=("word_dtype", "mode")),
-                        planes, jnp.asarray(keys), word_dtype="int64",
-                        mode=mode)
-    say(f"kernel windowed gather: LUT={words:,} n={n:,} bit-exact vs "
-        f"jnp.take, 0 escapes, tpu_custom_call x{n_cc}, "
-        f"{time.monotonic() - t0:.1f}s")
-
-    # MXU aggregate: G = MAX_GROUPS over 8M rows, four sums and a count
-    g = pallas_agg.MAX_GROUPS
-    batch = batch_from_numpy(
-        [rng.integers(0, g, rows).astype(np.int32)] +
-        [rng.integers(-10**9, 10**9, rows) for _ in range(4)])
-    aggs = tuple(AggSpec("sum", i) for i in range(1, 5)) + \
-        (AggSpec("count_star", None),)
-    t0 = time.monotonic()
-    got = pallas_agg.direct_group_aggregate_mxu(batch, (0,), (g,), aggs)
-    want = direct_group_aggregate(batch, (0,), (g,), aggs)
-    for cg, cw in zip(got.columns, want.columns):
-        assert bool(jnp.array_equal(cg.valid, cw.valid))
-        assert bool(jnp.array_equal(jnp.where(cg.valid, cg.data, 0),
-                                    jnp.where(cw.valid, cw.data, 0)))
-    n_cc = custom_calls(pallas_agg.direct_group_aggregate_mxu.__wrapped__,
-                        batch, (0,), (g,), aggs)
-    say(f"kernel MXU aggregate: G={g} rows={rows:,} equals the XLA "
-        f"direct aggregate, tpu_custom_call x{n_cc}, "
-        f"{time.monotonic() - t0:.1f}s")
 
 
 # ---------------------------------------------------------------------------
@@ -603,7 +509,6 @@ def main(argv=None) -> int:
                 f"one chip expected, JAX found {device['count']} " \
                 f"(use --chips 4 on a four-chip host)"
             run_served(cache)
-            run_kernels()
         say(f"compile cache: {cache.hits} hits, {cache.misses} misses; "
             f"total {time.monotonic() - t0:.0f}s")
     finally:

@@ -1,4 +1,4 @@
-"""The aggregation ladder (global, direct/MXU, sort) and the join ladder
+"""The aggregation ladder (global, direct, sort) and the join ladder
 (dense-LUT, sort-merge/sorted, expand; mark and membership joins) on
 their edge-case inputs, each compared with an independent answer: the
 sqlite oracle (tests/oracle.py) for SQL statements, numpy for kernel
@@ -404,20 +404,25 @@ def test_explain_carries_strategy_lines():
                    for r in rows + rows2)
 
 
-def test_removed_session_properties_are_unknown():
+@pytest.mark.parametrize("statement", [
+    "SET SESSION multiway_max_dims = 2",
+    "SET SESSION mxu_agg = true",
+    "SET SESSION enable_pallas_gather = false"])
+def test_removed_session_properties_are_unknown(statement):
     from trino_tpu.exec.session import SESSION_PROPERTY_DEFAULTS
-    assert len(SESSION_PROPERTY_DEFAULTS) == 40
+    assert len(SESSION_PROPERTY_DEFAULTS) == 38
     s = Session(default_schema="tiny")
     with pytest.raises(KeyError, match="unknown session property"):
-        s.execute("SET SESSION multiway_max_dims = 2")
+        s.execute(statement)
 
 
 def test_strategy_decision_metrics_move():
     from trino_tpu.metrics import (AGG_STRATEGY_DECISIONS,
                                    JOIN_STRATEGY_DECISIONS)
     # pre-initialized families (lint also enforces this)
-    for strat in ("global", "direct", "mxu", "sort"):
+    for strat in ("global", "direct", "sort"):
         assert AGG_STRATEGY_DECISIONS.has_sample(strategy=strat)
+    assert not AGG_STRATEGY_DECISIONS.has_sample(strategy="mxu")
     joins = ("dense-lut", "dense-lut-packed", "sort-probe", "sort-merge",
              "sorted", "expand")
     for strat in joins:

@@ -2,10 +2,11 @@
 
 The TPU's compiler is installed here and compiles for a chip that is
 described and not attached (`jax.experimental.topologies`). These tests
-hand the `pallas_call` wrappers and the q1 XLA stage the shapes the TPC-H
-SF10 queries produce and ask only: does the chip's compiler accept the
-program, and is the kernel in it (`tpu_custom_call`)? Nothing runs, so
-they say nothing about results or times — `chip_smoke.py` on the chip
+hand the join and aggregate kernels, the one `pallas_call` wrapper and the
+q1 stage the shapes the TPC-H SF10 queries produce and ask only: does the
+chip's compiler accept the program, and is the kernel in it
+(`tpu_custom_call`) exactly where the engine means it to be? Nothing runs,
+so they say nothing about results or times — `chip_smoke.py` on the chip
 does.
 
 This is the ONLY test file that touches the TPU compiler: one process at
@@ -16,15 +17,13 @@ worker collects the same tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from trino_tpu.ops import pallas_agg, pallas_gather as pg
-
-N_ROWS = 1 << 20            # rows per kernel call at the SF10 chunk shapes
 SF10_LINEITEM = 59_986_052  # tpch sf10 lineitem rows (60M)
 
 
@@ -55,75 +54,94 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
-    """shapes: (shape, dtype) pairs -> compiled executable on the
-    described chip (raises what the chip's compiler would raise)."""
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile()
+def _batch(one_chip, length, *dtypes):
+    from trino_tpu.batch import Batch, Column
 
-
-def _has_kernel(compiled) -> bool:
-    return "tpu_custom_call" in compiled.as_text()
+    def shape(dtype):
+        return jax.ShapeDtypeStruct((length,), dtype, sharding=one_chip)
+    return Batch(tuple(Column(shape(d), shape(jnp.bool_)) for d in dtypes),
+                 shape(jnp.bool_))
 
 
 # ---------------------------------------------------------------------------
-# tiled gather: both modes, P = 2 (one int64 table) and P = MAX_PLANES
+# the one Pallas kernel (ops/pallas_gather.py: a small build's payload):
+# P = 2 (one int64 table) and P = MAX_PLANES, at a split's 262,144 probes
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("planes", [2, pg.MAX_PLANES])
+@pytest.mark.parametrize("planes", [2, 12])
 def test_scan_gather_compiles(one_chip, planes):
+    from trino_tpu.ops import pallas_gather as pg
+    assert planes <= pg.MAX_PLANES
     i32 = jnp.int32
-    c = _compile(
-        lambda idx, p: pg._scan_gather_planes(idx, p, (0,) * planes,
-                                              False),
-        one_chip, ((N_ROWS,), i32), ((planes, pg.SCAN_MAX_ELEMS), i32))
-    assert _has_kernel(c)
+    args = [jax.ShapeDtypeStruct(shape, i32, sharding=one_chip)
+            for shape in ((262_144,), (planes, pg.SCAN_MAX_ELEMS))]
+    compiled = jax.jit(
+        lambda idx, p: pg._scan_gather_planes(idx, p, False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("planes", [2, pg.MAX_PLANES])
-def test_windowed_gather_compiles(one_chip, planes):
-    i32 = jnp.int32
-    words = 1 << 24             # a 16M-word LUT: whole WIN windows
-    c = _compile(
-        lambda idx, base, p: pg._window_gather_planes(
-            idx, base, p, (0,) * planes, False),
-        one_chip, ((N_ROWS,), i32), ((N_ROWS // pg.TILE,), i32),
-        ((planes, words), i32))
-    assert _has_kernel(c)
-
-
-def test_float64_planes_are_refused_and_gated_off(one_chip):
+def test_float64_planes_are_refused_and_gated_off(one_chip, monkeypatch):
     """DOUBLE tables never reach the kernel (supports_tables), because
     the chip's compiler refuses their split into int32 planes."""
+    from trino_tpu.ops import pallas_gather as pg
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     f64 = jax.ShapeDtypeStruct((1024,), jnp.float64)
     assert not pg.supports_tables([f64])
     assert not pg.gather_supported([f64])
     with pytest.raises(Exception, match="X64 element types"):
-        _compile(pg._split_planes, one_chip, ((1024,), jnp.float64))
+        jax.jit(pg._split_planes).lower(jax.ShapeDtypeStruct(
+            (1024,), jnp.float64, sharding=one_chip)).compile()
 
 
 # ---------------------------------------------------------------------------
-# MXU aggregate: q1's G=6 and the largest G supports() admits
+# the gather sites at the sizes where a hand-written kernel stood in for
+# them until PR 46 (tables of at most 65,536 entries; direct aggregates of
+# 12 groups and more). On a TPU a small build's payload still rides the
+# kernel; every other site is an XLA program at every size
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("groups,aggs", [(6, 6), (pallas_agg.MAX_GROUPS,
-                                                  pallas_agg.MAX_AGGS)])
-def test_mxu_sums_compiles(one_chip, groups, aggs):
-    i32 = jnp.int32
-    c = _compile(
-        lambda gid, hi, lo: pallas_agg._mxu_sums(gid, hi, lo, groups,
-                                                 False),
-        one_chip, ((N_ROWS,), i32), ((aggs, N_ROWS), i32),
-        ((aggs, N_ROWS), i32))
-    assert _has_kernel(c)
+def small_table_join(one_chip):
+    from trino_tpu.ops.join import dense_join_with_lut
+    probe = _batch(one_chip, 262_144, jnp.int64, jnp.int64)
+    build = _batch(one_chip, 1_024, jnp.int64, jnp.int64, jnp.int32)
+    lut = jax.ShapeDtypeStruct((4_097,), jnp.int32, sharding=one_chip)
+    return dense_join_with_lut.__wrapped__.lower(
+        probe, build, lut, (0,), (0,), "inner")
 
 
-def test_gather_family_is_on_on_tpu_by_rule(monkeypatch):
+def small_group_read_back(one_chip):
+    from trino_tpu.ops.aggregate import AggSpec, sort_group_aggregate
+    return sort_group_aggregate.__wrapped__.lower(
+        _batch(one_chip, 2_048, jnp.int64, jnp.int32, jnp.int64), (0, 1),
+        (AggSpec("sum", 2), AggSpec("count_star", None)), 2_048)
+
+
+def sixteen_group_direct_aggregate(one_chip):
+    from trino_tpu.ops.aggregate import AggSpec, direct_group_aggregate
+    return direct_group_aggregate.__wrapped__.lower(
+        _batch(one_chip, 262_144, jnp.int32, jnp.int64, jnp.int64), (0,),
+        (16,), (AggSpec("sum", 1), AggSpec("sum", 2), AggSpec("count", 1),
+                AggSpec("count_star", None)))
+
+
+@pytest.mark.parametrize("program", [small_table_join,
+                                     small_group_read_back,
+                                     sixteen_group_direct_aggregate])
+def test_what_a_small_gather_site_compiles_to(one_chip, program,
+                                              monkeypatch):
+    # the gate asks the process's backend, which here is the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # the gather family compiles, so auto turns it on there
-    assert pg.resolve_mode("auto") == "device"
-    assert pg.resolve_mode("false") == "off"
+    text = program(one_chip).compile().as_text()
+    gathers = len(re.findall(r" gather\(", text))
+    if program is small_table_join:
+        # the LUT's probe is XLA's gather, the 1,024-row build's validity
+        # word and two columns are one kernel call
+        assert text.count("tpu_custom_call") >= 1 and gathers == 1
+    else:
+        assert "tpu_custom_call" not in text
+        assert (gathers == 0) == (program is sixteen_group_direct_aggregate)
+        assert " scatter(" not in text or program is small_group_read_back
 
 
 # ---------------------------------------------------------------------------
@@ -153,23 +171,19 @@ def test_q1_stage_compiles_at_60m_rows(one_chip):
 
 @pytest.mark.parametrize("form", ["in-place", "dense", "permutation"])
 def test_packed_sort_aggregate_gathers_by_form(one_chip, form):
-    import re
-
-    from trino_tpu.batch import Batch, Column
     from trino_tpu.ops.aggregate import (AggSpec,
                                          packed_sort_group_aggregate)
     n, capacity = 16_384, 2_048
 
     def shape(dtype, length=n):
         return jax.ShapeDtypeStruct((length,), dtype, sharding=one_chip)
-    batch = Batch(tuple(Column(shape(jnp.int64), shape(jnp.bool_))
-                        for _ in range(3)), shape(jnp.bool_))
+    batch = _batch(one_chip, n, jnp.int64, jnp.int64, jnp.int64)
     aggs = (AggSpec("sum", 1), AggSpec("sum", 2))
     carried = form != "permutation"
 
     def program(batch, kmins, vmins):
         return packed_sort_group_aggregate(
-            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),), "off",
+            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),),
             vmins if carried else None, (2, 16) if carried else None,
             form == "in-place")
     text = jax.jit(program).lower(
@@ -199,21 +213,15 @@ def test_packed_sort_aggregate_gathers_by_form(one_chip, form):
 
 @pytest.mark.parametrize("form", ["packed-int32", "packed-int64", "rows"])
 def test_a_split_join_gathers_once_when_its_lut_is_packed(one_chip, form):
-    import re
-
-    from trino_tpu.batch import Batch, Column, bucket_capacity
+    from trino_tpu.batch import bucket_capacity
     from trino_tpu.ops.join import dense_join_packed, dense_join_with_lut
     n, domain, build_rows = bucket_capacity(250_000), 60_000_000, 1_572_864
 
     def shape(dtype, length):
         return jax.ShapeDtypeStruct((length,), dtype, sharding=one_chip)
-
-    def batch(length, *dtypes):
-        return Batch(tuple(Column(shape(d, length), shape(jnp.bool_, length))
-                           for d in dtypes), shape(jnp.bool_, length))
-    probe = batch(n, jnp.int64, jnp.int64, jnp.int64)
+    probe = _batch(one_chip, n, jnp.int64, jnp.int64, jnp.int64)
     if form == "rows":
-        build = batch(build_rows, jnp.int64, jnp.int32, jnp.int32)
+        build = _batch(one_chip, build_rows, jnp.int64, jnp.int32, jnp.int32)
         text = dense_join_with_lut.__wrapped__.lower(
             probe, build, shape(jnp.int32, domain + 1), (0,), (0,),
             "inner").compile().as_text()

@@ -55,3 +55,20 @@ def test_a_host_routed_answer_is_refused(monkeypatch, tmp_path):
     monkeypatch.setenv("TRINO_TPU_DATA_CACHE", str(tmp_path))
     with pytest.raises(AssertionError, match="q6: not on the device"):
         chip_smoke.run_served(chip_smoke.CacheCounter(), "tiny")
+
+
+@pytest.mark.parametrize("name,strategies,refused", [
+    ("q6", {"worker": {"AggregateNode": "global"}}, None),
+    ("q1", {"worker": {"AggregateNode": "direct"}}, None),
+    # a strategy no executor has any more is not what q1 may report
+    ("q1", {"worker": {"AggregateNode": "mxu"}}, "q1"),
+    ("q3", {"worker": {"AggregateNode": "sort", "JoinNode": "dense-lut"},
+            "coordinator": {"AggregateNode": "sort"}}, None),
+    ("q18", {"worker": {"AggregateNode": "sort"}}, "no join strategy"),
+])
+def test_expected_strategies_of_the_defaults(name, strategies, refused):
+    if refused is None:
+        chip_smoke.expect_strategies(name, strategies)
+    else:
+        with pytest.raises(AssertionError, match=refused):
+            chip_smoke.expect_strategies(name, strategies)

@@ -168,7 +168,7 @@ def test_merge_pair_equals_the_dense_pair_and_a_plain_join(case):
 
     new_cap = min(bucket_capacity(len(want)), probe.capacity)
     out = J.dense_join_compacted(probe, words, rows, build, keys, keys,
-                                 new_cap, "off")
+                                 new_cap)
     assert out.capacity == new_cap
     live = np.asarray(out.live)
     # matched rows first, in the probe's row order, nothing after them
@@ -188,7 +188,7 @@ def test_merge_pair_equals_the_dense_pair_and_a_plain_join(case):
             probe, build, keys, keys, domain)
         assert (int(d_dup), int(d_oob), int(d_count)) == (0, 0, len(want))
         same_batch(out, J.dense_join_compacted(
-            probe, d_words, d_rows, build, keys, keys, new_cap, "off"))
+            probe, d_words, d_rows, build, keys, keys, new_cap))
 
 
 def test_duplicate_build_keys_are_counted_by_both_forms():

@@ -463,7 +463,7 @@ def test_carried_aggregate_matches_numpy_and_the_general_kernel(name, form):
     in_place = carried and form == "in-place"
     capacity = b.capacity if form == "in-place" else 1024
     got = packed_sort_group_aggregate(
-        b, jnp.asarray(kmins), keys, bits, aggs, capacity, splits, "off",
+        b, jnp.asarray(kmins), keys, bits, aggs, capacity, splits,
         None if vmins is None else jnp.asarray(vmins), value_bits, in_place)
     assert got.capacity == (b.capacity if in_place else capacity)
     permuted = packed_sort_group_aggregate(
@@ -517,7 +517,7 @@ def addressed_reads(form, capacity):
         (jnp.zeros(1, jnp.int64), (16,))
     jaxpr = jax.make_jaxpr(
         lambda batch, kmins, vmins: packed_sort_group_aggregate(
-            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),), "off",
+            batch, kmins, (0,), (28,), aggs, capacity, ((0, 1),),
             vmins, carried[1], form == "in-place"))(
         b, jnp.zeros(1, jnp.int64), carried[0])
     out = []

@@ -107,8 +107,7 @@ def _spine_joins(target: L.PlanNode, driver: L.ScanNode) \
 
 
 def compile_fused_chunk(executor, target: L.PlanNode,
-                        driver: L.ScanNode, lut_specs=None, adapt=None,
-                        gather_mode: str = "off"):
+                        driver: L.ScanNode, lut_specs=None, adapt=None):
     """Compose the whole per-chunk path (joins with prebuilt LUTs,
     filters, projections, the partial aggregate) into ONE traced
     function so every chunk is a single device dispatch with zero host
@@ -120,7 +119,7 @@ def compile_fused_chunk(executor, target: L.PlanNode,
 
     `lut_specs` maps id(join node) -> spec from _fused_luts: ("rows",)
     joins gather per payload column off a row-id LUT; ("packed", meta,
-    word_dtype, bkey, out_dtypes) joins decode everything from ONE
+    bkey, out_dtypes) joins decode everything from ONE
     value-packed gather, and their entry of `luts` is the pair (LUT,
     the word's offsets `los`): the spec holds the schema's statics, the
     operands what follows the data.
@@ -133,14 +132,7 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     vector the DRIVER must verify (nonzero escaped => rerun the plain
     program).
 
-    `gather_mode` routes windowed packed probes through the Pallas
-    tiled-gather kernel (ops/pallas_gather.py): the driver prepares
-    per-LUT int32 planes ONCE and passes them as the program's fourth
-    argument; kernel window escapes fold into the same escaped flag the
-    verifier already checks, so a violated near-sorted guess reruns
-    plain exactly as before.
-
-    Returns (fn, join_nodes) where fn(chunk, builds, luts, gplanes) ->
+    Returns (fn, join_nodes) where fn(chunk, builds, luts) ->
     (partial Batch, stats int64[1 + n_joins]); stats layout:
     [escaped_total, span_0, span_1, ...].
     None when the shape doesn't apply (caller uses the per-node loop)."""
@@ -154,10 +146,10 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     windows = adapt or {}
 
     def emit(node):
-        """Returns f(chunk, builds, luts, gplanes) -> (Batch, stats
-        dict) or None. stats: {"escaped": scalar, "spans": [...]}."""
+        """Returns f(chunk, builds, luts) -> (Batch, stats dict) or
+        None. stats: {"escaped": scalar, "spans": [...]}."""
         if node is driver:
-            return lambda chunk, builds, luts, gp: (chunk, {
+            return lambda chunk, builds, luts: (chunk, {
                 "escaped": jnp.int64(0), "spans": []})
         if isinstance(node, L.FilterNode):
             child = emit(node.child)
@@ -165,8 +157,8 @@ def compile_fused_chunk(executor, target: L.PlanNode,
                 return None
             pred = executor.fold_scalars(node.predicate)
 
-            def run_filter(chunk, b, l, g, _child=child, _pred=pred):
-                bt, st = _child(chunk, b, l, g)
+            def run_filter(chunk, b, l, _child=child, _pred=pred):
+                bt, st = _child(chunk, b, l)
                 return apply_filter(bt, _pred), st
             return run_filter
         if isinstance(node, L.ProjectNode):
@@ -175,8 +167,8 @@ def compile_fused_chunk(executor, target: L.PlanNode,
                 return None
             exprs = executor.fold_scalars_tuple(node.exprs)
 
-            def run_project(chunk, b, l, g, _child=child, _exprs=exprs):
-                bt, st = _child(chunk, b, l, g)
+            def run_project(chunk, b, l, _child=child, _exprs=exprs):
+                bt, st = _child(chunk, b, l)
                 return filter_project(bt, None, None, _exprs), st
             return run_project
         if isinstance(node, L.JoinNode):
@@ -191,28 +183,26 @@ def compile_fused_chunk(executor, target: L.PlanNode,
             spec = lut_specs.get(id(node)) if lut_specs else None
             window = windows.get(idx)
 
-            def run_join(chunk, b, l, g, _child=child, _idx=idx,
+            def run_join(chunk, b, l, _child=child, _idx=idx,
                          _lk=lk, _rk=rk, _kind=kind, _spec=spec,
                          _win=window):
-                bt, st = _child(chunk, b, l, g)
+                bt, st = _child(chunk, b, l)
                 esc = jnp.int64(0)
                 if _spec is not None and _spec[0] == "packed":
-                    _, meta, _wd, bkey, out_dtypes = _spec
+                    _, meta, bkey, out_dtypes = _spec
                     lut, los = l[_idx]
                     if _win is not None:
-                        gp = g[_idx] if _idx < len(g) else None
                         out, esc, span = dense_join_packed_windowed(
                             bt, lut, los, _lk, meta, bkey, out_dtypes,
-                            _kind, _win, word_dtype=_wd,
-                            gather_mode=gather_mode, lut_planes=gp)
+                            _kind, _win)
                     else:
                         out = dense_join_packed(
                             bt, lut, los, _lk, meta, bkey, out_dtypes,
-                            _kind, gather_mode)
+                            _kind)
                         span = _key_span(bt, _lk)
                 else:
                     out = dense_join_with_lut(bt, b[_idx], l[_idx], _lk,
-                                              _rk, _kind, gather_mode)
+                                              _rk, _kind)
                     span = _key_span(bt, _lk)
                 return out, {"escaped": st["escaped"] + esc,
                              "spans": st["spans"] + [span]}
@@ -227,16 +217,16 @@ def compile_fused_chunk(executor, target: L.PlanNode,
                                  if a.arg is not None else None)
                          for a in node.aggs)
             if node.strategy == "global":
-                def run_gagg(chunk, b, l, g, _child=child, _aggs=aggs):
-                    bt, st = _child(chunk, b, l, g)
+                def run_gagg(chunk, b, l, _child=child, _aggs=aggs):
+                    bt, st = _child(chunk, b, l)
                     return global_aggregate(bt, _aggs), st
                 return run_gagg
             if node.strategy == "direct":
                 keys, domains = node.group_keys, node.key_domains
 
-                def run_dagg(chunk, b, l, g, _child=child, _aggs=aggs,
+                def run_dagg(chunk, b, l, _child=child, _aggs=aggs,
                              _keys=keys, _domains=domains):
-                    bt, st = _child(chunk, b, l, g)
+                    bt, st = _child(chunk, b, l)
                     return direct_group_aggregate(
                         bt, _keys, _domains, _aggs), st
                 return run_dagg
@@ -247,8 +237,8 @@ def compile_fused_chunk(executor, target: L.PlanNode,
     if inner is None:
         return None
 
-    def fn(chunk, builds, luts, gplanes=()):
-        out, st = inner(chunk, builds, luts, gplanes)
+    def fn(chunk, builds, luts):
+        out, st = inner(chunk, builds, luts)
         return out, jnp.stack([st["escaped"]] + st["spans"])
     return fn, joins
 
@@ -325,7 +315,7 @@ def _fused_luts(executor, joins) -> Optional[tuple]:
                 lut, exp, oob, occ = dense_build_packed_lut(
                     b, j.right_keys, j.build_key_domain, meta, wd, los)
                 lut = (lut, los)
-                specs[k] = ("packed", meta, wd, j.right_keys[0],
+                specs[k] = ("packed", meta, j.right_keys[0],
                             tuple(str(c.data.dtype) for c in b.columns))
                 dup_sig = exp - occ           # >0 = duplicate keys
             else:
@@ -371,18 +361,6 @@ def _fused_luts(executor, joins) -> Optional[tuple]:
                 executor._lut_cache[(keys[k], joins[k].build_key_domain)] \
                     = (luts[k], specs[k])
     return tuple(builds), tuple(luts), tuple(specs)
-
-
-def _windowed_planes(gmode: str, adapt, specs, luts, k):
-    """int32 gather planes for join k's LUT, or None when the Pallas
-    windowed probe won't run for it (mode off, not adapted to a window,
-    not value-packed, or domain too wide for 32-bit kernel indices)."""
-    from ..ops import pallas_gather
-    if gmode == "off" or k not in (adapt or {}) or specs[k] is None or \
-            specs[k][0] != "packed" or \
-            luts[k][0].shape[0] > pallas_gather.MAX_WINDOWED_ELEMS:
-        return None
-    return pallas_gather.prepare_word_planes(luts[k][0])
 
 
 # adaptive re-optimization safety margin: windows pad the measured
@@ -608,27 +586,19 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
         if bl is not None:
             builds, luts, specs = bl
             # one jitted wrapper per (plan structure, packing layout,
-            # adaptation, gather mode), reused across runs so
-            # re-executions hit the in-memory trace cache (a replan
-            # produces new node objects but identical static values)
-            gmode = executor.gather_mode()
+            # adaptation), reused across runs so re-executions hit the
+            # in-memory trace cache (a replan produces new node objects
+            # but identical static values)
             skey = executor.build_structure_key(per_chunk_target)
             adapt = _fused_adaptation(executor, skey, spine, specs)
-            # Pallas windowed probes gather off int32 planes prepared
-            # ONCE per pinned LUT (per-chunk re-splitting would re-read
-            # the whole domain-sized table every chunk)
-            gplanes = tuple(
-                _windowed_planes(gmode, adapt, specs, luts, k)
-                for k in range(len(spine)))
-            ckey = (skey, specs, repr(adapt), gmode) \
+            ckey = (skey, specs, repr(adapt)) \
                 if skey is not None else None
             jitted = executor._fused_cache.get(ckey) \
                 if ckey is not None else None
             if jitted is None:
                 mine = compile_fused_chunk(
                     executor, per_chunk_target, plan.driver,
-                    {id(j): s for j, s in zip(spine, specs)}, adapt,
-                    gather_mode=gmode)
+                    {id(j): s for j, s in zip(spine, specs)}, adapt)
                 if mine is not None:
                     # routed through the compile recorder: the first
                     # chunk call records the actual XLA compile (site
@@ -644,10 +614,8 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
                                 next(iter(executor._fused_cache)))
                         executor._fused_cache[ckey] = jitted
             if jitted is not None:
-                fused = (jitted, builds, luts, skey, adapt, gplanes)
+                fused = (jitted, builds, luts, skey, adapt)
                 executor.stats.fused_chunk_pipelines += 1
-                if gmode != "off":
-                    executor.stats.pallas_gather_calls += 1
     _prof(f"luts+fused ready (fused={fused is not None}, "
           f"adapt={fused[4] if fused else None}, "
           f"fact={fact is not None})")
@@ -719,7 +687,7 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
                     capacity=cap)
                 with RECORDER.prewarm_context():
                     jax.block_until_ready(
-                        fused[0](dummy, fused[1], fused[2], fused[5]))
+                        fused[0](dummy, fused[1], fused[2]))
             except Exception:
                 pass    # warm is best-effort; the loop compiles anyway
 
@@ -744,8 +712,7 @@ def execute_chunked(executor, root: L.OutputNode) -> Optional[Batch]:
                 chunk = pipeline.next(start)
             t0 = time.monotonic()
             if fused is not None:
-                out, stats_vec = fused[0](chunk, fused[1], fused[2],
-                                          fused[5])
+                out, stats_vec = fused[0](chunk, fused[1], fused[2])
                 chunk_stats.append(stats_vec)
                 if _profile_enabled():
                     jax.block_until_ready(out)

@@ -55,7 +55,6 @@ class ExecStats:
     agg_capacity_retries: int = 0
     dynamic_filter_compactions: int = 0
     agg_spill_chunks: int = 0
-    mxu_agg_calls: int = 0
     fact_cache_chunks: int = 0       # chunks sliced from device-resident
     chunk_lut_joins: int = 0         # sync-free reused-LUT probes
     packed_lut_joins: int = 0        # those of them whose LUT's word
@@ -70,8 +69,6 @@ class ExecStats:
                                      # program that tests a folded IN
                                      # subquery's member set (ir.InSet)
     fused_chunk_pipelines: int = 0   # whole-chunk-path single programs
-    pallas_gather_calls: int = 0     # probe sites dispatched with the
-                                     # tiled-gather kernel enabled
     jit_compiles: int = 0            # new jitted programs built (fused
                                      # chunk pipelines compiled fresh)
     escaped_window_reruns: int = 0   # adapted fused runs whose window /
@@ -217,15 +214,6 @@ class Executor:
         # bounded-memory aggregation: process scan chains in chunks of this
         # many rows (the spill-to-host analog; None = off)
         self.spill_chunk_rows: Optional[int] = None
-        # Pallas MXU aggregation (ops/pallas_agg.py): "auto" picks it in
-        # its measured win region (small-G direct aggregates past
-        # MXU_AGG_MIN_GROUPS on TPU); "true"/"false" force
-        self.enable_mxu_agg = "auto"
-        # Pallas tiled-gather probe kernel (ops/pallas_gather.py):
-        # "auto" = on for TPU backends; "true" forces it (interpret mode
-        # off-TPU, which is how tier-1 exercises the kernel logic);
-        # "false" = every site keeps its jnp.take path
-        self.enable_pallas_gather = "auto"
         # per-query record of the strategy each operator class actually
         # ran with (EXPLAIN `agg strategy:` lines, operator_stats column)
         self.strategy_decisions: Dict[str, str] = {}
@@ -598,8 +586,7 @@ class Executor:
         SAME plan structure (dynamic filtering alters intermediate live
         counts, merge-join toggles which kernel's dup check runs)."""
         return (self.enable_dynamic_filtering, self.enable_merge_join,
-                str(self.enable_mxu_agg), bool(self.stream_build_bytes),
-                self.spill_chunk_rows)
+                bool(self.stream_build_bytes), self.spill_chunk_rows)
 
     _DECISION_CACHE_FILE = "decisions.pkl"
 
@@ -1154,12 +1141,6 @@ class Executor:
                            inputRows=self._known_rows(node.child))
         return self.aggregate_batch(node, child, aggs)
 
-    def gather_mode(self) -> str:
-        """Resolved Pallas tiled-gather mode for this query: 'device' |
-        'interpret' | 'off' (see ops/pallas_gather.resolve_mode)."""
-        from ..ops.pallas_gather import resolve_mode
-        return resolve_mode(self.enable_pallas_gather)
-
     def _note_strategy(self, op: str, strategy: str, kind: str) -> None:
         """Record the strategy an operator actually ran with: the
         per-query EXPLAIN/operator_stats surface plus the
@@ -1173,51 +1154,12 @@ class Executor:
         else:
             JOIN_STRATEGY_DECISIONS.inc(strategy=strategy)
 
-    # auto mxu_agg gate: the one-hot matmul kernel's HBM plane
-    # materialization loses to the fused XLA reduction graph at q1's
-    # G=6 (7.4ms vs 2.1ms, kernel docstring) but the XLA graph grows
-    # linearly in G while the kernel stays one matmul pass — the
-    # measured crossover sits near the top of the dense-domain range
-    MXU_AGG_MIN_GROUPS = 12
-
-    def use_mxu_agg(self, child: Batch, aggs, domains) -> bool:
-        """Pallas MXU aggregation (ops/pallas_agg.py): TPU backend,
-        sum/count aggregates over integer columns, small dense group
-        domain. `mxu_agg` = auto picks it only in its measured win
-        region (G >= MXU_AGG_MIN_GROUPS — the docstring documents it
-        losing at the q1 shape); true/false force."""
-        setting = str(self.enable_mxu_agg).lower()
-        if setting in ("false", "0"):
-            return False
-        import jax as _jax
-        if _jax.default_backend() != "tpu":
-            return False
-        from ..ops.pallas_agg import supports
-        if not supports(aggs, domains):
-            return False
-        for a in aggs:
-            if a.arg_index is not None and not jnp.issubdtype(
-                    child.columns[a.arg_index].data.dtype, jnp.integer):
-                return False
-        if setting in ("true", "1"):
-            return True
-        g = 1
-        for d in domains:
-            g *= d
-        return g >= self.MXU_AGG_MIN_GROUPS
-
     def aggregate_batch(self, node: L.AggregateNode, child: Batch, aggs):
         """One partial aggregation (the PARTIAL step)."""
         if node.strategy == "global":
             self._note_strategy("AggregateNode", "global", "agg")
             return global_aggregate(child, aggs)
         if node.strategy == "direct":
-            if self.use_mxu_agg(child, aggs, node.key_domains):
-                from ..ops.pallas_agg import direct_group_aggregate_mxu
-                self.stats.mxu_agg_calls += 1
-                self._note_strategy("AggregateNode", "mxu", "agg")
-                return direct_group_aggregate_mxu(
-                    child, node.group_keys, node.key_domains, aggs)
             self._note_strategy("AggregateNode", "direct", "agg")
             return direct_group_aggregate(child, node.group_keys,
                                           node.key_domains, aggs)
@@ -1256,7 +1198,6 @@ class Executor:
                 capacity = min(capacity, SORT_SMALL_ROWS)
                 pack = None
         self._note_strategy("AggregateNode", "sort", "agg")
-        gm = self.gather_mode()
         retries = self.stats.agg_capacity_retries
         # where the plan found room for the aggregates' arguments in the
         # keys' sort word, the kernel carries them through its sort
@@ -1268,12 +1209,12 @@ class Executor:
                 kmins, bits, splits = pack[:3]
                 out = packed_sort_group_aggregate(
                     child, jnp.asarray(kmins), node.group_keys, bits,
-                    aggs, capacity, splits, gm, vmins, value_bits,
+                    aggs, capacity, splits, vmins, value_bits,
                     carried is not None and
                     in_place_output(capacity, child.capacity))
             else:
                 out = sort_group_aggregate(child, node.group_keys, aggs,
-                                           capacity, gm)
+                                           capacity)
             n_groups = self.fetch_ints(node, f"agggroups{capacity}",
                                        jnp.sum(out.live))[0]
             # in place (the value-carrying form, where the capacity is
@@ -1319,9 +1260,8 @@ class Executor:
                 kmins, bits, splits = pack
                 return packed_sort_group_aggregate(
                     merged, jnp.asarray(kmins), keys, bits, merge_aggs,
-                    capacity, splits, self.gather_mode())
-        return sort_group_aggregate(merged, keys, merge_aggs, capacity,
-                                    self.gather_mode())
+                    capacity, splits)
+        return sort_group_aggregate(merged, keys, merge_aggs, capacity)
 
     # ---- uncorrelated scalar subqueries (fold to constants) ----------
 
@@ -1686,9 +1626,6 @@ class Executor:
             out = self._chunk_lut_join(node, probe, build, domain)
             if out is not None:
                 return out
-        gm = self.gather_mode()
-        if gm != "off":
-            self.stats.pallas_gather_calls += 1
         n_sort_ops = 2 * (len(probe.columns) + len(build.columns)) + 4
         merge_ok = self.enable_merge_join and \
             n_sort_ops <= MAX_SORT_OPERANDS and \
@@ -1749,7 +1686,7 @@ class Executor:
                         self.stats.dynamic_filter_compactions += 1
                         return dense_join_compacted(
                             probe, words, rows, build, node.left_keys,
-                            node.right_keys, new_cap, gm)
+                            node.right_keys, new_cap)
                     if word_bits is None:
                         # unselective, and the LUT form has vouched for
                         # the domain: one shot at the probe's capacity
@@ -1757,13 +1694,13 @@ class Executor:
                                             "join")
                         return join_unique_build_dense(
                             probe, build, node.left_keys,
-                            node.right_keys, node.kind, domain, gm)[0]
+                            node.right_keys, node.kind, domain)[0]
                     # unselective after a merge: the one-shot kernels
                     # below, which check the domain themselves
         if domain is not None:
             out, dup, oob = join_unique_build_dense(
                 probe, build, node.left_keys, node.right_keys,
-                node.kind, domain, gm)
+                node.kind, domain)
             dup, oob, live = self.fetch_ints(
                 node, f"jdense:{domain}", dup, oob, jnp.sum(out.live))
             if oob == 0:
@@ -1817,14 +1754,12 @@ class Executor:
         if packed is None:
             self._note_strategy("JoinNode", "dense-lut", "join")
             return dense_join_with_lut(probe, build, lut, node.left_keys,
-                                       node.right_keys, node.kind,
-                                       self.gather_mode())
+                                       node.right_keys, node.kind)
         los, meta, out_dtypes = packed
         self.stats.packed_lut_joins += 1
         self._note_strategy("JoinNode", "dense-lut-packed", "join")
         return dense_join_packed(probe, lut, los, node.left_keys, meta,
-                                 node.right_keys[0], out_dtypes, node.kind,
-                                 self.gather_mode())
+                                 node.right_keys[0], out_dtypes, node.kind)
 
     def _packed_chunk_lut(self, node: L.JoinNode, build: Batch,
                           domain: int):
@@ -1946,7 +1881,7 @@ class Executor:
             if domain is not None:
                 dout, _dup, oob = join_unique_build_dense(
                     probe, build, node.left_keys, node.right_keys,
-                    "semi", domain, self.gather_mode())
+                    "semi", domain)
                 if self.fetch_ints(node, f"markoob:{domain}",
                                    oob)[0] == 0:
                     out = dout
@@ -1992,7 +1927,7 @@ class Executor:
             if domain is not None:
                 out, _dup, oob = join_unique_build_dense(
                     probe, build, node.left_keys, node.right_keys,
-                    node.kind, domain, self.gather_mode())
+                    node.kind, domain)
                 if self.fetch_ints(node, f"memoob:{domain}",
                                    oob)[0] == 0:
                     self._note_strategy("JoinNode", "dense-lut", "join")
@@ -2056,7 +1991,7 @@ def explain_strategy_lines(root: L.PlanNode, executor) -> List[str]:
     the per-operator strategy gate will pick for this plan (pre-order,
     matching explain_text). After EXPLAIN ANALYZE the executor's
     recorded decision is appended when it differs from the prediction
-    (e.g. a direct plan the MXU kernel took)."""
+    (e.g. a dense-lut plan whose stale stats sent it to sort-merge)."""
     lines: List[str] = []
     ran = executor.strategy_decisions
 

@@ -75,14 +75,6 @@ SESSION_PROPERTY_DEFAULTS = {
     # RAM/disk instead of failing
     "spill_enabled": (True, _bool),
     "spill_partitions": (8, int),
-    # Pallas MXU one-pass aggregation kernel (ops/pallas_agg.py): auto
-    # picks it in its measured win region (direct aggregates with
-    # G >= Executor.MXU_AGG_MIN_GROUPS on TPU); true/false force
-    "mxu_agg": ("auto", lambda v: str(v).lower()),
-    # Pallas tiled-gather probe kernel (ops/pallas_gather.py): auto =
-    # on for TPU backends; true forces it (interpret mode on CPU, the
-    # tier-1 test path); false = jnp.take everywhere
-    "enable_pallas_gather": ("auto", lambda v: str(v).lower()),
     # dense 'direct' aggregation bound (GroupByHash strategy choice);
     # capped by the kernel's compile-bound MAX_DIRECT_GROUPS
     "direct_agg_max_groups": (64, int),
@@ -258,8 +250,6 @@ class Session:
         ex.deadline = (t0 + max_s) if max_s else None
         kb = self.properties["stream_build_min_kb"]
         ex.stream_build_bytes = (kb << 10) if kb else None
-        ex.enable_pallas_gather = self.properties["enable_pallas_gather"]
-        ex.enable_mxu_agg = self.properties["mxu_agg"]
         ex.profile = self.properties["enable_profiling"]
         if ex.profile:
             ex.node_stats = {}       # per-query attribution
@@ -478,11 +468,6 @@ class Session:
         elif stmt.name == "spill_chunk_rows":
             self.executor.spill_chunk_rows = \
                 self.properties[stmt.name] or None
-        elif stmt.name == "mxu_agg":
-            self.executor.enable_mxu_agg = self.properties[stmt.name]
-        elif stmt.name == "enable_pallas_gather":
-            self.executor.enable_pallas_gather = \
-                self.properties[stmt.name]
         elif stmt.name == "enable_tracing":
             from ..utils.tracing import NOOP, Tracer, carried
             # under the dispatcher every query brings its own tracer: a

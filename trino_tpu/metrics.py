@@ -625,7 +625,7 @@ for _op in ("ScanNode", "JoinNode", "AggregateNode"):
     OPERATOR_COMPILE_MS.init_labels(operator=_op)
 for _target in ("host", "device"):
     ROUTER_DECISIONS.init_labels(target=_target)
-for _s in ("global", "direct", "mxu", "sort"):
+for _s in ("global", "direct", "sort"):
     AGG_STRATEGY_DECISIONS.init_labels(strategy=_s)
 for _s in ("dense-lut", "dense-lut-packed", "sort-probe", "sort-merge",
            "sorted", "expand"):

@@ -167,10 +167,9 @@ def _segmented_scan(vals: jax.Array, boundary: jax.Array, op):
     return out
 
 
-@recorded_jit(static_argnums=(1, 2, 3, 4))
+@recorded_jit(static_argnums=(1, 2, 3))
 def sort_group_aggregate(batch: Batch, key_indices: tuple, aggs: tuple,
-                         out_capacity: int,
-                         gather_mode: str = "off") -> Batch:
+                         out_capacity: int) -> Batch:
     """Group by arbitrary key columns via lexicographic sort.
 
     Exact (sorts real key values, not hashes). Output capacity is a static
@@ -225,24 +224,16 @@ def sort_group_aggregate(batch: Batch, key_indices: tuple, aggs: tuple,
             (ddata_s != jnp.roll(ddata_s, 1)) | \
             (dvinv_s != jnp.roll(dvinv_s, 1))
     return _grouped_reduce(batch, key_indices, aggs, out_capacity, perm,
-                           live_s, boundary, distinct_fresh, gather_mode)
+                           live_s, boundary, distinct_fresh)
 
 
 def _grouped_reduce(batch: Batch, key_indices: tuple, aggs: tuple,
                     out_capacity: int, perm, live_s, boundary,
-                    distinct_fresh, gather_mode: str = "off") -> Batch:
+                    distinct_fresh) -> Batch:
     """Shared segment machinery for the sorted aggregation kernels: given
     the sort permutation and group boundaries, locate segment extents and
     reduce every aggregate — used by both the general multi-operand kernel
-    and the packed 2-operand kernel (traced inside their jits).
-
-    `gather_mode` routes the GROUP READBACK gathers (representative row
-    per output group -> key columns) through the Pallas tiled-gather
-    kernel (ops/pallas_gather.py): one index decomposition feeds every
-    key data/validity plane. The kernel's win region is small batches
-    (its scan cost grows with the gathered table's length), so the
-    shape gate falls back to the jnp.take path at scale — bit-exact
-    either way."""
+    and the packed 2-operand kernel (traced inside their jits)."""
     n = batch.capacity
     seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1      # 0-based group id
     num_groups = boundary.sum()
@@ -263,28 +254,11 @@ def _grouped_reduce(batch: Batch, key_indices: tuple, aggs: tuple,
                         jnp.clip(next_start - 1, 0, n - 1), n - 1)
 
     out_cols = []
-    key_tables = []
+    rep = perm[start_c]                   # representative row per group
     for ki in key_indices:
-        key_tables.extend((batch.columns[ki].data,
-                           batch.columns[ki].valid))
-    from . import pallas_gather
-    if gather_mode != "off" and \
-            pallas_gather.gather_supported([perm] + key_tables):
-        # the group gather: ONE fused pass resolves the representative
-        # row (perm at segment starts) and every key data/valid plane
-        rep = pallas_gather.gather_columns([perm], start_c,
-                                           mode=gather_mode)[0]
-        outs = pallas_gather.gather_columns(key_tables, rep,
-                                            mode=gather_mode)
-        for j, ki in enumerate(key_indices):
-            out_cols.append(Column(data=outs[2 * j],
-                                   valid=outs[2 * j + 1] & group_live))
-    else:
-        rep = perm[start_c]               # representative row per group
-        for ki in key_indices:
-            col = batch.columns[ki]
-            out_cols.append(Column(data=col.data[rep],
-                                   valid=col.valid[rep] & group_live))
+        col = batch.columns[ki]
+        out_cols.append(Column(data=col.data[rep],
+                               valid=col.valid[rep] & group_live))
 
     def seg_total(values_sorted):
         """Per-group totals of a sorted value array via cumsum diff."""
@@ -534,12 +508,11 @@ def in_place_output(out_capacity: int, capacity: int) -> bool:
     return out_capacity * IN_PLACE_FACTOR >= capacity
 
 
-@recorded_jit(static_argnums=(2, 3, 4, 5, 6, 7, 9, 10))
+@recorded_jit(static_argnums=(2, 3, 4, 5, 6, 8, 9))
 def packed_sort_group_aggregate(batch: Batch, kmins, key_indices: tuple,
                                 key_bits: tuple, aggs: tuple,
                                 out_capacity: int,
                                 word_splits: tuple = None,
-                                gather_mode: str = "off",
                                 vmins=None,
                                 value_bits: tuple = None,
                                 in_place: bool = False) -> Batch:
@@ -591,7 +564,7 @@ def packed_sort_group_aggregate(batch: Batch, kmins, key_indices: tuple,
         diff = diff | (ws != jnp.roll(ws, 1))
     boundary = live_s & (first | diff)
     return _grouped_reduce(batch, key_indices, aggs, out_capacity, perm,
-                           live_s, boundary, {}, gather_mode)
+                           live_s, boundary, {})
 
 
 def _carried_group_aggregate(batch: Batch, key_word, kmins,

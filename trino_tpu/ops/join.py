@@ -55,17 +55,6 @@ from . import pallas_gather
 _SENTINEL = jnp.iinfo(jnp.int64).max
 
 
-def _lut_probe(lut: jax.Array, p_idx: jax.Array,
-               gather_mode: str) -> jax.Array:
-    """One LUT word per probe index — the Pallas tiled-gather kernel
-    when enabled and the table is inside its win region, else the XLA
-    gather (ops/pallas_gather.py; bit-exact either way)."""
-    if gather_mode != "off" and pallas_gather.gather_supported([lut]):
-        return pallas_gather.gather_columns([lut], p_idx,
-                                            mode=gather_mode)[0]
-    return lut[p_idx]
-
-
 def _combined_key(batch: Batch, key_indices: tuple) -> Tuple[jax.Array,
                                                              jax.Array]:
     """(key, key_valid) as int64. Multi-column keys pack 32 bits per
@@ -106,10 +95,9 @@ def _out_of_domain(key: jax.Array, ok: jax.Array, domain: int):
     return jnp.any(ok & ((key < 0) | (key >= domain)))
 
 
-@recorded_jit(static_argnums=(2, 3, 4, 5, 6))
+@recorded_jit(static_argnums=(2, 3, 4, 5))
 def join_unique_build_dense(probe: Batch, build: Batch, probe_keys: tuple,
-                            build_keys: tuple, kind: str, domain: int,
-                            gather_mode: str = "off"):
+                            build_keys: tuple, kind: str, domain: int):
     """Unique-build equi-join via dense LUT: one scatter to build, one
     gather per probe (the BigintGroupByHash-style fast path).
 
@@ -129,7 +117,7 @@ def join_unique_build_dense(probe: Batch, build: Batch, probe_keys: tuple,
     lut, dup = _dense_row_lut(bk, b_ok, domain)
 
     p_idx = jnp.where(pk_valid, jnp.clip(pk, 0, domain - 1), domain)
-    src = _lut_probe(lut, p_idx, gather_mode)
+    src = lut[p_idx]
     matched = (src >= 0) & pk_valid & probe.live & \
         (pk >= 0) & (pk < domain)
     src_c = jnp.clip(src, 0, build.capacity - 1)
@@ -139,27 +127,18 @@ def join_unique_build_dense(probe: Batch, build: Batch, probe_keys: tuple,
     if kind == "anti":
         return probe.with_live(probe.live & ~matched), dup, oob
     return (_gather_build_payload(probe, build, src_c, matched, pk,
-                                  build_keys, kind, gather_mode),
+                                  build_keys, kind),
             dup, oob)
 
 
 def _gather_build_payload(probe: Batch, build: Batch, src_c, matched, pk,
-                          build_keys: tuple, kind: str,
-                          gather_mode: str = "off") -> Batch:
+                          build_keys: tuple, kind: str) -> Batch:
     """Per-column build gathers of a dense-LUT probe result (traced
     helper shared by the one-shot and reused-LUT kernels). `src_c` must
-    already be clipped to [0, build.capacity).
-
-    With `gather_mode` on, the validity word and every payload column
-    ride ONE Pallas multi-table gather: the kernel decomposes each probe
-    index once and streams all planes past it (the whole point of the
-    tiled-gather kernel — per-index cost no longer scales with the
-    payload column count)."""
+    already be clipped to [0, build.capacity)."""
     bkey = build_keys[0] if len(build_keys) == 1 else None
     pack_valids = len(build.columns) <= 63
-    payload = [i for i in range(len(build.columns)) if i != bkey]
-    vbits = None
-    vword = None
+    vbits = gathered = None
     if pack_valids:
         # validity word: bit i = column i valid (skipping the key column,
         # whose validity IS `matched`)
@@ -168,18 +147,18 @@ def _gather_build_payload(probe: Batch, build: Batch, src_c, matched, pk,
             if i == bkey:
                 continue
             vword = vword | (col.valid.astype(jnp.int64) << i)
-
-    gathered = None
-    tables = ([vword] if pack_valids else []) + \
-        [build.columns[i].data for i in payload]
-    if gather_mode != "off" and pack_valids and \
-            pallas_gather.gather_supported(tables):
-        outs = pallas_gather.gather_columns(tables, src_c,
-                                            mode=gather_mode)
-        vbits = outs[0]
-        gathered = dict(zip(payload, outs[1:]))
-    elif pack_valids:
-        vbits = vword[src_c]
+        # a small build on a TPU: the validity word and every payload
+        # column ride ONE kernel call that decomposes each index once.
+        # Platform and table size decide (pallas_gather.gather_supported):
+        # 262,144 probes of a 2,048-row build read 86 us there against
+        # 13.3 ms for the eight planes as XLA gathers (PERF.md, PR 46)
+        payload = [i for i in range(len(build.columns)) if i != bkey]
+        tables = [vword] + [build.columns[i].data for i in payload]
+        if pallas_gather.gather_supported(tables):
+            outs = pallas_gather.gather_columns(tables, src_c)
+            vbits, gathered = outs[0], dict(zip(payload, outs[1:]))
+        else:
+            vbits = vword[src_c]
 
     build_cols = []
     for i, col in enumerate(build.columns):
@@ -191,7 +170,7 @@ def _gather_build_payload(probe: Batch, build: Batch, src_c, matched, pk,
             continue
         valid = ((vbits >> i) & 1).astype(jnp.bool_) if pack_valids \
             else col.valid[src_c]
-        data = gathered[i] if gathered is not None else col.data[src_c]
+        data = col.data[src_c] if gathered is None else gathered[i]
         build_cols.append(Column(data=data, valid=valid & matched))
     live = probe.live & matched if kind == "inner" else probe.live
     return Batch(columns=probe.columns + tuple(build_cols), live=live)
@@ -212,10 +191,10 @@ def dense_build_lut(build: Batch, build_keys: tuple, domain: int):
     return lut, dup, oob
 
 
-@recorded_jit(static_argnums=(3, 4, 5, 6))
+@recorded_jit(static_argnums=(3, 4, 5))
 def dense_join_with_lut(probe: Batch, build: Batch, lut: jax.Array,
                         probe_keys: tuple, build_keys: tuple,
-                        kind: str, gather_mode: str = "off") -> Batch:
+                        kind: str) -> Batch:
     """Probe a prebuilt (already-validated) dense LUT: no duplicate /
     out-of-domain checks, no host syncs, no compaction — the chunked
     driver's steady-state join. Output keeps probe capacity with a live
@@ -223,7 +202,7 @@ def dense_join_with_lut(probe: Batch, build: Batch, lut: jax.Array,
     domain = lut.shape[0] - 1
     pk, pk_valid = _combined_key(probe, probe_keys)
     p_idx = jnp.where(pk_valid, jnp.clip(pk, 0, domain - 1), domain)
-    src = _lut_probe(lut, p_idx, gather_mode)
+    src = lut[p_idx]
     matched = (src >= 0) & pk_valid & probe.live & \
         (pk >= 0) & (pk < domain)
     if kind == "semi":
@@ -232,7 +211,7 @@ def dense_join_with_lut(probe: Batch, build: Batch, lut: jax.Array,
         return probe.with_live(probe.live & ~matched)
     src_c = jnp.clip(src, 0, build.capacity - 1)
     return _gather_build_payload(probe, build, src_c, matched, pk,
-                                 build_keys, kind, gather_mode)
+                                 build_keys, kind)
 
 
 @recorded_jit(static_argnums=(2, 3))
@@ -403,24 +382,12 @@ def _unpack_build_columns(probe: Batch, word, matched, pk, meta: tuple,
 def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
                                los: jax.Array, probe_keys: tuple,
                                meta: tuple, bkey: int,
-                               out_dtypes: tuple, kind: str, window: int,
-                               word_dtype: str = None,
-                               gather_mode: str = "off",
-                               lut_planes=None):
+                               out_dtypes: tuple, kind: str, window: int):
     """dense_join_packed for NEAR-SORTED probe keys: gathers from a
     dynamic window slice of the LUT instead of the full table — the
     chunk's key span stays cache-resident, measured ~1.9x faster than
     the full-table gather on v5e. `window` is a static size from the
     decision cache (a previous run's measured max span, padded).
-
-    With `gather_mode` on and `lut_planes` prepared (one-time,
-    pallas_gather.prepare_word_planes), the probe instead runs the
-    Pallas windowed kernel: each (8,128) index tile fetches its own
-    WIN-sized pair of LUT blocks by scalar-prefetched block index and
-    resolves all 1024 probes in-register — per-probe cost independent of
-    both table size and chunk key span.  Kernel escapes (a tile spanning
-    more than WIN entries) land in the same `escaped` counter, so the
-    driver's existing rerun-plain machinery covers both paths.
 
     Returns (batch, escaped, span): `escaped` counts in-domain keys that
     fell OUTSIDE the window — the caller MUST check it is zero at the
@@ -435,20 +402,13 @@ def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
     lo = jnp.min(jnp.where(ok_rows, pk, big))
     hi = jnp.max(jnp.where(ok_rows, pk, jnp.int64(-1)))
     span = jnp.maximum(hi - lo + 1, 0)
-    if gather_mode != "off" and lut_planes is not None and \
-            domain + 1 <= pallas_gather.MAX_WINDOWED_ELEMS:
-        word, escaped = pallas_gather.gather_word_windowed(
-            lut_planes, jnp.where(ok_rows, pk, jnp.int64(-1)),
-            word_dtype or str(lut.dtype), mode=gather_mode)
-        matched = (word != 0) & ok_rows
-    else:
-        w0 = jnp.clip(lo, 0, jnp.maximum(domain + 1 - window, 0))
-        win = jax.lax.dynamic_slice(lut, (w0,), (window,))
-        local = pk - w0
-        in_win = (local >= 0) & (local < window)
-        word = win[jnp.clip(local, 0, window - 1)].astype(jnp.int64)
-        matched = (word != 0) & ok_rows & in_win
-        escaped = jnp.sum(ok_rows & ~in_win, dtype=jnp.int64)
+    w0 = jnp.clip(lo, 0, jnp.maximum(domain + 1 - window, 0))
+    win = jax.lax.dynamic_slice(lut, (w0,), (window,))
+    local = pk - w0
+    in_win = (local >= 0) & (local < window)
+    word = win[jnp.clip(local, 0, window - 1)].astype(jnp.int64)
+    matched = (word != 0) & ok_rows & in_win
+    escaped = jnp.sum(ok_rows & ~in_win, dtype=jnp.int64)
     if kind == "semi":
         return probe.with_live(probe.live & matched), escaped, span
     if kind == "anti":
@@ -458,11 +418,10 @@ def dense_join_packed_windowed(probe: Batch, lut: jax.Array,
             escaped, span)
 
 
-@recorded_jit(static_argnums=(3, 4, 5, 6, 7, 8))
+@recorded_jit(static_argnums=(3, 4, 5, 6, 7))
 def dense_join_packed(probe: Batch, lut: jax.Array, los: jax.Array,
                       probe_keys: tuple, meta: tuple, bkey: int,
-                      out_dtypes: tuple, kind: str,
-                      gather_mode: str = "off") -> Batch:
+                      out_dtypes: tuple, kind: str) -> Batch:
     """Probe a value-packed LUT (see dense_build_packed_lut): one gather
     yields presence + every payload value. Sync-free, no compaction:
     the output keeps the probe's capacity with a live mask, as
@@ -471,7 +430,7 @@ def dense_join_packed(probe: Batch, lut: jax.Array, los: jax.Array,
     domain = lut.shape[0] - 1
     pk, pk_valid = _combined_key(probe, probe_keys)
     p_idx = jnp.where(pk_valid, jnp.clip(pk, 0, domain - 1), domain)
-    word = _lut_probe(lut, p_idx, gather_mode).astype(jnp.int64)
+    word = lut[p_idx].astype(jnp.int64)
     matched = (word != 0) & pk_valid & probe.live & \
         (pk >= 0) & (pk < domain)
     if kind == "semi":
@@ -648,11 +607,10 @@ def merge_probe(probe: Batch, build: Batch, probe_keys: tuple,
             jnp.sum(matched, dtype=jnp.int64))
 
 
-@recorded_jit(static_argnums=(4, 5, 6, 7))
+@recorded_jit(static_argnums=(4, 5, 6))
 def dense_join_compacted(probe: Batch, words: jax.Array, rows: jax.Array,
                          build: Batch, probe_keys: tuple,
-                         build_keys: tuple, new_capacity: int,
-                         gather_mode: str = "off") -> Batch:
+                         build_keys: tuple, new_capacity: int) -> Batch:
     """Phase 2 (selective inner join): compact the matched probe rows
     first, then gather probe AND build payload columns at the compacted
     capacity only. For a 60M-capacity probe with a few-percent match
@@ -683,25 +641,14 @@ def dense_join_compacted(probe: Batch, words: jax.Array, rows: jax.Array,
         cols.append(Column(data=c.data[order], valid=c.valid[order]))
     bkey = build_keys[0] if len(build_keys) == 1 else None
     pack_valids = len(build.columns) <= 63
-    payload = [i for i in range(len(build.columns)) if i != bkey]
     vbits = None
-    vword = None
-    gathered = None
     if pack_valids:
         vword = jnp.zeros(build.capacity, dtype=jnp.int64)
         for i, col in enumerate(build.columns):
             if i == bkey:
                 continue
             vword = vword | (col.valid.astype(jnp.int64) << i)
-        tables = [vword] + [build.columns[i].data for i in payload]
-        if gather_mode != "off" and \
-                pallas_gather.gather_supported(tables):
-            outs = pallas_gather.gather_columns(tables, src_c,
-                                                mode=gather_mode)
-            vbits = outs[0]
-            gathered = dict(zip(payload, outs[1:]))
-        else:
-            vbits = vword[src_c]
+        vbits = vword[src_c]
     for i, col in enumerate(build.columns):
         if i == bkey:
             # matched rows' build key == probe key (single-key joins)
@@ -713,8 +660,7 @@ def dense_join_compacted(probe: Batch, words: jax.Array, rows: jax.Array,
             continue
         valid = ((vbits >> i) & 1).astype(jnp.bool_) if pack_valids \
             else col.valid[src_c]
-        data = gathered[i] if gathered is not None else col.data[src_c]
-        cols.append(Column(data=data, valid=valid & live))
+        cols.append(Column(data=col.data[src_c], valid=valid & live))
     return Batch(columns=tuple(cols), live=live)
 
 

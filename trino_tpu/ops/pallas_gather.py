@@ -1,63 +1,30 @@
-"""Pallas TPU tiled-gather kernel — the dense-join probe as a native kernel.
+"""Pallas TPU scan gather: a multi-table gather from a SMALL table.
 
-XLA's gather issued ~8-15 ns per gathered element regardless of table
-size on the earlier v5e rig (not re-measured), and a probe site pays
-that once PER PAYLOAD COLUMN.  This kernel restructures the probe around
-what the hardware is actually good at — (8,128)-aligned VMEM tiles and
-per-lane `take_along_axis` (the only gather form Mosaic lowers natively)
-— and fuses the per-row index arithmetic (windowed-LUT offset, validity
-mask, miss sentinel) with a MULTI-TABLE gather so each probe index is
-decomposed once and every payload plane rides the same row/lane split.
+`out[t][i] = tables[t][idx[i]]` for `0 <= idx[i] < W`, 0 otherwise,
+bit-exact with `jnp.take`. XLA's gather pays by the index
+and the 32-bit plane whatever the table's size (PERF.md section 6); this
+kernel streams the table through VMEM in SLAB-row slabs on a second grid
+dimension, tests each (8,128) probe tile against every slab row and
+selects by a lane gather (`take_along_axis`, the only gather form Mosaic
+lowers natively), and decomposes each index ONCE for every plane. Its
+cost grows with the table (about W / 1,024 VPU operations an element),
+so it is taken only on a TPU and only for tables of at most
+SCAN_MAX_ELEMS entries: platform and table size decide, no option does.
+The one site is a dense join's payload gathers over a small pinned
+build (`ops/join._gather_build_payload`): a worker Q18's `lineitem`
+stage, 241 laps a statement (PERF.md section 6, PR 46).
 
-Two kernel modes, one contract (`out[t][i] = tables[t][idx[i]]` for
-`0 <= idx[i] < W`, `fill[t]` otherwise — bit-exact vs `jnp.take` on the
-shared domain):
-
-- **scan mode** (`gather_columns`): the table streams through VMEM in
-  SLAB-row slabs on a second grid dimension; each probe tile tests its
-  indices against every slab row and selects via a lane gather.  Per
-  element the cost is ~W/(8*128) VPU ops, so it beats the XLA gather
-  only for SMALL tables (dimension LUTs, validation words); above
-  SCAN_MAX_ELEMS the wrapper falls back to `jnp.take` automatically.
-- **windowed mode** (`gather_word_windowed`): for NEAR-SORTED probe keys
-  (the chunked driver's fact scans — l_orderkey is ascending), each
-  (8,128)-tile picks ONE WIN-sized window of the LUT via a
-  scalar-prefetched block index (PrefetchScalarGridSpec: the per-tile
-  minimum key, computed in XLA, selects the DMA'd block), then resolves
-  all 1024 indices against that window in WIN_ROWS lane-gather rounds.
-  Per element that is ~WIN/(8*128) VPU ops INDEPENDENT of table size —
-  the sub-4 ns/element regime the round-5 break-even asks for.  Indices
-  escaping their tile's window come back as misses and are COUNTED; the
-  caller must treat a nonzero escape total exactly like the windowed-LUT
-  escape flag it already owns (exec/chunked.py reruns the plain
-  program), so correctness never rests on the near-sorted guess.
-
-int64/float64 tables ride as two int32 bit-planes (Mosaic has no 64-bit
-lanes; same trick as ops/pallas_agg.py); float32 bitcasts; narrow ints
-and bools widen to one int32 plane.  Everything reassembles bit-exactly.
-
-Reference role: Trino's compiled probe specialization — runtime bytecode
-generation fusing the hash lookup with per-channel page building
-(sql/gen/JoinProbeCompiler, PageJoiner.java:138) — re-expressed as a
-hand-written TPU kernel, per the co-processing literature's finding that
-probe-side gather/materialization is where accelerator joins win or
-lose (PAPERS.md: Revisiting Co-Processing for Hash Joins; Global Hash
-Tables Strike Back!).
-
-Session wiring: `enable_pallas_gather` = auto (on for TPU backends) |
-true (TPU: compiled; CPU: interpret mode — tier-1 runs the kernel logic
-through the Pallas interpreter) | false.  Every call site keeps the
-`jnp.take` path and falls back to it whenever the mode is off or the
-shape is outside the kernel's win region.
+int64 tables ride as two int32 bit-planes (Mosaic has no 64-bit lanes);
+float32 bitcasts; narrow ints and bools widen to one int32 plane.
+float64 takes the `jnp.take` path (the TPU compiler refuses its split).
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -65,38 +32,16 @@ SUB = 8                     # sublanes per probe tile
 LANES = 128                 # lanes per probe tile
 _LANE_BITS = 7              # row/lane splits are shifts and masks in-kernel
 TILE = SUB * LANES          # probe indices resolved per grid step
-SLAB_ROWS = 16              # scan mode: LUT rows (of LANES) per slab
+SLAB_ROWS = 16              # table rows (of LANES) per slab
 SLAB = SLAB_ROWS * LANES
-WIN_ROWS = 64               # windowed mode: rows per per-tile window
-WIN = WIN_ROWS * LANES      # 8192 LUT entries per tile window
 MAX_PLANES = 12             # int32 planes per pallas_call (VMEM budget)
-# scan mode's per-element cost is ~W/(SUB*LANES) VPU ops; beyond this the
-# XLA gather's flat ~8-15 ns/element wins (v5e break-even measurement)
+# beyond this the XLA gather's flat cost an index wins
 SCAN_MAX_ELEMS = 1 << 16
-# windowed indices are 32-bit in-kernel
-MAX_WINDOWED_ELEMS = (1 << 31) - 1
-
-
-def resolve_mode(setting) -> str:
-    """Session-property value -> kernel mode: 'device' (compiled TPU
-    kernel), 'interpret' (Pallas interpreter — the CPU/tier-1 path), or
-    'off' (every site uses its jnp.take fallback)."""
-    s = str(setting).lower()
-    on_tpu = jax.default_backend() == "tpu"
-    if s in ("true", "1"):
-        return "device" if on_tpu else "interpret"
-    if s == "auto":
-        return "device" if on_tpu else "off"
-    return "off"
 
 
 # --------------------------------------------------------------------------
 # int32 plane split / reassembly (bit-exact for every engine lane dtype)
 # --------------------------------------------------------------------------
-
-def plane_count(dtype) -> int:
-    return 2 if jnp.dtype(dtype).itemsize == 8 else 1
-
 
 def supports_tables(tables) -> bool:
     """Can every table ride int32 planes? Integer, bool and float32
@@ -137,33 +82,18 @@ def _join_planes(planes: Sequence[jax.Array], dtype) -> jax.Array:
     return planes[0].astype(dt)
 
 
-def _fill_planes(fill, dtype) -> Tuple[int, ...]:
-    """Static per-plane int32 fill words for a table-dtype fill value."""
-    arr = np.zeros(1, dtype=jnp.dtype(dtype).name)
-    arr[0] = fill
-    if arr.dtype.itemsize == 8:
-        lo, hi = arr.view(np.int32)
-        return (int(lo), int(hi))
-    if arr.dtype == np.float32:
-        return (int(arr.view(np.int32)[0]),)
-    # narrow ints extend like the _split_planes astype, then wrap to the
-    # int32 two's-complement range
-    v = int(arr.astype(np.int64)[0])
-    return (((v + (1 << 31)) % (1 << 32)) - (1 << 31),)
-
-
 # --------------------------------------------------------------------------
-# scan-mode kernel: LUT slabs stream on grid dim 1, output revisited
+# the kernel: table slabs stream on grid dim 1, output revisited
 # --------------------------------------------------------------------------
 
-def _scan_kernel(n_planes: int, fills: tuple):
+def _scan_kernel(n_planes: int):
     def kernel(idx_ref, planes_ref, out_ref):
         s = pl.program_id(1)
         local = idx_ref[...]                             # [SUB, LANES]
         row = jnp.where(local >= 0, local >> _LANE_BITS, -1)
         lane = jnp.where(local >= 0, local & (LANES - 1), 0)
-        accs = [jnp.where(s == 0,
-                          jnp.full((SUB, LANES), fills[p], jnp.int32),
+        # an index no slab row answers (a miss, -1) reads 0
+        accs = [jnp.where(s == 0, jnp.zeros((SUB, LANES), jnp.int32),
                           out_ref[p]) for p in range(n_planes)]
         base = s * SLAB_ROWS
         for r in range(SLAB_ROWS):
@@ -180,7 +110,7 @@ def _scan_kernel(n_planes: int, fills: tuple):
 
 
 def _scan_gather_planes(idx32: jax.Array, planes: jax.Array,
-                        fills: tuple, interpret: bool) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """idx32 [n_pad] int32 (pad/miss = -1), planes [P, W_pad] int32 ->
     gathered [P, n_pad] int32."""
     P, W = planes.shape
@@ -190,7 +120,7 @@ def _scan_gather_planes(idx32: jax.Array, planes: jax.Array,
     # enables jax_enable_x64 and Mosaic has no 64-bit lanes
     with jax.enable_x64(False):
         out = pl.pallas_call(
-            _scan_kernel(P, fills),
+            _scan_kernel(P),
             grid=(nb, n_slabs),
             in_specs=[
                 pl.BlockSpec((SUB, LANES), lambda i, s: (i, 0),
@@ -206,91 +136,6 @@ def _scan_gather_planes(idx32: jax.Array, planes: jax.Array,
           planes.reshape(P, W // LANES, LANES))
     return out.reshape(P, n)
 
-
-# --------------------------------------------------------------------------
-# windowed-mode kernel: per-tile window block via scalar prefetch
-# --------------------------------------------------------------------------
-
-def _window_kernel(n_planes: int, fills: tuple):
-    """Each tile resolves against TWO adjacent WIN blocks (its minimum
-    index's aligned window plus the next), so alignment never causes an
-    escape — only a tile whose true key span exceeds WIN does."""
-    def kernel(base_ref, idx_ref, lo_win_ref, hi_win_ref, out_ref,
-               esc_ref):
-        i = pl.program_id(0)
-        local = idx_ref[...]
-        base = base_ref[i] * WIN               # lo window element offset
-        rel = jnp.where(local >= 0, local - base, -1)
-        in_win = (rel >= 0) & (rel < 2 * WIN)
-        row = jnp.where(in_win, rel >> _LANE_BITS, -1)
-        lane = jnp.where(in_win, rel & (LANES - 1), 0)
-
-        @pl.when(i == 0)
-        def _():
-            esc_ref[...] = jnp.zeros((SUB, LANES), jnp.int32)
-
-        esc_ref[...] += ((local >= 0) & ~in_win).astype(jnp.int32)
-        accs = [jnp.full((SUB, LANES), fills[p], jnp.int32)
-                for p in range(n_planes)]
-        for r in range(2 * WIN_ROWS):
-            hit = row == r
-            win_ref = lo_win_ref if r < WIN_ROWS else hi_win_ref
-            for p in range(n_planes):
-                src = win_ref[p, r % WIN_ROWS, :]
-                g = jnp.take_along_axis(
-                    jnp.broadcast_to(src[None, :], (SUB, LANES)), lane,
-                    axis=1)
-                accs[p] = jnp.where(hit, g, accs[p])
-        for p in range(n_planes):
-            out_ref[p] = accs[p]
-    return kernel
-
-
-def _window_gather_planes(idx32: jax.Array, base_blocks: jax.Array,
-                          planes: jax.Array, fills: tuple,
-                          interpret: bool):
-    """idx32 [n_pad] int32 (miss = -1), base_blocks [nb] int32 (per-tile
-    WIN-block index, <= n_blocks - 2), planes [P, W_pad] int32 ->
-    ([P, n_pad] int32, total escape count int32)."""
-    P, W = planes.shape
-    n = idx32.shape[0]
-    nb = n // TILE
-    reshaped = planes.reshape(P, W // LANES, LANES)
-    # 64-bit off for the kernel body and the index maps (see the scan
-    # kernel). Escapes accumulate per lane position in ONE (8, 128) VMEM
-    # tile every grid step revisits, summed in XLA afterwards: per-tile
-    # (1, 1) SMEM blocks of an (nb, 1) array are refused by the TPU
-    # lowering (neither (8, 128)-divisible nor the whole array), and an
-    # in-kernel reduction to a scalar is re-traced at lowering time with
-    # the package's 64-bit mode back on ("64-bit types are not
-    # supported").
-    with jax.enable_x64(False):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb,),
-            in_specs=[
-                pl.BlockSpec((SUB, LANES), lambda i, base: (i, 0)),
-                pl.BlockSpec((P, WIN_ROWS, LANES),
-                             lambda i, base: (0, base[i], 0)),
-                pl.BlockSpec((P, WIN_ROWS, LANES),
-                             lambda i, base: (0, base[i] + 1, 0))],
-            out_specs=[
-                pl.BlockSpec((P, SUB, LANES), lambda i, base: (0, i, 0)),
-                pl.BlockSpec((SUB, LANES), lambda i, base: (0, 0))])
-        out, esc = pl.pallas_call(
-            _window_kernel(P, fills),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((P, nb * SUB, LANES), jnp.int32),
-                jax.ShapeDtypeStruct((SUB, LANES), jnp.int32)],
-            interpret=interpret,
-        )(base_blocks, idx32.reshape(nb * SUB, LANES), reshaped, reshaped)
-    return out.reshape(P, n), jnp.sum(esc, dtype=jnp.int32)
-
-
-# --------------------------------------------------------------------------
-# public wrappers (usable inside surrounding jits; all shapes static)
-# --------------------------------------------------------------------------
 
 def _sanitize_idx(idx: jax.Array, limit: int) -> jax.Array:
     """Clamp to the fill contract: anything outside [0, limit) becomes
@@ -308,63 +153,42 @@ def _pad_to(x: jax.Array, mult: int, value):
     return jnp.pad(x, width, constant_values=value)
 
 
-def gather_supported(tables, n_rows: Optional[int] = None,
-                     max_elems: int = SCAN_MAX_ELEMS) -> bool:
-    """Shape gate shared by every call site's auto-fallback."""
+def gather_supported(tables, interpret: bool = False) -> bool:
+    """Whether the kernel takes these tables: on a TPU (or in the
+    interpreter, which is how a test runs the kernel's logic), lanes that
+    ride int32 planes, one length, at most SCAN_MAX_ELEMS entries."""
     if not tables or not supports_tables(tables):
         return False
-    w = tables[0].shape[0]
-    if any(t.shape[0] != w for t in tables) or w > max_elems:
+    if not interpret and jax.default_backend() != "tpu":
         return False
-    return True
-
-
-def _xla_gather(tables, idx, fills):
-    """The fallback (and the parity reference): clip-free take with the
-    same miss-fill contract as the kernels."""
     w = tables[0].shape[0]
-    ok = (idx >= 0) & (idx < w)
-    idx_c = jnp.clip(idx, 0, w - 1)
-    return [jnp.where(ok, jnp.take(t, idx_c, axis=0),
-                      jnp.asarray(f, dtype=t.dtype))
-            for t, f in zip(tables, fills)]
+    return all(t.shape[0] == w for t in tables) and w <= SCAN_MAX_ELEMS
 
 
-def gather_columns(tables, idx, fills=None, *, mode: str = "off"):
+def gather_columns(tables, idx, *, interpret: bool = False):
     """Fused multi-table gather: out[t][i] = tables[t][idx[i]] when
-    0 <= idx[i] < W, else fills[t].  Bit-exact vs the jnp.take path;
-    falls back to it when mode is 'off' or the shape gate fails.
-    `mode` and all shapes must be static (call under jit is fine).
-
-    This is also the SHARD-LOCAL entry point: inside a shard_map body
-    (the mesh-partitioned join's per-chip probe) every shape it sees is
-    the per-shard local shape, so the kernel gathers against the 1/N
-    table slice resident on its own chip — no cross-chip traffic."""
+    0 <= idx[i] < W, else 0. Bit-exact with jnp.take on the shared
+    domain. The caller asks
+    gather_supported first and keeps its plain gathers for the rest. All
+    shapes must be static (a call under jit is fine)."""
     tables = list(tables)
-    if fills is None:
-        fills = [0] * len(tables)
-    if mode == "off" or not gather_supported(tables):
-        return _xla_gather(tables, idx, fills)
-    interpret = mode == "interpret"
+    assert gather_supported(tables, interpret)
     w = tables[0].shape[0]
     n = idx.shape[0]
     idx32 = _pad_to(_sanitize_idx(idx, w), TILE, -1)
 
     # split every table into int32 planes, group into VMEM-sized calls
     plane_list: List[jax.Array] = []
-    plane_fills: List[int] = []
     spans: List[Tuple[int, int, object]] = []   # (start, count, dtype)
-    for t, f in zip(tables, fills):
+    for t in tables:
         ps = _split_planes(t)
         spans.append((len(plane_list), len(ps), t.dtype))
         plane_list.extend(_pad_to(p, SLAB, 0) for p in ps)
-        plane_fills.extend(_fill_planes(f, t.dtype))
 
     gathered: List[jax.Array] = []
     for g0 in range(0, len(plane_list), MAX_PLANES):
         group = plane_list[g0:g0 + MAX_PLANES]
-        gf = tuple(plane_fills[g0:g0 + MAX_PLANES])
-        out = _scan_gather_planes(idx32, jnp.stack(group), gf, interpret)
+        out = _scan_gather_planes(idx32, jnp.stack(group), interpret)
         gathered.extend(out[p] for p in range(len(group)))
 
     results = []
@@ -372,66 +196,3 @@ def gather_columns(tables, idx, fills=None, *, mode: str = "off"):
         results.append(_join_planes(gathered[start:start + count],
                                     dtype)[:n])
     return results
-
-
-def window_base_blocks(idx32: jax.Array, n_blocks: int) -> jax.Array:
-    """Per-(8,128)-tile window choice: the tile's minimum in-range index
-    rounded down to a WIN block (computed in XLA, prefetched as scalars
-    so the BlockSpec index_map can steer the window DMA).  Clipped to
-    n_blocks - 2 because the kernel fetches base and base + 1."""
-    nb = idx32.shape[0] // TILE
-    tiles = idx32.reshape(nb, TILE)
-    sentinel = jnp.int32(2147483647)
-    lo = jnp.min(jnp.where(tiles >= 0, tiles, sentinel), axis=1)
-    return jnp.clip(lo // WIN, 0, max(n_blocks - 2, 0)).astype(jnp.int32)
-
-
-def prepare_word_planes(lut: jax.Array) -> jax.Array:
-    """One-time prep of a value-packed LUT for gather_word_windowed:
-    int32 planes, padded to whole windows (at least two — the kernel
-    always fetches a pair).  The chunked driver calls this ONCE per
-    pinned LUT so the per-chunk program only streams the windows it
-    touches (re-splitting per chunk would re-read the whole domain-sized
-    table every chunk)."""
-    planes = [_pad_to(p, WIN, 0) for p in _split_planes(lut)]
-    if planes[0].shape[0] < 2 * WIN:
-        planes = [_pad_to(p, 2 * WIN, 0) for p in planes]
-    return jnp.stack(planes)
-
-
-def gather_word_windowed(planes: jax.Array, idx, word_dtype: str,
-                         *, mode: str):
-    """Windowed single-word gather off prepared planes (see
-    prepare_word_planes): returns (words int64, escaped int64) where
-    escaped counts in-range indices that fell outside their tile's
-    window — those rows come back as 0 (the packed-LUT miss word) and
-    the CALLER MUST rerun via its escape machinery when escaped > 0.
-    `word_dtype` is the original LUT dtype (static)."""
-    P, W = planes.shape
-    n = idx.shape[0]
-    idx32 = _pad_to(_sanitize_idx(idx, W), TILE, -1)
-    base = window_base_blocks(idx32, W // WIN)
-    fills = _fill_planes(0, word_dtype)
-    out, esc = _window_gather_planes(idx32, base, planes, fills,
-                                     mode == "interpret")
-    word = _join_planes([out[p] for p in range(P)],
-                        word_dtype)[:n].astype(jnp.int64)
-    return word, esc.astype(jnp.int64)
-
-
-# --------------------------------------------------------------------------
-# pre-jitted, compile-recorded entry points. Inside an executor kernel the
-# ENCLOSING jit owns the compile (the recorder stays silent under an open
-# trace), so these exist for the eager boundary: the gather microbench and
-# any ad-hoc top-level kernel use route their XLA compiles through the
-# central recorder (exec/profiler.py) like every other jit site.
-# --------------------------------------------------------------------------
-
-from ..exec.profiler import instrument as _instrument  # noqa: E402
-
-gather_columns_jit = _instrument(
-    jax.jit(gather_columns, static_argnames=("fills", "mode")),
-    site="pallas_gather.gather_columns")
-gather_word_windowed_jit = _instrument(
-    jax.jit(gather_word_windowed, static_argnames=("word_dtype", "mode")),
-    site="pallas_gather.gather_word_windowed")
