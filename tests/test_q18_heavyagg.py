@@ -259,7 +259,9 @@ def test_a_worker_task_opens_operator_spans_beside_its_splits(
         assert isinstance(said.pop("split"), int)
         if sp["name"] == "join":
             # every lap says which LUT it probed, a refusal also why
+            # and that the LUT's miss stood in for the dynamic filter
             form, bits = said.pop("lutForm"), said.pop("wordBits")
+            assert said.pop("dynamicFilter") == "lut"
             assert bits in ((8, 16, 32, 64) if form == "packed" else (32,))
             assert (form == "rows") == ("packRefused" in said)
             said.pop("packRefused", None)

@@ -584,11 +584,25 @@ def _split_operators(spans):
     return ops, laps
 
 
+# what a split's `join` span says with the query that makes it say so:
+# the task's LUT answers an inner join over a unique build and no dynamic
+# filter runs in front of it (PR 50); a semi join keeps the range test
+# (the queries are defined further down: looked up when a test runs)
+JOIN_LAPS = {
+    "lut": (lambda day: orders_join(day),
+            {"lutForm": "packed", "wordBits": 16, "dynamicFilter": "lut"}),
+    "range": (lambda day: orders_exist(day), {"dynamicFilter": "range"}),
+}
+
+
+@pytest.mark.parametrize("filtered", sorted(JOIN_LAPS))
 @pytest.mark.parametrize("profiling", [False, True],
                          ids=["tracing-alone", "fenced"])
-def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling):
+def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling,
+                                                   filtered):
     coord, worker, session = cluster
-    sql = orders_join("1996-02-1" + str(int(profiling)))
+    query, join_says = JOIN_LAPS[filtered]
+    sql = query("1996-02-1" + str(int(profiling)))
     spans, _, _ = _traced(coord, sql, profiling=profiling)
     ids = {s["spanId"]: s for s in spans}
     ops, laps = _split_operators(spans)
@@ -598,25 +612,28 @@ def test_a_traced_tasks_splits_have_operator_spans(cluster, profiling):
     for key, lap in laps.items():
         # every split of every task, the probing task's with its join
         names = [s["name"] for s in sorted(ops[key], key=_interval)]
-        assert names == (["join", "filter-project", "aggregate"]
-                         if key in mine else ["filter-project"]), \
-            (key, names)
+        if key in mine:
+            assert names == ["join", "filter-project", "aggregate"], key
+        else:
+            assert set(names) == {"filter-project"}, (key, names)
         for s in ops[key]:
             # under the task, beside the lap, inside it on the clock,
-            # and saying which split and, a join, the form of its LUT:
-            # the one-column payload (five priorities) rides in the
-            # LUT's word, 1 + 8 + 1 bits in an int16
+            # and saying which split and, a join, what stood in front
+            # of it and the form of the LUT it probed: the one-column
+            # payload (five priorities) rides in the LUT's word, 1 + 8
+            # + 1 bits in an int16
             assert ids[s["parentSpanId"]]["name"] == "worker-task"
             assert s["attributes"] == dict(
-                {"split": key[1]}, **({"lutForm": "packed", "wordBits": 16}
-                                      if s["name"] == "join" else {}))
+                {"split": key[1]},
+                **(join_says if s["name"] == "join" else {}))
             assert _inside(s, lap), (s, lap)
         own = sorted(_interval(s) for s in ops[key])
         for (_, end), (start, _) in zip(own, own[1:]):
             assert start >= end - ROUNDING_NS      # each its own wall
-    # the eager ops on the build's key range: inside their join
+    # the eager ops on the build's key range, inside their join: in no
+    # split whose join the task's LUT answers
     filters = [s for s in spans if s["name"] == "dynamic-filter"]
-    assert len(filters) == 8
+    assert len(filters) == (8 if filtered == "range" else 0)
     for s in filters:
         join = ids[s["parentSpanId"]]
         assert join["name"] == "join" and _inside(s, join)
@@ -722,7 +739,7 @@ def test_tracing_off_a_task_builds_no_operator_span(cluster, monkeypatch):
     ex.operator_span("join")
     assert ex._open_operators == [] and built == []
     # and on: each of a task's operator spans has its `tt:` twin
-    spans, _, _ = _traced(coord, orders_join("1996-04-08"))
+    spans, _, _ = _traced(coord, orders_exist("1996-04-08"))
     for name in ("join", "aggregate", "filter-project", "dynamic-filter"):
         n = sum(s["name"] == name for s in spans)
         assert n >= 8 and CountingAnnotation.names.count("tt:" + name) == n
@@ -748,6 +765,17 @@ def orders_join(before: str) -> str:
             "FROM lineitem JOIN orders ON l_orderkey = o_orderkey "
             f"WHERE o_orderdate < DATE '{before}' "
             "GROUP BY o_orderpriority ORDER BY o_orderpriority")
+
+
+def orders_exist(before: str) -> str:
+    # the same build as a semi join's: the split's join is no LUT join,
+    # and the dynamic filter's range test runs in front of it
+    return ("SELECT l_returnflag, count(*) AS n, "
+            "sum(l_extendedprice) AS revenue FROM lineitem l "
+            "WHERE EXISTS (SELECT 1 FROM orders o "
+            "WHERE o.o_orderkey = l.l_orderkey "
+            f"AND o.o_orderdate < DATE '{before}') "
+            "GROUP BY l_returnflag ORDER BY l_returnflag")
 
 
 def _join_task(spans):

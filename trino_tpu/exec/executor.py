@@ -60,6 +60,9 @@ class ExecStats:
     packed_lut_joins: int = 0        # those of them whose LUT's word
                                      # carries the build's payload (one
                                      # gather a probe)
+    lut_filtered_joins: int = 0      # those of them entered with no
+                                     # dynamic filter in front: the
+                                     # LUT's miss is the range test
     value_puts: int = 0              # ValuesNodes put on the device
                                      # (run_values calls)
     literal_slots: int = 0           # literals and lookup tables bound as
@@ -1569,13 +1572,24 @@ class Executor:
 
     def _run_join_inner(self, node: L.JoinNode, probe: Batch,
                         build: Batch) -> Batch:
+        domain = node.build_key_domain
+        if self.chunk_mode and node.kind == "inner" and \
+                node.build_unique and domain is not None:
+            # a split's join over the task's pinned build: a probe key
+            # outside the build's key range has no LUT entry, so the
+            # LUT's miss IS the dynamic filter's range test (and chunk
+            # mode compacts on neither): no eager op runs in front
+            out = self._chunk_lut_join(node, probe, build, domain)
+            if out is not None:
+                self.stats.lut_filtered_joins += 1
+                self.stamp_operator(in_splits=True, dynamicFilter="lut")
+                return out
         probe = self.apply_dynamic_filter(node, probe, build)
         if node.kind == "mark":
             return self.run_mark_join(node, probe, build)
         if node.kind in ("semi", "anti"):
             return self.run_membership_join(node, probe, build)
         probe = self.maybe_compact(probe, node=node)
-        domain = node.build_key_domain
         if node.build_unique:
             out = self.try_unique_join(node, probe, build, domain)
             if out is not None:
@@ -1620,9 +1634,11 @@ class Executor:
         # /gather path carries the join: it compiles in seconds at any
         # size (9.4s at 60M measured) and runs at gather speed.
         # chunk mode: build+validate the dense LUT once per pinned build,
-        # then probe every chunk sync-free (see _chunk_lut_join)
+        # then probe every chunk sync-free (see _chunk_lut_join; an inner
+        # join was there first, _run_join_inner, and is here because its
+        # build failed validation)
         if self.chunk_mode and domain is not None and \
-                node.kind in ("inner", "left"):
+                node.kind == "left":
             out = self._chunk_lut_join(node, probe, build, domain)
             if out is not None:
                 return out
@@ -1842,6 +1858,7 @@ class Executor:
             return self._dynamic_filter(node, probe, build)
         finally:
             self._close_operators(depth)
+            self.stamp_operator(in_splits=True, dynamicFilter="range")
 
     def _dynamic_filter(self, node: L.JoinNode, probe: Batch,
                         build: Batch) -> Batch:
